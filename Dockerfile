@@ -23,6 +23,6 @@ RUN pip install --no-cache-dir .
 # XLA persistent compile cache lives on the cache volume
 # (docker-compose.yml mounts ${ROOT_CACHE_PATH} -> /root/.cache, the same
 # contract as the reference's compiled-model volume, docker-compose.yml:24-25).
-ENV VDT_COMPILE_CACHE_DIR=/root/.cache/vdt-xla
+ENV JAX_COMPILATION_CACHE_DIR=/root/.cache/vdt-xla
 
 ENTRYPOINT ["python3", "-m", "vllm_distributed_tpu"]

@@ -11,12 +11,13 @@ from tests.utils import make_tiny_llama
 from vllm_distributed_tpu.config import EngineArgs
 from vllm_distributed_tpu.engine.llm_engine import LLMEngine
 from vllm_distributed_tpu.sampling_params import SamplingParams
+from vllm_distributed_tpu.worker.aot_cache import package_source_digest
 
 PROMPTS = [[1, 5, 9, 23, 77, 41, 3], [7, 2, 88, 14]]
 
 
 def _greedy(model_dir, cache_dir):
-    env = {"VDT_AOT_CACHE": "1", "VDT_COMPILE_CACHE_DIR": cache_dir}
+    env = {"VDT_AOT_CACHE": "1", "JAX_COMPILATION_CACHE_DIR": cache_dir}
     with mock.patch.dict(os.environ, env):
         engine = LLMEngine.from_engine_args(
             EngineArgs(
@@ -31,6 +32,8 @@ def _greedy(model_dir, cache_dir):
         )
         runner = engine.executor.worker.runner
         assert runner._aot.enabled
+        # The artifact key carries the package's source digest.
+        assert runner._aot.context.split("|")[0] == package_source_digest()
         for i, p in enumerate(PROMPTS):
             engine.add_request(
                 f"r{i}",

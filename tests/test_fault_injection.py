@@ -114,7 +114,7 @@ def _fault_env(monkeypatch, tmp_path, port):
     )
     monkeypatch.setenv("VDT_HEARTBEAT_INTERVAL_SECONDS", str(HB_INTERVAL))
     monkeypatch.setenv("VDT_HEARTBEAT_MISS_THRESHOLD", str(HB_THRESHOLD))
-    monkeypatch.setenv("VDT_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
     # These tests assert the TERMINAL death contract (drain + reject);
     # disable the in-process supervisor so a HostFailure stays fatal.
     # The recovery suite below re-enables it with its own knobs.

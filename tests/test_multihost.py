@@ -41,7 +41,7 @@ def deployment(tmp_path, monkeypatch):
     port = get_open_port()
     monkeypatch.setenv("VDT_SERVER_PORT", str(port))
     monkeypatch.setenv("VDT_EXECUTE_MODEL_TIMEOUT_SECONDS", "20")
-    monkeypatch.setenv("VDT_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
     # Pin the advertisement so mocked boots skip the jax chip probe.
     agent = _spawn_agent(
         port,
@@ -163,7 +163,7 @@ def _engine_run(tmp_path, monkeypatch, *, streams: str,
     monkeypatch.setenv("VDT_MOCK_TOKEN_SEQ", seq)
     monkeypatch.setenv("VDT_MOCK_STEP_SECONDS", "0.01")
     monkeypatch.setenv("VDT_EXECUTE_MODEL_TIMEOUT_SECONDS", "30")
-    monkeypatch.setenv("VDT_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
     agent = _spawn_agent(
         port,
         {

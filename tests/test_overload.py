@@ -807,7 +807,7 @@ def _deployment_env(monkeypatch, tmp_path, port):
     monkeypatch.setenv("VDT_HEARTBEAT_INTERVAL_SECONDS", "0.5")
     monkeypatch.setenv("VDT_HEARTBEAT_MISS_THRESHOLD", "3")
     monkeypatch.setenv("VDT_EXECUTE_MODEL_TIMEOUT_SECONDS", "10")
-    monkeypatch.setenv("VDT_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
     monkeypatch.setenv("VDT_MOCK_TOKEN_SEQ", "1")
     monkeypatch.setenv("VDT_MOCK_EXECUTE_SLEEP_SECONDS", "0.05")
 

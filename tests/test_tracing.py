@@ -516,7 +516,7 @@ def traced_app(tmp_path, monkeypatch):
     port = get_open_port()
     monkeypatch.setenv("VDT_SERVER_PORT", str(port))
     monkeypatch.setenv("VDT_TRACING", "1")
-    monkeypatch.setenv("VDT_COMPILE_CACHE_DIR", str(tmp_path / "cc"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
     get_tracer().reset()
     agent = multiprocessing.Process(
         target=_agent_with_env,

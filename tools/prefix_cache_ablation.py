@@ -24,8 +24,8 @@ forced on vs forced off) — the empirical basis for setting
 
 Invocation (CPU, synthetic weights — no checkpoint needed):
 
-    JAX_PLATFORMS=cpu python tools/prefix_cache_ablation.py
-    JAX_PLATFORMS=cpu python tools/prefix_cache_ablation.py --tiered
+    JAX_PLATFORMS=cpu VDT_USE_PALLAS=reference python tools/prefix_cache_ablation.py
+    JAX_PLATFORMS=cpu VDT_USE_PALLAS=reference python tools/prefix_cache_ablation.py --tiered
 
 or against a real model / the TPU:
 

@@ -17,7 +17,7 @@ and this tool prints both sides of the bet.
 
 Invocation (CPU, synthetic weights — no checkpoint needed):
 
-    JAX_PLATFORMS=cpu python tools/spec_decode_ablation.py
+    JAX_PLATFORMS=cpu VDT_USE_PALLAS=reference python tools/spec_decode_ablation.py
 
 or against a real model / the TPU:
 

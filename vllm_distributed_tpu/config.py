@@ -388,16 +388,9 @@ class SchedulerConfig:
 
 @dataclass
 class DeviceConfig:
-    # "auto" picks tpu if available else cpu.
+    # Recorded, not acted on: the platform is whatever JAX finds
+    # (JAX_PLATFORMS), and the kernel backend is VDT_USE_PALLAS.
     device: str = "auto"
-
-    def resolved(self) -> str:
-        if self.device != "auto":
-            return self.device
-        import jax
-
-        platform = jax.default_backend()
-        return "cpu" if platform == "cpu" else "tpu"
 
 
 @dataclass

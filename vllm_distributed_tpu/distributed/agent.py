@@ -236,6 +236,10 @@ async def agent_async_main(server_ip: str, port: int | None = None) -> None:
 
     info_cache: dict[str, Any] = {}
 
+    def _probe_failed(error: str) -> dict:
+        logger.error("host_info: %s", error)
+        return {"num_chips": 0, "platform": "unknown", "error": error}
+
     async def host_info() -> dict:
         """Chips this host offers.  Probed in a SUBPROCESS: initializing
         jax here would pin the agent's backend before the worker's
@@ -252,15 +256,15 @@ async def agent_async_main(server_ip: str, port: int | None = None) -> None:
                 "import jax; print(jax.local_device_count(), "
                 "jax.default_backend())",
                 stdout=asyncio.subprocess.PIPE,
-                stderr=asyncio.subprocess.DEVNULL,
+                stderr=asyncio.subprocess.PIPE,
             )
             try:
                 # Deadline on the probe: a wedged TPU runtime must not
                 # hang the agent forever.  Must stay under the driver's
                 # 60s host_info budget (multihost._handle_agent) so the
-                # 0-chip fallback reply reaches the driver before its
-                # wait_for fires and it drops the connection.
-                out, _ = await asyncio.wait_for(proc.communicate(), 45)
+                # failure reply reaches the driver before its wait_for
+                # fires and it drops the connection.
+                out, err = await asyncio.wait_for(proc.communicate(), 45)
             except asyncio.TimeoutError:
                 proc.kill()
                 # vdt-lint: disable=unbounded-wait — just SIGKILL'd:
@@ -269,14 +273,22 @@ async def agent_async_main(server_ip: str, port: int | None = None) -> None:
                 # Deliberately NOT cached: a transient wedge (cold TPU
                 # runtime) must not mis-advertise this host for the
                 # agent's lifetime — the next host_info call re-probes.
-                return {"num_chips": 0, "platform": "unknown"}
+                return _probe_failed("chip probe timed out after 45s")
             try:
                 chips, platform = out.decode().split()[-2:]
                 info_cache.update(
                     num_chips=int(chips), platform=platform
                 )
             except (ValueError, IndexError):
-                info_cache.update(num_chips=0, platform="unknown")
+                # The child has exited by here (communicate returned), so
+                # it holds no chip when create_worker runs.  Its stderr
+                # is the only account of why JAX found no device: it
+                # goes to this log and back to the driver.
+                return _probe_failed(
+                    f"chip probe exited {proc.returncode}: "
+                    + (err.decode(errors="replace").strip()[-2000:]
+                       or "no output")
+                )
         return {
             "num_chips": int(env_chips or info_cache["num_chips"]),
             "platform": env_platform or info_cache["platform"],

@@ -131,6 +131,8 @@ class LLMEngine:
         # between scrapes — the recompile-storm case).
         self._telemetry_seq = 0
         self._telemetry_compiles_seen: dict[str, int] = {}
+        self._telemetry_fallbacks_seen: dict[str, int] = {}
+        self._telemetry_cache_seen: dict[str, int] = {}
 
         # Disaggregated prefill/decode hand-off (ISSUE 15): export holds
         # + inbound transfers.  Always constructed (cheap, idle costs
@@ -450,11 +452,30 @@ class LLMEngine:
             if seq > self._telemetry_seq:
                 self._telemetry_seq = seq
                 self.metrics.record_xla_compile_seconds(float(seconds))
-        for kind, total in (snap.get("compiles") or {}).items():
-            seen = self._telemetry_compiles_seen.get(kind, 0)
-            if total > seen:
-                self.metrics.record_xla_compiles(str(kind), total - seen)
-                self._telemetry_compiles_seen[kind] = total
+        # Cumulative worker totals -> counter increments, each counted
+        # exactly once.
+        for field, seen_by, record in (
+            (
+                "compiles",
+                self._telemetry_compiles_seen,
+                self.metrics.record_xla_compiles,
+            ),
+            (
+                "fallbacks",
+                self._telemetry_fallbacks_seen,
+                self.metrics.record_worker_fallbacks,
+            ),
+            (
+                "persistent_cache",
+                self._telemetry_cache_seen,
+                self.metrics.record_persistent_cache_events,
+            ),
+        ):
+            for kind, total in (snap.get(field) or {}).items():
+                seen = seen_by.get(kind, 0)
+                if total > seen:
+                    record(str(kind), total - seen)
+                    seen_by[kind] = total
         self.metrics.record_device_snapshot(snap)
         return snap
 

@@ -608,13 +608,11 @@ environment_variables: dict[str, Callable[[], Any]] = {
     ),
     # --- engine ---
     "VDT_LOG_LEVEL": lambda: os.environ.get("VDT_LOG_LEVEL", "INFO"),
-    "VDT_COMPILE_CACHE_DIR": lambda: os.environ.get(
-        "VDT_COMPILE_CACHE_DIR", os.path.expanduser("~/.cache/vdt/jax_cache")
-    ),
     # Persistent jax.export artifact cache for warm restarts (skips
     # trace+lower, not just XLA compile): "auto" = on for TPU,
-    # "1" = always, "0" = off.  Artifacts live under
-    # $VDT_COMPILE_CACHE_DIR/aot.
+    # "1" = always, "0" = off.  Artifacts live in aot/ under the
+    # compile cache (compile_cache.py: $JAX_COMPILATION_CACHE_DIR, else
+    # <checkout>/.jax_cache).
     "VDT_AOT_CACHE": lambda: os.environ.get("VDT_AOT_CACHE", "auto"),
     "VDT_HBM_UTILIZATION": lambda: float(
         os.environ.get("VDT_HBM_UTILIZATION", "0.9")
@@ -622,8 +620,9 @@ environment_variables: dict[str, Callable[[], Any]] = {
     "VDT_HTTP_TIMEOUT_KEEP_ALIVE": lambda: int(
         os.environ.get("VDT_HTTP_TIMEOUT_KEEP_ALIVE", "5")
     ),
-    # force the jax platform (cpu for tests, tpu in prod)
-    "VDT_PLATFORM": lambda: os.environ.get("VDT_PLATFORM", ""),
+    # Kernel backend (ops.resolve_kernel_backend): "auto" = compiled
+    # Pallas and an error off-TPU; "pallas" | "pallas_interpret" |
+    # "reference" by name.
     "VDT_USE_PALLAS": lambda: os.environ.get("VDT_USE_PALLAS", "auto"),
     # MoE expert dispatch: "auto" picks per call site — dense-fused for
     # bandwidth-bound shapes (decode, or quantized experts whose

@@ -315,11 +315,14 @@ class MultiHostExecutor(Executor):
             ):
                 logger.warning(
                     "agent %s offers %d chip(s) on platform %r; deployment "
-                    "needs %d per host — skipping this host",
+                    "needs %d per host — skipping this host%s",
                     addr,
                     info.get("num_chips", 0),
                     info.get("platform"),
                     required,
+                    f" (agent reports: {info['error']})"
+                    if info.get("error")
+                    else "",
                 )
                 writer.close()
                 return await self._await_readloop(readloop_task)

@@ -150,6 +150,8 @@ DOCUMENTED_METRICS = (
     "vllm:xla_compile_seconds",
     "vllm:hbm_live_bytes",
     "vllm:step_roofline_frac",
+    "vllm:worker_fallbacks_total",
+    "vllm:xla_persistent_cache_events_total",
     # ---- fleet sentinel (ISSUE 20) ----
     "vllm:slo_burn_rate",
     "vllm:itl_p99_ms",
@@ -530,13 +532,29 @@ class EngineMetrics:
         )
         self.hbm_live_bytes = gauge(
             "vllm:hbm_live_bytes",
-            "Live HBM bytes on the reply-rank worker's first device "
-            "(memory creep is a gauge, not an OOM post-mortem)",
+            "Live HBM bytes on the fullest local device of the reply-rank "
+            "worker (memory creep is a gauge, not an OOM post-mortem)",
         )
         self.step_roofline_frac = gauge(
             "vllm:step_roofline_frac",
             "Last step's estimated bytes-touched/second over the "
             "device's peak HBM bandwidth (0 when unknown)",
+        )
+        self._worker_fallbacks = Counter(
+            "vllm:worker_fallbacks",
+            "Times the reply-rank worker carried on without something "
+            "it was asked to use (aot_load | aot_export | "
+            "compile_cache_setup); a warm start must show none",
+            ["model_name", "kind"],
+            registry=self.registry,
+        )
+        self._persistent_cache_events = Counter(
+            "vllm:xla_persistent_cache_events",
+            "JAX persistent-compilation-cache events in the reply-rank "
+            "worker's process: lookups, hits, and writes (a miss whose "
+            "compile was long enough to be stored)",
+            ["model_name", "event"],
+            registry=self.registry,
         )
         # ---- fleet sentinel (ISSUE 20) ----
         self._slo_burn = Gauge(
@@ -832,6 +850,18 @@ class EngineMetrics:
         if self.enabled and n > 0:
             self._xla_compiles.labels(
                 model_name=self._model_name, kind=kind
+            ).inc(n)
+
+    def record_worker_fallbacks(self, kind: str, n: int) -> None:
+        if self.enabled and n > 0:
+            self._worker_fallbacks.labels(
+                model_name=self._model_name, kind=kind
+            ).inc(n)
+
+    def record_persistent_cache_events(self, event: str, n: int) -> None:
+        if self.enabled and n > 0:
+            self._persistent_cache_events.labels(
+                model_name=self._model_name, event=event
             ).inc(n)
 
     def record_xla_compile_seconds(self, seconds: float) -> None:

@@ -253,17 +253,30 @@ def get_model(
         model.validate_mesh(mesh)
     if load_format == "dummy":
         rng = rng if rng is not None else jax.random.PRNGKey(model_config.seed)
-        # One jitted program for the whole tree: init_params issues ~1
-        # tiny RNG/cast op per tensor, and on a remote-compile runtime
-        # every unique small program costs ~1 s of compile round trip
-        # (measured: 142 s to dummy-init a 1B model op-by-op).
-        params = jax.jit(model.init_params)(rng)
-        if mesh is not None or getattr(model, "quant_method", None):
-            specs = (
-                model.partition_specs()
-                if hasattr(model, "partition_specs")
-                else None
+        specs = (
+            model.partition_specs()
+            if hasattr(model, "partition_specs")
+            else None
+        )
+        # One jitted program for the whole tree (init_params issues ~1
+        # tiny RNG/cast op per tensor), with every tensor born on its
+        # final sharding: under a mesh no device ever holds more than
+        # its own shard, so a model larger than one chip can be built.
+        out_shardings = None
+        if mesh is not None:
+            out_shardings = jax.tree_util.tree_map_with_path(
+                lambda kp, _: NamedSharding(
+                    mesh,
+                    _spec_for(
+                        tuple(getattr(k, "key", getattr(k, "idx", None))
+                              for k in kp),
+                        specs,
+                    ),
+                ),
+                jax.eval_shape(model.init_params, rng),
             )
+        params = jax.jit(model.init_params, out_shardings=out_shardings)(rng)
+        if mesh is not None or getattr(model, "quant_method", None):
             params = _place_tree(model, params, specs, mesh)
         return model, params
     model_dir = resolve_model_dir(model_config.model)

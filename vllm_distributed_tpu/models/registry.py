@@ -18,16 +18,12 @@ def _populate() -> None:
     if _REGISTRY:
         return
     from vllm_distributed_tpu.models.llama import LlamaForCausalLM
+    from vllm_distributed_tpu.models.mixtral import MixtralForCausalLM
     from vllm_distributed_tpu.models.opt import OPTForCausalLM
 
     register_model(LlamaForCausalLM)
     register_model(OPTForCausalLM)
-    try:
-        from vllm_distributed_tpu.models.mixtral import MixtralForCausalLM
-
-        register_model(MixtralForCausalLM)
-    except ImportError:
-        pass
+    register_model(MixtralForCausalLM)
 
 
 def get_model_class(architecture: str):

@@ -137,6 +137,22 @@ def _kernel(
         wait_writes(s, buf)
 
 
+# Mosaic's own scratch, beside the byte model below.
+_VMEM_HEADROOM = 4 * 2**20
+
+
+def _vmem_limit_bytes(
+    rows: int, hd: int, pool_itemsize: int, k_blk: int, side_itemsize: int
+) -> int:
+    """The limit the call states: the two-deep K+V slab scratch, the
+    pipelined side block and the overlay's f32 working set (padded
+    side, rolled copy, current slab, select result, row iota)."""
+    slabs = 2 * 2 * rows * hd * pool_itemsize
+    side = 2 * 2 * k_blk * hd * side_itemsize
+    overlay = 5 * rows * hd * 4
+    return slabs + side + overlay + _VMEM_HEADROOM
+
+
 def kv_flush(
     kv_pages,  # [2, P, page, HD] — or (int8 data, per-head scales)
     side_kv: jax.Array,  # [S, 2, K, HD] (model dtype)
@@ -232,6 +248,15 @@ def kv_flush(
         out_shape=jax.ShapeDtypeStruct(kv_pages.shape, kv_pages.dtype),
         # Inputs: 0-3 scalar prefetch, 4 side, 5 pool → output 0.
         input_output_aliases={5: 0},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit_bytes(
+                npt * page_size,
+                hd,
+                jnp.dtype(kv_pages.dtype).itemsize,
+                k_blk,
+                jnp.dtype(side_kv.dtype).itemsize,
+            )
+        ),
         interpret=interpret,
     )(
         base_lens.astype(jnp.int32),

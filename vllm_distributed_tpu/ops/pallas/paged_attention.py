@@ -65,6 +65,9 @@ _STATE_BYTES = 6 * 1024 * 1024
 # Decode-shape fold target: grow F until a block's softmax chain has at
 # least this many rows (amortizes VPU op issue over more elements).
 _ROWS_TARGET = 32
+# Added to the byte model below for Mosaic's own scratch and whatever
+# the model rounds away (sublane/lane padding of the small blocks).
+_VMEM_HEADROOM = 8 * 1024 * 1024
 
 
 def _kernel(
@@ -336,6 +339,36 @@ def _pick_fold(hkv: int, d: int, hd_pad: int, g: int, mq_blk: int):
     return f, f * d, hkv // f
 
 
+def _vmem_limit_bytes(
+    *,
+    blk: int,
+    hd_pad: int,
+    kv_itemsize: int,
+    nf: int,
+    rows: int,
+    fd: int,
+    q_itemsize: int,
+    k_side: int,
+    side_itemsize: int,
+    hkv: int,
+    has_quant: bool,
+) -> int:
+    """The limit the call states, from the same quantities that size
+    its tiles: the rotating KV buffers (_NBUF slots of a K+V block), the
+    f32 flash state (acc + m + l), the pipelined (double-buffered) q,
+    out, side and scale blocks, and one fold's f32 temporaries (K and V
+    tiles, the score/probability chain, the PV product)."""
+    f32 = 4
+    kv_bufs = _NBUF * 2 * blk * hd_pad * kv_itemsize
+    state = nf * rows * (fd + 2 * _LANES) * f32
+    q_out = 2 * 2 * nf * rows * fd * q_itemsize
+    side = 2 * 2 * k_side * hd_pad * side_itemsize
+    scales = 2 * 2 * max(hkv, 8) * blk * f32 if has_quant else 0
+    cols = max(blk, k_side)
+    temps = f32 * (2 * cols * fd + 6 * rows * cols + 3 * rows * fd)
+    return kv_bufs + state + q_out + side + scales + temps + _VMEM_HEADROOM
+
+
 def paged_attention(
     q: jax.Array,  # [T, Hq, D] flat
     kv_pages: jax.Array,  # [2, P, page, HD]
@@ -520,6 +553,23 @@ def paged_attention(
             scratch_shapes=scratch,
         ),
         out_shape=jax.ShapeDtypeStruct((s, num_qb, nf, rows, fd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit_bytes(
+                blk=blk,
+                hd_pad=hd_pad,
+                kv_itemsize=jnp.dtype(kv_pages.dtype).itemsize,
+                nf=nf,
+                rows=rows,
+                fd=fd,
+                q_itemsize=jnp.dtype(q.dtype).itemsize,
+                k_side=side_kv.shape[2] if has_side else 0,
+                side_itemsize=(
+                    jnp.dtype(side_kv.dtype).itemsize if has_side else 0
+                ),
+                hkv=hkv,
+                has_quant=has_quant,
+            )
+        ),
         interpret=interpret,
     )(*scalars, *inputs)
 

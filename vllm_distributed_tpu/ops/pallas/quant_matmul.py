@@ -37,28 +37,52 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def fits_vmem_budget(in_dim: int, block_out: int, x_nbytes: int) -> bool:
+# Working-set cap for one grid step, by the byte models below.  A v5e
+# core has 128 MiB of VMEM (jax's pallas/mosaic/tpu_info.py), of which
+# the compiler scopes a kernel to a default far below these tiles
+# (16 MiB) unless the call states its own limit — so every pallas_call
+# here passes vmem_limit_bytes = its model + _VMEM_HEADROOM (Mosaic's
+# internal scratch, the double-buffered output tile, the scale rows).
+# With that, the largest approved tiles ([4096x1024] and [8192x512]
+# int8, [2048x512] int4) compile and run on the chip (PERF.md, PR 21).
+_VMEM_TILE_BUDGET = 26 * 2**20
+_VMEM_HEADROOM = 8 * 2**20
+
+
+def int8_vmem_bytes(in_dim: int, block_out: int, x_nbytes: int) -> int:
     """VMEM model per grid step: two double-buffered int8 weight tiles
     (in*blk*2) plus the f32 dequantized tile the kernel materializes
-    before the dot (in*blk*4) plus f32-promoted x (~2*x_nbytes).  The
-    26 MiB cap is empirically anchored: [8192x512] and [2048x2048]
-    tiles (both = 24 MiB by this model) compile and run on v5e across
-    the whole bench suite; one step up ([4096x2048] = 48 MiB) must not
-    be approved.  Single source of truth for the int8 caller's
-    eligibility check and the kernel's own guard (int4 has its own
+    before the dot (in*blk*4) plus f32-promoted x (~2*x_nbytes).
+    Single source of truth for the caller's eligibility check, the
+    kernel's own guard and the limit the call states (int4 has its own
     model below — its temporaries are larger)."""
-    return in_dim * block_out * 6 + 2 * x_nbytes <= 26 * 2**20
+    return in_dim * block_out * 6 + 2 * x_nbytes
 
 
-def fits_vmem_budget4(in_dim: int, block_out: int, x_nbytes: int) -> bool:
+def int4_vmem_bytes(in_dim: int, block_out: int, x_nbytes: int) -> int:
     """int4 kernel VMEM model: per LOGICAL input element the kernel
     holds ~half-height planes of int32 q (2B), two f32 nibble planes
     (4B), the expanded scales (2B) and the two scaled operands (4B),
     plus double-buffered packed tiles — ~16B/element against int8's
-    6B.  Same empirically-anchored 26 MiB cap."""
-    return in_dim * block_out * 16 + 2 * x_nbytes <= 26 * 2**20
+    6B."""
+    return in_dim * block_out * 16 + 2 * x_nbytes
+
+
+def fits_vmem_budget(in_dim: int, block_out: int, x_nbytes: int) -> bool:
+    return int8_vmem_bytes(in_dim, block_out, x_nbytes) <= _VMEM_TILE_BUDGET
+
+
+def fits_vmem_budget4(in_dim: int, block_out: int, x_nbytes: int) -> bool:
+    return int4_vmem_bytes(in_dim, block_out, x_nbytes) <= _VMEM_TILE_BUDGET
+
+
+def _compiler_params(model_bytes: int) -> pltpu.CompilerParams:
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=model_bytes + _VMEM_HEADROOM
+    )
 
 
 def _kernel(x_ref, q_ref, s_ref, o_ref, *, out_dtype):
@@ -139,6 +163,9 @@ def int4_matmul(
         ],
         out_specs=pl.BlockSpec((t, block_out), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((t, out_dim), x.dtype),
+        compiler_params=_compiler_params(
+            int4_vmem_bytes(in_dim, block_out, x.nbytes)
+        ),
         interpret=interpret,
     )(x2, q, scale.astype(jnp.float32))
 
@@ -176,5 +203,8 @@ def int8_matmul(
         ],
         out_specs=pl.BlockSpec((t, block_out), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((t, out_dim), x.dtype),
+        compiler_params=_compiler_params(
+            int8_vmem_bytes(in_dim, block_out, x.nbytes)
+        ),
         interpret=interpret,
     )(x, q, scale.reshape(1, -1).astype(jnp.float32))

@@ -21,6 +21,7 @@ structure via ``quant_spec``.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any
 
@@ -28,6 +29,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
+
+from vllm_distributed_tpu.logger import init_logger
+
+logger = init_logger(__name__)
 
 METHODS = ("int8", "int4")
 
@@ -162,18 +167,16 @@ def pick_matmul_mode(quant_method: str | None) -> str:
     """Execution backend for quantized matmuls, decided at load time:
     "pallas" streams compressed tiles through the Pallas kernels —
     int8 single-chip and per-tp-shard under shard_map; int4 single-chip
-    (tp>1 int4 falls back to dequant-in-graph at call time).
-    Non-quantized stays "dequant"."""
+    (tp>1 int4 dequantizes in-graph at call time).  The backend is the
+    one VDT_USE_PALLAS names ("reference" = dequant-in-graph);
+    non-quantized stays "dequant"."""
     if quant_method not in ("int8", "int4"):
         return "dequant"
     from vllm_distributed_tpu import envs
+    from vllm_distributed_tpu.ops import resolve_kernel_backend
 
-    backend = envs.VDT_USE_PALLAS
-    if backend == "auto":
-        backend = "pallas" if jax.default_backend() == "tpu" else "reference"
-    if backend in ("pallas", "pallas_interpret"):
-        return backend
-    return "dequant"
+    backend = resolve_kernel_backend(envs.VDT_USE_PALLAS)
+    return "dequant" if backend == "reference" else backend
 
 
 def _pick_block(
@@ -259,6 +262,21 @@ def _sharded_int8_matmul(x, w: QuantizedTensor, interpret: bool):
     return f(x, w.q, w.scale)
 
 
+@functools.cache
+def _warn_dequant_in_graph(bits: int, shape: tuple, sharded: bool) -> None:
+    """Said once per weight shape (at trace time): a decode-shape call
+    that asked for the streaming kernel and got x @ dequantize(w)
+    instead is legitimate routing, but it must not be silent."""
+    logger.warning(
+        "quant_matmul: no streaming-kernel tile for the int%d weight %s%s "
+        "(per-shard out dim not a multiple of 128, tile over the VMEM "
+        "budget, or int4 under tp); dequantizing in-graph",
+        bits,
+        shape,
+        " per tp shard" if sharded else "",
+    )
+
+
 def quant_matmul(x: jax.Array, w, bias=None) -> jax.Array:
     """x @ w for plain or QuantizedTensor weights.  On the Pallas path
     eligible int8 2D weights stream through ops/pallas/quant_matmul (the
@@ -303,6 +321,8 @@ def quant_matmul(x: jax.Array, w, bias=None) -> jax.Array:
                     interpret=interpret,
                 )
         if out is None:
+            if eligible:
+                _warn_dequant_in_graph(w.bits, tuple(w.shape), w.mesh is not None)
             out = x @ dequantize(w, x.dtype)
     else:
         out = x @ w.astype(x.dtype)

@@ -126,6 +126,12 @@ LLAMA_7B = dict(
     vocab_size=32000, hidden=4096, intermediate=11008, layers=32,
     heads=32, kv_heads=32, max_pos=4096, dtype="bfloat16",
 )
+# Llama-2-13B (BASELINE milestone 3): 26 GB in bf16, so it only runs
+# sharded — the four-chip shape of chip_smoke.py --tp 4.
+LLAMA_13B = dict(
+    vocab_size=32000, hidden=5120, intermediate=13824, layers=40,
+    heads=40, kv_heads=40, max_pos=4096, dtype="bfloat16",
+)
 # Mixtral-8x7B (milestone config 5), for dummy-weight EP perf runs.
 MIXTRAL_8X7B = dict(
     vocab_size=32000, hidden=4096, intermediate=14336, layers=32,

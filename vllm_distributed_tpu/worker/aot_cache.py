@@ -18,13 +18,15 @@ output treedef is pickled alongside the artifact.
 
 Scope: single-device programs (the runner gates on mesh is None).
 Artifacts are keyed by a hash of the program description, every leaf's
-shape/dtype, and the jax/jaxlib/device identity — any mismatch is a
+shape/dtype, the jax/jaxlib/device identity, and the caller's context
+(which carries a digest of this package's source) — any mismatch is a
 clean miss, never a wrong program.  Every failure path falls back to
-the normal jit call.
+the normal jit call and reports itself through ``on_fallback``.
 """
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import os
 import pickle
@@ -37,9 +39,38 @@ from vllm_distributed_tpu.logger import init_logger
 logger = init_logger(__name__)
 
 
+_PACKAGE_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@functools.cache
+def package_source_digest(root: str = _PACKAGE_ROOT) -> str:
+    """sha256 over every ``*.py`` under ``root`` (this package) as it is
+    on disk: relative path + bytes, in sorted order.  The step programs
+    are traced from these files, so the digest stands in the artifact
+    key where a version string cannot: it changes with any edit."""
+    h = hashlib.sha256()
+    for dirpath, dirnames, filenames in os.walk(root):
+        dirnames.sort()
+        for name in sorted(filenames):
+            if name.endswith(".py"):
+                path = os.path.join(dirpath, name)
+                h.update(os.path.relpath(path, root).encode())
+                with open(path, "rb") as f:
+                    h.update(f.read())
+    return h.hexdigest()[:16]
+
+
 class AotCache:
-    def __init__(self, cache_dir: str | None, context: str = "") -> None:
+    def __init__(
+        self,
+        cache_dir: str | None,
+        context: str = "",
+        on_fallback: Callable[[str], None] = lambda kind: None,
+    ) -> None:
         self.dir = os.path.join(cache_dir, "aot") if cache_dir else None
+        # Told the kind ("aot_load" | "aot_export") each time the cache
+        # carries on without an artifact it should have had.
+        self._on_fallback = on_fallback
         # Caller-supplied identity of everything traced into the
         # programs BEYOND leaf shapes/dtypes: model hyperparameters
         # (two checkpoints can share every tensor shape but differ in
@@ -110,6 +141,7 @@ class AotCache:
         except Exception as e:  # noqa: BLE001 — stale/corrupt artifact
             logger.warning("AOT artifact %s unusable (%s); recompiling",
                            key, e)
+            self._on_fallback("aot_load")
             runner = None
         if runner is None:
             runner = self._build_and_save(
@@ -158,6 +190,7 @@ class AotCache:
             logger.info("AOT artifact saved: %s (%s)", key, desc)
         except Exception as e:  # noqa: BLE001 — export is best-effort
             logger.warning("AOT export failed for %s (%s)", desc, e)
+            self._on_fallback("aot_export")
 
             def run(leaves):
                 out_leaves = jitted(*leaves)

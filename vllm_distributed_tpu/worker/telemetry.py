@@ -15,7 +15,14 @@ runner writes:
   memory creep is a gauge, not an OOM post-mortem;
 - **step roofline**: estimated bytes-touched / step-time over the
   device's peak HBM bandwidth — the steady-state twin of the offline
-  bench's ``roofline_frac``.
+  bench's ``roofline_frac``;
+- **fallbacks**: every time the worker carried on without something it
+  was asked to use (an unusable or unexportable AOT artifact, a compile
+  cache directory it could not create), by kind — semantically safe,
+  but a run that claims a warm start must show zero;
+- **persistent cache**: JAX's own compilation-cache events for this
+  process (lookups, hits, and entries written after a miss), so a warm
+  restart is judged by what the cache reports, not by a time threshold.
 
 The driver pulls snapshots over the existing ``collective_rpc`` path
 (``get_device_telemetry``) on ``/metrics`` scrapes; compile events
@@ -41,11 +48,59 @@ _PEAK_BW_BY_KIND = (
 )
 
 
+# JAX's persistent-compilation-cache events (jax/_src/compiler.py).  A
+# program that took under jax_persistent_cache_min_compile_time_secs to
+# compile is looked up but never written, so "writes" counts exactly the
+# step-program-sized misses.
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/compile_requests_use_cache": "lookups",
+    "/jax/compilation_cache/cache_hits": "hits",
+    "/jax/compilation_cache/cache_misses": "writes",
+}
+# The events are process-wide, so the tally is too: one listener,
+# installed on first use, shared by every DeviceTelemetry in the process.
+_cache_counts = dict.fromkeys(_CACHE_EVENTS.values(), 0)
+_cache_lock = threading.Lock()
+_cache_listener_installed = False
+
+
+def _on_jax_event(event: str, **_kwargs) -> None:
+    name = _CACHE_EVENTS.get(event)
+    if name is not None:
+        with _cache_lock:
+            _cache_counts[name] += 1
+
+
+def _install_cache_listener() -> None:
+    global _cache_listener_installed
+    with _cache_lock:
+        if _cache_listener_installed:
+            return
+        _cache_listener_installed = True
+    import jax.monitoring
+
+    jax.monitoring.register_event_listener(_on_jax_event)
+
+
 def peak_hbm_bandwidth(device_kind: str) -> float:
     for prefix, bw in _PEAK_BW_BY_KIND:
         if device_kind.startswith(prefix):
             return bw
     return 0.0
+
+
+def local_memory_stats() -> list[dict]:
+    """``memory_stats()`` of every local device, in device order; empty
+    when the backend reports none (the CPU backend returns None)."""
+    import jax
+
+    stats = [d.memory_stats() for d in jax.local_devices()]
+    return [s for s in stats if s]
+
+
+def persistent_cache_counts() -> dict[str, int]:
+    with _cache_lock:
+        return dict(_cache_counts)
 
 
 class DeviceTelemetry:
@@ -64,6 +119,12 @@ class DeviceTelemetry:
         self.last_step_seconds = 0.0
         self.last_step_bytes = 0
         self.roofline_frac = 0.0
+        self.fallbacks: dict[str, int] = {}
+        _install_cache_listener()
+
+    def record_fallback(self, kind: str) -> None:
+        with self._lock:
+            self.fallbacks[kind] = self.fallbacks.get(kind, 0) + 1
 
     def record_compile(self, kind: str, seconds: float, key: str) -> None:
         with self._lock:
@@ -86,24 +147,15 @@ class DeviceTelemetry:
             )
 
     def _memory_stats(self) -> tuple[int, int]:
-        """(live_bytes, limit_bytes) from the runtime; (0, 0) when the
-        backend exposes none (CPU tests, mock workers)."""
-        try:
-            import jax
-
-            stats = jax.local_devices()[0].memory_stats()
-            if stats:
-                return (
-                    int(stats.get("bytes_in_use", 0)),
-                    int(stats.get("bytes_limit", 0)),
-                )
-        except Exception as e:  # noqa: BLE001 — telemetry only, never fatal
-            import logging
-
-            logging.getLogger(__name__).debug(
-                "device memory_stats unavailable: %s", e
-            )
-        return 0, 0
+        """(live_bytes, limit_bytes) of the fullest local device; (0, 0)
+        when the backend exposes none (CPU tests, mock workers)."""
+        stats = local_memory_stats()
+        if not stats:
+            return 0, 0
+        return (
+            max(int(s.get("bytes_in_use", 0)) for s in stats),
+            min(int(s.get("bytes_limit", 0)) for s in stats),
+        )
 
     def snapshot(self, probe_memory: bool = True) -> dict:
         live, limit = self._memory_stats() if probe_memory else (0, 0)
@@ -117,4 +169,6 @@ class DeviceTelemetry:
                 "last_step_seconds": self.last_step_seconds,
                 "last_step_bytes": self.last_step_bytes,
                 "roofline_frac": self.roofline_frac,
+                "fallbacks": dict(self.fallbacks),
+                "persistent_cache": persistent_cache_counts(),
             }
