@@ -1,0 +1,13 @@
+"""Share of the traced stretch in which the device was idle and
+a thread was in ``runner.fetch`` (the resolver's for a pipelined scan,
+else the engine's) while the engine's thread was in no phase of its own: the gaps
+between device operations laid over the annotations the spans leave in
+the profile's host plane, on the profiler's clock.  The five
+``idle_in_*`` shares add up to ``device_idle_pct``.  Layer: engine.
+Moves ``tpot_p50_ms``."""
+
+from benchmark.layer_metrics import _named
+
+
+def read(ctx: dict):
+    return _named.idle_pct(ctx, "fetch")

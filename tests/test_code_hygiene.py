@@ -12,6 +12,10 @@ Checker unit tests (fixture corpus, waiver/baseline round-trips, CLI)
 live in tests/test_vdt_lint.py.
 """
 
+import ast
+import pathlib
+import re
+
 import pytest
 
 from tools.vdt_lint import (
@@ -48,3 +52,27 @@ def test_control_plane_carries_no_baseline_debt():
         or "/router/" in e.get("path", "")
     ]
     assert not offenders, offenders
+
+
+def test_every_pallas_call_states_a_unique_literal_vdt_name():
+    """PR 26: a Pallas kernel without ``name=`` takes whatever stands
+    innermost on its name stack as its instruction name (``flat_fn``,
+    ``closed_call``), so a profile cannot tell one kernel from another.
+    Every ``pl.pallas_call`` under ``ops/pallas/`` states a literal
+    ``name="vdt_…"``, and no two state the same."""
+    pkg = pathlib.Path(__file__).resolve().parents[1] / "vllm_distributed_tpu"
+    names = []
+    for path in sorted((pkg / "ops" / "pallas").glob("*.py")):
+        for node in ast.walk(ast.parse(path.read_text())):
+            if not (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "pallas_call"):
+                continue
+            kw = {k.arg: k.value for k in node.keywords}
+            where = f"{path.name}:{node.lineno}"
+            assert "name" in kw, f"{where}: pallas_call without name="
+            assert isinstance(kw["name"], ast.Constant) and isinstance(
+                kw["name"].value, str), f"{where}: name= is not a literal"
+            assert re.match(r"^vdt_[a-z0-9_]+$", kw["name"].value), where
+            names.append(kw["name"].value)
+    assert len(names) >= 4 and len(set(names)) == len(names), names

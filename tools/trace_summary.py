@@ -34,11 +34,19 @@ _STAGE_ORDER = [
     "engine.kv_handoff",
     "engine.prefill",
     "engine.decode",
+    # One trace per engine step (PR 26): the root and the host's phases.
+    "engine.step",
     "scheduler.schedule",
     "executor.dispatch",
     "executor.gather",
     "worker.execute",
     "worker.serialize",
+    "runner.prepare",
+    "runner.dispatch",
+    "runner.fetch",
+    "engine.process",
+    "engine.wait",
+    "profile.capture",
 ]
 
 

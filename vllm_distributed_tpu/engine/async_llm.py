@@ -48,7 +48,7 @@ from vllm_distributed_tpu.engine.supervisor import (
 from vllm_distributed_tpu.logger import init_logger
 from vllm_distributed_tpu.outputs import RequestOutput
 from vllm_distributed_tpu.sampling_params import SamplingParams
-from vllm_distributed_tpu.tracing import get_tracer
+from vllm_distributed_tpu.tracing import NOOP_SPAN, get_tracer
 
 logger = init_logger(__name__)
 
@@ -275,7 +275,19 @@ class AsyncLLM:
                 raise RuntimeError(self.engine._dead_message())
             if not self.engine.has_unfinished_requests():
                 self._phase = "idle"
-                self._wake.wait(timeout=0.2)
+                # Opened only while /debug/profile captures, and not
+                # kept: the span is there for the profile's host plane,
+                # where it tells waiting for work from the other things
+                # an engine thread outside a step can be doing.
+                tracer = get_tracer()
+                with (
+                    tracer.span(
+                        "engine.wait", trace_root=True, record=False
+                    )
+                    if tracer.profiling
+                    else NOOP_SPAN
+                ):
+                    self._wake.wait(timeout=0.2)
                 self._wake.clear()
                 continue
             self._phase = "step"

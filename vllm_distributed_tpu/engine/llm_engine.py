@@ -27,7 +27,11 @@ from vllm_distributed_tpu.tokenizer import (
     IncrementalDetokenizer,
     get_tokenizer,
 )
-from vllm_distributed_tpu.tracing import get_tracer
+from vllm_distributed_tpu.tracing import (
+    NOOP_SPAN,
+    get_tracer,
+    new_span_id,
+)
 
 logger = init_logger(__name__)
 
@@ -330,6 +334,7 @@ class LLMEngine:
     def _finalize_one(self) -> list[RequestOutput]:
         scheduler_output, result = self._pending.pop(0)
         if hasattr(result, "result"):  # Future
+            # The wait is the resolver thread's ``runner.fetch``.
             result = result.result()
         return self._process(scheduler_output, result)
 
@@ -354,11 +359,22 @@ class LLMEngine:
         return outputs
 
     def step(self) -> list[RequestOutput]:
+        """One engine step, a trace of its own: the root ``engine.step``
+        and a child for each phase the host goes through
+        (``scheduler.schedule``, ``runner.prepare`` / ``.dispatch`` /
+        ``.fetch``, ``engine.process``), each with the ``step_id`` of
+        the dispatch it works for — a step also finalizes dispatches
+        that earlier steps put in flight."""
         if self._failed:
             raise RuntimeError(self._dead_message())
+        with self.tracer.span("engine.step", trace_root=True) as span:
+            return self._step(span)
+
+    def _step(self, span) -> list[RequestOutput]:
         outputs: list[RequestOutput] = []
         outputs.extend(self._finalize_done())
-        if self._pending and not self._pipeline_safe():
+        pipeline_break = bool(self._pending) and not self._pipeline_safe()
+        if pipeline_break:
             # Reconciliation: the predicted continuation no longer holds
             # (a request finished mid-window, an admission arrived, …) —
             # drain so the next schedule sees settled state.  Deferred
@@ -367,6 +383,13 @@ class LLMEngine:
             self.metrics.record_pipeline_break()
             outputs.extend(self._drain_pending())
         scheduler_output = self._schedule()
+        if span is not NOOP_SPAN:
+            span.attributes.update(
+                step_id=scheduler_output.step_id,
+                decode_steps=scheduler_output.decode_steps,
+                pending=len(self._pending),
+                pipeline_break=pipeline_break,
+            )
         if self.flight_recorder.enabled:
             self._record_flight(scheduler_output)
         # Deadline sheds and preempt-to-sheds finish OUTSIDE
@@ -515,29 +538,44 @@ class LLMEngine:
 
     def _schedule(self):
         """One scheduler pass, wrapped in a per-step schedule span with
-        the batch composition attached (parented to the first traced
-        request in the batch; tracing off = plain call)."""
+        the batch composition attached: a child of the step, and stored
+        again under the first traced request in the batch (tracing off =
+        plain call)."""
         if not self.tracer.enabled:
             return self.scheduler.schedule()
-        start_wall = time.time()
-        t0 = time.monotonic()
-        scheduler_output = self.scheduler.schedule()
-        self.tracer.record_span(
-            "scheduler.schedule",
-            start_wall,
-            time.monotonic() - t0,
-            parent=scheduler_output.trace_ctx,
-            step_id=scheduler_output.step_id,
-            num_new=len(scheduler_output.new_requests),
-            num_cached=len(scheduler_output.cached_requests),
-            num_preempted=len(scheduler_output.preempted_req_ids),
-            decode_steps=scheduler_output.decode_steps,
-            total_tokens=scheduler_output.total_num_scheduled_tokens,
-            batch=",".join(
-                f"{rid}:{n}"
-                for rid, n in scheduler_output.num_scheduled_tokens.items()
-            ),
-        )
+        with self.tracer.span("scheduler.schedule") as span:
+            scheduler_output = self.scheduler.schedule()
+            if span is NOOP_SPAN:  # no step around it: nothing to parent
+                return scheduler_output
+            span.attributes.update(
+                step_id=scheduler_output.step_id,
+                num_new=len(scheduler_output.new_requests),
+                num_cached=len(scheduler_output.cached_requests),
+                num_preempted=len(scheduler_output.preempted_req_ids),
+                decode_steps=scheduler_output.decode_steps,
+                total_tokens=scheduler_output.total_num_scheduled_tokens,
+                batch=",".join(
+                    f"{rid}:{n}"
+                    for rid, n in (
+                        scheduler_output.num_scheduled_tokens.items()
+                    )
+                ),
+            )
+        if scheduler_output.trace_ctx is not None:
+            # The same interval in the trace of the first traced request
+            # of the batch, where a request's own chain
+            # (queue → schedule → dispatch → worker) is read.  Stored,
+            # not recorded: the span above has fed the metrics sink.
+            self.tracer.adopt(
+                [
+                    dict(
+                        span.to_wire(),
+                        trace_id=scheduler_output.trace_ctx[0],
+                        parent_id=scheduler_output.trace_ctx[1],
+                        span_id=new_span_id(),
+                    )
+                ]
+            )
         return scheduler_output
 
     def _record_stage(
@@ -605,6 +643,16 @@ class LLMEngine:
         )
 
     def _process(
+        self, scheduler_output, runner_output
+    ) -> list[RequestOutput]:
+        with self.tracer.span(
+            "engine.process", step_id=scheduler_output.step_id
+        ) as span:
+            outputs = self._process_outputs(scheduler_output, runner_output)
+            span.set_attribute("outputs", len(outputs))
+            return outputs
+
+    def _process_outputs(
         self, scheduler_output, runner_output
     ) -> list[RequestOutput]:
         finished = self.scheduler.update_from_output(

@@ -1112,26 +1112,54 @@ async def debug_profile(request: web.Request) -> web.Response:
         profile_dir, f"profile-{int(time.time() * 1000)}"
     )
 
-    def capture() -> None:
+    tracer = get_tracer()
+
+    def capture() -> dict:
         # Runs on an executor thread: the sleep must not block the
         # event loop for the capture window.
         import jax
 
         os.makedirs(path, exist_ok=True)
-        jax.profiler.start_trace(path)
-        try:
-            time.sleep(seconds)
-        finally:
-            jax.profiler.stop_trace()
+        # The capture's own span (a trace of its own in the ring) holds
+        # both host clocks at its two ends: span starts elsewhere are
+        # wall clock, the profile's events are on the profiler's.
+        # No Python tracer: its hook on every call of every thread made
+        # the host's phases several times longer than they are (the
+        # engine's output processing 30 ms where it takes 1.4 untraced,
+        # PERF.md) and the capture's end a 40 s stall.  The host plane
+        # keeps the runtime's own events and the spans' annotations.
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        with tracer.span("profile.capture", trace_root=True) as span:
+            jax.profiler.start_trace(path, profiler_options=options)
+            # From here every span is also a TraceAnnotation in the
+            # profile's host plane (tracing.Span.__enter__).
+            tracer.profiling = True
+            clocks = {
+                "begin_wall": time.time(),
+                "begin_mono": time.monotonic(),
+            }
+            try:
+                time.sleep(seconds)
+            finally:
+                clocks["end_wall"] = time.time()
+                clocks["end_mono"] = time.monotonic()
+                tracer.profiling = False
+                jax.profiler.stop_trace()
+            for key, value in clocks.items():
+                span.set_attribute(key, value)
+        return clocks
 
     request.app["_profiling"] = True
     try:
-        await asyncio.get_running_loop().run_in_executor(None, capture)
+        clocks = await asyncio.get_running_loop().run_in_executor(
+            None, capture
+        )
     except Exception as e:  # noqa: BLE001 — surface, don't 500-crash
         return _error(f"profile capture failed: {e}", 503)
     finally:
         request.app["_profiling"] = False
-    return web.json_response({"path": path, "seconds": seconds})
+    return web.json_response({"path": path, "seconds": seconds, **clocks})
 
 
 async def debug_traces(request: web.Request) -> web.Response:

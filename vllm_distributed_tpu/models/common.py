@@ -13,6 +13,29 @@ import jax
 import jax.numpy as jnp
 
 
+# The parts of a layer and of a step, as ``jax.named_scope`` names them
+# in every program the runner builds (llama.py, mixtral.py,
+# worker/model_runner.py).  A scope travels with an operation as the
+# tail of its ``op_name`` through jax.export and XLA into a profile;
+# the benchmark's readers file device time under these names, so a
+# rename here renames a metric's source.
+SCOPE_EMBED = "embed"
+SCOPE_ATTN_QKV = "attn_qkv"  # norm, q/k/v projections, rope
+SCOPE_KV_WRITE = "kv_write"
+SCOPE_ATTN = "attn"  # the attention kernel call and its layout glue
+SCOPE_ATTN_OUT = "attn_out"  # o projection and residual
+SCOPE_MOE_ROUTER = "moe_router"
+SCOPE_MOE_EXPERTS = "moe_experts"
+SCOPE_MLP = "mlp"  # post-attention norm; the dense MLP; MoE combine
+SCOPE_LM_HEAD = "lm_head"  # final norm, row gather, logits
+SCOPE_SAMPLER = "sampler"
+SCOPES = (
+    SCOPE_EMBED, SCOPE_ATTN_QKV, SCOPE_KV_WRITE, SCOPE_ATTN,
+    SCOPE_ATTN_OUT, SCOPE_MOE_ROUTER, SCOPE_MOE_EXPERTS, SCOPE_MLP,
+    SCOPE_LM_HEAD, SCOPE_SAMPLER,
+)
+
+
 class SupportsQuantization:
     """Weight-only quantization hooks shared by the model zoo.
 

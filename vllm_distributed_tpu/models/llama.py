@@ -22,6 +22,13 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from vllm_distributed_tpu.models.common import (
+    SCOPE_ATTN,
+    SCOPE_ATTN_OUT,
+    SCOPE_ATTN_QKV,
+    SCOPE_EMBED,
+    SCOPE_KV_WRITE,
+    SCOPE_LM_HEAD,
+    SCOPE_MLP,
     SupportsQuantization,
     apply_rope,
     linear,
@@ -298,40 +305,47 @@ class LlamaForCausalLM(SupportsQuantization):
         """Returns (logits [S, V] at meta.logits_indices, updated kv);
         with return_hidden also the final-norm hidden states [S, H]
         (embeddings / scoring, /v1/embeddings parity)."""
-        x = params["embed"][token_ids].astype(self.dtype)
+        with jax.named_scope(SCOPE_EMBED):
+            x = params["embed"][token_ids].astype(self.dtype)
         inv_freq = rope_frequencies(
             self.head_dim, self.rope_theta, rope_scaling=self.rope_scaling
         )
         new_kv = []
         t = token_ids.shape[0]
         for layer, kv_pages in zip(params["layers"], kv_caches):
-            h = rms_norm(x, layer["input_ln"], self.rms_eps)
-            q, k, v = self._qkv(h, layer, t)
-            if self.qk_norm:
-                q = rms_norm(q, layer["q_norm"], self.rms_eps)
-                k = rms_norm(k, layer["k_norm"], self.rms_eps)
-            q = apply_rope(q, meta.q_positions, inv_freq)
-            k = apply_rope(k, meta.q_positions, inv_freq)
-            kv_pages = kv_write_fn(kv_pages, k, v, meta.slot_mapping)
+            with jax.named_scope(SCOPE_ATTN_QKV):
+                h = rms_norm(x, layer["input_ln"], self.rms_eps)
+                q, k, v = self._qkv(h, layer, t)
+                if self.qk_norm:
+                    q = rms_norm(q, layer["q_norm"], self.rms_eps)
+                    k = rms_norm(k, layer["k_norm"], self.rms_eps)
+                q = apply_rope(q, meta.q_positions, inv_freq)
+                k = apply_rope(k, meta.q_positions, inv_freq)
+            with jax.named_scope(SCOPE_KV_WRITE):
+                kv_pages = kv_write_fn(kv_pages, k, v, meta.slot_mapping)
             new_kv.append(kv_pages)
-            attn = attn_fn(
-                q, kv_pages, meta,
-                scale=self.scale, num_kv_heads=self.num_kv_heads,
-            )
-            x = x + linear(attn.reshape(t, -1), layer["wo"])
+            with jax.named_scope(SCOPE_ATTN):
+                attn = attn_fn(
+                    q, kv_pages, meta,
+                    scale=self.scale, num_kv_heads=self.num_kv_heads,
+                )
+            with jax.named_scope(SCOPE_ATTN_OUT):
+                x = x + linear(attn.reshape(t, -1), layer["wo"])
 
-            h = rms_norm(x, layer["post_attn_ln"], self.rms_eps)
-            x = x + self._mlp(h, layer)
+            with jax.named_scope(SCOPE_MLP):
+                h = rms_norm(x, layer["post_attn_ln"], self.rms_eps)
+                x = x + self._mlp(h, layer)
 
-        x = rms_norm(x, params["norm"], self.rms_eps)
-        sel = x[meta.logits_indices]  # [S, H]
-        lm_head = params.get("lm_head")
-        if lm_head is None:
-            logits = sel @ params["embed"].T.astype(sel.dtype)
-        else:
-            from vllm_distributed_tpu.ops.quant import quant_matmul
+        with jax.named_scope(SCOPE_LM_HEAD):
+            x = rms_norm(x, params["norm"], self.rms_eps)
+            sel = x[meta.logits_indices]  # [S, H]
+            lm_head = params.get("lm_head")
+            if lm_head is None:
+                logits = sel @ params["embed"].T.astype(sel.dtype)
+            else:
+                from vllm_distributed_tpu.ops.quant import quant_matmul
 
-            logits = quant_matmul(sel, lm_head)
+                logits = quant_matmul(sel, lm_head)
         logits = logits.astype(jnp.float32)
         if return_hidden:
             return logits, new_kv, sel.astype(jnp.float32)

@@ -47,6 +47,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from vllm_distributed_tpu.models.common import (
+    SCOPE_MOE_EXPERTS,
+    SCOPE_MOE_ROUTER,
+)
 from vllm_distributed_tpu.models.llama import LlamaForCausalLM
 
 
@@ -312,20 +316,22 @@ class MixtralForCausalLM(LlamaForCausalLM):
         from vllm_distributed_tpu.ops.quant import maybe_dequantize
 
         t = h.shape[0]
-        topw, topi = self._route(h, layer)
-        combine = (
-            jnp.zeros((t, self.num_experts), jnp.float32)
-            .at[jnp.arange(t)[:, None], topi]
-            .set(topw)
-            .astype(h.dtype)
-        )
-        w1 = maybe_dequantize(layer["w1"], h.dtype)
-        w3 = maybe_dequantize(layer["w3"], h.dtype)
-        w2 = maybe_dequantize(layer["w2"], h.dtype)
-        h1 = jnp.einsum("th,ehi->tei", h, w1)
-        h3 = jnp.einsum("th,ehi->tei", h, w3)
-        inner = jax.nn.silu(h1) * h3
-        return jnp.einsum("tei,eih,te->th", inner, w2, combine)
+        with jax.named_scope(SCOPE_MOE_ROUTER):
+            topw, topi = self._route(h, layer)
+            combine = (
+                jnp.zeros((t, self.num_experts), jnp.float32)
+                .at[jnp.arange(t)[:, None], topi]
+                .set(topw)
+                .astype(h.dtype)
+            )
+        with jax.named_scope(SCOPE_MOE_EXPERTS):
+            w1 = maybe_dequantize(layer["w1"], h.dtype)
+            w3 = maybe_dequantize(layer["w3"], h.dtype)
+            w2 = maybe_dequantize(layer["w2"], h.dtype)
+            h1 = jnp.einsum("th,ehi->tei", h, w1)
+            h3 = jnp.einsum("th,ehi->tei", h, w3)
+            inner = jax.nn.silu(h1) * h3
+            return jnp.einsum("tei,eih,te->th", inner, w2, combine)
 
     def _mlp_ragged(self, h: jax.Array, layer: dict) -> jax.Array:
         """Sorted ragged dispatch (SURVEY §2.5's TPU plan; VERDICT r3
@@ -347,29 +353,31 @@ class MixtralForCausalLM(LlamaForCausalLM):
 
         t = h.shape[0]
         e, k = self.num_experts, self.top_k
-        topw, topi = self._route(h, layer)
-        flat_e = topi.reshape(-1).astype(jnp.int32)  # [T*k]
-        order = jnp.argsort(flat_e)
-        tok = order // k
-        xs = h[tok]  # [T*k, H] sorted by expert
-        gs = jnp.bincount(flat_e, length=e).astype(jnp.int32)
-        row_w = topw.reshape(-1)[order].astype(h.dtype)  # [T*k]
+        with jax.named_scope(SCOPE_MOE_ROUTER):
+            topw, topi = self._route(h, layer)
+            flat_e = topi.reshape(-1).astype(jnp.int32)  # [T*k]
+            order = jnp.argsort(flat_e)
+            tok = order // k
+            xs = h[tok]  # [T*k, H] sorted by expert
+            gs = jnp.bincount(flat_e, length=e).astype(jnp.int32)
+            row_w = topw.reshape(-1)[order].astype(h.dtype)  # [T*k]
 
-        w1 = maybe_dequantize(layer["w1"], h.dtype)
-        w3 = maybe_dequantize(layer["w3"], h.dtype)
-        w2 = maybe_dequantize(layer["w2"], h.dtype)
+        with jax.named_scope(SCOPE_MOE_EXPERTS):
+            w1 = maybe_dequantize(layer["w1"], h.dtype)
+            w3 = maybe_dequantize(layer["w3"], h.dtype)
+            w2 = maybe_dequantize(layer["w2"], h.dtype)
 
-        mesh = getattr(self, "_mesh", None)
-        tp = mesh.shape.get("tp", 1) if mesh is not None else 1
-        if tp > 1 and self.expert_parallel:
-            orows = self._ragged_ep(xs, gs, w1, w3, w2, mesh, tp)
-        elif tp > 1:
-            orows = self._ragged_tp(xs, gs, w1, w3, w2, mesh)
-        else:
-            h1 = jax.lax.ragged_dot(xs, w1, gs)
-            h3 = jax.lax.ragged_dot(xs, w3, gs)
-            inner = jax.nn.silu(h1) * h3
-            orows = jax.lax.ragged_dot(inner, w2, gs)
+            mesh = getattr(self, "_mesh", None)
+            tp = mesh.shape.get("tp", 1) if mesh is not None else 1
+            if tp > 1 and self.expert_parallel:
+                orows = self._ragged_ep(xs, gs, w1, w3, w2, mesh, tp)
+            elif tp > 1:
+                orows = self._ragged_tp(xs, gs, w1, w3, w2, mesh)
+            else:
+                h1 = jax.lax.ragged_dot(xs, w1, gs)
+                h3 = jax.lax.ragged_dot(xs, w3, gs)
+                inner = jax.nn.silu(h1) * h3
+                orows = jax.lax.ragged_dot(inner, w2, gs)
 
         # f32 accumulation for the k-way combine: the dense oracle's
         # einsum promotes the combine matrix to f32, so a bf16

@@ -226,6 +226,7 @@ def kv_flush(
     kernel = functools.partial(_kernel, page_size=page_size, npt=npt)
     out = pl.pallas_call(
         kernel,
+        name="vdt_kv_flush",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(s,),

@@ -542,6 +542,7 @@ def paged_attention(
     ]
     out_bd = pl.pallas_call(
         kernel,
+        name="vdt_paged_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
             grid=grid,

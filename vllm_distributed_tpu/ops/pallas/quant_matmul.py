@@ -153,6 +153,7 @@ def int4_matmul(
     )
     return pl.pallas_call(
         kernel,
+        name="vdt_int4_matmul",
         grid=(out_dim // block_out,),
         in_specs=[
             pl.BlockSpec((t, in_dim), lambda j: (0, 0)),
@@ -195,6 +196,7 @@ def int8_matmul(
     kernel = functools.partial(_kernel, out_dtype=x.dtype)
     return pl.pallas_call(
         kernel,
+        name="vdt_int8_matmul",
         grid=(out_dim // block_out,),
         in_specs=[
             pl.BlockSpec((t, in_dim), lambda j: (0, 0)),

@@ -29,6 +29,12 @@ Design rules:
   every completed local span, so the per-stage Prometheus histograms
   and the traces can never disagree.
 
+- **Spans on the profiler's clock.**  While ``POST /debug/profile``
+  captures (it sets ``Tracer.profiling``), entering a span also enters
+  a ``jax.profiler.TraceAnnotation`` of the same name, so the span is
+  in the trace's host plane beside the device plane: the one clock on
+  which a device gap can be laid against what the host was doing.
+
 Config: ``ObservabilityConfig.enable_tracing`` (CLI ``--enable-tracing``)
 or ``VDT_TRACING=1``; ring size via ``VDT_TRACE_RING_SIZE``.
 """
@@ -114,6 +120,7 @@ class Span:
         "_t0",
         "_token",
         "_record",
+        "_annotation",
     )
 
     def __init__(
@@ -138,6 +145,7 @@ class Span:
         self._t0 = time.monotonic()
         self._token: contextvars.Token | None = None
         self._record = record
+        self._annotation = None
 
     @property
     def ctx(self) -> TraceContext:
@@ -148,6 +156,10 @@ class Span:
 
     def __enter__(self) -> "Span":
         self._token = _current.set(self.ctx)
+        if self._tracer.profiling:
+            self._annotation = _annotate(
+                self.name, self.attributes.get("step_id")
+            )
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
@@ -160,6 +172,9 @@ class Span:
         if self.duration is not None:
             return  # already ended
         self.duration = time.monotonic() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+            self._annotation = None
         if self._token is not None:
             _current.reset(self._token)
             self._token = None
@@ -178,6 +193,20 @@ class Span:
             "duration": self.duration,
             "attributes": self.attributes,
         }
+
+
+def _annotate(name: str, step_id):
+    """Enter a ``jax.profiler.TraceAnnotation`` (only ever reached
+    while a capture runs, so jax is imported already)."""
+    from jax.profiler import TraceAnnotation
+
+    annotation = (
+        TraceAnnotation(name)
+        if step_id is None
+        else TraceAnnotation(name, step_id=step_id)
+    )
+    annotation.__enter__()
+    return annotation
 
 
 class _Trace:
@@ -206,6 +235,9 @@ class Tracer:
 
     def __init__(self) -> None:
         self.enabled = False
+        # True while /debug/profile captures: spans then also enter a
+        # TraceAnnotation (see Span.__enter__).
+        self.profiling = False
         self.host = "driver"
         self._lock = threading.Lock()
         self._active: dict[str, _Trace] = {}

@@ -36,6 +36,7 @@ from vllm_distributed_tpu.config import EngineConfig
 from vllm_distributed_tpu.engine.scheduler import SchedulerOutput
 from vllm_distributed_tpu.logger import init_logger
 from vllm_distributed_tpu.model_loader import get_model
+from vllm_distributed_tpu.models.common import SCOPE_KV_WRITE, SCOPE_SAMPLER
 from vllm_distributed_tpu.ops import resolve_kernel_backend
 from vllm_distributed_tpu.ops.attention import (
     AttentionMetadata,
@@ -48,6 +49,7 @@ from vllm_distributed_tpu.ops.sampling import (
 )
 from vllm_distributed_tpu.outputs import ModelRunnerOutput
 from vllm_distributed_tpu.sampling_params import SamplingParams
+from vllm_distributed_tpu.tracing import NOOP_SPAN, current_ctx, get_tracer
 from vllm_distributed_tpu.utils import cdiv, next_power_of_2
 from vllm_distributed_tpu.worker.telemetry import (
     DeviceTelemetry,
@@ -133,6 +135,24 @@ def _needs_penalties(sp: SamplingParams) -> bool:
         or sp.presence_penalty != 0.0
         or sp.frequency_penalty != 0.0
     )
+
+
+def program_name(kind: str, statics: dict, **dims: int) -> str:
+    """The stable name of one step program: its kind and the padded
+    sizes that pick it, ``vdt_decode_k8_s32_p256``.  XLA calls the
+    module ``jit_<name>``, which is how a profile, the benchmark's
+    readers and the ``runner.dispatch`` spans tell programs apart.  The
+    sampling flags that are on make a program of their own and a
+    suffix."""
+    name = f"vdt_{kind}_" + "_".join(f"{k}{v}" for k, v in dims.items())
+    for flag, suffix in (
+        ("do_penalties", "_pen"),
+        ("do_top_k_p", "_topkp"),
+        ("return_logprobs", "_lp"),
+    ):
+        if statics.get(flag):
+            name += suffix
+    return name
 
 
 class ModelRunner:
@@ -331,21 +351,68 @@ class ModelRunner:
             self._peak_bw = 0.0
 
     # ---- device telemetry helpers (ISSUE 12) ----
-    def _observed_call(self, kind: str, shape_key: str, fn):
-        """Run one jitted step program.  The FIRST execution of each
-        distinct (kind, shape) key is timed and recorded as an XLA
-        compile (trace+lower+compile dominate that call); later calls
-        are passthrough.  AOT-cache hits still count: a warm artifact
-        load is exactly the stall class the counter tracks, just
-        cheaper — the histogram shows the difference."""
-        key = f"{kind}:{shape_key}"
-        if key in self._compiled_keys:
-            return fn()
-        t0 = time.perf_counter()
-        out = fn()
-        self._compiled_keys.add(key)
-        self.telemetry.record_compile(kind, time.perf_counter() - t0, key)
-        return out
+    def _run_program(
+        self,
+        kind: str,
+        method: str,
+        name: str,
+        statics: dict,
+        args: tuple,
+        so: SchedulerOutput,
+        **counts: int,
+    ):
+        """Run one step program, ``method`` of this class with
+        ``statics`` baked in, under its stable ``name``: through the AOT
+        cache where that is armed, else through the method's own jit
+        (which XLA then names after the method: the name stays the
+        span's).  The call is a ``runner.dispatch`` span.
+
+        The FIRST execution of each distinct (kind, statics) key is
+        timed and recorded as an XLA compile (trace+lower+compile
+        dominate that call); later calls are passthrough.  AOT-cache
+        hits still count: a warm artifact load is exactly the stall
+        class the counter tracks, just cheaper — the histogram shows
+        the difference."""
+        shape_key = f"{sorted(statics.items())}"
+        key = f"{kind}:{name}:{shape_key}"
+        first_call = key not in self._compiled_keys
+
+        def run():
+            if self._aot.enabled:
+                return self._aot.call(
+                    f"{method}:{shape_key}",
+                    partial(
+                        getattr(type(self), method).__wrapped__,
+                        self,
+                        **statics,
+                    ),
+                    args,
+                    donate_args=(1,),
+                    name=name,
+                )
+            return getattr(self, method)(*args, **statics)
+
+        tracer = get_tracer()
+        attrs = (
+            dict(
+                step_id=so.step_id,
+                program=name,
+                first_call=first_call,
+                **counts,
+            )
+            if tracer.enabled
+            else {}
+        )
+        with tracer.span("runner.dispatch", **attrs):
+            if not first_call:
+                return run()
+            t0 = time.perf_counter()
+            out = run()
+            self._compiled_keys.add(key)
+            self.telemetry.record_compile(
+                kind, time.perf_counter() - t0, key
+            )
+            return out
 
     def _record_step_bw(
         self, seconds: float, kv_tokens: int, passes: int = 1
@@ -1115,6 +1182,14 @@ class ModelRunner:
 
     # ---- the step ----
     def execute_model(self, so: SchedulerOutput) -> ModelRunnerOutput:
+        # ``runner.prepare`` runs from here to the step program's
+        # dispatch: each path ends it (``prep.end()``) where its inputs
+        # are on their way to the device; the ``with`` closes it on the
+        # paths that dispatch nothing.
+        with get_tracer().span("runner.prepare", step_id=so.step_id) as prep:
+            return self._execute(so, prep)
+
+    def _execute(self, so: SchedulerOutput, prep) -> ModelRunnerOutput:
         self._apply_scheduler_deltas(so)
         # KV-tier spans land before ANY path may touch their pages
         # (spills before the evicted page is rewritten, restores before
@@ -1123,9 +1198,9 @@ class ModelRunner:
         if so.is_empty:
             return ModelRunnerOutput()
         if so.draft_token_ids:
-            return self._execute_spec_step(so)
+            return self._execute_spec_step(so, prep)
         if so.decode_steps > 1:
-            return self._execute_decode_steps(so)
+            return self._execute_decode_steps(so, prep)
         self._decode_carry = None
 
         order = [c.req_id for c in so.cached_requests] + [
@@ -1199,27 +1274,8 @@ class ModelRunner:
                     packed, NamedSharding(self.mesh, P())
                 )
             statics = dict(spec=pack_spec, max_q_pad=max_q_pad, **flags)
-
-            def _run_step():
-                if self._aot.enabled:
-                    return self._aot.call(
-                        f"step:{sorted(statics.items())}",
-                        partial(
-                            type(self)._jit_step_packed.__wrapped__,
-                            self,
-                            **statics,
-                        ),
-                        (self.params, self.kv_caches, packed),
-                        donate_args=(1,),
-                    )
-                return self._jit_step_packed(
-                    self.params, self.kv_caches, packed, **statics
-                )
-
-            t_step0 = time.perf_counter()
-            sampled, logprobs, self.kv_caches = self._observed_call(
-                "prefill", f"{sorted(statics.items())}", _run_step
-            )
+            method = "_jit_step_packed"
+            args = (self.params, self.kv_caches, packed)
         else:
             meta = AttentionMetadata(
                 q_seq_ids=jnp.asarray(seq_ids),
@@ -1236,31 +1292,35 @@ class ModelRunner:
             token_ids = jax.device_put(token_ids, spec)
             meta = jax.tree.map(lambda x: jax.device_put(x, spec), meta)
             smeta = jax.tree.map(lambda x: jax.device_put(x, spec), smeta)
-            t_step0 = time.perf_counter()
-            sampled, logprobs, self.kv_caches = self._observed_call(
-                "prefill",
-                f"t={t_pad},s={s_pad},p={pages_pad},q={max_q_pad},"
-                f"{sorted(flags.items())}",
-                lambda: self._jit_step(
-                    self.params,
-                    self.kv_caches,
-                    token_ids,
-                    meta,
-                    smeta,
-                    max_q_pad=max_q_pad,
-                    **flags,
-                ),
-            )
+            statics = dict(max_q_pad=max_q_pad, **flags)
+            method = "_jit_step"
+            args = (self.params, self.kv_caches, token_ids, meta, smeta)
 
-        if logprobs is not None:
-            sampled, logprobs = jax.device_get((sampled, logprobs))
-            sampled = np.asarray(sampled)
-            logprobs = np.asarray(logprobs)
-        else:
-            sampled = np.asarray(jax.device_get(sampled))
-        self._record_step_bw(
-            time.perf_counter() - t_step0, int(seq_lens.sum())
+        kv_tokens = int(seq_lens.sum())
+        prep.end()
+        t_step0 = time.perf_counter()
+        sampled, logprobs, self.kv_caches = self._run_program(
+            "prefill",
+            method,
+            program_name(
+                "step", flags, t=t_pad, q=max_q_pad, s=s_pad, p=pages_pad
+            ),
+            statics,
+            args,
+            so,
+            seqs=s_real,
+            tokens=t_real,
+            kv_tokens=kv_tokens,
         )
+
+        with get_tracer().span("runner.fetch", step_id=so.step_id):
+            if logprobs is not None:
+                sampled, logprobs = jax.device_get((sampled, logprobs))
+                sampled = np.asarray(sampled)
+                logprobs = np.asarray(logprobs)
+            else:
+                sampled = np.asarray(jax.device_get(sampled))
+        self._record_step_bw(time.perf_counter() - t_step0, kv_tokens)
 
         out = ModelRunnerOutput()
         # Restore-bearing steps are always admission (blocking) steps,
@@ -1388,13 +1448,14 @@ class ModelRunner:
             attn_fn=attn_fn,
             kv_write_fn=self._kv_write_fn,
         )
-        tokens, logprobs = sample(
-            logits,
-            smeta,
-            do_penalties=do_penalties,
-            do_top_k_p=do_top_k_p,
-            return_logprobs=return_logprobs,
-        )
+        with jax.named_scope(SCOPE_SAMPLER):
+            tokens, logprobs = sample(
+                logits,
+                smeta,
+                do_penalties=do_penalties,
+                do_top_k_p=do_top_k_p,
+                return_logprobs=return_logprobs,
+            )
         return tokens, logprobs, kv_caches
 
     @partial(
@@ -1494,7 +1555,9 @@ class ModelRunner:
             2,
         )
 
-    def _execute_spec_step(self, so: SchedulerOutput) -> ModelRunnerOutput:
+    def _execute_spec_step(
+        self, so: SchedulerOutput, prep=NOOP_SPAN
+    ) -> ModelRunnerOutput:
         """Verify every request's drafted tokens in ONE fused dispatch
         (ISSUE 11): feed ``[input token, d_1..d_d]`` per sequence
         through the single-pass forward (teacher-forced; causal within
@@ -1577,33 +1640,27 @@ class ModelRunner:
         if self.mesh is not None:
             packed = jax.device_put(packed, NamedSharding(self.mesh, P()))
         statics = dict(spec=pack_spec, max_q_pad=max_q_pad)
-
-        def _run_spec():
-            if self._aot.enabled:
-                return self._aot.call(
-                    f"spec_step:{sorted(statics.items())}",
-                    partial(
-                        type(self)._jit_spec_step.__wrapped__,
-                        self,
-                        **statics,
-                    ),
-                    (self.params, self.kv_caches, packed),
-                    donate_args=(1,),
-                )
-            return self._jit_spec_step(
-                self.params, self.kv_caches, packed, **statics
-            )
-
+        kv_tokens = int(seq_lens.sum())
+        prep.end()
         t_step0 = time.perf_counter()
-        toks, n_emit, self.kv_caches = self._observed_call(
-            "spec", f"{sorted(statics.items())}", _run_spec
+        toks, n_emit, self.kv_caches = self._run_program(
+            "spec",
+            "_jit_spec_step",
+            program_name(
+                "spec", statics, t=t_pad, q=kp1, s=s_pad, p=pages_pad
+            ),
+            statics,
+            (self.params, self.kv_caches, packed),
+            so,
+            seqs=s_real,
+            tokens=t_real,
+            kv_tokens=kv_tokens,
         )
-        toks, n_emit = jax.device_get((toks, n_emit))
+        with get_tracer().span("runner.fetch", step_id=so.step_id):
+            toks, n_emit = jax.device_get((toks, n_emit))
         # A verify window streams weights+KV ONCE for up to K+1 tokens —
         # the roofline asymmetry spec decode exists to exploit.
-        self._record_step_bw(
-            time.perf_counter() - t_step0, int(seq_lens.sum())
-        )
+        self._record_step_bw(time.perf_counter() - t_step0, kv_tokens)
         toks = np.asarray(toks)
         n_emit = np.asarray(n_emit)
 
@@ -1658,13 +1715,16 @@ class ModelRunner:
             attn_fn=attn_fn,
             kv_write_fn=self._kv_write_fn,
         )
-        toks, n_emit = spec_greedy_accept(
-            logits.reshape(s_pad, kp1, -1), draft_mat, n_drafts
-        )
+        with jax.named_scope(SCOPE_SAMPLER):
+            toks, n_emit = spec_greedy_accept(
+                logits.reshape(s_pad, kp1, -1), draft_mat, n_drafts
+            )
         return toks, n_emit, kv_caches
 
     # ---- fused multi-step decode (SchedulerOutput.decode_steps > 1) ----
-    def _execute_decode_steps(self, so: SchedulerOutput) -> ModelRunnerOutput:
+    def _execute_decode_steps(
+        self, so: SchedulerOutput, prep=NOOP_SPAN
+    ) -> ModelRunnerOutput:
         """Run `so.decode_steps` decode micro-steps in ONE device dispatch
         (a lax.scan feeding each sampled token back in).  Amortizes the
         host round trip the reference pays per scheduler step
@@ -1766,33 +1826,35 @@ class ModelRunner:
             do_penalties=flags["do_penalties"],
             do_top_k_p=flags["do_top_k_p"],
         )
-        def _run_decode():
-            if self._aot.enabled:
-                return self._aot.call(
-                    f"decode_steps:{sorted(statics.items())}",
-                    partial(
-                        type(self)._jit_decode_steps.__wrapped__,
-                        self,
-                        **statics,
-                    ),
-                    (self.params, self.kv_caches, packed, carry_tok),
-                    donate_args=(1,),
-                )
-            return self._jit_decode_steps(
-                self.params, self.kv_caches, packed, carry_tok, **statics
-            )
-
+        kv_tokens_scanned = int(base_lens.sum())
+        prep.end()
         t_step0 = time.perf_counter()
-        toks, carry_out, self.kv_caches = self._observed_call(
-            "decode", f"{sorted(statics.items())}", _run_decode
+        toks, carry_out, self.kv_caches = self._run_program(
+            "decode",
+            "_jit_decode_steps",
+            program_name(
+                "decode", statics, k=k_steps, s=s_pad, p=pages_pad
+            ),
+            statics,
+            (self.params, self.kv_caches, packed, carry_tok),
+            so,
+            seqs=s_real,
+            tokens=int(n_active.sum()),
+            kv_tokens=kv_tokens_scanned,
         )
         # Each sequence's LAST VALID token stays on device as the next
         # dispatch's input (under-K tails: token n_active-1, not K-1).
         self._decode_carry = (order, base_lens + n_active, carry_out)
-        kv_tokens_scanned = int(base_lens.sum())
+        # resolve() may run on the executor's resolver thread, which
+        # has no span context of its own: the fetch stays a child of
+        # the step that dispatched it.
+        step_ctx = current_ctx()
 
         def resolve() -> ModelRunnerOutput:
-            host_toks = np.asarray(jax.device_get(toks))  # [K, s_pad]
+            with get_tracer().span(
+                "runner.fetch", parent=step_ctx, step_id=so.step_id
+            ):
+                host_toks = np.asarray(jax.device_get(toks))  # [K, s_pad]
             # K micro-steps = K weights+KV HBM passes.  Wall time spans
             # dispatch→resolve (includes any pipeline overlap — an
             # estimate, like the byte count).
@@ -1965,13 +2027,14 @@ class ModelRunner:
                     attn_fn=attn_fn,
                     kv_write_fn=self._kv_write_fn,
                 )
-            new_tok, _ = sample(
-                logits,
-                smeta,
-                do_penalties=do_penalties,
-                do_top_k_p=do_top_k_p,
-                return_logprobs=False,
-            )
+            with jax.named_scope(SCOPE_SAMPLER):
+                new_tok, _ = sample(
+                    logits,
+                    smeta,
+                    do_penalties=do_penalties,
+                    do_top_k_p=do_top_k_p,
+                    return_logprobs=False,
+                )
             if do_penalties:
                 # Masked rows scatter out of bounds (dropped).
                 out_buf = out_buf.at[
@@ -2003,12 +2066,13 @@ class ModelRunner:
         if staged:
             # Per-sequence flush lengths: under-K tails staged only
             # n_active rows; columns past that are garbage.
-            kv_caches = [
-                self._kv_flush_fn(
-                    kv_l, side_l, block_tables, base_valid, n_active
-                )
-                for kv_l, side_l in zip(kv_caches, sides_out)
-            ]
+            with jax.named_scope(SCOPE_KV_WRITE):
+                kv_caches = [
+                    self._kv_flush_fn(
+                        kv_l, side_l, block_tables, base_valid, n_active
+                    )
+                    for kv_l, side_l in zip(kv_caches, sides_out)
+                ]
         # Next dispatch's input token: each sequence's last VALID one.
         carry_out = toks[
             jnp.clip(n_active - 1, 0, k_steps - 1), rows
