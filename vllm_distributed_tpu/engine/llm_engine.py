@@ -137,6 +137,7 @@ class LLMEngine:
         self._telemetry_compiles_seen: dict[str, int] = {}
         self._telemetry_fallbacks_seen: dict[str, int] = {}
         self._telemetry_cache_seen: dict[str, int] = {}
+        self._telemetry_moe_grouped_seen = 0
 
         # Disaggregated prefill/decode hand-off (ISSUE 15): export holds
         # + inbound transfers.  Always constructed (cheap, idle costs
@@ -499,6 +500,12 @@ class LLMEngine:
                 if total > seen:
                     record(str(kind), total - seen)
                     seen_by[kind] = total
+        grouped = int(snap.get("moe_grouped_steps") or 0)
+        if grouped > self._telemetry_moe_grouped_seen:
+            self.metrics.record_moe_grouped_steps(
+                grouped - self._telemetry_moe_grouped_seen
+            )
+            self._telemetry_moe_grouped_seen = grouped
         self.metrics.record_device_snapshot(snap)
         return snap
 

@@ -152,6 +152,7 @@ DOCUMENTED_METRICS = (
     "vllm:step_roofline_frac",
     "vllm:worker_fallbacks_total",
     "vllm:xla_persistent_cache_events_total",
+    "vllm:moe_grouped_steps_total",
     # ---- fleet sentinel (ISSUE 20) ----
     "vllm:slo_burn_rate",
     "vllm:itl_p99_ms",
@@ -556,6 +557,13 @@ class EngineMetrics:
             ["model_name", "event"],
             registry=self.registry,
         )
+        self.moe_grouped_steps = counter(
+            "vllm:moe_grouped_steps",
+            "Step programs dispatched on the reply-rank worker whose "
+            "int8 experts ran the grouped (routed) kernel: many-row "
+            "steps of an MoE model on one chip; a fused decode scan "
+            "never counts",
+        )
         # ---- fleet sentinel (ISSUE 20) ----
         self._slo_burn = Gauge(
             "vllm:slo_burn_rate",
@@ -857,6 +865,10 @@ class EngineMetrics:
             self._worker_fallbacks.labels(
                 model_name=self._model_name, kind=kind
             ).inc(n)
+
+    def record_moe_grouped_steps(self, n: int) -> None:
+        if self.enabled and n > 0:
+            self.moe_grouped_steps.inc(n)
 
     def record_persistent_cache_events(self, event: str, n: int) -> None:
         if self.enabled and n > 0:

@@ -281,8 +281,11 @@ class LlamaForCausalLM(SupportsQuantization):
             v.reshape(t, nkv, d),
         )
 
-    def _mlp(self, h: jax.Array, layer: dict) -> jax.Array:
-        """Post-attention MLP for one layer (overridden by MoE models)."""
+    def _mlp(
+        self, h: jax.Array, layer: dict, live: jax.Array | None = None
+    ) -> jax.Array:
+        """Post-attention MLP for one layer (overridden by MoE models,
+        which may skip the rows outside ``live``: a step's padding)."""
         wgu = layer.get("wgu")
         if wgu is not None:
             gu = linear(h, wgu)
@@ -312,6 +315,9 @@ class LlamaForCausalLM(SupportsQuantization):
         )
         new_kv = []
         t = token_ids.shape[0]
+        # Padding rows point one past the last sequence row (the
+        # runner's convention); nothing reads what they compute.
+        live = meta.q_seq_ids < meta.seq_lens.shape[0]
         for layer, kv_pages in zip(params["layers"], kv_caches):
             with jax.named_scope(SCOPE_ATTN_QKV):
                 h = rms_norm(x, layer["input_ln"], self.rms_eps)
@@ -334,7 +340,7 @@ class LlamaForCausalLM(SupportsQuantization):
 
             with jax.named_scope(SCOPE_MLP):
                 h = rms_norm(x, layer["post_attn_ln"], self.rms_eps)
-                x = x + self._mlp(h, layer)
+                x = x + self._mlp(h, layer, live)
 
         with jax.named_scope(SCOPE_LM_HEAD):
             x = rms_norm(x, params["norm"], self.rms_eps)

@@ -20,6 +20,10 @@ lowering of ragged_dot is verified truly grouped (cost-analysis flops ==
 2·M·H·I, checked on-chip by bench._check_kernels); token drop/capacity
 factors are never used — inference must match the reference exactly.
 
+Int8 experts on one chip take the same sorted dispatch through a
+Pallas kernel that streams the int8 tiles (ops/pallas/grouped_matmul)
+in many-row steps; ``select_moe_impl`` below has the rule.
+
 VDT_MOE_IMPL=dense keeps the GShard-style dense dispatch (every expert
 on every token, a [T, E] combine matrix) as the correctness oracle:
 
@@ -52,6 +56,56 @@ from vllm_distributed_tpu.models.common import (
     SCOPE_MOE_ROUTER,
 )
 from vllm_distributed_tpu.models.llama import LlamaForCausalLM
+
+# Routed rows (tokens × top_k) above which int8 experts on one chip run
+# the grouped kernel instead of the dense einsum.  Below it the step is
+# bound by reading the experts' weights, which both do once, and the
+# grouped path pads every expert to a 128-row tile: nothing to win.
+# Fixed from a one-layer sweep on the v5e at Mixtral's widths (PERF.md,
+# PR 27).
+GROUPED_MIN_ROWS = 256
+
+
+def select_moe_impl(requested: str, rows: int, w1, on_mesh: bool) -> str:
+    """"dense" | "ragged" | "grouped" for one expert layer, from
+    VDT_MOE_IMPL (``requested``), the routed rows of the step being
+    traced and the expert tensor itself.
+
+    ``auto``: int8 experts stamped for the Pallas backend, on one chip,
+    in a step of more than GROUPED_MIN_ROWS routed rows (prefill, a
+    mixed step, a spec-verify step) take the grouped int8 kernel: such
+    a step is compute-bound and the dense einsum spends E/k times the
+    model's FLOPs there.  Every other quantized case stays dense: decode
+    is weight-BANDWIDTH-bound, where XLA fuses the dequant into the
+    einsum so the compressed bytes stream once (93% of the HBM roofline
+    at batch 31, PERF.md), and ``ragged_dot`` cannot fuse a producer: it
+    materializes the bf16 expert stack per call (4.4x slower end to end
+    at batch 32, BENCH_r05) — a statement about ragged_dot, not about
+    routing.  Unquantized experts route through ragged_dot on big row
+    counts.  ``ragged`` on a tensor the grouped kernel takes means that
+    kernel; ``dense`` is always the dense einsum."""
+    from vllm_distributed_tpu.ops.pallas import grouped_matmul as gm
+    from vllm_distributed_tpu.ops.quant import QuantizedTensor
+
+    quantized = isinstance(w1, QuantizedTensor)
+    grouped_ok = (
+        quantized
+        and w1.bits == 8
+        and w1.matmul in ("pallas", "pallas_interpret")
+        and not on_mesh
+        and w1.mesh is None
+        and (
+            w1.matmul == "pallas_interpret"
+            or gm.has_tiles(w1.shape[-2], w1.shape[-1])
+        )
+    )
+    if requested == "auto":
+        if grouped_ok and rows > GROUPED_MIN_ROWS:
+            return "grouped"
+        return "dense" if (quantized or rows <= 256) else "ragged"
+    if requested == "ragged" and grouped_ok:
+        return "grouped"
+    return requested
 
 
 class MixtralForCausalLM(LlamaForCausalLM):
@@ -288,31 +342,33 @@ class MixtralForCausalLM(LlamaForCausalLM):
 
         return envs.VDT_MOE_IMPL
 
-    def _mlp(self, h: jax.Array, layer: dict) -> jax.Array:
-        impl = self._moe_impl()
-        if impl == "auto":
-            # Measured on v5e (BENCH_r05 moe config + PERF.md): decode
-            # is weight-BANDWIDTH-bound, where the dense einsum wins —
-            # XLA fuses the int8 dequant into the dot so the compressed
-            # bytes stream once, while ragged_dot cannot fuse a
-            # producer and materializes the bf16 expert stack per call
-            # (4.4x slower end-to-end at batch 32).  The ragged path's
-            # k/E FLOP saving only pays on big COMPUTE-bound row
-            # counts with unquantized experts.
-            from vllm_distributed_tpu.ops.quant import QuantizedTensor
+    def moe_impl(self, layer: dict, tokens: int) -> str:
+        """What ``_mlp`` traces for a step of ``tokens`` rows over this
+        layer's experts: "dense" | "ragged" | "grouped".  A function of
+        the trace-time shapes and of what the tensor says of itself, so
+        the runner can say which one a dispatched program holds."""
+        return select_moe_impl(
+            self._moe_impl(),
+            tokens * self.top_k,
+            layer["w1"],
+            on_mesh=getattr(self, "_mesh", None) is not None,
+        )
 
-            quantized = isinstance(layer["w1"], QuantizedTensor)
-            rows = h.shape[0] * self.top_k
-            impl = "dense" if (quantized or rows <= 256) else "ragged"
+    def _mlp(
+        self, h: jax.Array, layer: dict, live: jax.Array | None = None
+    ) -> jax.Array:
+        impl = self.moe_impl(layer, h.shape[0])
         if impl == "dense":
             return self._mlp_dense(h, layer)
+        if impl == "grouped":
+            return self._mlp_grouped(h, layer, live)
         return self._mlp_ragged(h, layer)
 
     def _mlp_dense(self, h: jax.Array, layer: dict) -> jax.Array:
         """GShard-style dense dispatch — every expert runs on every
         token, a [T, E] combine matrix (zeros outside the top-k) weights
         the results.  Exact and GSPMD-friendly; the correctness oracle
-        for the ragged path and the fallback for shapes it rejects."""
+        for the routed paths and the fallback for shapes they reject."""
         from vllm_distributed_tpu.ops.quant import maybe_dequantize
 
         t = h.shape[0]
@@ -333,6 +389,26 @@ class MixtralForCausalLM(LlamaForCausalLM):
             inner = jax.nn.silu(h1) * h3
             return jnp.einsum("tei,eih,te->th", inner, w2, combine)
 
+    def _sorted_dispatch(
+        self, h: jax.Array, layer: dict, live: jax.Array | None = None
+    ):
+        """The routed paths' shared first half: route, flatten the T×k
+        assignments and sort them by expert.  Returns (topw [T, k],
+        flat expert ids [T*k], order [T*k]: sorted row -> flat
+        assignment, group sizes [E]).  Rows of tokens outside ``live``
+        (a step's padding) take the id E: they sort last and join no
+        group."""
+        e, k = self.num_experts, self.top_k
+        topw, topi = self._route(h, layer)
+        flat_e = topi.reshape(-1).astype(jnp.int32)  # [T*k]
+        if live is not None:
+            flat_e = jnp.where(jnp.repeat(live, k), flat_e, e)
+        order = jnp.argsort(flat_e)
+        gs = (flat_e[:, None] == jnp.arange(e, dtype=jnp.int32)).sum(
+            axis=0, dtype=jnp.int32
+        )
+        return topw, flat_e, order, gs
+
     def _mlp_ragged(self, h: jax.Array, layer: dict) -> jax.Array:
         """Sorted ragged dispatch (SURVEY §2.5's TPU plan; VERDICT r3
         #4): flatten the T×k assignments, sort rows by expert, run ONE
@@ -352,14 +428,11 @@ class MixtralForCausalLM(LlamaForCausalLM):
         from vllm_distributed_tpu.ops.quant import maybe_dequantize
 
         t = h.shape[0]
-        e, k = self.num_experts, self.top_k
+        k = self.top_k
         with jax.named_scope(SCOPE_MOE_ROUTER):
-            topw, topi = self._route(h, layer)
-            flat_e = topi.reshape(-1).astype(jnp.int32)  # [T*k]
-            order = jnp.argsort(flat_e)
+            topw, _, order, gs = self._sorted_dispatch(h, layer)
             tok = order // k
             xs = h[tok]  # [T*k, H] sorted by expert
-            gs = jnp.bincount(flat_e, length=e).astype(jnp.int32)
             row_w = topw.reshape(-1)[order].astype(h.dtype)  # [T*k]
 
         with jax.named_scope(SCOPE_MOE_EXPERTS):
@@ -387,6 +460,55 @@ class MixtralForCausalLM(LlamaForCausalLM):
             :, None
         ]
         return y.at[tok].add(contrib).astype(h.dtype)
+
+    def _mlp_grouped(
+        self, h: jax.Array, layer: dict, live: jax.Array | None = None
+    ) -> jax.Array:
+        """The sorted dispatch over int8 experts on one chip: the rows
+        go through ops/pallas/grouped_matmul, which streams the int8
+        tiles themselves (no bf16 copy of the stack, which is what
+        ``ragged_dot`` would need).  Each expert's group is padded to
+        whole row tiles, so the gather writes that layout directly and
+        the combine gathers back out of it (a token's k rows, weighted
+        and summed in f32 like the other paths) instead of
+        scatter-adding.  Tokens outside ``live`` join no group and
+        come back as zeros."""
+        from vllm_distributed_tpu.ops.pallas import grouped_matmul as gm
+
+        t = h.shape[0]
+        e, k = self.num_experts, self.top_k
+        m = t * k
+        w1, w3, w2 = layer["w1"], layer["w3"], layer["w2"]
+        interpret = w1.matmul == "pallas_interpret"
+        mp = gm.padded_rows(m, e, gm.BLOCK_ROWS)
+        with jax.named_scope(SCOPE_MOE_ROUTER):
+            topw, flat_e, order, gs = self._sorted_dispatch(h, layer, live)
+            starts, tile_expert, _ = gm.group_layout(gs, mp, gm.BLOCK_ROWS)
+            # sorted row r of expert e sits at padded row r + shift[e]
+            shift = starts - (jnp.cumsum(gs) - gs)
+            row_e = jnp.repeat(tile_expert, gm.BLOCK_ROWS)
+            src = jnp.arange(mp, dtype=jnp.int32) - shift[row_e]
+            xs = h[order[jnp.clip(src, 0, m - 1)] // k]  # [MP, H]
+            # where each assignment's row went: its rank among the
+            # sorted rows, moved into its expert's padded range
+            rank = jnp.argsort(order).astype(jnp.int32)
+            pos = rank + shift[jnp.minimum(flat_e, e - 1)]
+            pos = jnp.minimum(pos, mp - 1).reshape(t, k)
+
+        with jax.named_scope(SCOPE_MOE_EXPERTS):
+            inner = gm.grouped_int8_matmul(
+                xs, w1.q, w1.scale, gs, q_up=w3.q, scale_up=w3.scale,
+                interpret=interpret,
+            )
+            orows = gm.grouped_int8_matmul(
+                inner, w2.q, w2.scale, gs, interpret=interpret
+            )
+
+        w = topw.astype(h.dtype).astype(jnp.float32)[..., None]
+        y = (orows[pos].astype(jnp.float32) * w).sum(axis=1)
+        if live is not None:
+            y = jnp.where(live[:, None], y, 0.0)
+        return y.astype(h.dtype)
 
     def _ragged_ep(self, xs, gs, w1, w3, w2, mesh, tp):
         """EP shard_map: each device's local experts own a contiguous

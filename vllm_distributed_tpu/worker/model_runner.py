@@ -359,13 +359,17 @@ class ModelRunner:
         statics: dict,
         args: tuple,
         so: SchedulerOutput,
+        rows: int,
         **counts: int,
     ):
         """Run one step program, ``method`` of this class with
         ``statics`` baked in, under its stable ``name``: through the AOT
         cache where that is armed, else through the method's own jit
         (which XLA then names after the method: the name stays the
-        span's).  The call is a ``runner.dispatch`` span.
+        span's).  The call is a ``runner.dispatch`` span; ``rows`` is
+        the padded token rows the program's layers see, from which an
+        MoE model says what its experts were traced with (the span's
+        ``moe``).
 
         The FIRST execution of each distinct (kind, statics) key is
         timed and recorded as an XLA compile (trace+lower+compile
@@ -392,6 +396,11 @@ class ModelRunner:
                 )
             return getattr(self, method)(*args, **statics)
 
+        moe_impl = getattr(self.model, "moe_impl", None)
+        if moe_impl is not None:
+            counts["moe"] = moe_impl(self.params["layers"][0], rows)
+            if counts["moe"] == "grouped":
+                self.telemetry.record_moe_grouped_step()
         tracer = get_tracer()
         attrs = (
             dict(
@@ -1308,6 +1317,7 @@ class ModelRunner:
             statics,
             args,
             so,
+            rows=t_pad,
             seqs=s_real,
             tokens=t_real,
             kv_tokens=kv_tokens,
@@ -1652,6 +1662,7 @@ class ModelRunner:
             statics,
             (self.params, self.kv_caches, packed),
             so,
+            rows=t_pad,
             seqs=s_real,
             tokens=t_real,
             kv_tokens=kv_tokens,
@@ -1838,6 +1849,7 @@ class ModelRunner:
             statics,
             (self.params, self.kv_caches, packed, carry_tok),
             so,
+            rows=s_pad,
             seqs=s_real,
             tokens=int(n_active.sum()),
             kv_tokens=kv_tokens_scanned,

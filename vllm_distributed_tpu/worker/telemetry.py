@@ -20,6 +20,9 @@ runner writes:
   was asked to use (an unusable or unexportable AOT artifact, a compile
   cache directory it could not create), by kind — semantically safe,
   but a run that claims a warm start must show zero;
+- **grouped MoE steps**: step programs dispatched whose int8 experts
+  were traced with the grouped kernel (models/mixtral.select_moe_impl),
+  so how often the routed path engages is a counter, not an inference;
 - **persistent cache**: JAX's own compilation-cache events for this
   process (lookups, hits, and entries written after a miss), so a warm
   restart is judged by what the cache reports, not by a time threshold.
@@ -120,11 +123,18 @@ class DeviceTelemetry:
         self.last_step_bytes = 0
         self.roofline_frac = 0.0
         self.fallbacks: dict[str, int] = {}
+        self.moe_grouped_steps = 0
         _install_cache_listener()
 
     def record_fallback(self, kind: str) -> None:
         with self._lock:
             self.fallbacks[kind] = self.fallbacks.get(kind, 0) + 1
+
+    def record_moe_grouped_step(self) -> None:
+        """One dispatched step program whose int8 experts run the
+        grouped (routed) kernel; a fused decode scan never is one."""
+        with self._lock:
+            self.moe_grouped_steps += 1
 
     def record_compile(self, kind: str, seconds: float, key: str) -> None:
         with self._lock:
@@ -170,5 +180,6 @@ class DeviceTelemetry:
                 "last_step_bytes": self.last_step_bytes,
                 "roofline_frac": self.roofline_frac,
                 "fallbacks": dict(self.fallbacks),
+                "moe_grouped_steps": self.moe_grouped_steps,
                 "persistent_cache": persistent_cache_counts(),
             }
