@@ -302,6 +302,30 @@ def test_grouped_steps_counter_rises_once_a_grouped_step_and_never_in_a_scan(
     assert dense["grouped_steps"] == dense["counter"] == 0
 
 
+def test_single_step_dispatch_span_counts_the_query_tiles(served):
+    """PR 30: a single step's span says how many query tiles the
+    attention kernel walked and how many rows they compute; a fused
+    scan's span does not; no program gained a key."""
+    import re
+
+    auto = served["auto"]["dispatches"]
+    singles = [a for a in auto if a["program"].startswith("vdt_step_")]
+    scans = [a for a in auto if a["program"].startswith("vdt_decode_")]
+    assert singles and scans
+    assert all("q_tiles" not in a and "q_rows" not in a for a in scans)
+    for a in singles:
+        assert re.fullmatch(r"vdt_step_t\d+_q\d+_s\d+_p\d+", a["program"])
+        assert 1 <= a["q_tiles"] and a["tokens"] <= a["q_rows"]
+    prefill = singles[0]  # prompts of 200 and 4 tokens, q bucket 256
+    assert prefill["tokens"] == 204 and prefill["seqs"] == 2
+    # at these toy widths a tile holds the whole 256-token bucket, so
+    # two chunks fill the dense grid's rows and no more
+    assert prefill["q_tiles"] == 2 and prefill["q_rows"] == 2 * 256
+    # the decode-only steps after the scans: one tile a sequence
+    assert all(a["q_tiles"] == a["seqs"] for a in singles
+               if a["tokens"] == a["seqs"])
+
+
 def test_a_dense_model_has_no_moe_on_its_dispatch_span(tmp_path):
     from tests.utils import make_tiny_llama
 
@@ -321,6 +345,8 @@ def test_a_dense_model_has_no_moe_on_its_dispatch_span(tmp_path):
         spans = [s for trace in tracer.snapshot() for s in trace["spans"]
                  if s["name"] == "runner.dispatch"]
         assert spans and all("moe" not in s["attributes"] for s in spans)
+        # nor query tiles: the reference backend has no kernel
+        assert all("q_tiles" not in s["attributes"] for s in spans)
         assert engine.executor.worker.runner.telemetry.moe_grouped_steps == 0
     finally:
         engine.shutdown()
@@ -379,3 +405,51 @@ def test_kernel_compiles_for_the_v5e_at_mixtral_widths(
         shaped((e,), jnp.int32)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "vdt_grouped_int8_matmul" in text
+
+
+@pytest.mark.parametrize("t,max_q,s,side,quant", [
+    (2048, 2048, 32, False, False),  # the cell's largest mixed step
+    (256, 128, 32, False, False),
+    (64, 1, 64, True, False),  # the fused scan's call
+    (512, 4, 64, False, False),  # a verify step: one call, dense list
+    (2048, 1024, 32, False, True),  # the int8 pool's scale blocks
+])
+def test_attention_compiles_for_the_v5e_at_mixtral_widths(
+        t, max_q, s, side, quant, one_chip, no_compile_cache):
+    """PR 30's tile-list kernel, both geometries, at Hq 32 / Hkv 8 /
+    D 128 over a 256-page table: Mosaic takes it, a chunk step is two
+    kernel calls (chunk tiles, single tokens), everything else one, and
+    no XLA loop comes with the lists."""
+    from vllm_distributed_tpu.ops.attention import AttentionMetadata
+    from vllm_distributed_tpu.ops.pallas.paged_attention import (
+        paged_attention,
+    )
+
+    hq, hkv, d, page, pages, pool = 32, 8, 128, 16, 256, 8192
+
+    def shaped(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    meta = AttentionMetadata(
+        q_seq_ids=shaped((t,)), q_positions=shaped((t,)),
+        slot_mapping=shaped((t,)), block_tables=shaped((s, pages)),
+        seq_lens=shaped((s,)), logits_indices=shaped((s,)),
+        chunk_starts=shaped((s,)))
+    kv = shaped((2, pool, page, hkv * d), jnp.int8 if quant else jnp.bfloat16)
+    if quant:
+        kv = (kv, shaped((2, pool, page, hkv), jnp.float32))
+    args = [shaped((t, hq, d), jnp.bfloat16), kv, meta]
+    if side:
+        args += [shaped((s, 2, 8, hkv * d), jnp.bfloat16), shaped((1,))]
+
+    def call(q, kv, meta, *staged):
+        kw = dict(side_kv=staged[0], side_len=staged[1]) if staged else {}
+        return paged_attention(
+            q, kv, meta, scale=0.088, num_kv_heads=hkv, max_q=max_q, **kw)
+
+    compiled = jax.jit(call).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == (2 if max_q >= 16 else 1)
+    assert " while(" not in text and " conditional(" not in text
+    # the staging is packed: at t 2048 the dense grid alone was 537 MB
+    assert compiled.memory_analysis().temp_size_in_bytes < 160e6

@@ -96,6 +96,9 @@ def _compare(case, scale=0.125, atol=2e-5):
         rtol=1e-4,
         atol=atol,
     )
+    # padding tokens read no tile (one past the live count is never
+    # written): their rows are zeros, whatever that memory holds
+    assert not np.asarray(got[t_real:]).any()
 
 
 def test_pure_decode_batch():
@@ -358,3 +361,234 @@ def test_staged_side_buffer_decode():
         np.asarray(got_pl)[live], np.asarray(want)[live],
         rtol=1e-4, atol=2e-5,
     )
+
+
+# ---- the ragged list of query tiles (PR 30) ------------------------------
+from vllm_distributed_tpu.ops.pallas import paged_attention as pa  # noqa: E402
+
+# At the test widths (hq 4, hkv 2, d 64, f32) the budgets below give
+# query tiles of 32 tokens and kv blocks of 64, so a chunk spans several
+# tiles, a context several blocks, and single tokens leave the chunk
+# tiles for the decode geometry (32 >= _SPLIT_SINGLES_MIN_MQ).
+_SMALL_TILES = dict(_STATE_BYTES=6144 * 32, _KV_BUF_BYTES=64 * 1024)
+
+
+def _decodes(n, rng):
+    return [(int(c), 1) for c in rng.integers(3, 300, size=n)]
+
+
+_RAGGED_CASES = {
+    # 31 decode rows + one chunk at s 32
+    "decodes_and_chunk_q128": dict(
+        specs=lambda rng: _decodes(31, rng) + [(128, 128)], s_pad=32),
+    "decodes_and_chunk_q1024": dict(
+        specs=lambda rng: _decodes(31, rng) + [(1024, 1024)], s_pad=32,
+        tiles=dict(_STATE_BYTES=6144 * 128, _KV_BUF_BYTES=256 * 1024)),
+    # unequal chunks; one continues a prompt and ends mid-tile
+    "unequal_chunks_one_continuation": dict(
+        specs=lambda rng: [(200, 70), (45, 45), (33, 1), (90, 90)]),
+    "empty_sequences_between_live_ones": dict(
+        specs=lambda rng: [(50, 1), (0, 0), (40, 40), (0, 0), (33, 1),
+                           (70, 3)]),
+    "token_padding_rows": dict(
+        specs=lambda rng: [(37, 5), (60, 1), (35, 35)], t_pad=128),
+    "int8_pool_in_a_mixed_step": dict(
+        specs=lambda rng: [(50, 1), (100, 40), (33, 1), (64, 64)],
+        quant=True),
+    # equal q lengths: the list is the dense grid
+    "verify_shape_equal_windows": dict(
+        specs=lambda rng: [(c, 4) for c in (9, 40, 77, 130, 4, 64)]),
+    "equal_long_chunks": dict(
+        specs=lambda rng: [(64, 64), (164, 64), (70, 64)]),
+    "prefill_only_no_singles": dict(
+        specs=lambda rng: [(100, 100), (31, 31)]),
+    "gqa_4_mixed": dict(
+        specs=lambda rng: [(50, 1), (75, 75), (20, 1)], hq=8, hkv=2),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_RAGGED_CASES))
+def test_ragged_query_tiles_match_reference(name, monkeypatch):
+    case = _RAGGED_CASES[name]
+    for key, value in case.get("tiles", _SMALL_TILES).items():
+        monkeypatch.setattr(pa, key, value)
+    rng = np.random.default_rng(sorted(_RAGGED_CASES).index(name))
+    specs = case["specs"](rng)
+    kw = {k: case[k] for k in ("s_pad", "t_pad", "hq", "hkv") if k in case}
+    ctx = sum(-(-c // 16) for c, _ in specs)
+    q, kv, meta, max_q, t_real, hkv = build_case(
+        rng, seq_specs=specs, num_pages=ctx + 2, **kw
+    )
+    if case.get("quant"):
+        from tests.test_kv_int8 import _quantize_pool
+
+        kv = _quantize_pool(kv, hkv)
+    _compare((q, kv, meta, max_q, t_real, hkv))
+
+
+def _walked_blocks(q_lens, ctx_lens, *, s_pad, t_pad, max_q, hq, hkv, d,
+                   blk):
+    """(live (tile, kv block) pairs the lists make the kernel walk,
+    tiles in use, static tiles) through the wrapper's own functions."""
+    hd = hkv * d
+    n = len(q_lens)
+    q_len = np.zeros(s_pad, np.int32)
+    q_len[:n] = q_lens
+    seq_lens = np.zeros(s_pad, np.int32)
+    seq_lens[:n] = ctx_lens
+    chunk_starts = seq_lens - q_len
+    pairs = tiles = static = 0
+    geo_1 = pa._tile_geometry(1, hq, hkv, d, hd)
+    geo_n = pa._tile_geometry(max_q, hq, hkv, d, hd)
+    split = max_q > 1 and pa._splits_singles(geo_n.mq_blk)
+    lists = []
+    if max_q == 1 or split:
+        per = (q_len == 1) if max_q > 1 else (q_len > 0)
+        lists.append((geo_1.mq_blk, per.astype(np.int32), s_pad))
+    if max_q > 1:
+        mq = geo_n.mq_blk
+        per = np.where(q_len > (1 if split else 0), -(-q_len // mq), 0)
+        lists.append(
+            (mq, per, pa.num_query_tiles(t_pad, s_pad, mq, geo_n.maxq)))
+    for mq, per, num_tiles in lists:
+        assert per.sum() <= num_tiles, (per.sum(), num_tiles)
+        (seq, q0, n_live), first = pa.tile_list(
+            jnp.asarray(per, jnp.int32), num_tiles, mq)
+        assert int(n_live[0]) == per.sum()
+        np.testing.assert_array_equal(
+            np.asarray(first), np.cumsum(per) - per)
+        nb = pa.tile_kv_blocks(
+            seq, q0, n_live, jnp.asarray(seq_lens),
+            jnp.asarray(chunk_starts), mq, blk)
+        used = int(n_live[0])
+        assert not np.asarray(nb)[used:].any()  # skipped past the count
+        # and they repeat the last live tile: no block index changes
+        assert used == 0 or (np.asarray(seq)[used:] == int(seq[used - 1])
+                             ).all()
+        pairs += int(nb.sum())
+        tiles += int(per.sum())
+        static += num_tiles
+    return pairs, tiles, static
+
+
+def test_tile_list_walks_the_analytic_number_of_blocks():
+    """Live (tile, kv block) pairs = per sequence, per tile of its
+    chunk, the kv blocks up to the tile's last row (causal), cut at the
+    sequence's length; a single token walks its whole context once."""
+    hq, hkv, d, blk = 32, 8, 128, 256
+    mq = pa._tile_geometry(2048, hq, hkv, d, hkv * d).mq_blk
+    assert mq == 128
+    q_lens = [1] * 31 + [700]
+    ctx_lens = [660] * 31 + [1724]  # the chunk continues a prompt
+    pairs, tiles, static = _walked_blocks(
+        q_lens, ctx_lens, s_pad=32, t_pad=1024, max_q=1024, hq=hq, hkv=hkv,
+        d=d, blk=blk)
+    want = 31 * -(-660 // blk)
+    for j in range(-(-700 // mq)):
+        want += -(-min(1724, 1024 + (j + 1) * mq) // blk)
+    assert pairs == want
+    assert tiles == 31 + 6 and static == 32 + (1024 // mq + 32)
+    assert pa.query_tile_counts(
+        q_lens, max_q=1024, num_q_heads=hq, num_kv_heads=hkv, head_dim=d
+    ) == (37, 31 * 1 + 6 * 128)
+    # the dense [sequences x longest chunk] grid walked every q block of
+    # every sequence: 8 q blocks each
+    dense = 31 * 8 * -(-660 // blk) + sum(
+        -(-min(1724, 1024 + (j + 1) * mq) // blk) for j in range(8))
+    assert dense > 5 * pairs
+    # the scan's call: one tile a sequence, the grid it walked before
+    pairs, tiles, static = _walked_blocks(
+        [1] * 31, [660] * 31, s_pad=64, t_pad=64, max_q=1, hq=hq, hkv=hkv,
+        d=d, blk=blk)
+    assert (pairs, tiles, static) == (31 * 3, 31, 64)
+
+
+def test_tile_list_never_overflows_at_the_lattice_corners():
+    """The list's static length holds the most tiles any step of a
+    bucket can make, at Mixtral's widths, for every bucket the cell's 32
+    clients reach (``benchmark/lattice.py``)."""
+    import json
+    import os
+
+    from benchmark import lattice
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    traffic = json.load(open(os.path.join(
+        here, "benchmark", "traffic", "chat-closed-32.json")))
+    sweep = traffic["warmup"]["sweep"]
+    hq, hkv, d = 32, 8, 128
+    buckets = lattice.buckets(32, sweep)
+    assert len(buckets) == 34
+    for t, q in buckets:
+        geo = pa._tile_geometry(q, hq, hkv, d, hkv * d)
+        mq, maxq = geo.mq_blk, geo.maxq
+        static = pa.num_query_tiles(t, 32, mq, maxq)
+        assert static <= 32 * (maxq // mq)  # never more than dense
+        # the most tiles: as many chunks as fit, each one token past a
+        # tile's edge (or two tokens, the shortest chunk there is)
+        for chunk in {2, mq + 1, q // 2 + 1, q}:
+            chunk = min(max(chunk, 2), q)
+            n = min(32, t // chunk)
+            if n == 0:
+                continue
+            q_lens = [chunk] * n
+            rest = t - chunk * n
+            if n < 32 and rest >= 2:
+                q_lens.append(min(rest, q))
+            tiles, _ = pa.query_tile_counts(
+                q_lens, max_q=q, num_q_heads=hq, num_kv_heads=hkv,
+                head_dim=d)
+            assert tiles <= static, (t, q, chunk, tiles, static)
+
+
+def _primitives(jxp):
+    for eqn in jxp.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue  # the kernel's own loops never reach XLA
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", None)
+            if inner is not None:
+                yield from _primitives(inner)
+
+
+def test_single_step_glue_has_no_xla_loop_and_no_dense_query_grid(
+        monkeypatch):
+    """Twin of test_grouped_matmul's loop-free test: the benchmark tells
+    a fused scan from a single step by the ``while`` the scan's program
+    holds, so the tile lists are counted by comparison and a cumulative
+    sum.  And nothing of a mixed step's staging has the dense grid's
+    s_pad x max_q_pad query rows."""
+    for key, value in _SMALL_TILES.items():
+        monkeypatch.setattr(pa, key, value)
+    rng = np.random.default_rng(0)
+    specs = _decodes(31, rng) + [(128, 128)]
+    q, kv, meta, max_q, _, hkv = build_case(
+        rng, seq_specs=specs, s_pad=32, num_pages=420)
+    jaxpr = jax.make_jaxpr(
+        lambda q_, kv_, m_: paged_attention(
+            q_, kv_, m_, scale=0.125, num_kv_heads=hkv, max_q=max_q,
+            interpret=True))(q, kv, meta)
+    eqns = list(_primitives(jaxpr.jaxpr))
+    names = {e.primitive.name for e in eqns}
+    assert sum(e.primitive.name == "pallas_call" for e in eqns) == 2
+    assert not names & {"while", "scan", "cond"}, names
+    t, hq, d = q.shape
+    dense_rows = 32 * max_q
+    for e in eqns:
+        for v in e.outvars:
+            shape = getattr(v.aval, "shape", ())
+            if len(shape) >= 2 and shape[-1] % d == 0:
+                rows = int(np.prod(shape)) // (hq * d)
+                assert rows < dense_rows, (e.primitive.name, shape)
+
+
+def test_attention_entry_has_no_new_key():
+    """What makes a program: ``max_q`` is still the one static the
+    runner passes, and the tile lists come from the step's metadata."""
+    import inspect
+
+    params = inspect.signature(paged_attention).parameters
+    assert list(params) == [
+        "q", "kv_pages", "metadata", "scale", "soft_cap", "num_kv_heads",
+        "max_q", "side_kv", "side_len", "interpret"]

@@ -3,16 +3,50 @@
 The TPU-native replacement for the CUDA PagedAttention/FlashAttention
 kernels the reference inherits from the vLLM image (SURVEY.md §2.2 and
 BASELINE.json north_star: "PagedAttention is a Pallas kernel").  One
-kernel serves both decode (1 query token/seq) and chunked prefill
-(many): queries are grouped per sequence and attention runs flash-style
-(online softmax) over the sequence's paged KV.
+kernel serves decode (1 query token/seq), chunked prefill (many) and
+the mixed steps that hold both; attention runs flash-style (online
+softmax) over each sequence's paged KV.
 
-Design (v2 — rebuilt after the round-3 on-chip ablation, PERF.md):
-the round-3 kernel was COMPUTE-bound, not DMA-bound (DMA-only ablation
-ran at 779 GB/s while the full kernel ran at ~250 GB/s): decode issued
-8 separate per-head op chains on 8-row tiles, so the VPU (softmax, mask,
-state updates) and tiny 6%-utilized MXU calls dominated while the DMA
-queues idled.  v2 changes, in order of impact:
+**The grid is (query tile, kv block), and it walks a list.**  A query
+tile is ``mq_blk`` consecutive query tokens of ONE sequence.  The
+wrapper builds the step's list of tiles from the step's own metadata
+(which sequence, first token within its chunk, kv blocks to walk) and
+hands it to the kernel by scalar prefetch; its static length comes from
+the shapes already in the program's key (``t // mq_blk + s``, never
+more than the dense grid), tiles past the count in use are skipped with
+``pl.when`` and repeat the last live tile's block indices, so a skipped
+step moves no bytes and computes nothing.  The query rows are gathered
+from the flat ``[T, Hq, D]`` batch into ``[tiles, mq_blk, Hq, D]`` and
+the outputs gathered back per token: no array of sequences × longest
+chunk exists anywhere (before PR 30 the grid and the staging were that
+dense array: a mixed step of 31 decoding sequences and one 2,048-token
+chunk computed ~1,500 tile pairs for ~110 that held a query and staged
+537 MB for 16 MB of queries).
+
+Two tile geometries, one kernel body, chosen from what the call can
+read of itself:
+
+- ``max_q == 1`` (the fused decode scan's call, a decode-only step):
+  one tile a sequence in the **decode geometry** — heads folded until a
+  softmax chain has ``_ROWS_TARGET`` rows.  The list is the dense grid
+  (tile i = sequence i), a row without pool history walks no block.
+- ``max_q > 1`` (prefill / mixed / verify steps): sequences' chunks are
+  cut into ``mq_blk``-token tiles (the largest whose f32 flash state
+  fits ``_STATE_BYTES``: 128 at Mixtral's widths).  Where such a tile
+  is wide (``mq_blk >= _SPLIT_SINGLES_MIN_MQ``), sequences with a
+  SINGLE query token leave that list for a second ``pallas_call`` of
+  the layer in the decode geometry over the compacted list of those
+  sequences, so a decoding sequence riding in a mixed step costs what
+  it costs in the scan, not a 128-row tile.  A verify step (every
+  sequence a few tokens) stays one call whose list is the dense grid.
+
+The list is computed in XLA by comparison and a cumulative sum — no
+``while`` (``searchsorted`` is one): the benchmark tells a fused scan
+from a single step by that op.  Contract on the flat batch: a
+sequence's query tokens are contiguous and in position order.
+
+Inside a tile (unchanged since the v2 rebuild after the round-3 on-chip
+ablation, PERF.md — that kernel was COMPUTE-bound, not DMA-bound):
 
 - **Folded-head block-diagonal compute.**  KV heads are processed in
   fold groups of F heads per matmul: queries are laid out
@@ -27,15 +61,16 @@ queues idled.  v2 changes, in order of impact:
 - **Combined flat KV pool** ``[2, P, page, HD]`` (ops/attention.py):
   two descriptors per page (K plane + V plane) cover all heads'
   contiguous lanes, and a 64-wide head dim is stored unpadded inside
-  HD (the r3 layout padded each head to 128 lanes — 2× wasted bytes on
-  Llama-1B-class models).
+  HD.
 - **Globally rotating triple buffer.**  Buffer index = (number of
   active blocks completed so far) % 3, tracked in SMEM — never resets
-  per sequence, so the cross-sequence block-0 prefetch can never target
-  the buffer the current (or previous) step reads.  This replaces the
-  r3 order-dependent safety argument (ADVICE r3 medium) with a
-  structural invariant.
-- Causal skip: kv blocks entirely above the q block's last position are
+  per tile, so the prefetch of the next live (tile, kv block) can never
+  target the buffer the current (or previous) step reads.  The
+  lookahead is one step: the same tile's next block, else block 0 of
+  the list's next tile; where it misses (a tile of an empty sequence in
+  between) ``_bootstrap_dma`` issues the copy and eats the stall —
+  correctness never depends on the lookahead.
+- Causal skip: kv blocks entirely above the tile's last position are
   skipped (no DMA, no compute) — half the work on prefill.
 
 Numerics: scores/softmax/accumulation in float32 regardless of cache
@@ -45,6 +80,7 @@ dtype; output cast back to q.dtype.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -65,6 +101,11 @@ _STATE_BYTES = 6 * 1024 * 1024
 # Decode-shape fold target: grow F until a block's softmax chain has at
 # least this many rows (amortizes VPU op issue over more elements).
 _ROWS_TARGET = 32
+# A lone query token leaves the chunk tiles for the decode geometry when
+# a chunk tile holds at least this many tokens: below it the token's
+# share of a tile costs less than a second call's walk over its grid
+# (the spec-verify windows, a few tokens wide, stay one call).
+_SPLIT_SINGLES_MIN_MQ = 16
 # Added to the byte model below for Mosaic's own scratch and whatever
 # the model rounds away (sublane/lane padding of the small blocks).
 _VMEM_HEADROOM = 8 * 1024 * 1024
@@ -72,15 +113,19 @@ _VMEM_HEADROOM = 8 * 1024 * 1024
 
 def _kernel(
     # scalar prefetch (+ side_len_ref when has_side)
-    block_tables_ref,  # [S, max_pages] int32 (SMEM)
+    tile_seq_ref,  # [NT] int32 (SMEM): sequence of each query tile
+    tile_q0_ref,  # [NT] int32: the tile's first token within its chunk
+    tile_nb_ref,  # [NT] int32: kv blocks the tile walks (0: skipped)
+    n_live_ref,  # [1] int32: tiles in use (the rest are skipped)
+    block_tables_ref,  # [S, max_pages] int32
     seq_lens_ref,  # [S] int32
     chunk_starts_ref,  # [S] int32
-    # then inputs (q_ref [1,1,NF,ROWS,FD]; side_ref [1,2,K,HD] when
+    # then inputs (q_ref [1,NF,ROWS,FD]; side_ref [1,2,K,HD] when
     # has_side; kv_pages_ref [2,P,page,HD] ANY; scale_blk_ref
     # [1,2,Hkv,BLK] f32 when has_quant — a regular pipelined block of
     # the per-sequence TRANSPOSED scale matrix the wrapper gathers in
     # XLA, so the kernel never DMAs sub-128-lane scale slabs), the out
-    # block [1,1,NF,ROWS,FD], and scratch (kv_vmem [NBUF,2,BLK,HD],
+    # block [1,NF,ROWS,FD], and scratch (kv_vmem [NBUF,2,BLK,HD],
     # m/l [NF,ROWS,LANES] f32, acc [NF,ROWS,FD] f32, DMA sems [NBUF],
     # cnt SMEM [2] = [completed active blocks (the buffer-rotation
     # cursor), prefetch-pending flag]).
@@ -104,22 +149,24 @@ def _kernel(
     scale_blk_ref = rest.pop(0) if has_quant else None
     out_ref, kv_vmem = rest.pop(0), rest.pop(0)
     m_scr, l_scr, acc_scr, sems, cnt = rest
-    s = pl.program_id(0)
-    qb = pl.program_id(1)
-    kvb = pl.program_id(2)
-    num_seqs = pl.num_programs(0)
-    num_qb = pl.num_programs(1)
-    num_kvb = pl.num_programs(2)
+    tile = pl.program_id(0)
+    kvb = pl.program_id(1)
+    num_tiles = pl.num_programs(0)
+    num_kvb = pl.num_programs(1)
     blk = pages_per_blk * page_size
-    seq_len = seq_lens_ref[s]
-    chunk_start = chunk_starts_ref[s]
-    # Number of active kv blocks for (s, qb): the causal skip bound.
-    q_pos_max = chunk_start + (qb + 1) * mq_blk - 1
-    span = jnp.minimum(seq_len, q_pos_max + 1)
-    nb = jnp.where(seq_len > 0, (span + blk - 1) // blk, 0)
+    # Most grid steps are skipped ones (the grid is sized for the
+    # bucket, the lists for the step): what a skipped step pays is the
+    # scalar work out here, so it is two SMEM reads and a compare; the
+    # kv-block counts come ready from the wrapper (``tile_kv_blocks``).
+    # A tile past the live count does nothing at all: its block indices
+    # are the last live tile's (see the index maps), so neither its
+    # state reset nor its output write may run.
+    in_list = tile < n_live_ref[0]
+    s = tile_seq_ref[tile]
+    nb = tile_nb_ref[tile]
     active = kvb < nb
 
-    @pl.when((s == 0) & (qb == 0) & (kvb == 0))
+    @pl.when((tile == 0) & (kvb == 0))
     def _boot():
         cnt[0] = 0
         cnt[1] = 0
@@ -144,7 +191,7 @@ def _kernel(
                 )
         return copies
 
-    @pl.when(kvb == 0)
+    @pl.when(in_list & (kvb == 0))
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _MASK)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -158,19 +205,18 @@ def _kernel(
         for cp in block_dma(s, kvb, cnt[0] % _NBUF):
             cp.start()
 
-    # Prefetch the NEXT active block (same q block, next q block, or the
-    # next sequence) into the next rotation slot while this one computes.
-    next_in_qb = kvb + 1 < nb
-    # (s, qb+1) restarts from kv block 0; (s+1) likewise.  An empty
-    # sequence between live ones defeats the one-step lookahead; the
-    # bootstrap above recovers (correctness never depends on lookahead).
-    have_next_qb = (qb + 1 < num_qb) & (seq_len > 0)
-    next_seq_ok = (s + 1 < num_seqs) & (
-        seq_lens_ref[jnp.minimum(s + 1, num_seqs - 1)] > 0
+    # Prefetch the NEXT live (tile, kv block) into the next rotation
+    # slot while this one computes: the same tile's next block, else
+    # block 0 of the next tile of the list.  A tile of an empty sequence
+    # between live ones defeats the one-step lookahead; the bootstrap
+    # above recovers (correctness never depends on the lookahead).
+    next_in_tile = kvb + 1 < nb
+    next_tile = jnp.minimum(tile + 1, num_tiles - 1)
+    has_next = next_in_tile | (
+        (tile + 1 < num_tiles) & (tile_nb_ref[next_tile] > 0)
     )
-    has_next = next_in_qb | have_next_qb | next_seq_ok
-    next_s = jnp.where(next_in_qb | have_next_qb, s, s + 1)
-    next_kvb = jnp.where(next_in_qb, kvb + 1, 0)
+    next_s = jnp.where(next_in_tile, s, tile_seq_ref[next_tile])
+    next_kvb = jnp.where(next_in_tile, kvb + 1, 0)
 
     @pl.when(active & has_next)
     def _prefetch():
@@ -185,7 +231,8 @@ def _kernel(
         rows = acc_scr.shape[1]
         row_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, ncols), 0)
         col_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, ncols), 1)
-        q_pos = chunk_start + qb * mq_blk + row_ids % mq_blk
+        q_start = chunk_starts_ref[s] + tile_q0_ref[tile]
+        q_pos = q_start + row_ids % mq_blk
         return q_pos, col_ids
 
     def scale_mat(st, nf, ncols):
@@ -207,7 +254,7 @@ def _kernel(
         the K factor folds into the scores, the V factor into p before
         the PV matmul — both cheaper than lane-expanding the scale to
         dequantize the [ncols, FD] tiles themselves."""
-        qn = q_ref[0, 0, nf].astype(jnp.float32)  # [ROWS, FD]
+        qn = q_ref[0, nf].astype(jnp.float32)  # [ROWS, FD]
         scores = jax.lax.dot_general(
             qn, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -243,6 +290,7 @@ def _kernel(
         buf = cnt[0] % _NBUF
         for cp in block_dma(s, kvb, buf):
             cp.wait()
+        seq_len = seq_lens_ref[s]
         q_pos, col_ids = row_positions(blk)
         c_pos = block_start + col_ids
         mask = (c_pos <= q_pos) & (c_pos < seq_len)
@@ -266,7 +314,7 @@ def _kernel(
         cnt[0] = cnt[0] + 1
         cnt[1] = has_next.astype(jnp.int32)
 
-    @pl.when(kvb == num_kvb - 1)
+    @pl.when(in_list & (kvb == num_kvb - 1))
     def _finalize():
         if has_side:
             # Staged decode writes: this dispatch's K/V rows live in the
@@ -275,6 +323,7 @@ def _kernel(
             # division.  seq_len here is the POOL length (the runner
             # passes base lengths when staging).
             n_side = side_len_ref[0]
+            seq_len = seq_lens_ref[s]
             kblk = side_ref.shape[2]
             q_pos, col_ids = row_positions(kblk)
             side_pos = seq_len + col_ids
@@ -294,7 +343,7 @@ def _kernel(
                 flash_update(nf, k, v, smask)
         for nf in range(num_fold):
             denom = jnp.maximum(l_scr[nf, :, 0:1], 1e-30)
-            out_ref[0, 0, nf] = (acc_scr[nf] / denom).astype(out_ref.dtype)
+            out_ref[0, nf] = (acc_scr[nf] / denom).astype(out_ref.dtype)
 
 
 def _pow2_floor(x: int) -> int:
@@ -369,6 +418,285 @@ def _vmem_limit_bytes(
     return kv_bufs + state + q_out + side + scales + temps + _VMEM_HEADROOM
 
 
+class _Geometry(NamedTuple):
+    """A query tile: mq_blk tokens of one sequence; F heads folded per
+    matmul into FD lanes, NF fold groups of ``rows`` softmax rows; maxq
+    is the (padded) longest chunk the geometry was sized for."""
+
+    mq_blk: int
+    f: int
+    fd: int
+    nf: int
+    rows: int
+    maxq: int
+
+
+def _tile_geometry(
+    max_q: int, hq: int, hkv: int, d: int, hd_pad: int
+) -> _Geometry:
+    """The query tile for chunks of up to ``max_q`` tokens: as many
+    tokens as keep the whole f32 flash state (acc + m + l, at the
+    alignment-minimum fold factor) inside its budget, then the head fold
+    factor.  For ``max_q = 1`` that is the decode geometry: heads folded
+    until a softmax chain has _ROWS_TARGET rows."""
+    g = hq // hkv
+    # maxq padded so a tile always has >= 8 rows (f32 sublane tile).
+    maxq = next_power_of_2(max_q)
+    while maxq * g * hkv < 8:
+        maxq *= 2
+    f_min = _fold_align(hkv, d, hd_pad)
+    mq_blk = maxq
+    while (
+        _state_bytes(hkv, g, mq_blk, f_min, d) > _STATE_BYTES and mq_blk > 1
+    ):
+        mq_blk //= 2
+    f, fd, nf = _pick_fold(hkv, d, hd_pad, g, mq_blk)
+    while f * g * mq_blk < 8:  # tiny-model corner: widen the tile
+        mq_blk *= 2
+        maxq = max(maxq, mq_blk)
+    return _Geometry(mq_blk, f, fd, nf, f * g * mq_blk, maxq)
+
+
+def _splits_singles(mq_blk: int) -> bool:
+    """Whether single-token sequences leave the chunk tiles for a call
+    of their own in the decode geometry."""
+    return mq_blk >= _SPLIT_SINGLES_MIN_MQ
+
+
+def num_query_tiles(t: int, s: int, mq_blk: int, maxq: int) -> int:
+    """Static length of a step's chunk-tile list: ``t`` tokens in at
+    most ``s`` chunks fill at most t/mq_blk whole tiles and leave one
+    partial tile a chunk, and never more than the dense grid."""
+    return max(min(t // mq_blk + s, s * (maxq // mq_blk)), 1)
+
+
+def tile_list(tiles_per_seq: jax.Array, num_tiles: int, mq_blk: int):
+    """The ragged list of query tiles, sequence by sequence — (sequence
+    of each tile [NT], first token of the tile within its chunk [NT],
+    tiles in use [1]) — and the first tile of each sequence [S].
+    Entries past the count in use repeat the last live tile, so they
+    change no block index.  Counted by comparison and a cumulative sum:
+    no XLA ``while`` may enter a single-step program (the benchmark
+    tells a scan from a single step by that op; ``searchsorted`` is
+    one)."""
+    s = tiles_per_seq.shape[0]
+    tile_end = jnp.cumsum(tiles_per_seq.astype(jnp.int32))
+    first = tile_end - tiles_per_seq
+    used = tile_end[-1:]
+    idx = jnp.arange(num_tiles, dtype=jnp.int32)
+    idx = jnp.minimum(idx, jnp.maximum(used - 1, 0))
+    # A tile's sequence: how many sequences end at or before it.
+    seq = jnp.minimum(
+        (idx[:, None] >= tile_end[None, :]).sum(axis=1, dtype=jnp.int32),
+        s - 1,
+    )
+    q0 = (idx - first[seq]) * mq_blk
+    return (seq, q0, used), first
+
+
+def tile_kv_blocks(
+    tile_seq, tile_q0, n_live, seq_lens, chunk_starts, mq_blk: int, blk: int
+) -> jax.Array:
+    """kv blocks each query tile walks [NT]: up to the causal bound of
+    its last row, cut at the sequence's length; 0 for a tile past the
+    live count or of a sequence with no pool history."""
+    seq_len = seq_lens[tile_seq]
+    span = jnp.minimum(seq_len, chunk_starts[tile_seq] + tile_q0 + mq_blk)
+    live = (jnp.arange(tile_seq.shape[0]) < n_live[0]) & (seq_len > 0)
+    return jnp.where(live, (span + blk - 1) // blk, 0).astype(jnp.int32)
+
+
+def query_tile_counts(
+    q_lens, *, max_q: int, num_q_heads: int, num_kv_heads: int, head_dim: int
+) -> tuple[int, int]:
+    """(tiles that hold a query, query rows those tiles compute) of one
+    step whose sequences bring ``q_lens`` query tokens: host-side twin
+    of the wrapper's tile lists, for the ``runner.dispatch`` span."""
+    hd = num_kv_heads * head_dim
+    mq_a = _tile_geometry(1, num_q_heads, num_kv_heads, head_dim, hd).mq_blk
+    mq_b = _tile_geometry(
+        max_q, num_q_heads, num_kv_heads, head_dim, hd
+    ).mq_blk
+    if max_q == 1:
+        n = sum(1 for n in q_lens if n > 0)
+        return n, n * mq_a
+    split = _splits_singles(mq_b)
+    singles = sum(1 for n in q_lens if n == 1) if split else 0
+    chunks = sum(cdiv(n, mq_b) for n in q_lens if n > (1 if split else 0))
+    return singles + chunks, singles * mq_a + chunks * mq_b
+
+
+def _kv_blocking(kv_pages, hkv: int, has_quant: bool, max_pages: int):
+    """(pages per kv block, kv blocks, tokens per block): blocks sized
+    to the per-slot VMEM budget."""
+    _, _, page_size, hd_pad = kv_pages.shape
+    kv_bytes_per_token = 2 * hd_pad * jnp.dtype(kv_pages.dtype).itemsize
+    if has_quant:
+        kv_bytes_per_token += 2 * hkv * 4  # f32 scale rows
+    blk_tokens = max(_KV_BUF_BYTES // kv_bytes_per_token, page_size)
+    blk_tokens = min(_pow2_floor(blk_tokens), max_pages * page_size)
+    if has_quant and blk_tokens < 128 and blk_tokens < max_pages * page_size:
+        # The scale block's lane dim is BLK: it must be a 128 multiple
+        # (or cover the whole context) for Mosaic's tiling.
+        blk_tokens = min(128, max_pages * page_size)
+    pages_per_blk = max(blk_tokens // page_size, 1)
+    return (
+        pages_per_blk,
+        cdiv(max_pages, pages_per_blk),
+        pages_per_blk * page_size,
+    )
+
+
+def _attend_tiles(
+    q_tiles: jax.Array,  # [NT, mq_blk, Hq, D]: the tiles' query rows
+    tiles,  # (tile_seq [NT], tile_q0 [NT], n_live [1])
+    geometry: _Geometry,
+    kv_pages: jax.Array,
+    scale_mat: jax.Array | None,  # [S, 2, Hkv, CTX_PAD] f32 (int8 pool)
+    block_tables: jax.Array,  # padded to whole kv blocks
+    metadata: AttentionMetadata,
+    blocking,  # _kv_blocking's
+    *,
+    scale: float,
+    soft_cap: float | None,
+    hkv: int,
+    side_kv: jax.Array | None,
+    side_len: jax.Array | None,
+    interpret: bool,
+) -> jax.Array:
+    """One ``pallas_call`` over a list of query tiles of one geometry;
+    returns the tiles' outputs [NT, mq_blk, Hq, D]."""
+    num_tiles, _, hq, d = q_tiles.shape
+    mq_blk, f, fd, nf, rows, _ = geometry
+    pages_per_blk, num_kvb, blk = blocking
+    _, _, page_size, hd_pad = kv_pages.shape
+    g = hq // hkv
+    has_side = side_kv is not None
+    has_quant = scale_mat is not None
+
+    # ---- block-diagonal fold:  [NT, NF, ROWS, FD] ----
+    q6 = q_tiles.reshape(num_tiles, mq_blk, nf, f, g, d)
+    q6 = q6.transpose(0, 2, 3, 4, 1, 5)  # [NT,NF,F,G,mq,D]
+    if f > 1:
+        eye = jnp.eye(f, dtype=q_tiles.dtype)
+        q6 = (
+            q6[:, :, :, :, :, None, :]
+            * eye[None, None, :, None, None, :, None]
+        )
+    q_bd = q6.reshape(num_tiles, nf, rows, f * d)
+    if fd > f * d:  # padded single-group case: zero lanes at the end
+        q_bd = jnp.pad(q_bd, [(0, 0), (0, 0), (0, 0), (0, fd - f * d)])
+
+    kernel = functools.partial(
+        _kernel,
+        scale=scale,
+        soft_cap=soft_cap,
+        page_size=page_size,
+        pages_per_blk=pages_per_blk,
+        group_size=g,
+        num_fold=nf,
+        fold_width=fd,
+        mq_blk=mq_blk,
+        has_side=has_side,
+        has_quant=has_quant,
+    )
+
+    # Index maps (scalar-prefetch refs ride along after grid indices).
+    # A tile past the live count keeps the last live tile's block
+    # indices (the list repeats its sequence there), and the scale
+    # block stops at the tile's last kv block: a step the kernel skips
+    # moves no bytes.
+    def live_tile(tile, refs):
+        return jnp.minimum(tile, jnp.maximum(refs[3][0] - 1, 0))
+
+    def tile_map(tile, b_, *refs):
+        return (live_tile(tile, refs), 0, 0, 0)
+
+    def seq_map(tile, b_, *refs):
+        return (refs[0][tile], 0, 0, 0)
+
+    def scale_map(tile, b_, *refs):
+        last = jnp.maximum(refs[2][live_tile(tile, refs)] - 1, 0)
+        return (refs[0][tile], 0, 0, jnp.minimum(b_, last))
+
+    tile_seq, tile_q0, n_live = tiles
+    scalars = [
+        tile_seq,
+        tile_q0,
+        tile_kv_blocks(
+            tile_seq, tile_q0, n_live, metadata.seq_lens,
+            metadata.chunk_starts, mq_blk, blk,
+        ),
+        n_live,
+        block_tables,
+        metadata.seq_lens,
+        metadata.chunk_starts,
+    ]
+    in_specs = [pl.BlockSpec((1, nf, rows, fd), tile_map)]
+    inputs = [q_bd]
+    k_side = 0
+    if has_side:
+        scalars.append(side_len.astype(jnp.int32))
+        k_side = side_kv.shape[2]
+        in_specs.append(pl.BlockSpec((1, 2, k_side, hd_pad), seq_map))
+        inputs.append(side_kv)
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+    inputs.append(kv_pages)
+    if has_quant:
+        in_specs.append(pl.BlockSpec((1, 2, hkv, blk), scale_map))
+        inputs.append(scale_mat)
+    scratch = [
+        pltpu.VMEM((_NBUF, 2, blk, hd_pad), kv_pages.dtype),
+        pltpu.VMEM((nf, rows, _LANES), jnp.float32),
+        pltpu.VMEM((nf, rows, _LANES), jnp.float32),
+        pltpu.VMEM((nf, rows, fd), jnp.float32),
+        pltpu.SemaphoreType.DMA((_NBUF,)),
+        pltpu.SMEM((2,), jnp.int32),
+    ]
+    out_bd = pl.pallas_call(
+        kernel,
+        name="vdt_paged_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(num_tiles, num_kvb),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, nf, rows, fd), tile_map),
+            scratch_shapes=scratch,
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (num_tiles, nf, rows, fd), q_tiles.dtype
+        ),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit_bytes(
+                blk=blk,
+                hd_pad=hd_pad,
+                kv_itemsize=jnp.dtype(kv_pages.dtype).itemsize,
+                nf=nf,
+                rows=rows,
+                fd=fd,
+                q_itemsize=jnp.dtype(q_tiles.dtype).itemsize,
+                k_side=k_side,
+                side_itemsize=(
+                    jnp.dtype(side_kv.dtype).itemsize if has_side else 0
+                ),
+                hkv=hkv,
+                has_quant=has_quant,
+            )
+        ),
+        interpret=interpret,
+    )(*scalars, *inputs)
+
+    # ---- extract the diagonal blocks back to token rows ----
+    ob = out_bd[..., : f * d].reshape(num_tiles, nf, f, g, mq_blk, f, d)
+    if f > 1:
+        # diagonal over (F_row, F_lane): row block i holds head i's
+        # output in lane block i; off-diagonal is cross-head garbage.
+        ob = jnp.einsum("acfgmfd->acfgmd", ob)
+    else:
+        ob = ob.reshape(num_tiles, nf, f, g, mq_blk, d)
+    return ob.transpose(0, 4, 1, 2, 3, 5).reshape(num_tiles, mq_blk, hq, d)
+
+
 def paged_attention(
     q: jax.Array,  # [T, Hq, D] flat
     kv_pages: jax.Array,  # [2, P, page, HD]
@@ -386,6 +714,10 @@ def paged_attention(
     flash kernel.  `max_q` is the static per-sequence query bound for this
     step (the runner's padded max chunk length).
 
+    The query tokens of a sequence are contiguous in the flat batch and
+    in position order: token ``first + j`` of sequence ``s`` sits at
+    position ``chunk_starts[s] + j`` (what the runner packs).
+
     ``side_kv``/``side_len``: staged decode writes — the fused decode
     scan keeps each micro-step's K/V rows in a dense per-sequence side
     buffer instead of scattering them into the paged pool every step
@@ -395,122 +727,28 @@ def paged_attention(
     yet written and are masked.
 
     An int8 pool arrives as a ``(data, per-head scales)`` tuple
-    (ops/attention.py kv_scales_shape); the kernel DMAs the tiny scale
-    slabs alongside the data pages and folds the dequant factors into
-    the score/probability matrices (the side buffer stays in model
-    dtype — only pool history is quantized)."""
+    (ops/attention.py kv_scales_shape); the kernel takes the scales as
+    pipelined blocks of a per-sequence transposed matrix and folds the
+    dequant factors into the score/probability matrices (the side
+    buffer stays in model dtype — only pool history is quantized)."""
     t, hq, d = q.shape
     has_quant = isinstance(kv_pages, tuple)
     kv_scales = None
     if has_quant:
         kv_pages, kv_scales = kv_pages
-    _, p_total, page_size, hd_pad = kv_pages.shape
+    _, _, page_size, hd_pad = kv_pages.shape
     s, max_pages = metadata.block_tables.shape
     hkv = num_kv_heads if num_kv_heads is not None else hq
-    g = hq // hkv
 
-    # maxq padded so a q block always has >= 8 rows (f32 sublane tile).
-    maxq = next_power_of_2(max_q)
-    while maxq * g * hkv < 8:
-        maxq *= 2
-
-    # Split maxq into q blocks whose full f32 flash state (acc + m + l,
-    # at the alignment-minimum fold factor) fits the budget, then pick
-    # the head fold factor.
-    f_min = _fold_align(hkv, d, hd_pad)
-    mq_blk = maxq
-    while (
-        _state_bytes(hkv, g, mq_blk, f_min, d) > _STATE_BYTES and mq_blk > 1
-    ):
-        mq_blk //= 2
-    f, fd, nf = _pick_fold(hkv, d, hd_pad, g, mq_blk)
-    while f * g * mq_blk < 8:  # tiny-model corner: widen the q block
-        mq_blk *= 2
-        maxq = max(maxq, mq_blk)
-    num_qb = maxq // mq_blk
-    rows = f * g * mq_blk
-
-    # ---- group flat queries per sequence ----
-    # Padding tokens carry q_seq_ids == S (one past the end); route their
-    # scatter to an out-of-bounds column so it is DROPPED instead of
-    # clobbering a real row (scatter drops OOB updates under jit).
-    valid = metadata.q_seq_ids < s
-    seq_idx = jnp.minimum(metadata.q_seq_ids, s - 1)
-    tok_in_chunk = metadata.q_positions - metadata.chunk_starts[seq_idx]
-    col = jnp.where(valid, tok_in_chunk, maxq)
-    q_grouped = jnp.zeros((s, maxq, hq, d), q.dtype)
-    q_grouped = q_grouped.at[seq_idx, col].set(q, mode="drop")
-
-    # ---- block-diagonal fold:  [S, NQB, NF, ROWS, FD] ----
-    q7 = q_grouped.reshape(s, num_qb, mq_blk, nf, f, g, d)
-    q7 = q7.transpose(0, 1, 3, 4, 5, 2, 6)  # [S,NQB,NF,F,G,mq,D]
-    eye = jnp.eye(f, dtype=q.dtype)
-    q_bd = (
-        q7[:, :, :, :, :, :, None, :]
-        * eye[None, None, None, :, None, None, :, None]
-    ).reshape(s, num_qb, nf, rows, f * d)
-    if fd > f * d:  # padded single-group case: zero lanes at the end
-        q_bd = jnp.pad(
-            q_bd, [(0, 0), (0, 0), (0, 0), (0, 0), (0, fd - f * d)]
-        )
-
-    # ---- kv blocking: size blocks to the VMEM budget ----
-    kv_bytes_per_token = 2 * hd_pad * jnp.dtype(kv_pages.dtype).itemsize
-    if has_quant:
-        kv_bytes_per_token += 2 * hkv * 4  # f32 scale rows
-    blk_tokens = max(_KV_BUF_BYTES // kv_bytes_per_token, page_size)
-    blk_tokens = min(_pow2_floor(blk_tokens), max_pages * page_size)
-    if has_quant and blk_tokens < 128 and blk_tokens < max_pages * page_size:
-        # The scale block's lane dim is BLK: it must be a 128 multiple
-        # (or cover the whole context) for Mosaic's tiling.
-        blk_tokens = min(128, max_pages * page_size)
-    pages_per_blk = max(blk_tokens // page_size, 1)
-    num_kvb = cdiv(max_pages, pages_per_blk)
-    blk = pages_per_blk * page_size
+    blocking = _kv_blocking(kv_pages, hkv, has_quant, max_pages)
+    pages_per_blk, num_kvb, blk = blocking
+    block_tables = metadata.block_tables
     if max_pages % pages_per_blk:
         # Pad the table so block_dma never reads a page id out of bounds
         # (padding pages are id 0 — a real page, masked out of scores).
         pad = pages_per_blk - max_pages % pages_per_blk
-        block_tables = jnp.pad(metadata.block_tables, ((0, 0), (0, pad)))
-    else:
-        block_tables = metadata.block_tables
-
-    grid = (s, num_qb, num_kvb)
-    has_side = side_kv is not None
-    kernel = functools.partial(
-        _kernel,
-        scale=scale,
-        soft_cap=soft_cap,
-        page_size=page_size,
-        pages_per_blk=pages_per_blk,
-        group_size=g,
-        num_fold=nf,
-        fold_width=fd,
-        mq_blk=mq_blk,
-        has_side=has_side,
-        has_quant=has_quant,
-    )
-    in_specs = [
-        pl.BlockSpec(
-            (1, 1, nf, rows, fd),
-            # Scalar-prefetch refs ride along after grid indices.
-            lambda s_, qb_, b_, *refs: (s_, qb_, 0, 0, 0),
-        ),
-    ]
-    scalars = [block_tables, metadata.seq_lens, metadata.chunk_starts]
-    inputs = [q_bd]
-    if has_side:
-        scalars.append(side_len.astype(jnp.int32))
-        k_side = side_kv.shape[2]
-        in_specs.append(
-            pl.BlockSpec(
-                (1, 2, k_side, hd_pad),
-                lambda s_, qb_, b_, *refs: (s_, 0, 0, 0),
-            )
-        )
-        inputs.append(side_kv)
-    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-    inputs.append(kv_pages)
+        block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
+    scale_mat = None
     if has_quant:
         # Per-sequence transposed scale matrix [S, 2, Hkv, CTX_PAD],
         # gathered in XLA (loop-invariant per fused dispatch, so XLA
@@ -522,65 +760,88 @@ def paged_attention(
         # (Hkv/ (4*D) of the data bytes — ~3% at D=64/f32 scales);
         # acceptable next to the chunk's matmul work, and shrinkable
         # with bf16 scales if it ever shows up in a profile.
-        ctx_pad = num_kvb * blk
         sc = kv_scales[:, block_tables]  # [2, S, PAD_PAGES, page, Hkv]
-        sc = sc.transpose(1, 0, 4, 2, 3).reshape(s, 2, hkv, ctx_pad)
-        in_specs.append(
-            pl.BlockSpec(
-                (1, 2, hkv, blk),
-                lambda s_, qb_, b_, *refs: (s_, 0, 0, b_),
-            )
+        scale_mat = sc.transpose(1, 0, 4, 2, 3).reshape(
+            s, 2, hkv, num_kvb * blk
         )
-        inputs.append(sc)
-    scratch = [pltpu.VMEM((_NBUF, 2, blk, hd_pad), kv_pages.dtype)]
-    scratch += [
-        pltpu.VMEM((nf, rows, _LANES), jnp.float32),
-        pltpu.VMEM((nf, rows, _LANES), jnp.float32),
-        pltpu.VMEM((nf, rows, fd), jnp.float32),
-        pltpu.SemaphoreType.DMA((_NBUF,)),
-        pltpu.SMEM((2,), jnp.int32),
-    ]
-    out_bd = pl.pallas_call(
-        kernel,
-        name="vdt_paged_attention",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(scalars),
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, nf, rows, fd),
-                lambda s_, qb_, b_, *refs: (s_, qb_, 0, 0, 0),
-            ),
-            scratch_shapes=scratch,
-        ),
-        out_shape=jax.ShapeDtypeStruct((s, num_qb, nf, rows, fd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_vmem_limit_bytes(
-                blk=blk,
-                hd_pad=hd_pad,
-                kv_itemsize=jnp.dtype(kv_pages.dtype).itemsize,
-                nf=nf,
-                rows=rows,
-                fd=fd,
-                q_itemsize=jnp.dtype(q.dtype).itemsize,
-                k_side=side_kv.shape[2] if has_side else 0,
-                side_itemsize=(
-                    jnp.dtype(side_kv.dtype).itemsize if has_side else 0
-                ),
-                hkv=hkv,
-                has_quant=has_quant,
-            )
-        ),
+    attend = functools.partial(
+        _attend_tiles,
+        kv_pages=kv_pages,
+        scale_mat=scale_mat,
+        block_tables=block_tables,
+        metadata=metadata,
+        blocking=blocking,
+        scale=scale,
+        soft_cap=soft_cap,
+        hkv=hkv,
+        side_kv=side_kv,
+        side_len=side_len,
         interpret=interpret,
-    )(*scalars, *inputs)
+    )
 
-    # ---- extract the diagonal blocks back to the flat layout ----
-    ob = out_bd[..., : f * d].reshape(s, num_qb, nf, f, g, mq_blk, f, d)
-    # diagonal over (F_row, F_lane): row block i holds head i's output
-    # in lane block i; everything off-diagonal is cross-head garbage.
-    out7 = jnp.einsum("abcfgmfd->abcfgmd", ob)  # [S,NQB,NF,F,G,mq,D]
-    out = out7.transpose(0, 1, 5, 2, 3, 4, 6).reshape(s, maxq, hq, d)
-    return out[seq_idx, jnp.clip(tok_in_chunk, 0, maxq - 1)]
+    # ---- where each sequence's queries are in the flat batch ----
+    # Padding tokens carry q_seq_ids == S (one past the end) and match
+    # no sequence.  One fused [S, T] comparison: no scatter, no loop.
+    tok = jnp.arange(t, dtype=jnp.int32)
+    match = metadata.q_seq_ids[None, :] == jnp.arange(s, dtype=jnp.int32)[
+        :, None
+    ]
+    q_len = match.sum(axis=1, dtype=jnp.int32)
+    first_tok = jnp.min(jnp.where(match, tok[None, :], t), axis=1)
+    seq_idx = jnp.minimum(metadata.q_seq_ids, s - 1)
+    tok_in_chunk = tok - first_tok[seq_idx]
+    # Padding tokens get zeros, not a gathered row: a tile past the
+    # live count is never written, and what such memory holds must not
+    # reach the padding rows' K/V (they land in page 0, which masked
+    # columns of every table still multiply by p = 0).
+    is_token = (metadata.q_seq_ids < s)[:, None, None]
+
+    def attend_list(tiles, geometry):
+        """The outputs [NT, mq_blk, Hq, D] of a list's tiles, their
+        query rows gathered from the flat batch."""
+        tile_seq, tile_q0, _ = tiles
+        rows = (first_tok[tile_seq] + tile_q0)[:, None] + jnp.arange(
+            geometry.mq_blk, dtype=jnp.int32
+        )
+        # Rows past a chunk's end (and whole dead tiles) read whatever
+        # token is there: finite, computed, never gathered back.
+        return attend(q[jnp.clip(rows, 0, t - 1)], tiles, geometry)
+
+    decode = _tile_geometry(1, hq, hkv, d, hd_pad)
+    if max_q == 1:
+        # Decode (the fused scan's call, a decode-only step): one tile a
+        # sequence, in the decode geometry; the list is the dense grid,
+        # and a row without pool history is skipped by its length.
+        seqs = jnp.arange(s, dtype=jnp.int32)
+        tiles = (seqs, jnp.zeros(s, jnp.int32), jnp.full((1,), s, jnp.int32))
+        out = attend_list(tiles, decode)[seq_idx, 0]
+        return jnp.where(is_token, out, 0)
+
+    # Chunks: tiles of mq_blk tokens where real query tokens are.
+    assert side_kv is None, "staged decode rows come with max_q = 1"
+    chunk = _tile_geometry(max_q, hq, hkv, d, hd_pad)
+    mq_blk = chunk.mq_blk
+    split = _splits_singles(mq_blk)
+    in_chunks = q_len > 1 if split else q_len > 0
+    tiles, first_tile = tile_list(
+        jnp.where(in_chunks, (q_len + mq_blk - 1) // mq_blk, 0),
+        num_query_tiles(t, s, mq_blk, chunk.maxq),
+        mq_blk,
+    )
+    out_rows = attend_list(tiles, chunk).reshape(-1, hq, d)
+    row = first_tile[seq_idx] * mq_blk + tok_in_chunk
+    out = out_rows[jnp.clip(row, 0, out_rows.shape[0] - 1)]
+    if split:
+        # Single-token sequences: a call of their own in the decode
+        # geometry, over the compacted list of those sequences, so none
+        # of them occupies (and computes) a whole mq_blk-row tile.
+        single = q_len == 1
+        tiles, first_tile = tile_list(
+            single.astype(jnp.int32), s, decode.mq_blk
+        )
+        out_1 = attend_list(tiles, decode)[first_tile[seq_idx], 0]
+        out = jnp.where(single[seq_idx][:, None, None], out_1, out)
+    return jnp.where(is_token, out, 0)
 
 
 paged_attention.needs_max_q = True

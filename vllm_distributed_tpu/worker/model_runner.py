@@ -489,6 +489,24 @@ class ModelRunner:
             return paged_attention_cpu
         return paged_attention
 
+    def _query_tiles(self, q_lens: list[int], max_q_pad: int):
+        """(query tiles the attention kernel walks in this step, the
+        query rows those tiles compute) on one head shard: the span's
+        ``q_tiles`` / ``q_rows``, beside its ``tokens``."""
+        from vllm_distributed_tpu.ops.pallas.paged_attention import (
+            query_tile_counts,
+        )
+
+        m = self.model
+        tp = self.mesh.shape.get("tp", 1) if self.mesh is not None else 1
+        return query_tile_counts(
+            q_lens,
+            max_q=max_q_pad,
+            num_q_heads=m.num_heads // tp,
+            num_kv_heads=m.num_kv_heads // tp,
+            head_dim=m.head_dim,
+        )
+
     def _pick_kv_flush_fn(self):
         """Per-dispatch flush of the staged decode side buffers.  None
         under the XLA reference backend, whose decode scan scatters each
@@ -1306,6 +1324,11 @@ class ModelRunner:
             args = (self.params, self.kv_caches, token_ids, meta, smeta)
 
         kv_tokens = int(seq_lens.sum())
+        counts = dict(seqs=s_real, tokens=t_real, kv_tokens=kv_tokens)
+        if getattr(self._attn_fn, "needs_max_q", False):
+            counts["q_tiles"], counts["q_rows"] = self._query_tiles(
+                num_new, max_q_pad
+            )
         prep.end()
         t_step0 = time.perf_counter()
         sampled, logprobs, self.kv_caches = self._run_program(
@@ -1318,9 +1341,7 @@ class ModelRunner:
             args,
             so,
             rows=t_pad,
-            seqs=s_real,
-            tokens=t_real,
-            kv_tokens=kv_tokens,
+            **counts,
         )
 
         with get_tracer().span("runner.fetch", step_id=so.step_id):
