@@ -30,8 +30,8 @@ accept kernel.
 ``VDT_MOCK_HBM_PASS_SECONDS`` (default 0) simulates memory-bound
 device time per step as cost × HBM passes: a fused decode window of K
 micro-steps streams weights+KV K times, a speculative verify window
-streams them ONCE — the roofline asymmetry the spec-decode bench gate
-measures deterministically without chips.
+streams them ONCE — the pass-count asymmetry that
+tests/test_spec_decode.py counts deterministically without chips.
 - transport faults: ``drop_writes`` / ``blackhole_writes`` /
                     ``corrupt_writes`` / ``delay_writes`` / ``hang_writes``
                     — armed with a small ``after_writes`` budget so the
@@ -52,9 +52,7 @@ from vllm_distributed_tpu.utils import next_power_of_2, run_method
 from vllm_distributed_tpu.worker.telemetry import DeviceTelemetry
 
 # Simulated device time per fused dispatch in the two-phase protocol
-# (per-process override: VDT_MOCK_STEP_SECONDS — the dispatch
-# microbench shrinks it to put driver overhead and device time in the
-# production regime).
+# (per-process override: VDT_MOCK_STEP_SECONDS).
 MOCK_STEP_SECONDS = 0.3
 
 _TRANSPORT_FAULTS = {
@@ -121,8 +119,8 @@ class MockWorker:
         self._compiled_buckets: set[str] = set()
         # Simulated per-scheduled-token device time (seconds): makes
         # prefill cost proportional to chunk length, so prefix-cache /
-        # restore ablations measure a real warm-TTFT delta without
-        # chips.
+        # restore tests can order a warm request before a cold one
+        # without chips.
         self._token_seconds = float(
             os.environ.get("VDT_MOCK_TOKEN_SECONDS", "0")
         )

@@ -26,6 +26,8 @@ from tools.vdt_lint import (
 
 pytestmark = pytest.mark.lint
 
+_ROOT = pathlib.Path(__file__).resolve().parents[1]
+
 
 def test_package_has_no_new_findings():
     report = run_lint()
@@ -60,9 +62,9 @@ def test_every_pallas_call_states_a_unique_literal_vdt_name():
     ``closed_call``), so a profile cannot tell one kernel from another.
     Every ``pl.pallas_call`` under ``ops/pallas/`` states a literal
     ``name="vdt_…"``, and no two state the same."""
-    pkg = pathlib.Path(__file__).resolve().parents[1] / "vllm_distributed_tpu"
     names = []
-    for path in sorted((pkg / "ops" / "pallas").glob("*.py")):
+    pallas = _ROOT / "vllm_distributed_tpu" / "ops" / "pallas"
+    for path in sorted(pallas.glob("*.py")):
         for node in ast.walk(ast.parse(path.read_text())):
             if not (isinstance(node, ast.Call)
                     and isinstance(node.func, ast.Attribute)
@@ -76,3 +78,70 @@ def test_every_pallas_call_states_a_unique_literal_vdt_name():
             assert re.match(r"^vdt_[a-z0-9_]+$", kw["name"].value), where
             names.append(kw["name"].value)
     assert len(names) >= 4 and len(set(names)) == len(names), names
+
+
+def test_every_registered_env_var_has_a_reader_in_the_package():
+    """A name in ``envs.environment_variables`` that nothing under
+    ``vllm_distributed_tpu/`` reads is an option that sets nothing:
+    each must be named somewhere in the package outside envs.py.  The
+    Hugging Face variables are registered only to be replicated; the
+    hub library reads them."""
+    from vllm_distributed_tpu import envs
+
+    source = "\n".join(
+        p.read_text()
+        for p in sorted((_ROOT / "vllm_distributed_tpu").rglob("*.py"))
+        if p.name != "envs.py"
+    )
+    unread = [
+        name
+        for name in envs.environment_variables
+        if name not in envs.ADDITIONAL_REPLICATED_ENV_VARS
+        and not re.search(rf"\b{name}\b", source)
+    ]
+    assert not unread, f"registered in envs.py, read by nothing: {unread}"
+
+
+_TREES = ("tools", "tests", "vllm_distributed_tpu", "benchmark")
+# A path under one of the repo's trees, or a bare *.py / *.json / *.md
+# name; not a placeholder in <angle brackets>, not the tail of a longer
+# path or URL.
+_CITED_PATH = re.compile(
+    r"(?<![\w/.<\-])"
+    rf"((?:{'|'.join(_TREES)})/[\w./\-]*\w"
+    r"|[A-Za-z_][\w\-]*(?:\.[\w\-]+)*\.(?:py|json|md))"
+    r"(?![\w/])"
+)
+# The reference deployment's launcher, cited as launch.py:<line>.
+_NOT_OURS = {"launch.py"}
+
+
+def test_every_file_the_readme_and_envs_comments_name_exists():
+    """README.md and the comments of envs.py tell an operator which
+    tool to run and which file holds what; a deleted file must take its
+    citations with it.  A path with a directory must exist as written;
+    a bare name must be a file at the root or somewhere in the repo's
+    trees."""
+    names = {p.name for p in _ROOT.iterdir() if p.is_file()}
+    for tree in _TREES:
+        names.update(p.name for p in (_ROOT / tree).rglob("*") if p.is_file())
+    envs_comments = "\n".join(
+        line
+        for line in (_ROOT / "vllm_distributed_tpu" / "envs.py")
+        .read_text().splitlines()
+        if line.lstrip().startswith("#")
+    )
+    dangling = []
+    for where, text in (
+        ("README.md", (_ROOT / "README.md").read_text()),
+        ("envs.py", envs_comments),
+    ):
+        for cited in sorted(set(_CITED_PATH.findall(text)) - _NOT_OURS):
+            found = (
+                (_ROOT / cited).exists() if "/" in cited else cited in names
+            )
+            if not found:
+                dangling.append(f"{where}: {cited}")
+    assert not dangling, "files cited that are not in the tree:\n" + "\n".join(
+        dangling
+    )

@@ -1,6 +1,6 @@
 """Staged-decode flush kernel vs the functional scatter oracle, in
-interpret mode on CPU (the production TPU path is re-checked on-chip by
-bench's _check_kernels)."""
+interpret mode on CPU (chip_smoke.py phase A compiles and checks the
+production TPU path on the chip)."""
 
 import jax.numpy as jnp
 import numpy as np

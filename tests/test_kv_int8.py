@@ -39,6 +39,71 @@ def _quantize_pool(kv_pages, hkv):
     return write_kv_pages((data, scales), k, v, slots)
 
 
+# write_kv_pages on an unquantized pool against a NumPy row loop.  It is
+# every single step's KV writer and the oracle of the staged flush
+# (tests/test_kv_flush.py) and of the int8 write path below.
+# (t, hkv, d, pool lane width or None for hkv*d, slots or None for
+# distinct random ones, pool dtype)
+_WRITE_CASES = {
+    "basic_scatter": (16, 2, 64, None, None, "float32"),
+    # hkv*d = 64 < the 128-lane tile, pool left unpadded (tiny-model and
+    # per-shard shapes).
+    "sub_tile_width_pool": (8, 1, 64, None, None, "float32"),
+    # The same rows into a pool padded to the lane tile: the writer pads
+    # each row with zeros up to the pool's width.
+    "sub_tile_rows_into_padded_pool": (8, 1, 64, 128, None, "float32"),
+    # Padding tokens all target reserved page 0 (never read).
+    "duplicate_slots_onto_page_0": (
+        8, 2, 64, None, [5, 5, 5, 17, 17, 3, 0, 0], "float32",
+    ),
+    "single_token_decode_shape": (1, 8, 128, None, None, "float32"),
+    "bfloat16_pool_casts_inputs": (4, 2, 64, None, None, "bfloat16"),
+}
+
+
+@pytest.mark.parametrize(
+    "t,hkv,d,width,slots,dtype",
+    list(_WRITE_CASES.values()),
+    ids=list(_WRITE_CASES),
+)
+def test_write_kv_pages_matches_row_loop(t, hkv, d, width, slots, dtype):
+    rng = np.random.default_rng(t * 131 + hkv)
+    num_pages, page_size = 8, 16
+    shape = list(kv_pool_shape(num_pages, page_size, hkv, d))
+    if width is not None:
+        shape[-1] = width
+    pool = jnp.asarray(rng.standard_normal(shape), jnp.float32).astype(dtype)
+    k = jnp.asarray(rng.standard_normal((t, hkv, d)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((t, hkv, d)), jnp.float32)
+    if slots is None:
+        slots = rng.choice(num_pages * page_size, size=t, replace=False)
+    slots = [int(s) for s in slots]
+
+    got = write_kv_pages(pool, k, v, jnp.asarray(slots, jnp.int32))
+    assert got.dtype == pool.dtype and got.shape == pool.shape
+    got = np.asarray(got.astype(jnp.float32))
+
+    def rows(x):  # what lands in the pool: cast, flattened, zero-padded
+        x = np.asarray(x.astype(dtype).astype(jnp.float32)).reshape(t, -1)
+        return np.pad(x, [(0, 0), (0, shape[-1] - hkv * d)])
+
+    k_rows, v_rows = rows(k), rows(v)
+    want = np.asarray(pool.astype(jnp.float32)).copy()
+    for slot in set(slots):
+        writers = [i for i, s in enumerate(slots) if s == slot]
+        page, row = divmod(slot, page_size)
+        # XLA leaves the order of duplicate scatter indices unspecified:
+        # a slot written more than once holds one of its writers' rows.
+        for plane, cand in ((0, k_rows), (1, v_rows)):
+            cell = got[plane, page, row]
+            assert any(np.array_equal(cell, cand[i]) for i in writers), (
+                f"slot {slot} plane {plane} holds a row no writer produced"
+            )
+            want[plane, page, row] = cell
+    # Every row no slot named is untouched.
+    np.testing.assert_array_equal(got, want)
+
+
 def test_quantize_roundtrip_error():
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((64, 4 * 32)) * 3, jnp.float32)

@@ -139,9 +139,7 @@ def test_ragged_dispatch_is_sparse(tiny_mixtral_16e):
     """The ragged MLP must dispatch T*k rows through grouped matmuls —
     not T*E token-expert pairs like the dense path.  Asserted on the
     jaxpr (op shapes): CPU's ragged_dot lowering is masked-dense, so
-    FLOP counts only reflect sparsity on TPU, where the real lowering
-    was verified at exactly 2*M*H*I flops (bench _check_kernels asserts
-    this on-chip every run)."""
+    FLOP counts would not show sparsity here."""
     import jax
     import jax.numpy as jnp
     import numpy as np

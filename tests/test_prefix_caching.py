@@ -224,7 +224,7 @@ def test_flag_off_uses_seed_allocator():
     sched = Scheduler(SchedulerConfig(), CacheConfig(), num_pages=64)
     assert type(sched.allocator) is PageAllocator
     # The radix index (ISSUE 14) is the prefix-caching default; the
-    # PR 1 hash-chain stays reachable as the "flat" ablation baseline.
+    # PR 1 hash-chain stays reachable as "flat", the tests' reference index.
     on = Scheduler(
         SchedulerConfig(),
         CacheConfig(enable_prefix_caching=True),

@@ -189,7 +189,7 @@ def test_int8_mixtral_ep(tiny_mixtral):
 
 def test_int8_matmul_kernel_interpret():
     """Pallas weight-streaming matmul vs dequant-in-graph (interpret
-    mode; the bench re-checks on the live chip)."""
+    mode; chip_smoke.py phase A re-checks on the live chip)."""
     import jax.numpy as jnp
 
     from vllm_distributed_tpu.ops.pallas.quant_matmul import int8_matmul

@@ -150,7 +150,8 @@ class CacheConfig:
     # Prefix index structure (ISSUE 14): "radix" = radix tree over
     # token sequences with leaf-first cache-aware LRU eviction and the
     # optional host-DRAM spill tier; "flat" = the PR 1 hash-chain map
-    # (the ablation baseline).  Ignored unless enable_prefix_caching.
+    # (kept as the tests' reference index).  Ignored unless
+    # enable_prefix_caching.
     prefix_cache_index: str = "radix"
     # Host-DRAM spill tier (ISSUE 14): pages evicted from HBM spill to
     # a bounded host pool of this many pages and stream back ahead of a
@@ -740,7 +741,7 @@ class EngineArgs:
             choices=["radix", "flat"],
             help="prefix index structure: radix tree with leaf-first "
             "cache-aware eviction + optional host-DRAM spill tier, or "
-            "the flat hash-chain map (ablation baseline)",
+            "the flat hash-chain map (kept as the tests' reference index)",
         )
         parser.add_argument(
             "--kv-spill-host-pages",

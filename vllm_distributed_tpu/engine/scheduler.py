@@ -126,7 +126,7 @@ class Scheduler:
         # Prefix caching swaps the allocator behind the same interface;
         # with the flag off the seed allocator (and behaviour) is
         # untouched.  The radix index (ISSUE 14) is the default cache;
-        # "flat" keeps the PR 1 hash-chain as the ablation baseline.
+        # "flat" keeps the PR 1 hash-chain as the tests' reference index.
         self.enable_prefix_caching = cache_config.enable_prefix_caching
         if not self.enable_prefix_caching:
             self.allocator = PageAllocator(num_pages, cache_config.page_size)

@@ -1,7 +1,7 @@
 """Draftless speculative decoding: n-gram prompt-lookup proposer.
 
-Decode is memory-bound (BENCH r03-r05: 0.5-1.5 GiB of KV traffic per
-micro-step dominates step time), so verifying K drafted tokens in ONE
+Decode is memory-bound (the weights and the KV stream from HBM once a
+micro-step: decode_weight_bw_pct 78.8, ledger, PR 30), so verifying K drafted tokens in ONE
 forward pass multiplies tokens-per-HBM-pass by the acceptance length —
 the classic speculative-decoding win (Leviathan et al. 2023, PAPERS.md).
 A separate draft model would need its own shards, compile cache, and

@@ -1538,9 +1538,8 @@ async def _bench_serve_async(args: argparse.Namespace) -> dict:
             result["server_metrics"]["prefix_cache_hits"] = hits
         if shared_prefix_len:
             result["shared_prefix_len"] = shared_prefix_len
-        # Engine-side pipeline flushes over the run window: the serve
-        # analogue of the microbench's stall_windows (0 = the async
-        # scheduler never had to drain and re-plan mid-run).
+        # Engine-side pipeline flushes over the run window (0 = the
+        # async scheduler never had to drain and re-plan mid-run).
         result["stall_windows"] = int(
             delta("vllm:pipeline_breaks_total")
         )

@@ -24,9 +24,6 @@ environment_variables: dict[str, Callable[[], Any]] = {
     "VDT_EXECUTE_MODEL_TIMEOUT_SECONDS": lambda: int(
         os.environ.get("VDT_EXECUTE_MODEL_TIMEOUT_SECONDS", "300")
     ),
-    "VDT_HEALTH_CHECK_TIMEOUT_SECONDS": lambda: int(
-        os.environ.get("VDT_HEALTH_CHECK_TIMEOUT_SECONDS", "10")
-    ),
     # Heartbeat liveness: the driver pings every remote agent on this
     # interval (seconds, float; 0 disables); VDT_HEARTBEAT_MISS_THRESHOLD
     # consecutive misses mark the host dead without waiting for a request
@@ -154,8 +151,9 @@ environment_variables: dict[str, Callable[[], Any]] = {
     ),
     # Restore-vs-recompute crossover: a host-resident run shorter than
     # this many tokens is recomputed instead of restored (below the
-    # crossover a DMA round trip costs more than the prefill it saves —
-    # bench the sweep with tools/prefix_cache_ablation.py --tiered).
+    # crossover a DMA round trip is taken to cost more than the prefill
+    # it saves).  Not measured on the chip; set from a CPU sweep before
+    # the round.  ROADMAP.md R-W4's cell will measure it.
     "VDT_KV_SPILL_RESTORE_MIN_TOKENS": lambda: int(
         os.environ.get("VDT_KV_SPILL_RESTORE_MIN_TOKENS", "32")
     ),
@@ -289,9 +287,10 @@ environment_variables: dict[str, Callable[[], Any]] = {
     ),
     # Prompt-length crossover (router-side): only prompts at/above this
     # many (estimated) tokens are prefilled on the prefill pool and
-    # handed off; below it the transfer costs more than the prefill it
-    # isolates, so the request is served on the decode/mixed pool like
-    # today (tools/disagg_crossover.py benches the sweep).
+    # handed off; below it the transfer is taken to cost more than the
+    # prefill it isolates, so the request is served on the decode/mixed
+    # pool.  Not measured on the chip; set from a CPU sweep before the
+    # round.  The disagg cell under ROADMAP.md D4 will measure it.
     "VDT_DISAGG_MIN_PROMPT_TOKENS": lambda: int(
         os.environ.get("VDT_DISAGG_MIN_PROMPT_TOKENS", "512")
     ),

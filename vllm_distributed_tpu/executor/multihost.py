@@ -916,8 +916,8 @@ class MultiHostExecutor(Executor):
 
     def step_stream_stats(self) -> dict:
         """Per-host run-loop stats ({dispatched, resolved, stalls,
-        inflight, max_queue_depth}) for the bench harness and the
-        dispatch microbench."""
+        inflight, max_queue_depth}): what tests/test_multihost.py holds
+        the pipelined dispatch to."""
         stats: dict[str, dict] = {}
         if self._local_runner is not None:
             stats["host0"] = self._local_runner.stats()

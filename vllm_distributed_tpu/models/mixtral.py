@@ -15,9 +15,7 @@ The default expert computation is a *sorted ragged dispatch*
 sorted by expert, and each projection is ONE grouped matmul
 (jax.lax.ragged_dot) over the sorted rows — ~k/E of the dense FLOPs,
 which is what makes a 160-expert/8-active flagship
-(Qwen3-Coder-480B-A35B, the reference's deployment) servable.  The TPU
-lowering of ragged_dot is verified truly grouped (cost-analysis flops ==
-2·M·H·I, checked on-chip by bench._check_kernels); token drop/capacity
+(Qwen3-Coder-480B-A35B, the reference's deployment) servable.  Token drop/capacity
 factors are never used — inference must match the reference exactly.
 
 Int8 experts on one chip take the same sorted dispatch through a
@@ -75,12 +73,13 @@ def select_moe_impl(requested: str, rows: int, w1, on_mesh: bool) -> str:
     in a step of more than GROUPED_MIN_ROWS routed rows (prefill, a
     mixed step, a spec-verify step) take the grouped int8 kernel: such
     a step is compute-bound and the dense einsum spends E/k times the
-    model's FLOPs there.  Every other quantized case stays dense: decode
-    is weight-BANDWIDTH-bound, where XLA fuses the dequant into the
-    einsum so the compressed bytes stream once (93% of the HBM roofline
-    at batch 31, PERF.md), and ``ragged_dot`` cannot fuse a producer: it
-    materializes the bf16 expert stack per call (4.4x slower end to end
-    at batch 32, BENCH_r05) — a statement about ragged_dot, not about
+    model's FLOPs there (step_experts_ms 53.97 -> 16.70, ledger, PR 27).
+    Every other quantized case stays dense: decode is
+    weight-BANDWIDTH-bound, where XLA fuses the dequant into the einsum
+    so the compressed bytes stream once (decode_experts_roofline_pct
+    92.6, ledger, PR 30), and ``ragged_dot`` cannot fuse a producer: it
+    materializes the bf16 expert stack per call (pre-round; its cost is
+    not measured on this tree) — a statement about ragged_dot, not about
     routing.  Unquantized experts route through ragged_dot on big row
     counts.  ``ragged`` on a tensor the grouped kernel takes means that
     kernel; ``dense`` is always the dense einsum."""

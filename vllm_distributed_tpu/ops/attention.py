@@ -8,19 +8,19 @@ runner's KV scatter, and the kernels:
 - KV pool: ONE combined array per layer of shape ``[2, num_pages,
   page_size, HD]`` — dim 0 is K/V, dims 1-2 address the token slot,
   and ``HD = num_kv_heads * head_dim`` is the flat head×dim lane axis.
-  Rationale (measured on v5e, see PERF.md):
+  Rationale:
     * heads are stored unpadded and contiguous in HD, so the attention
       kernel computes on ``[BLK, F*D]`` tiles with ONE matmul + softmax
-      chain per fold group instead of per-head slivers (the r3 kernel
-      was compute-bound on those), and a 64-wide head model (Llama-1B
-      class) no longer pays the 2× lane-padding tax of a per-head
-      ``[..., Hkv, 128]`` layout;
+      chain per fold group instead of per-head slivers, and a 64-wide
+      head model (Llama-1B class) pays no 2× lane-padding tax as a
+      per-head ``[..., Hkv, 128]`` layout would;
     * a page is ``.at[kv, page]`` — a slice of the two MAJOR dims, so
       the kernel fetches it as one contiguous ``[page, HD]`` DMA per
       K/V plane.  (Mosaic cannot slice single rows of the tiled
-      (page_size, HD) minor pair, which is why the decode-path writer
-      uses XLA dynamic_update_slice instead of a DMA kernel —
-      ops/pallas/kv_update.py);
+      (page_size, HD) minor pair, which is why single steps write
+      their rows with the XLA scatter of ``write_kv_pages`` and the
+      fused scan stages its rows and flushes them by whole pages —
+      ops/pallas/kv_flush.py);
     * token ``t`` of a request lives at page ``page_ids[t //
       page_size]``, row ``t % page_size``.
 - A step's work is a flat token batch ``[T]`` spanning mixed prefill
@@ -119,7 +119,7 @@ def split_kv_pages(
 
 
 def merge_kv_pages(k_pages: jax.Array, v_pages: jax.Array) -> jax.Array:
-    """Inverse of split_kv_pages (test/bench helper)."""
+    """Inverse of split_kv_pages (test helper)."""
     p, page, hkv, d = k_pages.shape
     return jnp.stack(
         [
@@ -168,10 +168,10 @@ def write_kv_pages(
     """Scatter this step's K/V into the combined paged pool (quantizing
     on write when the pool is int8).
 
-    Functional reference / CPU / prefill path.  The production decode
-    path is the per-row dynamic_update_slice writer
-    (ops/pallas/kv_update.py) — XLA does not keep this scatter in place
-    inside the fused decode scan at large pool sizes.
+    Every single step's writer, and the oracle of the staged flush
+    (ops/pallas/kv_flush.py), which takes its place inside the fused
+    decode scan: XLA does not keep this scatter in place there at large
+    pool sizes.
     """
     if isinstance(kv_pages, tuple):
         data, scales = kv_pages
@@ -195,7 +195,7 @@ def write_kv_pages(
     t, hkv, d = k.shape
     k = k.reshape(t, hkv * d).astype(kv_pages.dtype)
     v = v.reshape(t, hkv * d).astype(kv_pages.dtype)
-    if hkv * d < hd:  # sub-tile pools pad HD (kv_update does the same)
+    if hkv * d < hd:  # sub-tile pools pad HD to the lane tile
         pad = [(0, 0), (0, hd - hkv * d)]
         k = jnp.pad(k, pad)
         v = jnp.pad(v, pad)

@@ -45,8 +45,7 @@ The list is computed in XLA by comparison and a cumulative sum — no
 from a single step by that op.  Contract on the flat batch: a
 sequence's query tokens are contiguous and in position order.
 
-Inside a tile (unchanged since the v2 rebuild after the round-3 on-chip
-ablation, PERF.md — that kernel was COMPUTE-bound, not DMA-bound):
+Inside a tile:
 
 - **Folded-head block-diagonal compute.**  KV heads are processed in
   fold groups of F heads per matmul: queries are laid out

@@ -19,9 +19,9 @@ in ``vdt_router:handoffs``, never in ``vdt_router:migrations``, and
 never burn ``VDT_ROUTER_MAX_MIGRATIONS`` budget.  Only a failure of the
 decode-side continuation itself enters the normal migration loop.
 
-Below the ``VDT_DISAGG_MIN_PROMPT_TOKENS`` crossover (benched by
-``tools/disagg_crossover.py``) the hand-off is not planned at all and
-the request serves on the decode/mixed pool exactly as today; a fleet
+Below the ``VDT_DISAGG_MIN_PROMPT_TOKENS`` crossover (not measured on
+the chip; set from a CPU sweep before the round, ROADMAP.md D4) the
+hand-off is not planned at all and the request serves on the decode/mixed pool exactly as today; a fleet
 with no prefill-role replica never takes this path.
 """
 

@@ -1,4 +1,4 @@
-"""Synthetic model snapshots for tests, dryruns, and benches.
+"""Synthetic model snapshots for tests, dryruns, and chip_smoke.py.
 
 Zero-egress environments can't download checkpoints, so anything that
 needs a model builds one: a config.json on disk (weights come from
@@ -117,7 +117,7 @@ def full_attention_reference(q, k, v, scale, causal=True):
     return jnp.einsum("hqk,khd->qhd", p, v)
 
 
-# Shapes of real family members, for dummy-weight perf runs.
+# Shapes of real family members, for chip_smoke.py's dummy-weight runs.
 LLAMA_1B = dict(
     vocab_size=32000, hidden=2048, intermediate=8192, layers=16,
     heads=32, kv_heads=8, max_pos=4096, dtype="bfloat16",
@@ -131,18 +131,4 @@ LLAMA_7B = dict(
 LLAMA_13B = dict(
     vocab_size=32000, hidden=5120, intermediate=13824, layers=40,
     heads=40, kv_heads=40, max_pos=4096, dtype="bfloat16",
-)
-# Mixtral-8x7B (milestone config 5), for dummy-weight EP perf runs.
-MIXTRAL_8X7B = dict(
-    vocab_size=32000, hidden=4096, intermediate=14336, layers=32,
-    heads=32, kv_heads=8, num_experts=8, top_k=2, max_pos=4096,
-    dtype="bfloat16",
-)
-# Mixtral-style MoE scaled to fit ONE v5e chip with int8 weights
-# (~4.8B params): same 8-expert/top-2 routing shape as the flagship
-# family, 1B-class dims — the single-chip MoE bench config.
-MIXTRAL_8X1B = dict(
-    vocab_size=32000, hidden=2048, intermediate=5632, layers=16,
-    heads=32, kv_heads=8, num_experts=8, top_k=2, max_pos=4096,
-    dtype="bfloat16",
 )

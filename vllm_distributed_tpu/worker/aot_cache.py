@@ -213,7 +213,7 @@ class AotCache:
         # and whichever form runs first is what lands in the persistent
         # XLA cache — compiling the jitted form here would leave a warm
         # RESTART paying a full backend compile for the exp.call form
-        # (measured: r5 bench warm probes at 12-47 s before this).
+        # (pre-round observation; not measured on this tree).
         return self._runner_from_exported(
             exp, out_box["tree"], dleaves, name
         )

@@ -14,8 +14,8 @@ runner writes:
 - **HBM**: live/limit bytes from the runtime's ``memory_stats`` so
   memory creep is a gauge, not an OOM post-mortem;
 - **step roofline**: estimated bytes-touched / step-time over the
-  device's peak HBM bandwidth — the steady-state twin of the offline
-  bench's ``roofline_frac``;
+  device's peak HBM bandwidth (host wall time, not a device
+  reading);
 - **fallbacks**: every time the worker carried on without something it
   was asked to use (an unusable or unexportable AOT artifact, a compile
   cache directory it could not create), by kind — semantically safe,
