@@ -116,3 +116,50 @@ def test_cancel_aborts_request(engine):
         assert not engine.engine.has_unfinished_requests()
 
     asyncio.new_event_loop().run_until_complete(go())
+
+
+def test_loop_waits_out_the_slack_and_takes_arrivals_in(tmp_path):
+    """PR 32: with a dispatch in flight the loop does not schedule the
+    next step at once but waits while ``schedule_slack()`` allows; a
+    request that arrives meanwhile wakes it, is taken in and is in the
+    next schedule.  Tokens are those of an engine that never waits."""
+    def build(name, **kw):
+        return AsyncLLM.from_engine_args(EngineArgs(
+            model=make_tiny_llama(str(tmp_path / name)),
+            skip_tokenizer_init=True, num_kv_pages=64, max_model_len=128,
+            **kw))
+
+    async def go(eng):
+        sp = SamplingParams(temperature=0.0, max_tokens=30, ignore_eos=True)
+        t1 = asyncio.create_task(_consume(eng.generate(
+            "a", prompt_token_ids=[1, 2, 3], sampling_params=sp)))
+        await asyncio.sleep(0.15)
+        t2 = asyncio.create_task(_consume(eng.generate(
+            "b", prompt_token_ids=[4, 5, 6, 7], sampling_params=sp)))
+        return await asyncio.gather(t1, t2)
+
+    eng = build("m", num_decode_steps=4)
+    waits = []
+    real = eng.engine.schedule_slack
+
+    def slack():
+        real()  # the real arithmetic runs; the CPU's steps are too short
+        if eng.engine._pending and len(waits) < 40:
+            waits.append(time.monotonic())
+            return 0.02
+        return 0.0
+
+    eng.engine.schedule_slack = slack
+    try:
+        got = asyncio.new_event_loop().run_until_complete(go(eng))
+    finally:
+        eng.shutdown()
+    ref = build("r", num_decode_steps=1)
+    try:
+        want = asyncio.new_event_loop().run_until_complete(go(ref))
+    finally:
+        ref.shutdown()
+    assert len(waits) >= 5
+    assert [r.outputs[0].token_ids for r in got] == [
+        r.outputs[0].token_ids for r in want]
+    assert all(len(r.outputs[0].token_ids) == 30 for r in got)

@@ -146,11 +146,14 @@ _STAGGERED = [(3 + 2 * i, 9 + i) for i in range(3)]
 def _engine_run(tmp_path, monkeypatch, *, streams: str,
                 decode_steps: int, spec_k: int = 0, seq: str = "1",
                 requests=_STAGGERED, step_seconds: str = "0.01",
-                step_stats: list | None = None):
+                step_stats: list | None = None,
+                late: dict | None = None, probe: dict | None = None):
     """Boot a full LLMEngine over the mocked 2-host deployment and run
     ``requests`` to completion; returns req_id -> tokens.  With
     ``step_stats`` the executor's ``step_stream_stats()`` after every
-    engine step is appended to it."""
+    engine step is appended to it.  ``late`` maps an engine step to
+    (prompt tokens, max_tokens) requests added before it; ``probe``
+    receives the engine's dispatch and drain counts."""
     from vllm_distributed_tpu.engine.llm_engine import LLMEngine
     from vllm_distributed_tpu.sampling_params import SamplingParams
 
@@ -187,6 +190,10 @@ def _engine_run(tmp_path, monkeypatch, *, streams: str,
                 distributed_executor_backend=MockedMultiHostExecutor,
             )
         )
+        if probe is not None:
+            from tests.utils import hold_results
+
+            hold_results(engine)  # what overlaps: not this machine's speed
         for i, (prompt_len, max_tokens) in enumerate(requests):
             engine.add_request(
                 f"r{i}",
@@ -196,11 +203,30 @@ def _engine_run(tmp_path, monkeypatch, *, streams: str,
                 ),
             )
         tokens: dict[str, list[int]] = {}
-        while engine.has_unfinished_requests():
+        late = dict(late or {})
+        step = 0
+        while late or engine.has_unfinished_requests():
+            for j, (prompt_len, max_tokens) in enumerate(late.pop(step, ())):
+                engine.add_request(
+                    f"late{step}-{j}",
+                    prompt_token_ids=list(range(1, prompt_len + 1)),
+                    sampling_params=SamplingParams(
+                        temperature=0.0, max_tokens=max_tokens,
+                        ignore_eos=True,
+                    ),
+                )
+            step += 1
+            if not engine.has_unfinished_requests():
+                continue
             for out in engine.step():
                 tokens[out.request_id] = list(out.outputs[0].token_ids)
             if step_stats is not None:
                 step_stats.append(engine.executor.step_stream_stats())
+        if probe is not None:
+            probe.update(
+                dispatches=dict(engine.dispatch_counts),
+                breaks=dict(engine.pipeline_break_reasons),
+            )
         return tokens
     finally:
         if engine is not None:
@@ -255,6 +281,33 @@ def test_pipelined_vs_blocking_engine_outputs_bit_identical(
     for host in ("host0", "host1"):
         assert full[-1][host]["dispatched"] > full[0][host]["dispatched"]
         assert full[-1][host]["stalls"] == full[0][host]["stalls"], stats
+
+
+@pytest.mark.parametrize("streams", ["1", "0"])
+def test_single_steps_behind_scans_over_two_hosts(
+    tmp_path, monkeypatch, streams
+):
+    """PR 32: arrivals while a scan is in flight are admitted by a
+    single step issued WITHOUT blocking, over the step streams (a
+    non-blocking frame, two-phase on every host) and over the legacy
+    dispatch_model / fetch_results pairs alike; the multi-host executor
+    takes the same path as the single process.  Tokens are the
+    deterministic stream's, nothing drains, and both kinds of dispatch
+    were issued behind another."""
+    late = {3: [(6, 11)], 4: [(2, 7), (9, 5)], 8: [(4, 6)]}
+    probe: dict = {}
+    got = _engine_run(
+        tmp_path, monkeypatch, streams=streams, decode_steps=4,
+        requests=[(5, 40), (3, 33)], late=late, probe=probe,
+    )
+    expected = {"r0": list(range(5, 45)), "r1": list(range(3, 36))}
+    for step, reqs in late.items():
+        for j, (p, m) in enumerate(reqs):
+            expected[f"late{step}-{j}"] = list(range(p, p + m))
+    assert got == expected
+    assert probe["breaks"] == {}
+    assert probe["dispatches"].get(("step", True), 0) >= 1, probe
+    assert probe["dispatches"].get(("decode", True), 0) >= 1, probe
 
 
 def test_spec_decode_over_step_streams_bit_identical(

@@ -187,3 +187,52 @@ def test_notices_held_across_empty_steps():
     sched.update_from_output(out, {"b": [7]})
     out2 = sched.schedule()
     assert "a" not in out2.finished_req_ids
+
+
+def test_prefill_chunks_in_flight_are_counted():
+    """PR 32: three schedules with no result applied in between.  The
+    chunk in flight counts as computed (the next chunk starts behind
+    it), the request turns into a decode row once its last chunk is in
+    flight, and its budget counts the token that chunk will sample."""
+    sched = make_scheduler(max_num_batched_tokens=16)
+    req = make_req("a", prompt_len=24, max_tokens=3)
+    sched.add_request(req)
+    o1 = sched.schedule()
+    assert o1.new_requests[0].num_new_tokens == 16
+    assert (req.num_computed_tokens, req.num_inflight_tokens) == (0, 16)
+    assert req.is_prefill and not req.prefill_dispatched
+    o2 = sched.schedule()
+    (c2,) = o2.cached_requests
+    assert (c2.num_computed_tokens, c2.num_new_tokens) == (16, 8)
+    assert o2.decode_steps == 1
+    assert req.prefill_dispatched and req.num_tokens_with_inflight == 25
+    o3 = sched.schedule()  # a decode row: 2 of its 3 tokens are left
+    (c3,) = o3.cached_requests
+    assert c3.num_computed_tokens == 24
+    assert c3.num_new_tokens == min(o3.decode_steps, 2) == 2
+    assert sched.decode_room(req) == 0
+    assert sched.schedule().is_empty  # its whole budget is in flight
+    # The results land in order: no chunk before the last samples.
+    assert sched.update_from_output(o1, {}) == []
+    assert (req.num_computed_tokens, req.num_inflight_tokens) == (16, 10)
+    assert sched.update_from_output(o2, {"a": [7]}) == []
+    assert not req.is_prefill and req.num_inflight_tokens == 2
+    (done,) = sched.update_from_output(o3, {"a": [7, 7]})
+    assert done is req and req.status == RequestStatus.FINISHED_LENGTH
+    assert req.num_inflight_tokens == 0 and req.num_output_tokens == 3
+    assert sched.allocator.num_free_pages == sched.allocator.num_pages - 1
+
+
+def test_abort_with_a_prefill_chunk_in_flight_defers_the_free():
+    """A request aborted while its prompt chunk is on the device keeps
+    its pages until that step has landed (nothing may be handed the
+    pages the device is still writing)."""
+    sched = make_scheduler(max_num_batched_tokens=16)
+    sched.add_request(make_req("a", prompt_len=24, max_tokens=3))
+    o1 = sched.schedule()
+    free = sched.allocator.num_free_pages
+    sched.abort_request("a")
+    assert sched.allocator.num_free_pages == free  # deferred
+    assert sched.update_from_output(o1, {}) == []
+    assert sched.allocator.num_free_pages == sched.allocator.num_pages - 1
+    assert not sched._deferred_frees

@@ -73,6 +73,61 @@ def test_tp2_dp2_matches_single_device(tiny_llama, baseline):
     assert _greedy(tiny_llama, tp=2, dp=2) == baseline
 
 
+def _late_arrival_run(model_dir, **kw):
+    """Two requests decode; a third arrives once a scan is in flight
+    (or after the first step of a blocking engine).  Returns (tokens,
+    engine)."""
+    from tests.utils import hold_results
+
+    engine = hold_results(
+        LLMEngine.from_engine_args(
+            EngineArgs(
+                model=model_dir,
+                skip_tokenizer_init=True,
+                num_kv_pages=64,
+                max_model_len=256,
+                **kw,
+            )
+        )
+    )
+    sp = SamplingParams(temperature=0.0, max_tokens=21, ignore_eos=True)
+    for i, p in enumerate(PROMPTS[:2]):
+        engine.add_request(f"r{i}", prompt_token_ids=p, sampling_params=sp)
+    for _ in range(20):
+        engine.step()
+        if engine._pending or kw.get("num_decode_steps") == 1:
+            break
+    engine.add_request(
+        "late", prompt_token_ids=PROMPTS[-1] + [9, 4], sampling_params=sp
+    )
+    done = {}
+    while engine.has_unfinished_requests():
+        for out in engine.step():
+            if out.finished:
+                done[out.request_id] = out.outputs[0].token_ids
+    return done, engine
+
+
+@pytest.mark.parametrize("tp,dp", [(2, 2), (4, 1)])
+def test_single_step_behind_a_scan_on_a_mesh(tiny_llama, tp, dp):
+    """PR 32 on a mesh: the unpacked dp-sharded ``_jit_step`` (dp > 1)
+    and the packed one under tp take the same path as one chip — the
+    single step that admits an arrival is issued behind the scan in
+    flight, its decode rows read the replicated carry by row — and the
+    tokens are those of the blocking single-device engine."""
+    want, _ = _late_arrival_run(tiny_llama, num_decode_steps=1)
+    got, engine = _late_arrival_run(
+        tiny_llama,
+        num_decode_steps=4,
+        tensor_parallel_size=tp,
+        data_parallel_size=dp,
+    )
+    assert got == want
+    assert engine.pipeline_breaks == 0, engine.pipeline_break_reasons
+    assert engine.dispatch_counts.get(("step", True), 0) >= 1
+    assert engine.dispatch_counts.get(("decode", True), 0) >= 1
+
+
 def test_tp4_pallas_matches_single_device(tiny_llama, baseline):
     """The PRODUCTION kernel path (interpret-mode Pallas attention +
     in-place KV writer) under shard_map on a real tp=4 mesh must be

@@ -6,7 +6,7 @@ nothing at all with tracing off."""
 
 import pytest
 
-from tests.utils import make_tiny_llama
+from tests.utils import hold_results, make_tiny_llama
 from vllm_distributed_tpu import tracing
 from vllm_distributed_tpu.config import EngineArgs
 from vllm_distributed_tpu.engine.llm_engine import LLMEngine
@@ -206,6 +206,65 @@ def test_a_pipelined_scan_is_fetched_under_the_step_that_dispatched_it(
             names = by_step[sid]
             # … and its fetch stayed with the dispatching step
             assert names["runner.fetch"] == names["runner.dispatch"] == sid
+    finally:
+        engine.shutdown()
+        tracer.configure(False)
+        tracer.reset()
+
+
+def test_a_mixed_step_issued_behind_a_scan_is_fetched_under_its_own_step(
+        tmp_path):
+    """A request arrives while a scan is in flight: the single step
+    that admits it is issued without blocking (``overlapped``), its
+    ``runner.fetch`` runs on the resolver thread under the step that
+    dispatched it, and a later step processes it.  The attributes the
+    benchmark's readers take from ``runner.dispatch`` are all there."""
+    tracer = get_tracer()
+    tracer.reset()
+    engine = hold_results(
+        _engine(tmp_path, num_decode_steps=4, enable_tracing=True))
+    try:
+        for i in range(2):
+            engine.add_request(
+                f"r{i}", prompt_token_ids=[1, 5, 9, 23 + i],
+                sampling_params=SamplingParams(
+                    temperature=0.0, max_tokens=24, ignore_eos=True))
+        while not engine._pending:
+            engine.step()
+        engine.add_request(
+            "late", prompt_token_ids=[2, 4, 6, 8, 10],
+            sampling_params=SamplingParams(
+                temperature=0.0, max_tokens=6, ignore_eos=True))
+        while engine.has_unfinished_requests():
+            engine.step()
+        assert engine.pipeline_breaks == 0
+        by_step: dict = {}
+        dispatches = {}
+        for root, children in _step_traces(tracer):
+            assert {"step_id", "decode_steps", "pending",
+                    "pipeline_break"} <= set(root["attributes"])
+            assert root["attributes"]["pipeline_break"] is False
+            for c in children:
+                sid = c["attributes"]["step_id"]
+                by_step.setdefault(sid, {})[c["name"]] = root[
+                    "attributes"]["step_id"]
+                if c["name"] == "runner.dispatch":
+                    dispatches[sid] = c["attributes"]
+        for a in dispatches.values():
+            assert {"step_id", "program", "first_call", "overlapped",
+                    "seqs", "tokens", "kv_tokens"} <= set(a)
+        mixed = [sid for sid, a in dispatches.items()
+                 if a["program"].startswith("vdt_step_") and a["overlapped"]]
+        assert mixed, dispatches
+        for sid in mixed:
+            names = by_step[sid]
+            assert names["runner.prepare"] == names["runner.dispatch"] == sid
+            assert names["runner.fetch"] == sid
+            assert names["engine.process"] != sid
+        # the first dispatch of all had nothing in flight before it
+        assert dispatches[min(dispatches)]["overlapped"] is False
+        assert any(a["overlapped"] for a in dispatches.values()
+                   if a["program"].startswith("vdt_decode_"))
     finally:
         engine.shutdown()
         tracer.configure(False)

@@ -411,3 +411,36 @@ def make_tiny_qwen3(tmpdir: str, **kw) -> str:
     )
 
 
+
+
+class _HeldFuture:
+    """A dispatch's future that says it is done only once its result
+    is asked for: a device that is never early.  Whether a step finds
+    the dispatch before it still in flight then no longer depends on
+    how fast this machine ran it."""
+
+    def __init__(self, fut) -> None:
+        self._fut = fut
+
+    def done(self) -> bool:
+        return False
+
+    def result(self, timeout=None):
+        return self._fut.result(timeout)
+
+    def add_done_callback(self, fn) -> None:
+        self._fut.add_done_callback(fn)
+
+
+def hold_results(engine):
+    """Make every non-blocking dispatch of ``engine`` stay in flight
+    until the engine blocks on it (``LLMEngine._finalize_one``), so
+    tests of what overlaps are deterministic.  Returns the engine."""
+    real = engine.executor.execute_model
+
+    def execute_model(scheduler_output, non_block: bool = False):
+        out = real(scheduler_output, non_block=non_block)
+        return _HeldFuture(out) if non_block else out
+
+    engine.executor.execute_model = execute_model
+    return engine

@@ -290,6 +290,18 @@ class AsyncLLM:
                     self._wake.wait(timeout=0.2)
                 self._wake.clear()
                 continue
+            # The next step queues behind what is on the device, and
+            # what arrives after its schedule queues behind both: wait
+            # for arrivals while the device allows it (an arrival wakes
+            # the loop, which takes it in and asks again; so does the
+            # idle loop's own bound, so that a dead executor or a
+            # shutdown is seen as soon as when nothing is running).
+            slack = self.engine.schedule_slack()
+            if slack > 0.0:
+                self._phase = "slack"
+                self._wake.wait(timeout=min(slack, 0.2))
+                self._wake.clear()
+                continue
             self._phase = "step"
             outputs = self.engine.step()
             if outputs and self._loop is not None:

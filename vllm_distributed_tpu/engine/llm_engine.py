@@ -8,7 +8,9 @@ program per worker) → update request state → detokenize/stream.
 
 from __future__ import annotations
 
+import threading
 import time
+from collections import deque
 from typing import Any
 
 from vllm_distributed_tpu.config import EngineArgs, EngineConfig
@@ -162,17 +164,36 @@ class LLMEngine:
         self._failed = False
         self.failure_info = None  # HostFailure from the executor, if any
         self.executor.register_failure_callback(self._on_failure)
-        # Pipelining: dispatched-but-unapplied fused-decode steps (at most
-        # one between step() calls, two briefly within a call) — the
-        # engine-side realization of the reference's in-flight batches
-        # (max_concurrent_batches, launch.py:298-302).
-        self._pending: list[tuple[Any, Any]] = []
+        # Pipelining: dispatched-but-unapplied steps, fused scans and
+        # single steps alike (at most one between step() calls, two
+        # briefly within a call) — the engine-side realization of the
+        # reference's in-flight batches (max_concurrent_batches,
+        # launch.py:298-302).
+        # Entries: (SchedulerOutput, future, shape key, time issued).
+        self._pending: list[tuple] = []
         # Async-scheduling reconciliation count: times the pipeline had
-        # to fully drain because the predicted post-step state was
-        # invalidated (stop/EOS/budget mid-window, admissions, logprob
-        # requests).  0 at steady-state decode; surfaced by bench-serve
-        # as its stall_windows field.
+        # to fully drain because the next step needed settled host state
+        # (logprobs, penalties, a spec verify pass, page pressure, KV
+        # tier or transfer work).  0 in steady state, admissions and
+        # budget ends included; surfaced by bench-serve as its
+        # stall_windows field.
         self.pipeline_breaks = 0
+        # The same count by what forced the drain (logprobs, penalties,
+        # spec, pages, kv_tier, kv_transfer), and every dispatch issued
+        # by (kind, overlapped): overlapped = issued while another was
+        # in flight.  Mirrors of vllm:pipeline_breaks_total{reason} and
+        # vllm:dispatches_total{kind,overlapped}.
+        self.pipeline_break_reasons: dict[str, int] = {}
+        self.dispatch_counts: dict[tuple[str, bool], int] = {}
+        # What schedule_slack() reads: the shortest of the last few
+        # device times of each shape of dispatch (kept by the threads
+        # that resolve them, under the lock), when the last dispatch
+        # ended, and how long the host takes to issue one (its lead).
+        self._device_seconds: dict[tuple, deque] = {}
+        self._device_estimate: dict[tuple, float] = {}
+        self._done_lock = threading.Lock()
+        self._last_done = 0.0
+        self._issue_seconds = 0.005
 
     @classmethod
     def from_engine_args(cls, engine_args: EngineArgs) -> "LLMEngine":
@@ -273,16 +294,18 @@ class LLMEngine:
         return self.scheduler.has_unfinished_requests()
 
     # ---- the loop ----
-    def _pipeline_safe(self) -> bool:
-        """True when the next schedule() is guaranteed to be a pure decode
-        continuation of what's in flight: same running set, no admissions,
-        no prefills, no per-step host feedback (logprobs/penalties), and
-        enough free pages that scheduling cannot preempt anything."""
+    def _drain_reason(self) -> str | None:
+        """Why the dispatches in flight must land before the next
+        schedule() may run, or None: it needs settled host state only
+        where the schedule itself reads what a dispatch in flight will
+        change.  A waiting request, a running prefill, a request whose
+        budget ends in flight and a batch whose rows moved are none of
+        that: the scheduler counts in-flight work of both kinds and the
+        runner reads the decode rows' tokens from the device carry."""
         s = self.scheduler
-        if s.config.num_decode_steps <= 1 or s.waiting or not s.running:
-            return False
         if (
             s.spec is not None
+            and s.running
             and s.spec_wants_sync()
             and all(spec_eligible(r.sampling_params) for r in s.running)
         ):
@@ -297,43 +320,142 @@ class LLMEngine:
             # (spec_wants_sync hysteresis) keep the async dispatch
             # pipeline; the periodic probe drain re-engages spec when
             # the text turns repetitive.
-            return False
+            return "spec"
+        # No preemption of in-flight work: schedule() may evict a
+        # running request only when nothing of it is on the device, so
+        # the pool must hold the worst case of the step to come (the
+        # pages of what is in flight were taken when it was scheduled).
+        k = s.config.fused_decode_steps()
+        budget = s.config.max_num_batched_tokens
+        worst = 0
         for r in s.running:
-            sp = r.sampling_params
+            if r.prefill_dispatched:
+                n = k
+            else:
+                n = min(r.prefill_target - r.num_dispatched_tokens, budget)
+            worst += n // s.page_size + 1
+        if s.allocator.num_free_pages < worst:
+            return "pages"
+        return None
+
+    def _sync_reason(self, so) -> str | None:
+        """Why this step's results must be back before anything else is
+        scheduled (it is run blocking, on settled host state), or None:
+        it may be issued behind whatever is in flight."""
+        if self.config.kv_transfer_config is not None:
+            # The KV-connector path fans every step out to all workers
+            # and aggregates their replies inline (executor/abstract.py).
+            return "kv_transfer"
+        if so.kv_restore_ops or so.kv_spill_ops:
+            # Tier copies run eagerly on the pool, outside the step
+            # program, and the restore stall is read off this step.
+            return "kv_tier"
+        if so.draft_token_ids:
+            return "spec"
+        requests = self.scheduler.requests
+        for req_id in so.num_scheduled_tokens:
+            req = requests.get(req_id)
+            if req is None:
+                continue
+            sp = req.sampling_params
+            if sp.logprobs is not None:
+                # Per-token logprob rows are fetched and attached step
+                # by step (host token feedback).
+                return "logprobs"
             if (
-                r.is_prefill
-                or sp.logprobs is not None
-                or sp.repetition_penalty != 1.0
+                sp.repetition_penalty != 1.0
                 or sp.presence_penalty != 0.0
                 or sp.frequency_penalty != 0.0
             ):
-                return False
-            # A request whose remaining budget is fully in flight would be
-            # skipped by the scheduler, shrinking the batch and breaking
-            # the device carry's request order — drain first instead.
-            room = (
-                min(r.max_total_tokens, s.config.max_model_len)
-                - r.num_tokens
-                - r.num_inflight_tokens
-            )
-            if room <= 0:
-                return False
-        if self._pending:
-            prev_order = [
-                c.req_id for c in self._pending[-1][0].cached_requests
-            ]
-            if prev_order != [r.request_id for r in s.running]:
-                return False
-            k = s.config.num_decode_steps
-            worst = sum(
-                k // s.page_size + 1 for _ in s.running
-            )
-            if s.allocator.num_free_pages < worst:
-                return False
-        return True
+                # The penalty inputs are built from the host's token
+                # history.
+                return "penalties"
+        return None
+
+    def _break_pipeline(self, reason: str) -> list[RequestOutput]:
+        """Reconciliation: drain so that what follows sees settled
+        state.  Deferred page frees settle in the same drain."""
+        self.pipeline_breaks += 1
+        self.pipeline_break_reasons[reason] = (
+            self.pipeline_break_reasons.get(reason, 0) + 1
+        )
+        self.metrics.record_pipeline_break(reason)
+        return self._drain_pending()
+
+    def _count_dispatch(self, so, overlapped: bool) -> None:
+        kind = (
+            "spec"
+            if so.draft_token_ids
+            else "decode" if so.decode_steps > 1 else "step"
+        )
+        key = (kind, overlapped)
+        self.dispatch_counts[key] = self.dispatch_counts.get(key, 0) + 1
+        self.metrics.record_dispatch(kind, overlapped)
+
+    @staticmethod
+    def _shape_key(so) -> tuple:
+        """What decides a dispatch's device time, as far as the engine
+        can see it: the step program's kind and token buckets."""
+        tokens = so.total_num_scheduled_tokens
+        longest = max(so.num_scheduled_tokens.values(), default=1)
+        return (
+            so.decode_steps,
+            1 << (tokens - 1).bit_length(),
+            1 << (longest - 1).bit_length(),
+        )
+
+    def _issue(self, so, t_step0: float) -> None:
+        """Issue ``so`` without blocking and note what the next
+        schedule_slack() needs of it."""
+        fut = self.executor.execute_model(so, non_block=True)
+        key = self._shape_key(so)
+        t_issued = time.monotonic()
+        took = t_issued - t_step0
+        if took < 0.05:
+            # A first call compiles or loads its program for far longer
+            # and is no lead: averaged in, it moves the next schedules
+            # from just before a dispatch's end to just after its start,
+            # which is when a closed loop's next requests arrive.
+            self._issue_seconds += 0.2 * (took - self._issue_seconds)
+
+        def done(_fut) -> None:
+            # Resolver thread.  On the device the dispatch began when it
+            # was issued or when the one before it ended, if later.
+            now = time.monotonic()
+            with self._done_lock:
+                seen = self._device_seconds.setdefault(key, deque(maxlen=8))
+                seen.append(now - max(t_issued, self._last_done))
+                self._device_estimate[key] = min(seen)
+                self._last_done = now
+
+        fut.add_done_callback(done)
+        self._pending.append((so, fut, key, t_issued))
+
+    def schedule_slack(self) -> float:
+        """Seconds the caller may still wait for arrivals before the
+        next ``step()`` has to be called to keep the device fed.
+
+        A step issued behind a dispatch in flight queues behind it, and
+        whatever arrives after it was scheduled queues behind both: so
+        the schedule is best made as late as the device allows, not as
+        soon as the host is free.  The dispatches in flight are
+        expected to end when dispatches of their shape did lately (the
+        shortest of the last few, so an estimate errs early), and the
+        host needs its own lead to schedule, prepare and issue (twice
+        its recent mean).  0.0 where nothing is in flight, a shape has
+        not been seen yet, or the time is up."""
+        if not self._pending:
+            return 0.0
+        end = self._last_done
+        for _, fut, key, t_issued in self._pending:
+            expected = self._device_estimate.get(key)
+            if expected is None or fut.done():
+                return 0.0
+            end = max(end, t_issued) + expected
+        return max(end - 2.0 * self._issue_seconds - time.monotonic(), 0.0)
 
     def _finalize_one(self) -> list[RequestOutput]:
-        scheduler_output, result = self._pending.pop(0)
+        scheduler_output, result = self._pending.pop(0)[:2]
         if hasattr(result, "result"):  # Future
             # The wait is the resolver thread's ``runner.fetch``.
             result = result.result()
@@ -372,24 +494,29 @@ class LLMEngine:
             return self._step(span)
 
     def _step(self, span) -> list[RequestOutput]:
+        t_step0 = time.monotonic()
         outputs: list[RequestOutput] = []
         outputs.extend(self._finalize_done())
-        pipeline_break = bool(self._pending) and not self._pipeline_safe()
-        if pipeline_break:
-            # Reconciliation: the predicted continuation no longer holds
-            # (a request finished mid-window, an admission arrived, …) —
-            # drain so the next schedule sees settled state.  Deferred
-            # page frees settle in the same drain.
-            self.pipeline_breaks += 1
-            self.metrics.record_pipeline_break()
-            outputs.extend(self._drain_pending())
+        # One dispatch of any kind may be issued behind another: with
+        # fused decode on, the host's work for step N+1 (fetch and
+        # process of N-1, schedule, prepare, dispatch) runs while N is
+        # on the device.  The blocking path is the fallback for what
+        # needs settled host state, not a mode.
+        overlap = self.scheduler.config.num_decode_steps > 1
+        reason = self._drain_reason() if self._pending else None
+        if reason is not None:
+            outputs.extend(self._break_pipeline(reason))
         scheduler_output = self._schedule()
+        sync_reason = (
+            self._sync_reason(scheduler_output) if overlap else None
+        )
         if span is not NOOP_SPAN:
             span.attributes.update(
                 step_id=scheduler_output.step_id,
                 decode_steps=scheduler_output.decode_steps,
                 pending=len(self._pending),
-                pipeline_break=pipeline_break,
+                pipeline_break=reason is not None
+                or bool(self._pending and sync_reason),
             )
         if self.flight_recorder.enabled:
             self._record_flight(scheduler_output)
@@ -404,16 +531,16 @@ class LLMEngine:
             if self._pending:
                 outputs.extend(self._finalize_one())
             return outputs
-        if scheduler_output.decode_steps > 1 and self._pipeline_safe():
-            fut = self.executor.execute_model(
-                scheduler_output, non_block=True
-            )
-            self._pending.append((scheduler_output, fut))
+        if overlap and sync_reason is None:
+            self._count_dispatch(scheduler_output, bool(self._pending))
+            self._issue(scheduler_output, t_step0)
             depth = self.config.scheduler_config.max_concurrent_dispatches
             while len(self._pending) > depth - 1:
                 outputs.extend(self._finalize_one())
             return outputs
-        outputs.extend(self._drain_pending())
+        if self._pending:
+            outputs.extend(self._break_pipeline(sync_reason))
+        self._count_dispatch(scheduler_output, False)
         runner_output = self.executor.execute_model(scheduler_output)
         outputs.extend(self._process(scheduler_output, runner_output))
         return outputs

@@ -59,9 +59,10 @@ class Request:
     output_token_ids: list[int] = field(default_factory=list)
     # How many tokens have had their KV computed (chunked prefill cursor).
     num_computed_tokens: int = 0
-    # Decode tokens scheduled to the device but whose sampled results have
-    # not been applied yet (engine pipelining: dispatch N+1 can be issued
-    # before N's tokens arrive; the device scan carries the real values).
+    # Tokens scheduled to the device whose results have not been applied
+    # yet, decode rows and prefill chunks alike (engine pipelining:
+    # dispatch N+1 is issued before N's tokens arrive; a device carry
+    # holds each row's last sampled token).
     num_inflight_tokens: int = 0
     # Page ids owned by this request, in order.
     page_ids: list[int] = field(default_factory=list)
@@ -144,6 +145,29 @@ class Request:
     @property
     def is_prefill(self) -> bool:
         return self.num_computed_tokens < self.prefill_target
+
+    @property
+    def num_dispatched_tokens(self) -> int:
+        """Tokens whose KV is computed or on its way: what the workers
+        count as computed when the next dispatch is built."""
+        return self.num_computed_tokens + self.num_inflight_tokens
+
+    @property
+    def prefill_dispatched(self) -> bool:
+        """The whole prompt has been dispatched (settled or in flight):
+        the next dispatch runs this request as a decode row."""
+        return self.num_dispatched_tokens >= self.prefill_target
+
+    @property
+    def num_tokens_with_inflight(self) -> int:
+        """``num_tokens`` once everything in flight has landed: every
+        decode token in flight samples one; a prefill chunk brings none
+        until it reaches the prompt's end, where it samples the first,
+        and what was dispatched past that is decode."""
+        if not self.is_prefill:
+            return self.num_tokens + self.num_inflight_tokens
+        past = self.num_dispatched_tokens - self.prefill_target
+        return self.num_tokens + (past + 1 if past >= 0 else 0)
 
     @property
     def max_total_tokens(self) -> int:

@@ -465,7 +465,7 @@ class Scheduler:
             decode_prios = [
                 self._qos_priority(r) if self.qos.enabled else 0
                 for r in self.running
-                if not r.is_prefill
+                if r.prefill_dispatched
             ]
             if decode_prios:
                 max_decode_prio = max(decode_prios)
@@ -498,10 +498,13 @@ class Scheduler:
         # rather than one per token.  Steps where nothing drafts fall
         # back to the fused scan, so non-repetitive stretches keep the
         # fused-decode throughput.
+        # In-flight work counts (pipelining): a request whose last prompt
+        # chunk is on the device is a decode row of this schedule, its
+        # input token taken from the device carry.
         decode_only = bool(
             self.running
             and not self.waiting
-            and all(not r.is_prefill for r in self.running)
+            and all(r.prefill_dispatched for r in self.running)
             and all(
                 r.sampling_params.logprobs is None for r in self.running
             )
@@ -526,8 +529,9 @@ class Scheduler:
             if token_budget <= 0:
                 break
             drafts = None
-            if req.is_prefill:
-                remaining = req.prefill_target - req.num_computed_tokens
+            is_prefill = not req.prefill_dispatched
+            if is_prefill:
+                remaining = req.prefill_target - req.num_dispatched_tokens
                 chunk = min(remaining, token_budget)
                 if (
                     prefill_cap is not None
@@ -542,11 +546,7 @@ class Scheduler:
             else:
                 # Skip decodes that already have their whole remaining
                 # budget in flight (pipelining: results not applied yet).
-                room = (
-                    min(req.max_total_tokens, self.config.max_model_len)
-                    - req.num_tokens
-                    - req.num_inflight_tokens
-                )
+                room = self.decode_room(req)
                 if room <= 0:
                     continue
                 drafts = spec_drafts.get(req.request_id)
@@ -582,18 +582,16 @@ class Scheduler:
                     new_page_ids=new_pages,
                     # The worker's view of "computed" at dispatch time
                     # includes tokens still in flight on the device.
-                    num_computed_tokens=req.num_computed_tokens
-                    + req.num_inflight_tokens,
+                    num_computed_tokens=req.num_dispatched_tokens,
                     num_new_tokens=num_new,
                 )
             )
             if drafts is not None:
                 out.draft_token_ids[req.request_id] = drafts
                 self.spec_drafted_tokens += len(drafts)
-            if req.is_prefill:
+            if is_prefill:
                 prefill_used += num_new
-            else:
-                req.num_inflight_tokens += num_new
+            req.num_inflight_tokens += num_new
             scheduled_running.append(req)
 
         # 2) admit waiting requests while budget and seats remain.
@@ -723,6 +721,7 @@ class Scheduler:
                 )
             )
             prefill_used += num_new
+            req.num_inflight_tokens += num_new
 
         # Tiered KV (ISSUE 14): ship the spill/restore spans this
         # schedule produced (evictions during allocate, restores during
@@ -764,6 +763,14 @@ class Scheduler:
             out.finished_req_ids = []
             out.preempted_req_ids = []
         return out
+
+    def decode_room(self, req: Request) -> int:
+        """Tokens ``req`` may still be given, what is in flight counted
+        as spent: 0 or less once its whole budget is on the device."""
+        return (
+            min(req.max_total_tokens, self.config.max_model_len)
+            - req.num_tokens_with_inflight
+        )
 
     def _propose_drafts(self) -> dict[str, list[int]]:
         """N-gram prompt-lookup proposals for an all-decode step

@@ -128,6 +128,7 @@ DOCUMENTED_METRICS = (
     "vllm:step_gather_time_seconds",
     "vllm:request_success_total",
     "vllm:pipeline_breaks_total",
+    "vllm:dispatches_total",
     "vllm:requests_rejected_total",
     "vllm:engine_drain_state",
     "vllm:admission_queued_tokens",
@@ -156,6 +157,14 @@ DOCUMENTED_METRICS = (
     # ---- fleet sentinel (ISSUE 20) ----
     "vllm:slo_burn_rate",
     "vllm:itl_p99_ms",
+)
+
+
+# Why the engine drained its in-flight dispatches before a step
+# (LLMEngine._drain_reason / _sync_reason): the ``reason`` label of
+# vllm:pipeline_breaks_total.
+PIPELINE_BREAK_REASONS = (
+    "logprobs", "penalties", "spec", "pages", "kv_tier", "kv_transfer",
 )
 
 
@@ -387,13 +396,34 @@ class EngineMetrics:
             "Per-host reply wait per step (bounds device time + DCN)",
             _STEP_BUCKETS,
         )
-        self.pipeline_breaks = counter(
+        self._pipeline_breaks = Counter(
             "vllm:pipeline_breaks",
-            "Async-scheduling reconciliation drains: the predicted "
-            "post-step state was invalidated (stop/EOS/budget "
-            "mid-window, admission, preemption risk) and the dispatch "
-            "pipeline flushed before rescheduling",
+            "Async-scheduling reconciliation drains: the dispatches in "
+            "flight had to land before the next step, by what needed "
+            "settled host state: logprobs | penalties | spec | pages | "
+            "kv_tier | kv_transfer",
+            ["model_name", "reason"],
+            registry=self.registry,
         )
+        self._dispatches = Counter(
+            "vllm:dispatches",
+            "Step programs issued, by kind (decode = fused scan, step = "
+            "single prefill/mixed step, spec = verify pass) and whether "
+            "another dispatch was in flight when this one was issued",
+            ["model_name", "kind", "overlapped"],
+            registry=self.registry,
+        )
+        # Every series exists from the start, at 0: a scrape's delta
+        # never has to tell "no sample yet" from "none happened".
+        for reason in PIPELINE_BREAK_REASONS:
+            self._pipeline_breaks.labels(
+                model_name=model_name, reason=reason
+            )
+        for kind in ("decode", "step", "spec"):
+            for overlapped in ("true", "false"):
+                self._dispatches.labels(
+                    model_name=model_name, kind=kind, overlapped=overlapped
+                )
         self._success = Counter(
             "vllm:request_success",
             "Finished requests by finish reason",
@@ -612,9 +642,19 @@ class EngineMetrics:
         if self.enabled and n:
             self.preemptions.inc(n)
 
-    def record_pipeline_break(self) -> None:
+    def record_pipeline_break(self, reason: str) -> None:
         if self.enabled:
-            self.pipeline_breaks.inc()
+            self._pipeline_breaks.labels(
+                model_name=self._model_name, reason=reason
+            ).inc()
+
+    def record_dispatch(self, kind: str, overlapped: bool) -> None:
+        if self.enabled:
+            self._dispatches.labels(
+                model_name=self._model_name,
+                kind=kind,
+                overlapped="true" if overlapped else "false",
+            ).inc()
 
     def record_prompt_tokens(self, n: int) -> None:
         if self.enabled and n:

@@ -123,6 +123,9 @@ class CachedReqState:
     num_computed: int
     prefill_target: int  # sample only once computed tokens reach this
     num_prompt: int  # true prompt/output boundary (stable across preemption)
+    # Row of the device carry that holds this request's last sampled
+    # token; -1 until a dispatch first includes the request.
+    slot: int = -1
 
 
 def _needs_top_k_p(sp: SamplingParams) -> bool:
@@ -173,12 +176,21 @@ class ModelRunner:
         self.requests: dict[str, CachedReqState] = {}
         self.attn_backend = resolve_kernel_backend(attn_backend)
         self._attn_fn = None
-        # Device-resident decode carry: after a fused-K decode dispatch,
-        # (request order, next base lens, last-token device array).  Lets
-        # the next dispatch start from on-device tokens so the engine can
-        # pipeline dispatches without waiting for results (SURVEY.md §3.3,
+        # Device carry: every request's last sampled token, looked up by
+        # row.  Each step program (fused scan or single step) takes the
+        # array, reads the rows of the requests whose token the host has
+        # not fetched yet (``CachedReqState.slot``), and returns it with
+        # the rows of the requests it sampled for rewritten.  So a
+        # dispatch of any kind can be built while another is in flight,
+        # whatever joined or left the batch in between (SURVEY.md §3.3,
         # launch.py:298-302's max_concurrent_batches analog).
-        self._decode_carry: tuple | None = None
+        self._carry: jax.Array | None = None
+        self._free_slots: list[int] = []
+        # Dispatches whose results have not been fetched: a dispatch
+        # issued while this is non-zero overlaps another (the
+        # ``runner.dispatch`` span's ``overlapped``).
+        self._num_dispatched = 0
+        self._num_fetched = 0
         # Input sharding (set at load): step inputs shard their leading
         # dim over the mesh's "dp" axis; with dp=1 they are replicated.
         self._input_spec = None
@@ -402,11 +414,16 @@ class ModelRunner:
             if counts["moe"] == "grouped":
                 self.telemetry.record_moe_grouped_step()
         tracer = get_tracer()
+        # Each counter has one writer at a time (this thread; the thread
+        # that fetches), so their difference needs no lock.
+        overlapped = self._num_dispatched > self._num_fetched
+        self._num_dispatched += 1
         attrs = (
             dict(
                 step_id=so.step_id,
                 program=name,
                 first_call=first_call,
+                overlapped=overlapped,
                 **counts,
             )
             if tracer.enabled
@@ -682,7 +699,7 @@ class ModelRunner:
     def warmup_decode(self) -> int:
         """Pre-compile the fused-decode program so serving never
         recompiles mid-stream — with the pinned sequence bucket
-        (_seq_bucket), uniform K (scheduler), and the traced carry flag
+        (_seq_bucket), uniform K (scheduler), and the traced carry rows
         there is exactly ONE decode program per config.  Two
         back-to-back dispatches exercise both the host-token and
         device-carry paths through it.  Returns the number of dispatches
@@ -741,22 +758,19 @@ class ModelRunner:
                     decode_steps=k,
                 )
 
-            # Two back-to-back dispatches without resolving: the carry
-            # flag is traced (one program), but the second dispatch
-            # still validates the device-carry handoff end to end.  The
-            # scheduler deltas for the second dispatch must land first —
-            # they advance num_computed past the host token list, which
-            # is what flips the carry flag on.
+            # Two back-to-back dispatches without resolving: which rows
+            # read the carry is traced (one program), but the second
+            # dispatch still validates the device-carry handoff end to
+            # end: it starts past the host token list, so every row
+            # reads its carry row.
             r1 = self._execute_decode_steps(so(0))
             self._apply_scheduler_deltas(so(1))
-            assert self._decode_carry is not None
             r2 = self._execute_decode_steps(so(1))
             r1()
             r2()
             n += 2
             for rid in ids:
-                self.requests.pop(rid, None)
-            self._decode_carry = None
+                self._forget(rid)
         logger.info(
             "decode warmup: %d dispatches over %s seq buckets in %.1fs",
             n,
@@ -840,7 +854,7 @@ class ModelRunner:
             )
             self._execute_spec_step(so)
             for rid in ids:
-                self.requests.pop(rid, None)
+                self._forget(rid)
             n += 1
         logger.info(
             "spec-decode warmup: %d token buckets %s in %.1fs",
@@ -904,8 +918,10 @@ class ModelRunner:
                 total_num_scheduled_tokens=prompt_len,
                 decode_steps=1,
             )
-            self.execute_model(so)
-            self.requests.pop("__warmp", None)
+            out = self.execute_model(so)
+            if callable(out):
+                out()
+            self._forget("__warmp")
             n += 1
         logger.info(
             "prefill warmup: %d token buckets %s in %.1fs",
@@ -1005,7 +1021,7 @@ class ModelRunner:
         """Fused-decode sequence bucket: PINNED to the max_num_seqs
         power-of-2 so batch growth/shrink never changes the compiled
         decode program — with uniform K (scheduler) and the traced carry
-        flag, steady-state decode is ONE program per config.  Padded
+        rows, steady-state decode is ONE program per config.  Padded
         rows cost ~nothing in decode (seq_len 0 ⇒ the kernel skips them;
         the matmuls are weight-bandwidth-bound, not row-bound).  The
         single-step path keeps growth bucketing: its q-grouping scratch
@@ -1187,12 +1203,62 @@ class ModelRunner:
         return {"host_slots": len(self._host_kv), "host_bytes": total}
 
     # ---- per-step state mirroring ----
+    def _forget(self, req_id: str) -> None:
+        """Drop a request's mirrored state; its carry row is free for
+        the next request (programs run in dispatch order, so one still
+        in flight writes the row before its next owner's does)."""
+        st = self.requests.pop(req_id, None)
+        if st is not None and st.slot >= 0:
+            self._free_slots.append(st.slot)
+
+    def _carry_inputs(
+        self, states: list[CachedReqState], sampling: list[bool]
+    ) -> tuple[jax.Array, np.ndarray]:
+        """(the device carry a step program takes, ``slot_rows``: for
+        each carry row the batch row whose sampled token the program
+        writes there, -1 where it leaves the row as it is).  Gives each
+        of ``states`` a row on its first dispatch."""
+        size = self._seq_bucket()
+        if self._carry is None:
+            self._carry = jnp.zeros(
+                size,
+                jnp.int32,
+                device=(
+                    NamedSharding(self.mesh, P())
+                    if self.mesh is not None
+                    else None
+                ),
+            )
+            self._free_slots = list(range(size - 1, -1, -1))
+        slot_rows = np.full(size, -1, np.int32)
+        for row, (st, samples) in enumerate(zip(states, sampling)):
+            if st.slot < 0:
+                if not self._free_slots:
+                    raise RuntimeError(
+                        f"no free carry row for {st.req_id}: "
+                        f"{len(self.requests)} requests mirrored, "
+                        f"{size} rows"
+                    )
+                st.slot = self._free_slots.pop()
+            if samples:
+                slot_rows[st.slot] = row
+        return self._carry, slot_rows
+
+    @staticmethod
+    def _merge_carry(carry, slot_rows, sampled):
+        """Inside a step program: the carry with the rows this step
+        sampled for rewritten (one gather, no loop)."""
+        return jnp.where(
+            slot_rows >= 0, sampled[jnp.maximum(slot_rows, 0)], carry
+        )
+
     def _apply_scheduler_deltas(self, so: SchedulerOutput) -> None:
         for req_id in so.finished_req_ids:
-            self.requests.pop(req_id, None)
+            self._forget(req_id)
         for req_id in so.preempted_req_ids:
-            self.requests.pop(req_id, None)
+            self._forget(req_id)
         for new in so.new_requests:
+            self._forget(new.req_id)
             self.requests[new.req_id] = CachedReqState(
                 req_id=new.req_id,
                 token_ids=list(new.prompt_token_ids),
@@ -1208,7 +1274,12 @@ class ModelRunner:
             state.num_computed = cached.num_computed_tokens
 
     # ---- the step ----
-    def execute_model(self, so: SchedulerOutput) -> ModelRunnerOutput:
+    def execute_model(self, so: SchedulerOutput):
+        """Issue one step.  The fused scan and the single step return a
+        ``resolve()`` closure (the program is in flight; the closure
+        fetches its tokens and brings the mirrored state up to date);
+        the spec-verify step and an empty one return their
+        ``ModelRunnerOutput``."""
         # ``runner.prepare`` runs from here to the step program's
         # dispatch: each path ends it (``prep.end()``) where its inputs
         # are on their way to the device; the ``with`` closes it on the
@@ -1216,7 +1287,7 @@ class ModelRunner:
         with get_tracer().span("runner.prepare", step_id=so.step_id) as prep:
             return self._execute(so, prep)
 
-    def _execute(self, so: SchedulerOutput, prep) -> ModelRunnerOutput:
+    def _execute(self, so: SchedulerOutput, prep):
         self._apply_scheduler_deltas(so)
         # KV-tier spans land before ANY path may touch their pages
         # (spills before the evicted page is rewritten, restores before
@@ -1228,11 +1299,14 @@ class ModelRunner:
             return self._execute_spec_step(so, prep)
         if so.decode_steps > 1:
             return self._execute_decode_steps(so, prep)
-        self._decode_carry = None
 
-        order = [c.req_id for c in so.cached_requests] + [
-            n.req_id for n in so.new_requests
-        ]
+        # A row starts where the scheduler says (what is in flight
+        # counted as computed), not at the mirrored cursor, which a
+        # resolve() still to come will move.
+        rows = [(c.req_id, c.num_computed_tokens) for c in so.cached_requests]
+        rows += [(n.req_id, n.num_computed_tokens) for n in so.new_requests]
+        order = [r for r, _ in rows]
+        starts = [lo for _, lo in rows]
         states = [self.requests[r] for r in order]
         num_new = [so.num_scheduled_tokens[r] for r in order]
 
@@ -1257,13 +1331,30 @@ class ModelRunner:
         seq_lens = np.zeros(s_pad, np.int32)
         logits_idx = np.zeros(s_pad, np.int32)
         chunk_starts = np.zeros(s_pad, np.int32)
-        needs_sample = [False] * s_real
+        # Per token: the carry row its id is read from on the device,
+        # -1 where the host gives it (every prompt token; a decode
+        # row's once the host has fetched it).
+        carry_idx = np.full(t_pad, -1, np.int32)
+        needs_sample = [
+            lo + n >= st.prefill_target
+            for st, lo, n in zip(states, starts, num_new)
+        ]
+        carry, slot_rows = self._carry_inputs(states, needs_sample)
 
         cursor = 0
-        for s, (state, n) in enumerate(zip(states, num_new)):
-            lo, hi = state.num_computed, state.num_computed + n
-            ids = state.token_ids[lo:hi]
-            tokens[cursor : cursor + n] = ids
+        for s, (state, lo, n) in enumerate(zip(states, starts, num_new)):
+            hi = lo + n
+            # Thread invariant (see _execute_decode_steps): resolve() of
+            # the dispatch before this one may extend token_ids now.  A
+            # prefill row reads prompt tokens only, which never move; a
+            # decode row reads ONE list element or none, and either
+            # way gets the same token: from the list once it is there,
+            # from the carry row until then.
+            if lo >= state.prefill_target and lo >= len(state.token_ids):
+                assert n == 1 and state.slot >= 0, (state.req_id, lo, n)
+                carry_idx[cursor] = state.slot
+            else:
+                tokens[cursor : cursor + n] = state.token_ids[lo:hi]
             pos = np.arange(lo, hi, dtype=np.int32)
             positions[cursor : cursor + n] = pos
             seq_ids[cursor : cursor + n] = s
@@ -1276,11 +1367,18 @@ class ModelRunner:
             seq_lens[s] = hi
             logits_idx[s] = cursor + n - 1
             chunk_starts[s] = lo
-            needs_sample[s] = hi >= state.prefill_target
             cursor += n
 
         max_q_pad = max(next_power_of_2(max(num_new)), 1)
         smeta_np, flags = self._build_sampling_metadata(states, s_pad)
+        # The PRNG stream position is the index of the token a row
+        # samples, by the dispatched count: the mirrored token list may
+        # lag behind it.
+        smeta_np.keys[:s_real, 1] = seq_lens[:s_real].astype(np.uint32)
+        assert not (flags["do_penalties"] and (carry_idx >= 0).any()), (
+            "penalties need the host's token history: the engine drains "
+            "before such a step"
+        )
 
         if self._dp == 1:
             # One packed host→device transfer per step (see
@@ -1293,7 +1391,7 @@ class ModelRunner:
                     smeta_np.min_p, smeta_np.repetition_penalty,
                     smeta_np.presence_penalty, smeta_np.frequency_penalty,
                     smeta_np.keys, smeta_np.prompt_tokens,
-                    smeta_np.output_tokens,
+                    smeta_np.output_tokens, carry_idx, slot_rows,
                 ]
             )
             if self.mesh is not None:
@@ -1302,7 +1400,7 @@ class ModelRunner:
                 )
             statics = dict(spec=pack_spec, max_q_pad=max_q_pad, **flags)
             method = "_jit_step_packed"
-            args = (self.params, self.kv_caches, packed)
+            args = (self.params, self.kv_caches, packed, carry)
         else:
             meta = AttentionMetadata(
                 q_seq_ids=jnp.asarray(seq_ids),
@@ -1319,9 +1417,15 @@ class ModelRunner:
             token_ids = jax.device_put(token_ids, spec)
             meta = jax.tree.map(lambda x: jax.device_put(x, spec), meta)
             smeta = jax.tree.map(lambda x: jax.device_put(x, spec), smeta)
+            whole = NamedSharding(self.mesh, P())
             statics = dict(max_q_pad=max_q_pad, **flags)
             method = "_jit_step"
-            args = (self.params, self.kv_caches, token_ids, meta, smeta)
+            args = (
+                self.params, self.kv_caches, token_ids, meta, smeta,
+                carry,
+                jax.device_put(carry_idx, spec),
+                jax.device_put(slot_rows, whole),
+            )
 
         kv_tokens = int(seq_lens.sum())
         counts = dict(seqs=s_real, tokens=t_real, kv_tokens=kv_tokens)
@@ -1331,7 +1435,7 @@ class ModelRunner:
             )
         prep.end()
         t_step0 = time.perf_counter()
-        sampled, logprobs, self.kv_caches = self._run_program(
+        sampled, logprobs, self.kv_caches, self._carry = self._run_program(
             "prefill",
             method,
             program_name(
@@ -1343,37 +1447,48 @@ class ModelRunner:
             rows=t_pad,
             **counts,
         )
+        # As in the fused scan: resolve() may run on the executor's
+        # resolver thread, and the fetch stays a child of this step.
+        step_ctx = current_ctx()
 
-        with get_tracer().span("runner.fetch", step_id=so.step_id):
-            if logprobs is not None:
-                sampled, logprobs = jax.device_get((sampled, logprobs))
-                sampled = np.asarray(sampled)
-                logprobs = np.asarray(logprobs)
-            else:
-                sampled = np.asarray(jax.device_get(sampled))
-        self._record_step_bw(time.perf_counter() - t_step0, kv_tokens)
+        def resolve() -> ModelRunnerOutput:
+            with get_tracer().span(
+                "runner.fetch", parent=step_ctx, step_id=so.step_id
+            ):
+                if logprobs is not None:
+                    host_toks, host_lps = jax.device_get((sampled, logprobs))
+                    host_lps = np.asarray(host_lps)
+                else:
+                    host_toks, host_lps = jax.device_get(sampled), None
+                host_toks = np.asarray(host_toks)
+            self._num_fetched += 1
+            self._record_step_bw(time.perf_counter() - t_step0, kv_tokens)
 
-        out = ModelRunnerOutput()
-        # Restore-bearing steps are always admission (blocking) steps,
-        # so the stall lands on the output the engine actually reads.
-        out.kv_tier_seconds = tier_s
-        for s, (state, n) in enumerate(zip(states, num_new)):
-            state.num_computed += n
-            if not needs_sample[s]:
-                out.num_prompt_tokens_processed[state.req_id] = n
-                continue
-            tok = int(sampled[s])
-            state.token_ids.append(tok)
-            out.sampled_token_ids[state.req_id] = [tok]
-            nlp = state.sampling_params.logprobs
-            if nlp is not None and logprobs is not None:
-                row = logprobs[s]
-                nlp = min(nlp, row.shape[-1] - 1)
-                top = np.argpartition(row, -max(nlp, 1))[-max(nlp, 1) :]
-                d = {int(i): float(row[i]) for i in top}
-                d[tok] = float(row[tok])
-                out.logprobs[state.req_id] = [d]
-        return out
+            out = ModelRunnerOutput()
+            # Restore-bearing steps are always admission (blocking)
+            # steps, so the stall lands on the output the engine reads.
+            out.kv_tier_seconds = tier_s
+            for s, (state, lo, n) in enumerate(zip(states, starts, num_new)):
+                # Never backwards: the deltas of a dispatch built since
+                # may have moved the cursor on.
+                state.num_computed = max(state.num_computed, lo + n)
+                if not needs_sample[s]:
+                    out.num_prompt_tokens_processed[state.req_id] = n
+                    continue
+                tok = int(host_toks[s])
+                state.token_ids.append(tok)
+                out.sampled_token_ids[state.req_id] = [tok]
+                nlp = state.sampling_params.logprobs
+                if nlp is not None and host_lps is not None:
+                    row = host_lps[s]
+                    nlp = min(nlp, row.shape[-1] - 1)
+                    top = np.argpartition(row, -max(nlp, 1))[-max(nlp, 1) :]
+                    d = {int(i): float(row[i]) for i in top}
+                    d[tok] = float(row[tok])
+                    out.logprobs[state.req_id] = [d]
+            return out
+
+        return resolve
 
     def _build_sampling_metadata(
         self,
@@ -1463,11 +1578,19 @@ class ModelRunner:
         token_ids,
         meta: AttentionMetadata,
         smeta: SamplingMetadata,
+        carry,
+        carry_idx,
+        slot_rows,
         max_q_pad: int,
         do_penalties: bool,
         do_top_k_p: bool,
         return_logprobs: bool,
     ):
+        # A decode row whose last token the host has not fetched reads
+        # it from the carry, by row.
+        token_ids = jnp.where(
+            carry_idx >= 0, carry[jnp.maximum(carry_idx, 0)], token_ids
+        )
         attn_fn = self._attn_fn
         if getattr(attn_fn, "needs_max_q", False):
             attn_fn = partial(attn_fn, max_q=max_q_pad)
@@ -1487,7 +1610,8 @@ class ModelRunner:
                 do_top_k_p=do_top_k_p,
                 return_logprobs=return_logprobs,
             )
-        return tokens, logprobs, kv_caches
+        carry = self._merge_carry(carry, slot_rows, tokens)
+        return tokens, logprobs, kv_caches, carry
 
     @partial(
         jax.jit,
@@ -1507,6 +1631,9 @@ class ModelRunner:
         token_ids,
         meta: AttentionMetadata,
         smeta: SamplingMetadata,
+        carry,
+        carry_idx,
+        slot_rows,
         *,
         max_q_pad: int,
         do_penalties: bool,
@@ -1515,6 +1642,7 @@ class ModelRunner:
     ):
         return self._step_core(
             params, kv_caches, token_ids, meta, smeta,
+            carry, carry_idx, slot_rows,
             max_q_pad, do_penalties, do_top_k_p, return_logprobs,
         )
 
@@ -1535,6 +1663,7 @@ class ModelRunner:
         params,
         kv_caches,
         packed,
+        carry,
         *,
         spec: tuple,
         max_q_pad: int,
@@ -1545,7 +1674,7 @@ class ModelRunner:
         (
             tokens, seq_ids, positions, slots, block_tables, seq_lens,
             logits_idx, chunk_starts, temp, top_k, top_p, min_p, rep,
-            pres, freq, keys, prompt_toks, out_toks,
+            pres, freq, keys, prompt_toks, out_toks, carry_idx, slot_rows,
         ) = unpack_device_arrays(packed, spec)
         meta = AttentionMetadata(
             q_seq_ids=seq_ids,
@@ -1570,6 +1699,7 @@ class ModelRunner:
         )
         return self._step_core(
             params, kv_caches, tokens, meta, smeta,
+            carry, carry_idx, slot_rows,
             max_q_pad, do_penalties, do_top_k_p, return_logprobs,
         )
 
@@ -1600,7 +1730,6 @@ class ModelRunner:
         the reconciled cursor and are overwritten in place by the next
         window — never registered by the prefix cache, never read by
         later attention (seq_lens follows the accepted cursor)."""
-        self._decode_carry = None
         order = [c.req_id for c in so.cached_requests]
         states = [self.requests[r] for r in order]
         num_new = [so.num_scheduled_tokens[r] for r in order]
@@ -1690,6 +1819,7 @@ class ModelRunner:
         )
         with get_tracer().span("runner.fetch", step_id=so.step_id):
             toks, n_emit = jax.device_get((toks, n_emit))
+        self._num_fetched += 1
         # A verify window streams weights+KV ONCE for up to K+1 tokens —
         # the roofline asymmetry spec decode exists to exploit.
         self._record_step_bw(time.perf_counter() - t_step0, kv_tokens)
@@ -1765,6 +1895,9 @@ class ModelRunner:
         k_steps = so.decode_steps
         order = tuple(c.req_id for c in so.cached_requests)
         states = [self.requests[r] for r in order]
+        # Where each row starts is the scheduler's word (what is in
+        # flight counted as computed), not the mirrored cursor.
+        starts = [c.num_computed_tokens for c in so.cached_requests]
         # Per-sequence scheduled token counts: a request whose remaining
         # budget is under k_steps runs its first n micro-steps and is
         # MASKED for the rest (queries dropped, KV writes routed to the
@@ -1774,16 +1907,16 @@ class ModelRunner:
         # Thread-interleaving invariant (engine thread here vs a prior
         # dispatch's resolve() on the executor's resolver thread): both
         # may touch CachedReqState concurrently, which is safe because
-        # (a) resolve() writes num_computed as an ABSOLUTE value equal
-        # to its base_lens + k, which the host_current check below
-        # treats identically whether it reads the pre- or post-resolve
-        # value (both outcomes converge to the same dispatched token:
-        # either token_ids[-1] already holds it or the device carry
-        # does), and (b) penalties/logprobs — the only consumers of
-        # token_ids contents — are excluded by _pipeline_safe when a
-        # dispatch is in flight.  CPython's GIL makes each individual
-        # list/int access atomic.  Do not add reads of st.token_ids
-        # beyond the patterns below without revisiting this.
+        # (a) a row's start comes from the SchedulerOutput, never from
+        # the cursor resolve() moves, (b) the one read of token_ids
+        # below gives the same dispatched token whether it lands before
+        # or after resolve() appends (either the list already holds the
+        # token at index ``start`` or the request's carry row does), and
+        # (c) penalties/logprobs — the only consumers of token_ids
+        # contents — make the engine drain before the step.  CPython's
+        # GIL makes each individual list/int access atomic.  Do not add
+        # reads of st.token_ids beyond the patterns below without
+        # revisiting this.
         s_real = len(order)
         s_pad = self._seq_bucket()
         max_pages = max(max(len(st.page_ids) for st in states), 1)
@@ -1795,37 +1928,23 @@ class ModelRunner:
         n_active = np.zeros(s_pad, np.int32)
         block_tables = np.zeros((s_pad, pages_pad), np.int32)
         out_lens = np.zeros(s_pad, np.int32)
-        host_current = True
-        for s, st in enumerate(states):
-            base_lens[s] = st.num_computed
+        # Per row: the carry row its first token is read from on the
+        # device, -1 where the host gives it.  Traced (not static), so
+        # every mix of the two shares ONE compiled program.
+        carry_idx = np.full(s_pad, -1, np.int32)
+        carry, slot_rows = self._carry_inputs(states, [True] * s_real)
+        for s, (st, lo) in enumerate(zip(states, starts)):
+            base_lens[s] = lo
             valid[s] = 1
             n_active[s] = num_new[st.req_id]
             block_tables[s, : len(st.page_ids)] = st.page_ids
             out_lens[s] = len(st.token_ids) - st.num_prompt
-            if st.num_computed == len(st.token_ids) - 1:
-                tokens[s] = st.token_ids[-1]
+            if lo < len(st.token_ids):
+                tokens[s] = st.token_ids[lo]
             else:
-                # Results of a previous dispatch are still in flight; the
-                # real token values live in the device carry.
-                host_current = False
-
-        use_carry = False
-        if not host_current:
-            carry = self._decode_carry
-            assert (
-                carry is not None
-                and carry[0] == order
-                and np.array_equal(carry[1][:s_real], base_lens[:s_real])
-            ), "pipelined decode dispatch without a matching device carry"
-            use_carry = True
-        carry_tok = (
-            self._decode_carry[2]
-            if use_carry
-            else jnp.zeros(s_pad, jnp.int32)
-        )
-        # Traced (not static) so both carry variants share ONE compiled
-        # program — the r4 static use_carry doubled every warmup/compile.
-        use_carry_flag = np.full(1, int(use_carry), np.int32)
+                # Results of a previous dispatch are still in flight;
+                # the token lives in the request's carry row.
+                carry_idx[s] = st.slot
 
         smeta_np, flags = self._build_sampling_metadata(
             states, s_pad, extra_output_len=k_steps + 1
@@ -1833,7 +1952,7 @@ class ModelRunner:
         assert not flags["return_logprobs"], (
             "scheduler must not fuse decode steps when logprobs are on"
         )
-        assert not (use_carry and flags["do_penalties"]), (
+        assert not (flags["do_penalties"] and (carry_idx >= 0).any()), (
             "pipelined decode cannot run with penalties (stale host state)"
         )
         # PRNG stream position must follow the device-side token count,
@@ -1841,7 +1960,7 @@ class ModelRunner:
         smeta_np.keys[:s_real, 1] = (base_lens[:s_real] + 1).astype(np.uint32)
         packed, pack_spec = pack_host_arrays(
             [
-                tokens, base_lens, valid, n_active, use_carry_flag,
+                tokens, base_lens, valid, n_active, carry_idx, slot_rows,
                 block_tables, out_lens,
                 smeta_np.temperature, smeta_np.top_k, smeta_np.top_p,
                 smeta_np.min_p, smeta_np.repetition_penalty,
@@ -1861,23 +1980,23 @@ class ModelRunner:
         kv_tokens_scanned = int(base_lens.sum())
         prep.end()
         t_step0 = time.perf_counter()
-        toks, carry_out, self.kv_caches = self._run_program(
+        toks, self._carry, self.kv_caches = self._run_program(
             "decode",
             "_jit_decode_steps",
             program_name(
                 "decode", statics, k=k_steps, s=s_pad, p=pages_pad
             ),
             statics,
-            (self.params, self.kv_caches, packed, carry_tok),
+            (self.params, self.kv_caches, packed, carry),
             so,
             rows=s_pad,
             seqs=s_real,
             tokens=int(n_active.sum()),
             kv_tokens=kv_tokens_scanned,
         )
-        # Each sequence's LAST VALID token stays on device as the next
-        # dispatch's input (under-K tails: token n_active-1, not K-1).
-        self._decode_carry = (order, base_lens + n_active, carry_out)
+        # Each sequence's LAST VALID token stays on device, in its carry
+        # row, as the input of the next dispatch that holds the request
+        # (under-K tails: token n_active-1, not K-1).
         # resolve() may run on the executor's resolver thread, which
         # has no span context of its own: the fetch stays a child of
         # the step that dispatched it.
@@ -1888,6 +2007,7 @@ class ModelRunner:
                 "runner.fetch", parent=step_ctx, step_id=so.step_id
             ):
                 host_toks = np.asarray(jax.device_get(toks))  # [K, s_pad]
+            self._num_fetched += 1
             # K micro-steps = K weights+KV HBM passes.  Wall time spans
             # dispatch→resolve (includes any pipeline overlap — an
             # estimate, like the byte count).
@@ -1898,9 +2018,9 @@ class ModelRunner:
             for s, st in enumerate(states):
                 n = int(n_active[s])
                 seq_toks = [int(t) for t in host_toks[:n, s]]
-                # Absolute (not +=): scheduler deltas for a pipelined
-                # next dispatch may already have advanced num_computed.
-                st.num_computed = int(base_lens[s]) + n
+                # Never backwards: the deltas of a dispatch built since
+                # may have moved the cursor on.
+                st.num_computed = max(st.num_computed, int(base_lens[s]) + n)
                 st.token_ids.extend(seq_toks)
                 out.sampled_token_ids[st.req_id] = seq_toks
             return out
@@ -1923,7 +2043,7 @@ class ModelRunner:
         params,
         kv_caches,
         packed,
-        carry_tok,
+        carry,
         *,
         spec: tuple,
         k_steps: int,
@@ -1931,11 +2051,13 @@ class ModelRunner:
         do_top_k_p: bool,
     ):
         (
-            tokens, base_lens, valid, n_active, use_carry_flag,
+            tokens, base_lens, valid, n_active, carry_idx, slot_rows,
             block_tables, out_lens, temp, top_k,
             top_p, min_p, rep, pres, freq, keys, prompt_toks, out_toks,
         ) = unpack_device_arrays(packed, spec)
-        tokens = jnp.where(use_carry_flag[0] > 0, carry_tok, tokens)
+        tokens = jnp.where(
+            carry_idx >= 0, carry[jnp.maximum(carry_idx, 0)], tokens
+        )
         s_pad = tokens.shape[0]
         rows = jnp.arange(s_pad, dtype=jnp.int32)
         page_size = self.page_size
@@ -2106,8 +2228,6 @@ class ModelRunner:
                     )
                     for kv_l, side_l in zip(kv_caches, sides_out)
                 ]
-        # Next dispatch's input token: each sequence's last VALID one.
-        carry_out = toks[
-            jnp.clip(n_active - 1, 0, k_steps - 1), rows
-        ]
-        return toks, carry_out, kv_caches
+        # Each sequence's last VALID token, into its carry row.
+        last = toks[jnp.clip(n_active - 1, 0, k_steps - 1), rows]
+        return toks, self._merge_carry(carry, slot_rows, last), kv_caches
