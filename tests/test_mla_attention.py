@@ -1,0 +1,318 @@
+"""``vdt_mla_attention`` (ops/pallas/mla_attention.py): the interpreted
+kernel against plain ``jnp`` over ragged chunks, cached prefixes and
+decode rows with the staged side buffer; the one-plane flush; the
+interleaved RoPE against its pairwise definition; and both kernels
+compiled at the LongCat widths for a described v5e chip."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from vllm_distributed_tpu.models.common import (
+    apply_rope_interleaved,
+    rope_frequencies,
+)
+from vllm_distributed_tpu.ops.attention import (
+    AttentionMetadata,
+    mla_attention_reference,
+    write_kv_pages,
+)
+from vllm_distributed_tpu.ops.pallas import mla_attention as mla
+from vllm_distributed_tpu.ops.pallas.kv_flush import kv_flush, kv_flush_cpu
+
+PAGE = 16
+
+
+def _plain(q, rows, q_pos, scale, vw):
+    """One sequence, no pages: q [n, H, W] at positions q_pos over rows
+    [ctx, W] (row j at position j)."""
+    s = jnp.einsum("nhw,cw->nhc", q, rows) * scale
+    mask = jnp.arange(rows.shape[0])[None, :] <= q_pos[:, None]
+    p = jax.nn.softmax(jnp.where(mask[:, None, :], s, -jnp.inf), axis=-1)
+    return jnp.einsum("nhc,cv->nhv", p, rows[:, :vw])
+
+
+def _batch(rng, seqs, h, w, wp, t_pad, s_pad, pages_pad, num_pages=64):
+    """seqs: [(cached tokens, new tokens)].  Returns q, pool, metadata
+    and the dense rows of every sequence."""
+    pool = jnp.zeros((1, num_pages, PAGE, wp), jnp.float32)
+    q = np.zeros((t_pad, h, w), np.float32)
+    ids = np.full(t_pad, s_pad, np.int32)
+    pos = np.zeros(t_pad, np.int32)
+    bt = np.zeros((s_pad, pages_pad), np.int32)
+    lens = np.zeros(s_pad, np.int32)
+    starts = np.zeros(s_pad, np.int32)
+    dense, cursor, page = [], 0, 1
+    for s, (cached, new) in enumerate(seqs):
+        total = cached + new
+        n_pages = -(-total // PAGE)
+        bt[s, :n_pages] = np.arange(page, page + n_pages)
+        rows = rng.standard_normal((total, w)).astype(np.float32)
+        full = np.zeros((total, 1, wp), np.float32)
+        full[:, 0, :w] = rows
+        slots = bt[s, np.arange(total) // PAGE] * PAGE + np.arange(total) % PAGE
+        pool = write_kv_pages(pool, jnp.asarray(full), None, jnp.asarray(slots))
+        page += n_pages
+        q[cursor:cursor + new] = rng.standard_normal((new, h, w))
+        ids[cursor:cursor + new] = s
+        pos[cursor:cursor + new] = np.arange(cached, total)
+        lens[s], starts[s] = total, cached
+        dense.append((rows, slice(cursor, cursor + new)))
+        cursor += new
+    meta = AttentionMetadata(
+        q_seq_ids=jnp.asarray(ids), q_positions=jnp.asarray(pos),
+        slot_mapping=jnp.zeros(t_pad, jnp.int32),
+        block_tables=jnp.asarray(bt), seq_lens=jnp.asarray(lens),
+        logits_indices=jnp.zeros(s_pad, jnp.int32),
+        chunk_starts=jnp.asarray(starts))
+    return jnp.asarray(q), pool, meta, dense
+
+
+@pytest.mark.parametrize("seqs,max_q", [
+    # ragged chunks, two with a cached prefix, single tokens between
+    ([(0, 40), (50, 1), (33, 17), (0, 1), (90, 1), (16, 3)], 64),
+    ([(0, 5), (7, 2)], 8),  # tiles too narrow to split the singles off
+    ([(35, 1), (0, 1), (64, 1), (17, 1)], 1),  # a decode-only step
+])
+def test_kernel_matches_plain_attention(seqs, max_q):
+    rng = np.random.default_rng(0)
+    h, w, wp, vw = 4, 24, 128, 16
+    q, pool, meta, dense = _batch(rng, seqs, h, w, wp, 64, 8, 8)
+    scale = 0.3
+    got = np.asarray(mla.mla_attention_cpu(
+        q, pool, meta, scale=scale, value_width=vw, max_q=max_q))
+    ref = np.asarray(mla_attention_reference(
+        q, pool, meta, scale=scale, value_width=vw))
+    for rows, sl in dense:
+        n = sl.stop - sl.start
+        pos = jnp.arange(rows.shape[0] - n, rows.shape[0])
+        want = np.asarray(_plain(q[sl], jnp.asarray(rows), pos, scale, vw))
+        assert np.abs(got[sl] - want).max() < 2e-5
+        assert np.abs(ref[sl] - want).max() < 2e-5
+    assert not got[sum(n for _, n in seqs):].any()  # padding rows: zeros
+
+
+def test_a_step_compiled_for_its_token_bucket_serves_every_longer_chunk(
+        monkeypatch):
+    """``mla_max_q_class``: where the chunk tile is at its most, the
+    token bucket stands for every longest-chunk bucket, and the program
+    made for it gives what the tight one gives."""
+    # a state budget of four query tokens a tile at these widths
+    h, w, wp, vw = 4, 24, 128, 16
+    monkeypatch.setattr(
+        mla, "_STATE_BYTES", 4 * h * (vw + 2 * mla._LANES) * 4)
+    kw = dict(num_heads=h, value_width=vw)
+    assert [mla.mla_max_q_class(m, 64, **kw) for m in (1, 2, 4, 8, 64)] == [
+        1, 2, 64, 64, 64]
+    rng = np.random.default_rng(3)
+    seqs = [(0, 9), (50, 1), (33, 6), (0, 1), (16, 3)]
+    q, pool, meta, dense = _batch(rng, seqs, h, w, wp, 64, 8, 8)
+    tight, wide = (
+        np.asarray(mla.mla_attention_cpu(
+            q, pool, meta, scale=0.3, value_width=vw, max_q=m))
+        for m in (16, mla.mla_max_q_class(16, 64, **kw)))
+    assert np.array_equal(tight, wide)
+    for rows, sl in dense:
+        n = sl.stop - sl.start
+        pos = jnp.arange(rows.shape[0] - n, rows.shape[0])
+        want = np.asarray(_plain(q[sl], jnp.asarray(rows), pos, 0.3, vw))
+        assert np.abs(wide[sl] - want).max() < 2e-5
+
+
+def test_the_cells_lattice_is_one_program_a_token_bucket_past_the_tile():
+    """At the LongCat widths a chunk tile holds 16 tokens: of the 30
+    (token bucket, longest chunk) pairs that 64 clients make, 11 step
+    programs are left to compile."""
+    import json
+
+    from benchmark import lattice
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    mix = json.load(open(os.path.join(
+        repo, "benchmark", "traffic", "chat-closed-64.json")))
+    pairs = lattice.buckets(
+        mix["arrival"]["clients"], mix["warmup"]["sweep"])
+    kw = dict(num_heads=64, value_width=512)
+    assert mla._tile_tokens(2048, **kw) == 16
+    classes = {(t, mla.mla_max_q_class(q, t, **kw)) for t, q in pairs}
+    assert len(pairs) == 30 and len(classes) == 11
+    # a class's tile list is never shorter than its members need
+    for t, q in pairs:
+        c = mla.mla_max_q_class(q, t, **kw)
+        mq = mla._tile_tokens(c, **kw)
+        assert mq == mla._tile_tokens(q, **kw)
+        assert mla.num_query_tiles(t, 64, mq, c) >= mla.num_query_tiles(
+            t, 64, mq, max(q, mq))
+
+
+def test_decode_rows_read_the_pool_and_the_staged_side_buffer():
+    """The fused scan's call: ``seq_lens`` is the pool-resident length,
+    this dispatch's rows are in the side buffer; then the one-plane
+    flush puts them where a single step's scatter would."""
+    rng = np.random.default_rng(1)
+    h, w, wp, vw, k_blk = 4, 24, 128, 16, 4
+    base = [(35, 0), (16, 0), (0, 0), (50, 0)]  # (pool tokens, -)
+    _, pool, meta, dense = _batch(
+        rng, [(c, 1) for c, _ in base], h, w, wp, 8, 8, 8)
+    # pool holds base+1 rows a sequence; the scan sees ``base`` of them
+    lens = np.array([c for c, _ in base] + [0] * 4, np.int32)
+    n_side = 3  # micro-step 2: three staged rows a sequence
+    side = np.zeros((8, 1, k_blk, wp), np.float32)
+    side[:4, 0, :n_side, :w] = rng.standard_normal((4, n_side, w))
+    q = rng.standard_normal((8, h, w)).astype(np.float32)
+    ids = np.array([0, 1, 8, 3, 8, 8, 8, 8], np.int32)  # row 2 masked
+    meta = AttentionMetadata(
+        q_seq_ids=jnp.asarray(ids),
+        q_positions=jnp.asarray(lens + n_side - 1),
+        slot_mapping=meta.slot_mapping, block_tables=meta.block_tables,
+        seq_lens=jnp.asarray(lens), logits_indices=meta.logits_indices,
+        chunk_starts=jnp.asarray(lens + n_side - 1))
+    kw = dict(scale=0.3, value_width=vw, side_kv=jnp.asarray(side),
+              side_len=jnp.asarray([n_side], jnp.int32))
+    got = np.asarray(mla.mla_attention_cpu(
+        jnp.asarray(q), pool, meta, max_q=1, **kw))
+    ref = np.asarray(mla_attention_reference(jnp.asarray(q), pool, meta, **kw))
+    for s in (0, 1, 3):
+        rows = np.concatenate([dense[s][0][:lens[s]], side[s, 0, :n_side, :w]])
+        want = np.asarray(_plain(
+            jnp.asarray(q[s:s + 1]), jnp.asarray(rows),
+            jnp.asarray([rows.shape[0] - 1]), 0.3, vw))
+        assert np.abs(got[s] - want[0]).max() < 2e-5
+        assert np.abs(ref[s] - want[0]).max() < 2e-5
+    assert not got[2].any()
+    # the flush: rows [base, base + n) of each live sequence, in place
+    n_active = jnp.asarray([3, 2, 0, 3, 0, 0, 0, 0], jnp.int32)
+    flushed = kv_flush_cpu(
+        pool, jnp.asarray(side), meta.block_tables, jnp.asarray(lens),
+        n_active)
+    want_pool = pool
+    for s, n in ((0, 3), (1, 2), (3, 3)):
+        p = lens[s] + np.arange(n)
+        slots = np.asarray(meta.block_tables)[s, p // PAGE] * PAGE + p % PAGE
+        want_pool = write_kv_pages(
+            want_pool, jnp.asarray(side[s, 0, :n])[:, None, :], None,
+            jnp.asarray(slots))
+    assert np.array_equal(np.asarray(flushed)[0, 1:], np.asarray(want_pool)[0, 1:])
+
+
+def test_interleaved_rope_is_the_pairwise_rotation():
+    rng = np.random.default_rng(2)
+    x = rng.standard_normal((5, 3, 8)).astype(np.float32)
+    pos = np.array([0, 1, 7, 100, 4095], np.int32)
+    inv = np.asarray(rope_frequencies(8, 1e7))
+    got = np.asarray(apply_rope_interleaved(
+        jnp.asarray(x), jnp.asarray(pos), jnp.asarray(inv)))
+    assert inv.shape == (4,) and np.allclose(inv[1], 1e7 ** (-2 / 8))
+    for t in range(5):
+        for i in range(4):
+            a = pos[t] * inv[i]
+            rot = np.array([[np.cos(a), -np.sin(a)], [np.sin(a), np.cos(a)]])
+            want = x[t, :, 2 * i:2 * i + 2] @ rot.T
+            assert np.abs(got[t, :, 2 * i:2 * i + 2] - want).max() < 1e-5
+    # a rotation: norms kept, position 0 untouched, and the score of a
+    # query and a key depends on their distance alone
+    assert np.allclose(np.linalg.norm(got, axis=-1),
+                       np.linalg.norm(x, axis=-1), atol=1e-5)
+    assert np.array_equal(got[0], x[0])
+
+    def score(pq, pk):
+        r = apply_rope_interleaved(
+            jnp.asarray(x[:2]), jnp.asarray([pq, pk]), jnp.asarray(inv))
+        return float((r[0, 0] * r[1, 0]).sum())
+
+    assert abs(score(10, 3) - score(1007, 1000)) < 1e-4
+
+
+def test_tile_counts_are_the_wrappers_lists():
+    # 64 heads: a chunk tile holds 16 tokens (1,024 softmax rows)
+    assert mla._tile_tokens(1, 64, 512) == 1
+    assert mla._tile_tokens(2048, 64, 512) == 16
+    assert mla._tile_tokens(4, 64, 512) == 4
+    tiles, rows = mla.mla_query_tile_counts(
+        [1] * 40 + [100, 17], max_q=128, num_heads=64, value_width=512)
+    assert (tiles, rows) == (40 + 7 + 2, 40 + 9 * 16)
+    assert mla.mla_query_tile_counts(
+        [1] * 64, max_q=1, num_heads=64, value_width=512) == (64, 64)
+
+
+# ---- compiled for a described v5e chip, no chip attached -----------------
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "not here"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.mark.parametrize("t,max_q,s,side", [
+    (2048, 2048, 64, False),  # the cell's largest mixed step
+    (256, 128, 64, False),
+    (64, 1, 64, True),  # the fused scan's call
+    (64, 1, 64, False),  # a decode-only single step
+])
+def test_attention_compiles_for_the_v5e_at_longcat_widths(
+        t, max_q, s, side, one_chip, no_compile_cache):
+    """64 heads over one 576-value row in 640 lanes, a 256-page table:
+    Mosaic takes it, a chunk step is two kernel calls (chunk tiles,
+    single tokens), a decode step one, and no XLA loop comes with the
+    lists."""
+    h, w, wp, vw, pages, pool = 64, 576, 640, 512, 256, 8192
+
+    def shaped(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    meta = AttentionMetadata(
+        q_seq_ids=shaped((t,)), q_positions=shaped((t,)),
+        slot_mapping=shaped((t,)), block_tables=shaped((s, pages)),
+        seq_lens=shaped((s,)), logits_indices=shaped((s,)),
+        chunk_starts=shaped((s,)))
+    args = [shaped((t, h, w), jnp.bfloat16),
+            shaped((1, pool, PAGE, wp), jnp.bfloat16), meta]
+    if side:
+        args += [shaped((s, 1, 8, wp), jnp.bfloat16), shaped((1,))]
+
+    def call(q, kv, meta, *staged):
+        kw = dict(side_kv=staged[0], side_len=staged[1]) if staged else {}
+        return mla.mla_attention(
+            q, kv, meta, scale=192 ** -0.5, value_width=vw, max_q=max_q, **kw)
+
+    compiled = jax.jit(call).lower(*args).compile()
+    text = compiled.as_text()
+    assert "vdt_mla_attention" in text
+    assert text.count("tpu_custom_call") == (2 if max_q >= 16 else 1)
+    assert " while(" not in text and " conditional(" not in text
+    # the absorbed queries are 576 values a head: 151 MB for 2,048
+    # tokens; their tiles and the latent outputs are the rest
+    assert compiled.memory_analysis().temp_size_in_bytes < 500e6
+
+
+def test_one_plane_flush_compiles_for_the_v5e(one_chip, no_compile_cache):
+    def shaped(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(kv_flush).lower(
+        shaped((1, 8192, PAGE, 640), jnp.bfloat16),
+        shaped((64, 1, 8, 640), jnp.bfloat16), shaped((64, 256)),
+        shaped((64,)), shaped((64,))).compile()
+    assert "vdt_kv_flush" in compiled.as_text()

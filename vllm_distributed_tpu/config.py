@@ -57,6 +57,8 @@ class ModelConfig:
     enable_expert_parallel: bool = False
 
     def __post_init__(self) -> None:
+        if self.quantization == "none":  # spelt out: no quantization
+            self.quantization = None
         if self.quantization is not None:
             from vllm_distributed_tpu.ops.quant import METHODS
 
@@ -93,31 +95,62 @@ class ModelConfig:
         archs = getattr(self.hf_config, "architectures", None) or []
         return archs[0] if archs else self.hf_config.model_type
 
+    def _first_key(self, what: str, keys: tuple) -> int:
+        """The first of ``keys`` the config has, or an error that names
+        them: a config that spells a size some other way fails here,
+        not on a bare AttributeError deep in the runner."""
+        for key in keys:
+            value = getattr(self.hf_config, key, None)
+            if value is not None:
+                return int(value)
+        raise ValueError(
+            f"the config of {self.model!r} ("
+            f"{getattr(self.hf_config, 'model_type', '?')}) states no "
+            f"{what}: none of {list(keys)} is set"
+        )
+
+    @property
+    def latent_attention(self) -> bool:
+        """A config with ``kv_lora_rank`` caches one latent row a token
+        (MLA): it has no KV heads and no single head size."""
+        return getattr(self.hf_config, "kv_lora_rank", None) is not None
+
     def get_num_layers(self) -> int:
-        return getattr(
-            self.hf_config,
-            "num_hidden_layers",
-            getattr(self.hf_config, "n_layer", None),
+        # A latent-attention config of the double-layer family counts
+        # its layers in ``num_layers``; the HF class of that family also
+        # carries ``num_hidden_layers``, its count of attention
+        # sublayers, which is not the number of layers.
+        if self.latent_attention and hasattr(self.hf_config, "num_layers"):
+            return self._first_key("number of layers", ("num_layers",))
+        return self._first_key(
+            "number of layers", ("num_hidden_layers", "n_layer", "num_layers")
         )
 
     def get_hidden_size(self) -> int:
-        return getattr(
-            self.hf_config, "hidden_size", getattr(self.hf_config, "n_embd", None)
-        )
+        return self._first_key("hidden size", ("hidden_size", "n_embd"))
 
     def get_num_attention_heads(self) -> int:
-        return getattr(
-            self.hf_config,
-            "num_attention_heads",
-            getattr(self.hf_config, "n_head", None),
+        return self._first_key(
+            "number of attention heads", ("num_attention_heads", "n_head")
         )
 
+    def _refuse_latent(self, what: str) -> None:
+        if self.latent_attention:
+            raise ValueError(
+                f"the config of {self.model!r} has latent attention "
+                f"(kv_lora_rank): it states no {what}; what its cache "
+                "holds is the model's kv_cache_layout()"
+            )
+
     def get_num_kv_heads(self) -> int:
-        return getattr(
-            self.hf_config, "num_key_value_heads", self.get_num_attention_heads()
+        self._refuse_latent("number of KV heads")
+        return self._first_key(
+            "number of KV heads",
+            ("num_key_value_heads", "num_attention_heads", "n_head"),
         )
 
     def get_head_dim(self) -> int:
+        self._refuse_latent("single head size")
         head_dim = getattr(self.hf_config, "head_dim", None)
         if head_dim is not None:
             return head_dim

@@ -139,6 +139,7 @@ class LLMEngine:
         self._telemetry_compiles_seen: dict[str, int] = {}
         self._telemetry_fallbacks_seen: dict[str, int] = {}
         self._telemetry_cache_seen: dict[str, int] = {}
+        self._telemetry_moe_picks_seen: dict[str, int] = {}
         self._telemetry_moe_grouped_seen = 0
 
         # Disaggregated prefill/decode hand-off (ISSUE 15): export holds
@@ -620,6 +621,11 @@ class LLMEngine:
                 "persistent_cache",
                 self._telemetry_cache_seen,
                 self.metrics.record_persistent_cache_events,
+            ),
+            (
+                "moe_picks",
+                self._telemetry_moe_picks_seen,
+                self.metrics.record_moe_picks,
             ),
         ):
             for kind, total in (snap.get(field) or {}).items():

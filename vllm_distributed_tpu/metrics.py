@@ -154,6 +154,7 @@ DOCUMENTED_METRICS = (
     "vllm:worker_fallbacks_total",
     "vllm:xla_persistent_cache_events_total",
     "vllm:moe_grouped_steps_total",
+    "vllm:moe_picks_total",
     # ---- fleet sentinel (ISSUE 20) ----
     "vllm:slo_burn_rate",
     "vllm:itl_p99_ms",
@@ -594,6 +595,15 @@ class EngineMetrics:
             "steps of an MoE model on one chip; a fused decode scan "
             "never counts",
         )
+        self._moe_picks = Counter(
+            "vllm:moe_picks",
+            "Router picks on the reply-rank worker by kind: zero "
+            "(identity experts: no compute), held (experts this chip "
+            "holds), absent (experts held by other chips: nothing is "
+            "computed for them here)",
+            ["model_name", "kind"],
+            registry=self.registry,
+        )
         # ---- fleet sentinel (ISSUE 20) ----
         self._slo_burn = Gauge(
             "vllm:slo_burn_rate",
@@ -903,6 +913,12 @@ class EngineMetrics:
     def record_worker_fallbacks(self, kind: str, n: int) -> None:
         if self.enabled and n > 0:
             self._worker_fallbacks.labels(
+                model_name=self._model_name, kind=kind
+            ).inc(n)
+
+    def record_moe_picks(self, kind: str, n: int) -> None:
+        if self.enabled and n > 0:
+            self._moe_picks.labels(
                 model_name=self._model_name, kind=kind
             ).inc(n)
 

@@ -29,10 +29,13 @@ SCOPE_MOE_EXPERTS = "moe_experts"
 SCOPE_MLP = "mlp"  # post-attention norm; the dense MLP; MoE combine
 SCOPE_LM_HEAD = "lm_head"  # final norm, row gather, logits
 SCOPE_SAMPLER = "sampler"
+# latent attention only: queries into the latent space (q_nope · W_uk)
+# and the attended latent back out of it (· W_uv)
+SCOPE_MLA_ABSORB = "mla_absorb"
 SCOPES = (
     SCOPE_EMBED, SCOPE_ATTN_QKV, SCOPE_KV_WRITE, SCOPE_ATTN,
     SCOPE_ATTN_OUT, SCOPE_MOE_ROUTER, SCOPE_MOE_EXPERTS, SCOPE_MLP,
-    SCOPE_LM_HEAD, SCOPE_SAMPLER,
+    SCOPE_LM_HEAD, SCOPE_SAMPLER, SCOPE_MLA_ABSORB,
 )
 
 
@@ -135,3 +138,21 @@ def apply_rope(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
     )
     return out.astype(x.dtype)
+
+
+def apply_rope_interleaved(
+    x: jax.Array,  # [T, H, D]
+    positions: jax.Array,  # [T]
+    inv_freq: jax.Array,  # [D // 2]
+) -> jax.Array:
+    """DeepSeek-V3 convention: lanes (2i, 2i+1) are the pair that
+    frequency i rotates; the output keeps the lanes where they were."""
+    angles = positions[:, None].astype(jnp.float32) * inv_freq[None, :]
+    cos = jnp.cos(angles)[:, None, :]  # [T, 1, D/2]
+    sin = jnp.sin(angles)[:, None, :]
+    xf = x.astype(jnp.float32)
+    even, odd = xf[..., 0::2], xf[..., 1::2]
+    out = jnp.stack(
+        [even * cos - odd * sin, odd * cos + even * sin], axis=-1
+    )
+    return out.reshape(x.shape).astype(x.dtype)

@@ -196,6 +196,13 @@ class LlamaForCausalLM(SupportsQuantization):
             specs["lm_head"] = P(None, "tp")
         return specs
 
+    def kv_cache_layout(self):
+        from vllm_distributed_tpu.ops.attention import KVCacheLayout
+
+        return KVCacheLayout.by_heads(
+            self.num_layers, self.num_kv_heads, self.head_dim
+        )
+
     def kv_cache_spec(self) -> P:
         """Combined KV pool [2, P, page, HD]: shard the flat head×dim
         lanes over tp (heads are contiguous in HD, so this is per-kv-head

@@ -171,6 +171,13 @@ class OPTForCausalLM(SupportsQuantization):
             specs["project_out"] = P()
         return specs
 
+    def kv_cache_layout(self):
+        from vllm_distributed_tpu.ops.attention import KVCacheLayout
+
+        return KVCacheLayout.by_heads(
+            self.num_layers, self.num_kv_heads, self.head_dim
+        )
+
     def kv_cache_spec(self) -> P:
         """Combined KV pool [2, P, page, HD]: shard flat head lanes."""
         return P(None, None, None, "tp")

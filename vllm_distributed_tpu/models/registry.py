@@ -18,12 +18,16 @@ def _populate() -> None:
     if _REGISTRY:
         return
     from vllm_distributed_tpu.models.llama import LlamaForCausalLM
+    from vllm_distributed_tpu.models.longcat_flash import (
+        LongcatFlashForCausalLM,
+    )
     from vllm_distributed_tpu.models.mixtral import MixtralForCausalLM
     from vllm_distributed_tpu.models.opt import OPTForCausalLM
 
     register_model(LlamaForCausalLM)
     register_model(OPTForCausalLM)
     register_model(MixtralForCausalLM)
+    register_model(LongcatFlashForCausalLM)
 
 
 def get_model_class(architecture: str):

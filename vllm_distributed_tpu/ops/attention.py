@@ -82,6 +82,51 @@ def kv_scales_shape(
     return (2, num_pages, page_size, num_kv_heads)
 
 
+@dataclass(frozen=True)
+class KVCacheLayout:
+    """What a model states of its paged caches; the runner's pool,
+    its page bytes, the fused scan's side buffers, the flush, the tier
+    operations and page export/import read this and nothing else of the
+    model.  Every cache is ``[planes, P, page, width]``: ``planes`` is 2
+    for a key and a value of every KV head (``width`` their flat
+    head×dim lanes, ``scale_width`` the lanes of an int8 pool's f32
+    scale plane: one a KV head), 1 for a latent row that is key and
+    value at once (``scale_width`` 0: such a row has no heads to scale
+    by, and no int8 form).  How a pool shards over a mesh stays the
+    model's ``kv_cache_spec()``."""
+
+    num_caches: int
+    planes: int
+    width: int
+    scale_width: int = 0
+
+    @classmethod
+    def by_heads(
+        cls, num_layers: int, num_kv_heads: int, head_dim: int
+    ) -> "KVCacheLayout":
+        """One cache a layer: a key and a value plane of every KV
+        head's lanes, an int8 pool scaled per (token, KV head)."""
+        return cls(
+            num_caches=num_layers,
+            planes=2,
+            width=kv_pool_width(num_kv_heads, head_dim),
+            scale_width=num_kv_heads,
+        )
+
+    def token_bytes(self, itemsize: int, quantized: bool = False) -> int:
+        """Bytes one token holds over all of the model's caches."""
+        per_plane = self.width * itemsize
+        if quantized:
+            per_plane += self.scale_width * 4  # f32 scale row
+        return self.num_caches * self.planes * per_plane
+
+    def pool_shape(self, num_pages: int, page_size: int) -> tuple:
+        return (self.planes, num_pages, page_size, self.width)
+
+    def scales_shape(self, num_pages: int, page_size: int) -> tuple:
+        return (self.planes, num_pages, page_size, self.scale_width)
+
+
 def quantize_kv_heads(
     k: jax.Array,  # [..., Hkv * D] flat rows (model dtype)
     num_kv_heads: int,
@@ -162,7 +207,7 @@ class AttentionMetadata:
 def write_kv_pages(
     kv_pages,  # [2, P, page, HD] or (int8 pool, scales) tuple
     k: jax.Array,  # [T, Hkv, D]
-    v: jax.Array,
+    v: jax.Array | None,  # None: a one-plane pool [1, P, page, W]
     slot_mapping: jax.Array,
 ):
     """Scatter this step's K/V into the combined paged pool (quantizing
@@ -193,6 +238,11 @@ def write_kv_pages(
         return (data, scales)
     _, _, page_size, hd = kv_pages.shape
     t, hkv, d = k.shape
+    if v is None:  # a one-plane (latent) pool: the row is key and value
+        rows = k.reshape(t, hkv * d).astype(kv_pages.dtype)
+        return kv_pages.at[
+            0, slot_mapping // page_size, slot_mapping % page_size
+        ].set(rows)
     k = k.reshape(t, hkv * d).astype(kv_pages.dtype)
     v = v.reshape(t, hkv * d).astype(kv_pages.dtype)
     if hkv * d < hd:  # sub-tile pools pad HD to the lane tile
@@ -277,3 +327,57 @@ def paged_attention_reference(
 
     out = jnp.einsum("thgc,tchd->thgd", probs, v_tok.astype(jnp.float32))
     return out.reshape(t, hq, d).astype(q.dtype)
+
+
+@partial(jax.jit, static_argnames=("scale", "value_width"))
+def mla_attention_reference(
+    q: jax.Array,  # [T, H, W] absorbed queries (W = latent + rope lanes)
+    kv_pages: jax.Array,  # [1, P, page, WP]: one latent row a token, its
+    # first W lanes live (the rest pads the row to whole lane tiles)
+    metadata: AttentionMetadata,
+    *,
+    scale: float,
+    value_width: int,
+    side_kv: jax.Array | None = None,  # [S, 1, K, WP] staged decode rows
+    side_len: jax.Array | None = None,  # [1] int32
+) -> jax.Array:
+    """Latent (absorbed MLA) attention of flat query tokens against
+    their sequences' paged latent rows: every head scores the same row
+    ``[c, k_rope]`` over all ``W`` lanes, and the value is the row's
+    first ``value_width`` lanes.  Returns [T, H, value_width].  The
+    oracle and XLA path of ops/pallas/mla_attention.py; same contract
+    on ``side_kv``/``side_len`` as ``paged_attention_reference``."""
+    t, h, w = q.shape
+    _, _, page_size, _ = kv_pages.shape
+    s, max_pages = metadata.block_tables.shape
+    max_ctx = max_pages * page_size
+    rows = kv_pages[0][metadata.block_tables][..., :w].reshape(
+        s, max_ctx, w
+    )
+    seq_lens_tok = metadata.seq_lens[metadata.q_seq_ids]  # [T]
+    ctx_pos = jnp.arange(max_ctx, dtype=jnp.int32)
+    valid = ctx_pos[None, :] <= metadata.q_positions[:, None]
+    valid &= ctx_pos[None, :] < seq_lens_tok[:, None]
+    if side_kv is not None:
+        k_blk = side_kv.shape[2]
+        rows = jnp.concatenate([rows, side_kv[:, 0, :, :w]], axis=1)
+        j = jnp.arange(k_blk, dtype=jnp.int32)
+        side_pos = seq_lens_tok[:, None] + j[None, :]
+        side_valid = (
+            (j[None, :] < side_len[0])
+            & (side_pos <= metadata.q_positions[:, None])
+            & (seq_lens_tok[:, None] > 0)
+        )
+        valid = jnp.concatenate([valid, side_valid], axis=1)
+    rows_tok = rows[metadata.q_seq_ids].astype(jnp.float32)  # [T, C, W]
+    scores = jnp.einsum(
+        "thw,tcw->thc", q.astype(jnp.float32), rows_tok
+    ) * scale
+    scores = jnp.where(valid[:, None, :], scores, DEFAULT_MASK_VALUE)
+    scores = scores - jnp.max(scores, axis=-1, keepdims=True)
+    probs = jnp.exp(scores)
+    probs = probs / jnp.maximum(
+        jnp.sum(probs, axis=-1, keepdims=True), 1e-30
+    )
+    out = jnp.einsum("thc,tcv->thv", probs, rows_tok[..., :value_width])
+    return out.astype(q.dtype)

@@ -41,15 +41,16 @@ def _kernel(
     page0_ref,  # [S] int32: base // page_size (logical first page)
     page_ids_ref,  # [S, NPT] int32: touched page ids (clamped, padded)
     n_side_ref,  # [S] int32: rows to flush per sequence (<= K)
-    side_ref,  # [1, 2, K, HD] VMEM block (this sequence's staged rows)
-    pool_in,  # [2, P, page, HD] ANY (aliased with pool_out)
+    side_ref,  # [1, PL, K, HD] VMEM block (this sequence's staged rows)
+    pool_in,  # [PL, P, page, HD] ANY (aliased with pool_out)
     pool_out,
-    slab_vmem,  # [2(pipe), 2(kv), NPT*page, HD]
+    slab_vmem,  # [2(pipe), PL, NPT*page, HD]
     read_sems,  # [2]
     write_sem,
     *,
     page_size: int,
     npt: int,
+    planes: int,  # PL: 2 for K and V, 1 for a latent row
 ):
     s = pl.program_id(0)
     num_s = pl.num_programs(0)
@@ -68,7 +69,7 @@ def _kernel(
 
             @pl.when(page != 0)
             def _go(page=page, pt=pt):
-                for kvi in range(2):
+                for kvi in range(planes):
                     slab = slab_vmem.at[
                         buf, kvi, pl.ds(pt * page_size, page_size)
                     ]
@@ -124,7 +125,7 @@ def _kernel(
             jnp.int32, (rows, side_ref.shape[3]), 0
         )
         in_window = (row_ids >= off) & (row_ids < off + n_side)
-        for kvi in range(2):
+        for kvi in range(planes):
             # Side row j lands at slab row off + j: pad side to the slab
             # height and roll it down by `off`.  Mosaic only rotates
             # 32-bit lanes, so roll in f32 (exact for bf16/int8 values).
@@ -142,20 +143,22 @@ _VMEM_HEADROOM = 4 * 2**20
 
 
 def _vmem_limit_bytes(
-    rows: int, hd: int, pool_itemsize: int, k_blk: int, side_itemsize: int
+    rows: int, hd: int, pool_itemsize: int, k_blk: int, side_itemsize: int,
+    planes: int = 2,
 ) -> int:
     """The limit the call states: the two-deep K+V slab scratch, the
     pipelined side block and the overlay's f32 working set (padded
     side, rolled copy, current slab, select result, row iota)."""
-    slabs = 2 * 2 * rows * hd * pool_itemsize
-    side = 2 * 2 * k_blk * hd * side_itemsize
+    slabs = 2 * planes * rows * hd * pool_itemsize
+    side = 2 * planes * k_blk * hd * side_itemsize
     overlay = 5 * rows * hd * 4
     return slabs + side + overlay + _VMEM_HEADROOM
 
 
 def kv_flush(
-    kv_pages,  # [2, P, page, HD] — or (int8 data, per-head scales)
-    side_kv: jax.Array,  # [S, 2, K, HD] (model dtype)
+    kv_pages,  # [PL, P, page, HD] — or (int8 data, per-head scales)
+    side_kv: jax.Array,  # [S, PL, K, HD] (model dtype); PL planes: 2, or
+    # 1 for a latent pool
     block_tables: jax.Array,  # [S, max_pages] int32
     base_lens: jax.Array,  # [S] int32 (0 = padding row, skipped)
     n_side: jax.Array,  # [S] (or [1], broadcast) int32: rows per sequence
@@ -204,7 +207,7 @@ def kv_flush(
         scales = scales.at[0, pages, rows].set(side_s[:, 0])
         scales = scales.at[1, pages, rows].set(side_s[:, 1])
         return (data, scales)
-    _, p_total, page_size, hd = kv_pages.shape
+    planes, p_total, page_size, hd = kv_pages.shape
     s, _, k_blk, _ = side_kv.shape
     npt = (k_blk + page_size - 1) // page_size + 1
     if n_side.shape[0] != s:
@@ -223,7 +226,9 @@ def kv_flush(
     )
     page_ids = jnp.where(in_table, gathered, 0)
 
-    kernel = functools.partial(_kernel, page_size=page_size, npt=npt)
+    kernel = functools.partial(
+        _kernel, page_size=page_size, npt=npt, planes=planes
+    )
     out = pl.pallas_call(
         kernel,
         name="vdt_kv_flush",
@@ -232,7 +237,7 @@ def kv_flush(
             grid=(s,),
             in_specs=[
                 pl.BlockSpec(
-                    (1, 2, k_blk, hd),
+                    (1, planes, k_blk, hd),
                     lambda s_, *refs: (s_, 0, 0, 0),
                 ),
                 pl.BlockSpec(memory_space=pl.ANY),
@@ -240,7 +245,7 @@ def kv_flush(
             out_specs=pl.BlockSpec(memory_space=pl.ANY),
             scratch_shapes=[
                 pltpu.VMEM(
-                    (2, 2, npt * page_size, hd), kv_pages.dtype
+                    (2, planes, npt * page_size, hd), kv_pages.dtype
                 ),
                 pltpu.SemaphoreType.DMA((2,)),
                 pltpu.SemaphoreType.DMA,
@@ -256,6 +261,7 @@ def kv_flush(
                 jnp.dtype(kv_pages.dtype).itemsize,
                 k_blk,
                 jnp.dtype(side_kv.dtype).itemsize,
+                planes,
             )
         ),
         interpret=interpret,

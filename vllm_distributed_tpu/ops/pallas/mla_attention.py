@@ -1,0 +1,505 @@
+"""Pallas latent (absorbed MLA) attention over the one-plane paged pool.
+
+The pool is ``[1, P, page, WP]``: one row ``[c, k_rope]`` a token
+(``W = kv_lora_rank + qk_rope_head_dim`` live lanes, padded to ``WP``,
+whole 128-lane tiles: Mosaic moves no narrower slice of HBM), key and
+value at once — the value is the row's first ``value_width`` lanes.
+The queries arrive absorbed (``[q_nope · W_uk, q_rope]``, ``W`` lanes a
+head), so every head scores the same row: a page is fetched ONCE for all heads, and a
+query tile's softmax chain has ``heads × tokens`` rows over it.
+
+The grid, the ragged list of query tiles it walks, the globally
+rotating triple buffer and the one-step prefetch are
+ops/pallas/paged_attention.py's (its docstring has the reasons; the
+list code is imported from there, not copied): a tile is ``mq_blk``
+consecutive query tokens of one sequence; ``max_q == 1`` (the fused
+decode scan, a decode-only step) is one tile a sequence; in chunked
+steps single-token sequences leave the chunk tiles for a second call in
+the decode geometry.  No XLA ``while`` is made here either.
+
+What differs: one DMA a page instead of two, no head folding (there is
+one key/value row), no int8 form, and the score is two dots — the
+latent lanes and the rope lanes — so that neither contracts over a
+width that is not a lane-tile multiple.  Operands keep the pool's dtype
+on the MXU; scores, softmax state and the output accumulate in
+float32.  The staged side buffer of the fused scan is ``[S, 1, K, WP]``.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from vllm_distributed_tpu.ops.attention import AttentionMetadata
+from vllm_distributed_tpu.ops.pallas.paged_attention import (
+    _pow2_floor,
+    _splits_singles,
+    num_query_tiles,
+    tile_kv_blocks,
+    tile_list,
+)
+from vllm_distributed_tpu.utils import cdiv, next_power_of_2
+
+_MASK = -0.7 * float(jnp.finfo(jnp.float32).max)
+_LANES = 128
+_NBUF = 3
+# Per-slot budget of the rotating page buffer, and of the f32 score /
+# probability tile [rows, kv block] a grid step holds.  A latent row is
+# 1,280 B, so a whole 1,024-token sequence is one block of the decode
+# geometry: a grid step costs ~0.35 us whether it works or is skipped,
+# as much as reading 256 rows (with 256-token blocks the fused scan's
+# call spent most of its 0.36 ms on steps, tools/mla_microbench.py, my
+# chip run, PR 34).
+_KV_BUF_BYTES = 1536 * 1024
+_SCORE_BYTES = 2 * 1024 * 1024
+# Budget of the f32 flash state (acc [rows, value_width] + m + l).
+_STATE_BYTES = 3 * 1024 * 1024
+_VMEM_HEADROOM = 8 * 1024 * 1024
+
+
+def _kernel(
+    # scalar prefetch (+ side_len_ref when has_side)
+    tile_seq_ref,  # [NT] int32: sequence of each query tile
+    tile_q0_ref,  # [NT] int32: the tile's first token within its chunk
+    tile_nb_ref,  # [NT] int32: kv blocks the tile walks (0: skipped)
+    n_live_ref,  # [1] int32: tiles in use
+    block_tables_ref,  # [S, max_pages] int32
+    seq_lens_ref,  # [S] int32
+    chunk_starts_ref,  # [S] int32
+    # then q_ref [1, ROWS, W] (row r = token r // heads of the tile,
+    # head r % heads: the flat batch's own order), side_ref
+    # [1, 1, K, WP] when has_side, kv_pages_ref [1, P, page, WP] ANY, the
+    # out block [1, ROWS, VW], and scratch: kv_vmem [NBUF, BLK, WP],
+    # m/l [ROWS, LANES] f32, acc [ROWS, VW] f32, DMA sems [NBUF], cnt
+    # SMEM [2] = [completed active blocks, prefetch-pending flag].
+    *rest,
+    scale: float,
+    page_size: int,
+    pages_per_blk: int,
+    num_heads: int,
+    value_width: int,
+    width: int,  # W: the row's live lanes
+    has_side: bool,
+):
+    rest = list(rest)
+    side_len_ref = rest.pop(0) if has_side else None
+    q_ref = rest.pop(0)
+    side_ref = rest.pop(0) if has_side else None
+    kv_pages_ref = rest.pop(0)
+    out_ref, kv_vmem, m_scr, l_scr, acc_scr, sems, cnt = rest
+    tile = pl.program_id(0)
+    kvb = pl.program_id(1)
+    num_tiles = pl.num_programs(0)
+    num_kvb = pl.num_programs(1)
+    blk = pages_per_blk * page_size
+    vw = value_width
+    in_list = tile < n_live_ref[0]
+    s = tile_seq_ref[tile]
+    nb = tile_nb_ref[tile]
+    active = kvb < nb
+
+    @pl.when((tile == 0) & (kvb == 0))
+    def _boot():
+        cnt[0] = 0
+        cnt[1] = 0
+
+    def block_dma(seq, block_idx, buf):
+        """One descriptor a page: the row is key and value at once."""
+        copies = []
+        for i in range(pages_per_blk):
+            page = block_tables_ref[seq, block_idx * pages_per_blk + i]
+            copies.append(
+                pltpu.make_async_copy(
+                    kv_pages_ref.at[0, page],
+                    kv_vmem.at[buf, pl.ds(i * page_size, page_size)],
+                    sems.at[buf],
+                )
+            )
+        return copies
+
+    @pl.when(in_list & (kvb == 0))
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _MASK)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(active & (cnt[1] == 0))
+    def _bootstrap_dma():
+        for cp in block_dma(s, kvb, cnt[0] % _NBUF):
+            cp.start()
+
+    next_in_tile = kvb + 1 < nb
+    next_tile = jnp.minimum(tile + 1, num_tiles - 1)
+    has_next = next_in_tile | (
+        (tile + 1 < num_tiles) & (tile_nb_ref[next_tile] > 0)
+    )
+    next_s = jnp.where(next_in_tile, s, tile_seq_ref[next_tile])
+    next_kvb = jnp.where(next_in_tile, kvb + 1, 0)
+
+    @pl.when(active & has_next)
+    def _prefetch():
+        for cp in block_dma(next_s, next_kvb, (cnt[0] + 1) % _NBUF):
+            cp.start()
+
+    def row_positions(ncols):
+        """Row r holds query token r // heads of the tile."""
+        rows = acc_scr.shape[0]
+        row_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, ncols), 0)
+        col_ids = jax.lax.broadcasted_iota(jnp.int32, (rows, ncols), 1)
+        q_pos = (
+            chunk_starts_ref[s] + tile_q0_ref[tile] + row_ids // num_heads
+        )
+        return q_pos, col_ids
+
+    def flash_update(c, kr, mask):
+        """One online-softmax step over rows ``[c | kr]`` ([ncols, VW],
+        [ncols, W - VW]) of the pool's dtype."""
+        nt = (((1,), (1,)), ((), ()))
+        scores = jax.lax.dot_general(
+            q_ref[0, :, :vw], c, nt, preferred_element_type=jnp.float32
+        ) + jax.lax.dot_general(
+            q_ref[0, :, vw:], kr, nt, preferred_element_type=jnp.float32
+        )  # [ROWS, ncols]
+        scores = jnp.where(mask, scores * scale, _MASK)
+        m_prev = m_scr[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(mask, jnp.exp(scores - m_new), 0.0)
+        l_new = l_scr[:, 0:1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        acc_scr[:] = acc_scr[:] * alpha + pv
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(active)
+    def _compute():
+        buf = cnt[0] % _NBUF
+        for cp in block_dma(s, kvb, buf):
+            cp.wait()
+        q_pos, col_ids = row_positions(blk)
+        c_pos = kvb * blk + col_ids
+        mask = (c_pos <= q_pos) & (c_pos < seq_lens_ref[s])
+        flash_update(kv_vmem[buf, :, :vw], kv_vmem[buf, :, vw:width], mask)
+        cnt[0] = cnt[0] + 1
+        cnt[1] = has_next.astype(jnp.int32)
+
+    @pl.when(in_list & (kvb == num_kvb - 1))
+    def _finalize():
+        if has_side:
+            # This dispatch's rows live in the side buffer (positions
+            # seq_len + j; seq_len is the POOL length when staging).
+            n_side = side_len_ref[0]
+            seq_len = seq_lens_ref[s]
+            q_pos, col_ids = row_positions(side_ref.shape[2])
+            smask = (
+                (col_ids < n_side)
+                & (seq_len + col_ids <= q_pos)
+                & (seq_len > 0)
+            )
+            flash_update(
+                side_ref[0, 0, :, :vw], side_ref[0, 0, :, vw:width], smask
+            )
+        denom = jnp.maximum(l_scr[:, 0:1], 1e-30)
+        out_ref[0] = (acc_scr[:] / denom).astype(out_ref.dtype)
+
+
+def _tile_tokens(max_q: int, num_heads: int, value_width: int) -> int:
+    """Query tokens a tile: the decode geometry (``max_q == 1``) is one
+    token (more only where a tiny head count leaves under a sublane
+    tile of rows); a chunk tile holds as many as keep the f32 flash
+    state inside its budget."""
+    mq = 1
+    while mq * num_heads < 8:
+        mq *= 2
+    if max_q == 1:
+        return mq
+    row_bytes = (value_width + 2 * _LANES) * 4
+    most = max(_pow2_floor(_STATE_BYTES // (row_bytes * num_heads)), mq)
+    return max(min(next_power_of_2(max_q), most), mq)
+
+
+def _kv_blocking(width: int, itemsize: int, rows: int, page_size: int,
+                 max_pages: int):
+    """(pages a kv block, kv blocks, tokens a block): as many tokens as
+    the buffer slot and the score tile's budget hold."""
+    lanes = cdiv(width, _LANES) * _LANES
+    tokens = min(
+        _KV_BUF_BYTES // (lanes * itemsize), _SCORE_BYTES // (rows * 4)
+    )
+    tokens = min(_pow2_floor(max(tokens, page_size)), max_pages * page_size)
+    pages_per_blk = max(tokens // page_size, 1)
+    return pages_per_blk, cdiv(max_pages, pages_per_blk), (
+        pages_per_blk * page_size
+    )
+
+
+def _vmem_limit_bytes(
+    *, blk: int, width: int, value_width: int, rows: int, itemsize: int,
+    k_side: int,
+) -> int:
+    """The limit the call states: the rotating page buffers, the f32
+    flash state, the pipelined (double-buffered) query, output and side
+    blocks, and a step's f32 temporaries (scores, probabilities and
+    their exponent chain, the PV product)."""
+    lanes = cdiv(width, _LANES) * _LANES
+    kv_bufs = _NBUF * blk * lanes * itemsize
+    state = rows * (value_width + 2 * _LANES) * 4
+    q_out = 2 * rows * (lanes + value_width) * itemsize
+    side = 2 * max(k_side, 16) * lanes * itemsize
+    cols = max(blk, k_side)
+    temps = 4 * (6 * rows * cols + 3 * rows * value_width)
+    return kv_bufs + state + q_out + side + temps + _VMEM_HEADROOM
+
+
+def mla_query_tile_counts(
+    q_lens, *, max_q: int, num_heads: int, value_width: int
+) -> tuple[int, int]:
+    """(tiles that hold a query, query rows those tiles compute) of one
+    step: host-side twin of the wrapper's lists, for the
+    ``runner.dispatch`` span."""
+    mq_a = _tile_tokens(1, num_heads, value_width)
+    mq_b = _tile_tokens(max_q, num_heads, value_width)
+    if max_q == 1:
+        n = sum(1 for n in q_lens if n > 0)
+        return n, n * mq_a
+    split = _splits_singles(mq_b)
+    singles = sum(1 for n in q_lens if n == 1) if split else 0
+    chunks = sum(cdiv(n, mq_b) for n in q_lens if n > (1 if split else 0))
+    return singles + chunks, singles * mq_a + chunks * mq_b
+
+
+def _attend_tiles(
+    q_tiles: jax.Array,  # [NT, mq_blk, H, W]
+    tiles,  # (tile_seq [NT], tile_q0 [NT], n_live [1])
+    kv_pages: jax.Array,  # [1, P, page, WP]
+    metadata: AttentionMetadata,
+    *,
+    scale: float,
+    value_width: int,
+    side_kv: jax.Array | None,
+    side_len: jax.Array | None,
+    interpret: bool,
+) -> jax.Array:
+    """One ``pallas_call`` over a list of query tiles of one geometry;
+    returns the tiles' outputs [NT, mq_blk, H, value_width]."""
+    num_tiles, mq_blk, h, w = q_tiles.shape
+    _, _, page_size, wp = kv_pages.shape
+    _, max_pages = metadata.block_tables.shape
+    rows = h * mq_blk
+    vw = value_width
+    itemsize = jnp.dtype(kv_pages.dtype).itemsize
+    pages_per_blk, num_kvb, blk = _kv_blocking(
+        wp, itemsize, rows, page_size, max_pages
+    )
+    block_tables = metadata.block_tables
+    if max_pages % pages_per_blk:
+        # Padding pages are id 0: a real page, masked out of scores.
+        pad = pages_per_blk - max_pages % pages_per_blk
+        block_tables = jnp.pad(block_tables, ((0, 0), (0, pad)))
+    has_side = side_kv is not None
+
+    # rows in the flat batch's own order (token, head): no transpose;
+    # operands in the pool's dtype on the MXU
+    q_rows = q_tiles.reshape(num_tiles, rows, w).astype(kv_pages.dtype)
+
+    def live_tile(tile, refs):
+        return jnp.minimum(tile, jnp.maximum(refs[3][0] - 1, 0))
+
+    def tile_map(tile, b_, *refs):
+        return (live_tile(tile, refs), 0, 0)
+
+    def seq_map(tile, b_, *refs):
+        return (refs[0][tile], 0, 0, 0)
+
+    tile_seq, tile_q0, n_live = tiles
+    scalars = [
+        tile_seq,
+        tile_q0,
+        tile_kv_blocks(
+            tile_seq, tile_q0, n_live, metadata.seq_lens,
+            metadata.chunk_starts, mq_blk, blk,
+        ),
+        n_live,
+        block_tables,
+        metadata.seq_lens,
+        metadata.chunk_starts,
+    ]
+    in_specs = [pl.BlockSpec((1, rows, w), tile_map)]
+    inputs = [q_rows]
+    k_side = 0
+    if has_side:
+        scalars.append(side_len.astype(jnp.int32))
+        k_side = side_kv.shape[2]
+        in_specs.append(pl.BlockSpec((1, 1, k_side, wp), seq_map))
+        inputs.append(side_kv.astype(kv_pages.dtype))
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+    inputs.append(kv_pages)
+    kernel = functools.partial(
+        _kernel,
+        scale=scale,
+        page_size=page_size,
+        pages_per_blk=pages_per_blk,
+        num_heads=h,
+        value_width=vw,
+        width=w,
+        has_side=has_side,
+    )
+    out = pl.pallas_call(
+        kernel,
+        name="vdt_mla_attention",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(num_tiles, num_kvb),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, rows, vw), tile_map),
+            scratch_shapes=[
+                pltpu.VMEM((_NBUF, blk, wp), kv_pages.dtype),
+                pltpu.VMEM((rows, _LANES), jnp.float32),
+                pltpu.VMEM((rows, _LANES), jnp.float32),
+                pltpu.VMEM((rows, vw), jnp.float32),
+                pltpu.SemaphoreType.DMA((_NBUF,)),
+                pltpu.SMEM((2,), jnp.int32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((num_tiles, rows, vw), q_tiles.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_vmem_limit_bytes(
+                blk=blk, width=wp, value_width=vw, rows=rows,
+                itemsize=itemsize, k_side=k_side,
+            )
+        ),
+        interpret=interpret,
+    )(*scalars, *inputs)
+    return out.reshape(num_tiles, mq_blk, h, vw)
+
+
+def mla_attention(
+    q: jax.Array,  # [T, H, W] absorbed queries, flat
+    kv_pages: jax.Array,  # [1, P, page, W]
+    metadata: AttentionMetadata,
+    *,
+    scale: float,
+    value_width: int,
+    max_q: int = 1,
+    side_kv: jax.Array | None = None,  # [S, 1, K, WP] staged decode rows
+    side_len: jax.Array | None = None,  # [1] int32: valid side columns
+    interpret: bool = False,
+) -> jax.Array:
+    """Drop-in for ``mla_attention_reference`` (ops/attention.py; same
+    contract, ``paged_attention``'s on the flat batch, ``max_q`` and the
+    staged side buffer).  Returns [T, H, value_width].
+
+    The body is a jitted function of its own: a step program calls it
+    once a sublayer with the same shapes, and the kernels' bodies are
+    then traced once a program and not once a sublayer (5.5 s of a
+    program's 7.3 s of tracing at eight sublayers, compiled for a
+    described v5e)."""
+    return _attention(
+        q, kv_pages, metadata, side_kv, side_len, scale=scale,
+        value_width=value_width, max_q=max_q, interpret=interpret,
+    )
+
+
+@functools.partial(
+    jax.jit, static_argnames=("scale", "value_width", "max_q", "interpret")
+)
+def _attention(
+    q, kv_pages, metadata, side_kv, side_len, *, scale, value_width, max_q,
+    interpret,
+):
+    t, h, w = q.shape
+    s, _ = metadata.block_tables.shape
+    attend = functools.partial(
+        _attend_tiles,
+        kv_pages=kv_pages,
+        metadata=metadata,
+        scale=scale,
+        value_width=value_width,
+        side_kv=side_kv,
+        side_len=side_len,
+        interpret=interpret,
+    )
+    # where each sequence's queries are in the flat batch (padding
+    # tokens carry q_seq_ids == S and match no sequence)
+    tok = jnp.arange(t, dtype=jnp.int32)
+    match = metadata.q_seq_ids[None, :] == jnp.arange(s, dtype=jnp.int32)[
+        :, None
+    ]
+    q_len = match.sum(axis=1, dtype=jnp.int32)
+    first_tok = jnp.min(jnp.where(match, tok[None, :], t), axis=1)
+    seq_idx = jnp.minimum(metadata.q_seq_ids, s - 1)
+    tok_in_chunk = tok - first_tok[seq_idx]
+    is_token = (metadata.q_seq_ids < s)[:, None, None]
+
+    def attend_list(tiles, mq_blk):
+        tile_seq, tile_q0, _ = tiles
+        rows = (first_tok[tile_seq] + tile_q0)[:, None] + jnp.arange(
+            mq_blk, dtype=jnp.int32
+        )
+        return attend(q[jnp.clip(rows, 0, t - 1)], tiles)
+
+    mq_1 = _tile_tokens(1, h, value_width)
+    if max_q == 1:
+        seqs = jnp.arange(s, dtype=jnp.int32)
+        tiles = (seqs, jnp.zeros(s, jnp.int32), jnp.full((1,), s, jnp.int32))
+        out = attend_list(tiles, mq_1)[seq_idx, 0]
+        return jnp.where(is_token, out, 0)
+
+    assert side_kv is None, "staged decode rows come with max_q = 1"
+    mq_blk = _tile_tokens(max_q, h, value_width)
+    maxq = max(next_power_of_2(max_q), mq_blk)
+    split = _splits_singles(mq_blk)
+    in_chunks = q_len > 1 if split else q_len > 0
+    tiles, first_tile = tile_list(
+        jnp.where(in_chunks, (q_len + mq_blk - 1) // mq_blk, 0),
+        num_query_tiles(t, s, mq_blk, maxq),
+        mq_blk,
+    )
+    out_rows = attend_list(tiles, mq_blk).reshape(-1, h, value_width)
+    row = first_tile[seq_idx] * mq_blk + tok_in_chunk
+    out = out_rows[jnp.clip(row, 0, out_rows.shape[0] - 1)]
+    if split:
+        single = q_len == 1
+        tiles, first_tile = tile_list(single.astype(jnp.int32), s, mq_1)
+        out_1 = attend_list(tiles, mq_1)[first_tile[seq_idx], 0]
+        out = jnp.where(single[seq_idx][:, None, None], out_1, out)
+    return jnp.where(is_token, out, 0)
+
+
+def mla_max_q_class(
+    max_q: int, tokens: int, *, num_heads: int, value_width: int
+) -> int:
+    """The longest-chunk bucket a step of ``tokens`` rows is compiled
+    for.  Once a chunk tile holds as many tokens as it may, programs of
+    different ``max_q`` differ only in the static length of their tile
+    list, which ``max_q`` bounds no tighter than the step's rows do
+    beyond a few tiles a sequence: the token bucket, which bounds every
+    chunk, then stands for all of them, and a model of eight sublayers
+    compiles one program a token bucket and not one a (token bucket,
+    longest chunk) pair.  Entries of the list past the tiles in use
+    change no block index and do no work."""
+    if max_q > 1 and _tile_tokens(max_q, num_heads, value_width) == (
+        _tile_tokens(tokens, num_heads, value_width)
+    ):
+        return tokens
+    return max_q
+
+
+mla_attention.needs_max_q = True
+mla_attention.max_q_class = mla_max_q_class
+
+
+def mla_attention_cpu(*args, **kwargs):
+    """Interpret-mode entry for CPU tests."""
+    return mla_attention(*args, interpret=True, **kwargs)
+
+
+mla_attention_cpu.needs_max_q = True
+mla_attention_cpu.max_q_class = mla_max_q_class

@@ -98,6 +98,78 @@ def write_mixtral_config(
     return dirname
 
 
+def longcat_config(
+    *,
+    vocab_size: int = 128,
+    hidden: int = 64,
+    ffn: int = 96,
+    expert_ffn: int = 32,
+    layers: int = 2,
+    heads: int = 4,
+    q_lora: int = 24,
+    kv_lora: int = 16,
+    nope: int = 8,
+    rope: int = 8,
+    v_dim: int = 8,
+    held: int = 4,
+    ep_size: int = 2,
+    ep_rank: int = 0,
+    zero: int = 4,
+    top_k: int = 3,
+    max_pos: int = 2048,
+    dtype: str = "float32",
+) -> dict:
+    """A LongCat-Flash-architecture config (latent attention, double
+    layers, a router over ``held * ep_size + zero`` outputs of which
+    this chip holds ``held`` experts), keys as the published file
+    spells them."""
+    return {
+        "architectures": ["LongcatFlashForCausalLM"],
+        "model_type": "longcat_flash",
+        "attention_bias": False,
+        "attention_method": "MLA",
+        "hidden_size": hidden,
+        "ffn_hidden_size": ffn,
+        "expert_ffn_hidden_size": expert_ffn,
+        "num_layers": layers,
+        "num_attention_heads": heads,
+        "q_lora_rank": q_lora,
+        "kv_lora_rank": kv_lora,
+        "qk_nope_head_dim": nope,
+        "qk_rope_head_dim": rope,
+        "v_head_dim": v_dim,
+        "mla_scale_q_lora": True,
+        "mla_scale_kv_lora": True,
+        "n_routed_experts": held,
+        "expert_parallel_size": ep_size,
+        "expert_parallel_rank": ep_rank,
+        "zero_expert_num": zero,
+        "zero_expert_type": "identity",
+        "moe_topk": top_k,
+        "routed_scaling_factor": 6.0,
+        "vocab_size": vocab_size,
+        "max_position_embeddings": max_pos,
+        "rms_norm_eps": 1e-5,
+        "rope_theta": 10000000.0,
+        "initializer_range": 0.02,
+        "torch_dtype": dtype,
+        "hidden_act": "silu",
+        "bos_token_id": 1,
+        "eos_token_id": 2,
+    }
+
+
+def write_longcat_config(dirname: str | None = None, **sizes) -> str:
+    """Write ``longcat_config(**sizes)`` as a config.json; returns the
+    directory."""
+    if dirname is None:
+        dirname = tempfile.mkdtemp(prefix="vdt_tiny_longcat_")
+    os.makedirs(dirname, exist_ok=True)
+    with open(os.path.join(dirname, "config.json"), "w") as f:
+        json.dump(longcat_config(**sizes), f)
+    return dirname
+
+
 def full_attention_reference(q, k, v, scale, causal=True):
     """Dense (non-paged) attention oracle [T, H, D] with GQA repeat —
     the reference for ring attention (tests + multichip dryrun)."""

@@ -228,6 +228,7 @@ class ModelRunner:
         # tensor (llama.py fuse_quantized_projections).
         if hasattr(self.model, "fuse_quantized_projections"):
             self.params = self.model.fuse_quantized_projections(self.params)
+        self._refuse_unsupported_latent()
         self._attn_fn = self._pick_attn_fn()
         # Two writers: prefill/mixed steps keep the functional XLA
         # scatter (batched, GSPMD-partitionable — the aliased Pallas
@@ -261,7 +262,7 @@ class ModelRunner:
                     "windows) — use dp=1 or --speculative-ngram-k 0"
                 )
             tp = self.mesh.shape.get("tp", 1)
-            if tp > 1 and self.model.num_kv_heads % tp:
+            if tp > 1 and not self._latent and self.model.num_kv_heads % tp:
                 # The combined pool shards its flat head×dim lanes; a tp
                 # that does not divide the head count would silently
                 # split heads mid-lane instead of failing.
@@ -340,21 +341,11 @@ class ModelRunner:
             )
         except Exception:  # noqa: BLE001 — telemetry only
             self._param_bytes = 0
-        kv_itemsize = (
-            1
-            if self.config.cache_config.cache_dtype == "int8"
-            else (4 if mc.dtype == "float32" else 2)
+        # (an int8 pool's scale planes are left out, as they always were)
+        self._kv_token_bytes = self._kv_layout.token_bytes(
+            1 if self.kv_cache_quantized
+            else jnp.dtype(self.kv_cache_dtype()).itemsize
         )
-        try:
-            self._kv_token_bytes = (
-                mc.get_num_layers()
-                * 2  # K and V
-                * mc.get_num_kv_heads()
-                * mc.get_head_dim()
-                * kv_itemsize
-            )
-        except Exception:  # noqa: BLE001 — telemetry only
-            self._kv_token_bytes = 0
         try:
             self._peak_bw = peak_hbm_bandwidth(
                 getattr(jax.local_devices()[0], "device_kind", "")
@@ -494,7 +485,58 @@ class ModelRunner:
                 self._kv_flush_fn, self.mesh
             )
 
+    @property
+    def _kv_layout(self):
+        """What the model states of its caches (``KVCacheLayout``)."""
+        return self.model.kv_cache_layout()
+
+    @property
+    def _latent(self) -> bool:
+        """A one-plane layout is a latent (MLA) cache, with kernels and
+        limits of its own."""
+        return self._kv_layout.planes == 1
+
+    def _refuse_unsupported_latent(self) -> None:
+        """What a latent (one-plane) pool cannot do fails here, at load,
+        with a message that names it."""
+        if not self._latent:
+            return
+        cc = self.config.cache_config
+        if self.kv_cache_quantized:
+            raise ValueError(
+                "--kv-cache-dtype int8 is not supported for a latent "
+                "(MLA) cache: its rows have no heads to scale by, and "
+                "no int8 form of them is written (worker/model_runner.py)"
+            )
+        if getattr(cc, "kv_spill_host_pages", 0):
+            raise ValueError(
+                "--kv-spill-host-pages is not supported for a latent "
+                "(MLA) cache: the spill tier has not been carried over "
+                "to one-plane pools (worker/model_runner.py)"
+            )
+        if self.config.kv_transfer_config is not None:
+            raise ValueError(
+                "--kv-transfer-config is not supported for a latent "
+                "(MLA) cache: KV page transfer (engine/kv_transfer.py) "
+                "has not been carried over to one-plane pools"
+            )
+
     def _pick_attn_fn(self):
+        if self._latent:
+            from vllm_distributed_tpu.ops.attention import (
+                mla_attention_reference,
+            )
+
+            if self.attn_backend == "reference":
+                return mla_attention_reference
+            from vllm_distributed_tpu.ops.pallas.mla_attention import (
+                mla_attention,
+                mla_attention_cpu,
+            )
+
+            if self.attn_backend == "pallas_interpret":
+                return mla_attention_cpu
+            return mla_attention
         if self.attn_backend == "reference":
             return paged_attention_reference
         from vllm_distributed_tpu.ops.pallas.paged_attention import (
@@ -515,6 +557,15 @@ class ModelRunner:
         )
 
         m = self.model
+        if self._latent:
+            from vllm_distributed_tpu.ops.pallas.mla_attention import (
+                mla_query_tile_counts,
+            )
+
+            return mla_query_tile_counts(
+                q_lens, max_q=max_q_pad, num_heads=m.num_heads,
+                value_width=m.kv_lora_rank,
+            )
         tp = self.mesh.shape.get("tp", 1) if self.mesh is not None else 1
         return query_tile_counts(
             q_lens,
@@ -556,27 +607,20 @@ class ModelRunner:
         return jnp.dtype(name)
 
     def kv_cache_bytes_per_page(self) -> int:
-        from vllm_distributed_tpu.ops.attention import kv_pool_width
-
-        m = self.model
-        dtype_size = jnp.dtype(self.kv_cache_dtype()).itemsize
-        per_token = kv_pool_width(m.num_kv_heads, m.head_dim) * dtype_size
-        if self.kv_cache_quantized:
-            per_token += m.num_kv_heads * 4  # f32 scale row
-        return m.num_layers * 2 * self.page_size * per_token
+        return self.page_size * self._kv_layout.token_bytes(
+            jnp.dtype(self.kv_cache_dtype()).itemsize,
+            self.kv_cache_quantized,
+        )
 
     def _pipeline_reserve_bytes(self) -> int:
         """HBM held by in-flight fused-decode dispatches beyond the pool:
         each concurrent dispatch's program keeps its staged side buffers
-        ([S, 2, K, HD] per layer) live for the program's duration.  At
+        ([S, planes, K, width] per cache) live for the program's duration.  At
         7B/K=32/depth-6 this is ~3 GiB — unreserved, the allocator
         thrashes mid-serve (measured: multi-second stalls)."""
         if not getattr(self, "_staged_decode", False):
             return 0
         sc = self.config.scheduler_config
-        m = self.model
-        from vllm_distributed_tpu.ops.attention import kv_pool_width
-
         # Side buffers stay in MODEL dtype even for an int8 pool.
         side_dtype = (
             self.model.dtype
@@ -585,11 +629,8 @@ class ModelRunner:
         )
         side = (
             sc.max_num_seqs
-            * 2
             * sc.num_decode_steps
-            * kv_pool_width(m.num_kv_heads, m.head_dim)
-            * jnp.dtype(side_dtype).itemsize
-            * m.num_layers
+            * self._kv_layout.token_bytes(jnp.dtype(side_dtype).itemsize)
         )
         return side * max(sc.max_concurrent_dispatches, 1)
 
@@ -649,20 +690,15 @@ class ModelRunner:
         return int(num_pages)
 
     def alloc_kv_pool(self, num_pages: int) -> list:
-        """Allocate a paged KV pool: one combined [2, P, page, HD] array
-        per layer (see ops/attention.py layout — K/V fused so a page is
-        ONE DMA, flat head lanes unpadded), sharded per the model's
-        kv_cache_spec.  Used for the serving cache and for aux-forward
+        """Allocate a paged KV pool: as many [planes, P, page, width]
+        arrays as the model states caches (``kv_cache_layout``; for K
+        and V of every head one a layer, see ops/attention.py — K/V
+        fused so a page is ONE DMA, flat head lanes unpadded), sharded
+        per the model's kv_cache_spec.  Used for the serving cache and for aux-forward
         scratch pools — one definition of the layout."""
-        from vllm_distributed_tpu.ops.attention import (
-            kv_pool_shape,
-            kv_scales_shape,
-        )
-
         m = self.model
-        shape = kv_pool_shape(
-            num_pages, self.page_size, m.num_kv_heads, m.head_dim
-        )
+        layout = self._kv_layout
+        shape = layout.pool_shape(num_pages, self.page_size)
         # Zeros are created on their final sharding: a full per-layer
         # pool staged on device 0 first would be a transient of tp× one
         # shard on a device the weights already fill.
@@ -674,9 +710,7 @@ class ModelRunner:
         if self.kv_cache_quantized:
             # (int8 data, per-head f32 scales) — the scale plane's lane
             # axis is kv heads, sharding like the data plane's HD lanes.
-            s_shape = kv_scales_shape(
-                num_pages, self.page_size, m.num_kv_heads
-            )
+            s_shape = layout.scales_shape(num_pages, self.page_size)
 
             def alloc():
                 return (
@@ -689,7 +723,7 @@ class ModelRunner:
             def alloc():
                 return jnp.zeros(shape, dtype, device=sharding)
 
-        return [alloc() for _ in range(m.num_layers)]
+        return [alloc() for _ in range(layout.num_caches)]
 
     def init_kv_cache(self, num_pages: int) -> None:
         self.num_pages = num_pages
@@ -935,6 +969,7 @@ class ModelRunner:
     @partial(jax.jit, static_argnames=("self",))
     def _jit_aux_forward(self, params, kv, tokens, meta):
         from vllm_distributed_tpu.ops.attention import (
+            mla_attention_reference,
             paged_attention_reference,
             write_kv_pages,
         )
@@ -944,7 +979,11 @@ class ModelRunner:
             tokens,
             kv,
             meta,
-            attn_fn=paged_attention_reference,
+            attn_fn=(
+                mla_attention_reference
+                if self._latent
+                else paged_attention_reference
+            ),
             kv_write_fn=write_kv_pages,
             return_hidden=True,
         )
@@ -1130,6 +1169,7 @@ class ModelRunner:
         """
         import hashlib
 
+        self._refuse_latent_transfer()
         tree = jax.tree_util
         leaves, _ = tree.tree_flatten(self.kv_caches)
         num_layers = len(leaves)
@@ -1161,6 +1201,7 @@ class ModelRunner:
         the transfer, so no step can be reading (or writing) them."""
         import hashlib
 
+        self._refuse_latent_transfer()
         tree = jax.tree_util
         leaves, treedef = tree.tree_flatten(self.kv_caches)
         n = len(page_ids)
@@ -1193,6 +1234,13 @@ class ModelRunner:
             )
         self.kv_caches = tree.tree_unflatten(treedef, leaves)
         return {"ok": True}
+
+    def _refuse_latent_transfer(self) -> None:
+        if self._latent:
+            raise ValueError(
+                "KV page export/import is not supported for a latent "
+                "(MLA) cache (worker/model_runner.py)"
+            )
 
     def host_kv_stats(self) -> dict:
         """Host-tier occupancy (driver telemetry + leak assertions)."""
@@ -1370,6 +1418,13 @@ class ModelRunner:
             cursor += n
 
         max_q_pad = max(next_power_of_2(max(num_new)), 1)
+        max_q_class = getattr(self._attn_fn, "max_q_class", None)
+        if max_q_class is not None:
+            # longest-chunk buckets the kernel compiles alike are one
+            max_q_pad = max_q_class(
+                max_q_pad, t_pad, num_heads=self.model.num_heads,
+                value_width=self.model.kv_lora_rank,
+            )
         smeta_np, flags = self._build_sampling_metadata(states, s_pad)
         # The PRNG stream position is the index of the token a row
         # samples, by the dispatched count: the mirrored token list may
@@ -1454,13 +1509,15 @@ class ModelRunner:
         def resolve() -> ModelRunnerOutput:
             with get_tracer().span(
                 "runner.fetch", parent=step_ctx, step_id=so.step_id
-            ):
+            ) as fetch:
                 if logprobs is not None:
                     host_toks, host_lps = jax.device_get((sampled, logprobs))
                     host_lps = np.asarray(host_lps)
                 else:
                     host_toks, host_lps = jax.device_get(sampled), None
                 host_toks = np.asarray(host_toks)
+                if host_toks.shape[0] > s_pad:
+                    self._record_moe_picks(fetch, host_toks[s_pad:])
             self._num_fetched += 1
             self._record_step_bw(time.perf_counter() - t_step0, kv_tokens)
 
@@ -1594,6 +1651,7 @@ class ModelRunner:
         attn_fn = self._attn_fn
         if getattr(attn_fn, "needs_max_q", False):
             attn_fn = partial(attn_fn, max_q=max_q_pad)
+        stats = self._model_stats()
         logits, kv_caches = self.model.forward(
             params,
             token_ids,
@@ -1601,6 +1659,7 @@ class ModelRunner:
             meta,
             attn_fn=attn_fn,
             kv_write_fn=self._kv_write_fn,
+            **stats,
         )
         with jax.named_scope(SCOPE_SAMPLER):
             tokens, logprobs = sample(
@@ -1611,7 +1670,30 @@ class ModelRunner:
                 return_logprobs=return_logprobs,
             )
         carry = self._merge_carry(carry, slot_rows, tokens)
+        if stats:
+            # The router's pick counts ride behind the sampled tokens:
+            # one array, one transfer (resolve() reads the tail).
+            tokens = jnp.concatenate(
+                [tokens, stats["stats"]["moe_picks"].astype(tokens.dtype)]
+            )
         return tokens, logprobs, kv_caches, carry
+
+    def _model_stats(self) -> dict:
+        """``forward``'s ``stats`` argument for a model that counts its
+        router's picks on the device, nothing for any other."""
+        if getattr(self.model, "moe_pick_kinds", None):
+            return {"stats": {}}
+        return {}
+
+    def _record_moe_picks(self, span, host_tail) -> None:
+        """The pick counts a step program appended to its tokens: onto
+        the worker's counters and the step's fetch span."""
+        counts = {
+            k: int(n) for k, n in zip(self.model.moe_pick_kinds, host_tail)
+        }
+        self.telemetry.record_moe_picks(counts)
+        for kind, n in counts.items():
+            span.set_attribute(f"moe_picks_{kind}", n)
 
     @partial(
         jax.jit,
@@ -2005,8 +2087,10 @@ class ModelRunner:
         def resolve() -> ModelRunnerOutput:
             with get_tracer().span(
                 "runner.fetch", parent=step_ctx, step_id=so.step_id
-            ):
+            ) as fetch:
                 host_toks = np.asarray(jax.device_get(toks))  # [K, s_pad]
+                if host_toks.shape[0] > k_steps:
+                    self._record_moe_picks(fetch, host_toks[k_steps, :3])
             self._num_fetched += 1
             # K micro-steps = K weights+KV HBM passes.  Wall time spans
             # dispatch→resolve (includes any pipeline overlap — an
@@ -2081,8 +2165,9 @@ class ModelRunner:
                 kv, side, i = entry
                 t = k.shape[0]
                 hd = side.shape[-1]
+                planes = [k] if v is None else [k, v]
                 rows_kv = jnp.stack(
-                    [k.reshape(t, -1), v.reshape(t, -1)], axis=1
+                    [p.reshape(t, -1) for p in planes], axis=1
                 ).astype(side.dtype)
                 if rows_kv.shape[-1] < hd:
                     rows_kv = jnp.pad(
@@ -2105,6 +2190,7 @@ class ModelRunner:
 
         def body(carry, i):
             kv, sides, tok, out_buf = carry
+            stats = self._model_stats()
             pos = base_lens + i
             # Micro-step i runs only sequences with i < n_active: under-K
             # tails drop their queries (id == s_pad, the kernels' drop
@@ -2170,6 +2256,7 @@ class ModelRunner:
                     meta,
                     attn_fn=staged_attn,
                     kv_write_fn=staged_write,
+                    **stats,
                 )
                 kv = [e[0] for e in new_entries]
                 sides = [e[1] for e in new_entries]
@@ -2181,6 +2268,7 @@ class ModelRunner:
                     meta,
                     attn_fn=attn_fn,
                     kv_write_fn=self._kv_write_fn,
+                    **stats,
                 )
             with jax.named_scope(SCOPE_SAMPLER):
                 new_tok, _ = sample(
@@ -2196,6 +2284,9 @@ class ModelRunner:
                     rows,
                     jnp.where(live, out_lens + i, out_buf.shape[1]),
                 ].set(new_tok, mode="drop")
+            if stats:
+                return (kv, sides, new_tok, out_buf), (
+                    new_tok, stats["stats"]["moe_picks"])
             return (kv, sides, new_tok, out_buf), new_tok
 
         if staged:
@@ -2208,7 +2299,9 @@ class ModelRunner:
                     if isinstance(kv_l, tuple)
                     else data.dtype
                 )
-                return jnp.zeros((s_pad, 2, k_steps, data.shape[-1]), dt)
+                return jnp.zeros(
+                    (s_pad, data.shape[0], k_steps, data.shape[-1]), dt
+                )
 
             sides0 = [side0(kv_l) for kv_l in kv_caches]
         else:
@@ -2228,6 +2321,17 @@ class ModelRunner:
                     )
                     for kv_l, side_l in zip(kv_caches, sides_out)
                 ]
+        picks = None
+        if isinstance(toks, tuple):
+            toks, picks = toks
         # Each sequence's last VALID token, into its carry row.
         last = toks[jnp.clip(n_active - 1, 0, k_steps - 1), rows]
-        return toks, self._merge_carry(carry, slot_rows, last), kv_caches
+        carry = self._merge_carry(carry, slot_rows, last)
+        if picks is not None:
+            # The router's pick counts over the K micro-steps ride as
+            # one more row behind the tokens: one array, one transfer.
+            row = jnp.zeros((1, s_pad), toks.dtype).at[0, :3].set(
+                picks.sum(axis=0).astype(toks.dtype)
+            )
+            toks = jnp.concatenate([toks, row], axis=0)
+        return toks, carry, kv_caches

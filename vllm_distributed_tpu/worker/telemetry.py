@@ -23,6 +23,10 @@ runner writes:
 - **grouped MoE steps**: step programs dispatched whose int8 experts
   were traced with the grouped kernel (models/mixtral.select_moe_impl),
   so how often the routed path engages is a counter, not an inference;
+- **router picks**: of a model that routes over more experts than
+  this chip holds, the picks that went to identity (zero-compute)
+  experts, to experts held here and to experts held elsewhere, counted
+  on the device and fetched with the step's tokens;
 - **persistent cache**: JAX's own compilation-cache events for this
   process (lookups, hits, and entries written after a miss), so a warm
   restart is judged by what the cache reports, not by a time threshold.
@@ -124,6 +128,7 @@ class DeviceTelemetry:
         self.roofline_frac = 0.0
         self.fallbacks: dict[str, int] = {}
         self.moe_grouped_steps = 0
+        self.moe_picks: dict[str, int] = {}
         _install_cache_listener()
 
     def record_fallback(self, kind: str) -> None:
@@ -135,6 +140,13 @@ class DeviceTelemetry:
         grouped (routed) kernel; a fused decode scan never is one."""
         with self._lock:
             self.moe_grouped_steps += 1
+
+    def record_moe_picks(self, counts: dict[str, int]) -> None:
+        """Router picks of one fetched step by kind (zero | held |
+        absent), as the step program counted them on the device."""
+        with self._lock:
+            for kind, n in counts.items():
+                self.moe_picks[kind] = self.moe_picks.get(kind, 0) + n
 
     def record_compile(self, kind: str, seconds: float, key: str) -> None:
         with self._lock:
@@ -181,5 +193,6 @@ class DeviceTelemetry:
                 "roofline_frac": self.roofline_frac,
                 "fallbacks": dict(self.fallbacks),
                 "moe_grouped_steps": self.moe_grouped_steps,
+                "moe_picks": dict(self.moe_picks),
                 "persistent_cache": persistent_cache_counts(),
             }
