@@ -111,6 +111,54 @@ def test_engine_agrees_with_the_reference_through_the_latent_cache(
     assert sum(picks.values()) % (cfg["moe_topk"] * cfg["num_layers"]) == 0
 
 
+def test_one_request_goes_materialised_then_absorbed_then_the_scan(tmp_path):
+    """A 75-token prompt against a 32-token budget, interpreted
+    kernels: its first chunk is fresh (the flat per-head kernel), the
+    next two have a cached prefix (the absorbed chunk tiles behind the
+    conditional), then the fused scan decodes.  The returned logprobs
+    (single steps) and the scan's tokens are the plain reference's, the
+    steps' ``runner.dispatch`` spans say which form each chunk took,
+    and ``vllm:mla_chunk_tokens_total`` adds up to them."""
+    from vllm_distributed_tpu.tracing import get_tracer
+
+    d = testing.write_longcat_config(str(tmp_path), layers=1)
+    cfg = testing.longcat_config(layers=1)
+    rng = np.random.default_rng(5)
+    prompts = [list(map(int, rng.integers(3, 128, 75)))]
+    tracer = get_tracer()
+    tracer.reset()
+    with mock.patch.dict(os.environ, {"VDT_USE_PALLAS": "pallas_interpret"}):
+        engine = _engine(d, enable_tracing=True)
+        try:
+            with_lp = _generate(engine, prompts, logprobs=3, max_tokens=5)
+            spans = [s["attributes"] for tr in tracer.snapshot()
+                     for s in tr["spans"] if s["name"] == "runner.dispatch"]
+            fused = _generate(engine, prompts, max_tokens=5)
+            engine.refresh_device_telemetry()
+            text = engine.metrics.render().decode()
+        finally:
+            engine.shutdown()
+            tracer.configure(False)
+            tracer.reset()
+    diff, regret = _worst(cfg, prompts, with_lp)
+    assert diff is not None and diff < TOL, diff
+    assert regret < TOL
+    assert _worst(cfg, prompts, fused)[1] < TOL
+    assert fused[0].token_ids == with_lp[0].token_ids
+    chunks = [(a["chunk_tokens_fresh"], a["chunk_tokens_prefixed"])
+              for a in spans if "chunk_tokens_fresh" in a]
+    assert chunks == [(32, 0), (0, 32), (0, 11)]
+    # decode-only single steps (max_q 1) keep the absorbed form and
+    # carry neither attribute
+    assert sum("chunk_tokens_fresh" not in a for a in spans) == 4
+    counter = {}
+    for line in text.splitlines():
+        if line.startswith("vllm:mla_chunk_tokens_total{"):
+            form = line.split('form="')[1].split('"')[0]
+            counter[form] = float(line.rsplit(" ", 1)[1])
+    assert counter == {"materialised": 64.0, "absorbed": 86.0}  # two runs
+
+
 def test_picks_reach_the_metrics_and_the_fetch_spans(tmp_path):
     """``vllm:moe_picks_total`` by kind on ``/metrics``' text and the
     same counts as attributes of the steps' ``runner.fetch`` spans: the
@@ -463,3 +511,54 @@ def test_a_single_step_holds_no_xla_while(zero, conds, tmp_path):
     assert "pallas_call" in names
     assert names.count("cond") == conds  # two layers
     assert not set(names) & {"while", "scan"}, set(names)
+
+
+def test_a_single_step_as_the_runner_binds_it_holds_no_xla_while(tmp_path):
+    """With the head-space entry bound (``bind_max_q``: fresh chunks in
+    per-head form) a step has one more conditional and two more kernel
+    calls a sublayer than the absorbed form alone, and still no loop."""
+    from functools import partial
+
+    from vllm_distributed_tpu.ops.attention import (
+        AttentionMetadata,
+        bind_max_q,
+    )
+    from vllm_distributed_tpu.ops.pallas.mla_attention import (
+        mla_attention_cpu,
+    )
+
+    model = _model(tmp_path, zero=60)
+    params = jax.eval_shape(model.init_params, jax.random.PRNGKey(0))
+    t, s = 64, 8
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+    meta = AttentionMetadata(
+        q_seq_ids=i32(t), q_positions=i32(t), slot_mapping=i32(t),
+        block_tables=i32(s, 16), seq_lens=i32(s), logits_indices=i32(s),
+        chunk_starts=i32(s))
+    kv = [jax.ShapeDtypeStruct((1, 32, 16, 128), jnp.float32)] * 4
+
+    def primitives(jxp):
+        for eqn in jxp.eqns:
+            yield eqn.primitive.name
+            if eqn.primitive.name == "pallas_call":
+                continue
+            for v in eqn.params.values():
+                for inner in (v if isinstance(v, (tuple, list)) else (v,)):
+                    inner = getattr(inner, "jaxpr", inner)
+                    if hasattr(inner, "eqns"):
+                        yield from primitives(inner)
+
+    def names(attn_fn):
+        return list(primitives(jax.make_jaxpr(
+            lambda p, tok, kv, meta: model.forward(
+                p, tok, kv, meta, attn_fn=attn_fn))(
+                    params, i32(t), kv, meta).jaxpr))
+
+    absorbed = names(partial(mla_attention_cpu, max_q=32))
+    bound = names(bind_max_q(mla_attention_cpu, 32))
+    assert bound.count("cond") == absorbed.count("cond") + 4  # 2 layers
+    # absorbed: chunk tiles + one-token rows; bound: the flat kernel,
+    # one-token rows, and the chunk tiles inside the conditional
+    assert absorbed.count("pallas_call") == 2 * 4
+    assert bound.count("pallas_call") == 3 * 4
+    assert not set(bound) & {"while", "scan"}, set(bound)

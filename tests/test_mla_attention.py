@@ -1,8 +1,11 @@
 """``vdt_mla_attention`` (ops/pallas/mla_attention.py): the interpreted
 kernel against plain ``jnp`` over ragged chunks, cached prefixes and
 decode rows with the staged side buffer; the one-plane flush; the
-interleaved RoPE against its pairwise definition; and both kernels
-compiled at the LongCat widths for a described v5e chip."""
+interleaved RoPE against its pairwise definition; the materialised
+form's flat kernel (``vdt_mla_chunk_attention``) over ragged fresh
+chunks, and a step of all three kinds of row against the reference;
+and the kernels compiled at the LongCat widths for a described v5e
+chip."""
 
 import os
 
@@ -17,10 +20,13 @@ from vllm_distributed_tpu.models.common import (
 )
 from vllm_distributed_tpu.ops.attention import (
     AttentionMetadata,
+    bind_max_q,
     mla_attention_reference,
+    mla_sublayer_attention,
     write_kv_pages,
 )
 from vllm_distributed_tpu.ops.pallas import mla_attention as mla
+from vllm_distributed_tpu.ops.pallas import mla_chunk_attention as chunk
 from vllm_distributed_tpu.ops.pallas.kv_flush import kv_flush, kv_flush_cpu
 
 PAGE = 16
@@ -238,6 +244,166 @@ def test_tile_counts_are_the_wrappers_lists():
         [1] * 64, max_q=1, num_heads=64, value_width=512) == (64, 64)
 
 
+# ---- the materialised form: fresh chunks over the flat batch ---------------
+def _plain_heads(q, k, v, scale):
+    """One sequence, per head, causal: q, k [n, H, D], v [n, H, dv]."""
+    n = q.shape[0]
+    s = np.einsum("qhd,khd->hqk", q, k) * scale
+    s = np.where(np.tril(np.ones((n, n), bool))[None], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return np.einsum("hqk,khv->qhv", p / p.sum(-1, keepdims=True), v)
+
+
+@pytest.mark.parametrize("tq,tk", [(16, 16), (32, 16), (64, 64), (16, 8)])
+def test_chunk_kernel_matches_plain_attention_over_ragged_fresh_chunks(tq, tk):
+    """Fresh chunks of unequal lengths between rows of other kinds (one
+    decode row, three more), padding tokens at the end of the flat
+    batch: each fresh row sees its own sequence's rows up to itself and
+    nothing else; every other row comes out finite."""
+    rng = np.random.default_rng(3)
+    h, dn, dr, dv, t = 4, 16, 8, 16, 64
+    kinds = [(3, False), (20, True), (1, False), (15, True), (9, True)]
+    start, fresh = np.arange(t, dtype=np.int32), np.zeros(t, bool)
+    cursor, chunks = 0, []
+    for n, is_fresh in kinds:
+        if is_fresh:
+            start[cursor:cursor + n], fresh[cursor:cursor + n] = cursor, True
+            chunks.append(slice(cursor, cursor + n))
+        cursor += n
+    assert cursor == 48  # rows 48..63 are padding
+    qn, kn = rng.standard_normal((2, t, h, dn)).astype(np.float32)
+    qr = rng.standard_normal((t, h, dr)).astype(np.float32)
+    kr = rng.standard_normal((t, dr)).astype(np.float32)
+    v = rng.standard_normal((t, h, dv)).astype(np.float32)
+    got = np.asarray(chunk.mla_chunk_attention(
+        *(jnp.asarray(x.reshape(t, -1)) for x in (qn, qr, kn)),
+        jnp.asarray(kr), jnp.asarray(v.reshape(t, -1)), jnp.asarray(start),
+        jnp.asarray(fresh), num_heads=h, scale=0.3, tile_q=tq, tile_k=tk,
+        interpret=True)).reshape(t, h, dv)
+    assert np.isfinite(got).all()
+    for sl in chunks:
+        n = sl.stop - sl.start
+        k = np.concatenate(
+            [kn[sl], np.broadcast_to(kr[sl, None], (n, h, dr))], -1)
+        want = _plain_heads(
+            np.concatenate([qn[sl], qr[sl]], -1), k, v[sl], 0.3)
+        assert np.abs(got[sl] - want).max() < 2e-5
+    if tq == 16:  # the last tile holds no fresh row: zeros, not garbage
+        assert not got[48:].any()
+
+
+def test_the_pair_list_skips_other_sequences_and_is_bounded_by_the_rows():
+    """Three 680-token prompts in a 2,048-row batch walk 20 (tile,
+    block) pairs of 256 rows, not the flat triangle's 36; one sequence
+    filling the batch walks all 36, which is the list's static length
+    whatever the chunks are."""
+    t = 2048
+    assert chunk.num_pair_entries(t, 256, 256) == 36
+    assert chunk.num_pair_entries(64, 32, 16) == 2 + 4
+
+    def pairs(lens):
+        start, fresh = np.arange(t, dtype=np.int32), np.zeros(t, bool)
+        cursor = 0
+        for n in lens:
+            start[cursor:cursor + n], fresh[cursor:cursor + n] = cursor, True
+            cursor += n
+        pq, pk, nb, n = chunk.pair_list(
+            jnp.asarray(start), jnp.asarray(fresh), 256, 256)
+        assert pq.shape == pk.shape == (36,)
+        return (np.asarray(pq), np.asarray(pk), np.asarray(nb), int(n[0]))
+
+    pq, pk, nb, n = pairs([680, 680, 680])
+    assert nb.tolist() == [1, 2, 3, 2, 3, 4, 2, 3] and n == 20
+    # the tile of rows 768..1023 is all the second prompt (from row 680)
+    assert pk[pq == 3].tolist()[:2] == [2, 3]
+    # entries past the live count repeat the last live pair
+    assert set(pq[n:]) == {pq[n - 1]} and set(pk[n:]) == {pk[n - 1]}
+    _, _, nb, n = pairs([2048])
+    assert nb.tolist() == list(range(1, 9)) and n == 36
+    # half the batch is padding: four tiles are one zero-writing entry
+    _, _, nb, n = pairs([1000])
+    assert nb.tolist() == [1, 2, 3, 4, 0, 0, 0, 0] and n == 10 + 4
+
+
+def _latent_step(rng, seqs, t_pad, s_pad, pages_pad, h=4, dn=16, dr=8,
+                 dv=16, c_w=24, wp=128):
+    """A step in head space: seqs [(cached tokens, new tokens)]; the
+    pool holds the cached rows and this step's own ``[c, k_rope]``."""
+    w = c_w + dr
+    pool = jnp.zeros((1, 64, PAGE, wp), jnp.float32)
+    qn = rng.standard_normal((t_pad, h, dn)).astype(np.float32)
+    qr = rng.standard_normal((t_pad, h, dr)).astype(np.float32)
+    c = rng.standard_normal((t_pad, c_w)).astype(np.float32)
+    kr = rng.standard_normal((t_pad, dr)).astype(np.float32)
+    ids = np.full(t_pad, s_pad, np.int32)
+    pos = np.zeros(t_pad, np.int32)
+    bt = np.zeros((s_pad, pages_pad), np.int32)
+    lens, starts = np.zeros((2, s_pad), np.int32)
+    cursor, page = 0, 1
+    for s, (cached, new) in enumerate(seqs):
+        total = cached + new
+        n_pages = -(-total // PAGE)
+        bt[s, :n_pages] = np.arange(page, page + n_pages)
+        page += n_pages
+        slots = bt[s, np.arange(total) // PAGE] * PAGE + np.arange(total) % PAGE
+        rows = np.zeros((total, 1, wp), np.float32)
+        rows[:cached, 0, :w] = rng.standard_normal((cached, w))
+        rows[cached:, 0, :c_w] = c[cursor:cursor + new]
+        rows[cached:, 0, c_w:w] = kr[cursor:cursor + new]
+        pool = write_kv_pages(pool, jnp.asarray(rows), None, jnp.asarray(slots))
+        ids[cursor:cursor + new] = s
+        pos[cursor:cursor + new] = np.arange(cached, total)
+        lens[s], starts[s] = total, cached
+        cursor += new
+    meta = AttentionMetadata(
+        q_seq_ids=jnp.asarray(ids), q_positions=jnp.asarray(pos),
+        slot_mapping=jnp.zeros(t_pad, jnp.int32),
+        block_tables=jnp.asarray(bt), seq_lens=jnp.asarray(lens),
+        logits_indices=jnp.zeros(s_pad, jnp.int32),
+        chunk_starts=jnp.asarray(starts))
+    w_uk = rng.standard_normal((h, dn, c_w)).astype(np.float32) * 0.3
+    w_uv = rng.standard_normal((h, c_w, dv)).astype(np.float32) * 0.3
+    ops = [jnp.asarray(x) for x in (qn, qr, c, kr, w_uk, w_uv)]
+    return ops + [pool, meta], cursor
+
+
+@pytest.mark.parametrize("seqs,taken", [
+    # a fresh chunk, chunks with a cached prefix, one-token rows
+    ([(0, 25), (50, 1), (33, 17), (0, 1), (90, 1), (0, 3), (16, 3)], True),
+    # no chunk with a cached prefix: the conditional is not taken
+    ([(0, 30), (12, 1), (0, 20), (3, 1)], False),
+    ([(40, 9), (7, 12)], True),  # nothing fresh
+])
+def test_a_step_of_all_three_kinds_of_row_matches_the_reference(seqs, taken):
+    """``mla_attention_from_heads`` (what a chunked step's model call
+    reaches through ``bind_max_q``) against the absorbed form around
+    ``mla_attention_reference``: fresh chunks through the flat kernel,
+    one-token rows through the decode geometry, chunks with a cached
+    prefix behind the conditional, whether or not it is taken."""
+    rng = np.random.default_rng(4)
+    ops, n = _latent_step(rng, seqs, 64, 8, 8)
+    want = np.asarray(mla_sublayer_attention(
+        mla_attention_reference, *ops, scale=0.3))
+    fn = bind_max_q(mla.mla_attention_cpu, 64)
+    got = np.asarray(mla_sublayer_attention(fn, *ops, scale=0.3))
+    assert got.shape == (64, 4 * 16)  # flat, as W_o takes it
+    got = got.reshape(want.shape)
+    assert np.isfinite(got).all()  # padding rows too: nothing poisons
+    assert np.abs(got[:n] - want[:n]).max() < 2e-5
+    meta = ops[-1]
+    q_len = np.bincount(np.asarray(meta.q_seq_ids), minlength=9)[:8]
+    assert bool(((q_len > 1) & (np.asarray(meta.chunk_starts) > 0)).any()
+                ) is taken
+    fresh, prefixed = mla.mla_chunk_token_counts(
+        [new for _, new in seqs], [cached for cached, _ in seqs])
+    assert fresh == sum(new for c, new in seqs if new > 1 and c == 0)
+    assert prefixed == sum(new for c, new in seqs if new > 1 and c > 0)
+    # the fused scan's and a decode-only step's calls keep the absorbed
+    # form: no head-space entry is bound at max_q = 1, nor on a closure
+    assert not hasattr(bind_max_q(mla.mla_attention_cpu, 1), "from_heads")
+    assert bind_max_q(mla_attention_reference, 64) is mla_attention_reference
+
+
 # ---- compiled for a described v5e chip, no chip attached -----------------
 @pytest.fixture(scope="module")
 def one_chip():
@@ -305,6 +471,45 @@ def test_attention_compiles_for_the_v5e_at_longcat_widths(
     # the absorbed queries are 576 values a head: 151 MB for 2,048
     # tokens; their tiles and the latent outputs are the rest
     assert compiled.memory_analysis().temp_size_in_bytes < 500e6
+
+
+@pytest.mark.parametrize("t", [2048, 256])
+def test_chunk_attention_compiles_for_the_v5e_at_longcat_widths(
+        t, one_chip, no_compile_cache):
+    """The materialised form at the cell's widths (64 heads of 128 + 64
+    / 128 over 512-wide latents): Mosaic takes the flat kernel's column
+    blocks of eight heads, a chunked step's sublayer is three kernel
+    calls outside the conditional (the flat kernel, the one-token rows)
+    and in it (the absorbed chunk tiles), and no XLA loop comes with
+    the lists."""
+    h, dn, dr, dv, c_w, wp, s, pages, pool = 64, 128, 64, 128, 512, 640, 64, 256, 8192
+    bf = jnp.bfloat16
+
+    def shaped(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    assert chunk.heads_per_step(h, dn, dr, dv) == 8
+    meta = AttentionMetadata(
+        q_seq_ids=shaped((t,)), q_positions=shaped((t,)),
+        slot_mapping=shaped((t,)), block_tables=shaped((s, pages)),
+        seq_lens=shaped((s,)), logits_indices=shaped((s,)),
+        chunk_starts=shaped((s,)))
+    args = [shaped((t, h, dn), bf), shaped((t, h, dr), bf),
+            shaped((t, c_w), bf), shaped((t, dr), bf),
+            shaped((h, dn, c_w), bf), shaped((h, c_w, dv), bf),
+            shaped((1, pool, PAGE, wp), bf), meta]
+    fn = bind_max_q(mla.mla_attention, t)
+    compiled = jax.jit(lambda *a: mla_sublayer_attention(
+        fn, *a, scale=192 ** -0.5)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "vdt_mla_chunk_attention" in text and "vdt_mla_attention" in text
+    assert text.count("tpu_custom_call") == 3
+    assert " while(" not in text and text.count(" conditional(") == 1
+    # the per-head keys and values come out row-major: no transpose of
+    # a [T, H, 128] product is left in front of the kernel
+    assert "{0,2,1" not in "".join(
+        line for line in text.splitlines()
+        if "mla_absorb/dot_general" in line and "cond" not in line)
 
 
 def test_one_plane_flush_compiles_for_the_v5e(one_chip, no_compile_cache):

@@ -8,15 +8,26 @@ the chip, at the benchmark configuration's widths (PERF.md, PR 34).
 products over their gathered rows) at ``--tokens`` rows.  Times are host-clock seconds
 over back-to-back calls that end in ``block_until_ready``.
 
+``--fresh`` instead times one sublayer's many-row attention in both
+forms (PERF.md, PR 37) at ``--tokens`` rows: three fresh prompts fill
+the flat batch (then the same beside 40 decode rows, then with one of
+the three a chunk with a cached prefix, so the conditional is taken).
+Absorbed: the whole sublayer, its absorb products alone, its kernel and
+tile gathers alone.  Materialised: the whole sublayer, the K/V
+up-projection alone, ``vdt_mla_chunk_attention`` alone over a sweep of
+(query tile, key block, heads a step), and what is left (layout).
+
 Usage: python tools/mla_microbench.py [--tokens 64 256 2048] [--ctx 1024]
-       python tools/mla_microbench.py --cpu-rehearsal   (toy widths,
-       interpreted kernel: counts nothing, proves the script runs)
+       python tools/mla_microbench.py --fresh [--tokens 256 1024 2048]
+       python tools/mla_microbench.py --cpu-rehearsal [--fresh]  (toy
+       widths, interpreted kernel: counts nothing, proves the script runs)
 """
 
 from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -28,8 +39,23 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from vllm_distributed_tpu.ops.attention import AttentionMetadata
-from vllm_distributed_tpu.ops.pallas.mla_attention import mla_attention
+from vllm_distributed_tpu.ops.attention import (
+    AttentionMetadata,
+    bind_max_q,
+    mla_absorb_queries,
+    mla_sublayer_attention,
+    mla_unabsorb_outputs,
+)
+from vllm_distributed_tpu.ops.pallas.mla_attention import (
+    mla_attention,
+    mla_attention_cpu,
+)
+from vllm_distributed_tpu.ops.pallas.mla_chunk_attention import (
+    TILE_K,
+    TILE_Q,
+    heads_per_step,
+    mla_chunk_attention,
+)
 
 PAGE = 16
 
@@ -68,9 +94,128 @@ def _meta(seqs: int, ctx: int, new: int, t_pad: int, s_pad: int,
         chunk_starts=jnp.asarray(starts))
 
 
+def _step_meta(seqs, t_pad: int, s_pad: int, pages_pad: int):
+    """``seqs``: [(cached tokens, new tokens)] in flat-batch order,
+    pages handed out in order from page 1."""
+    ids = np.full(t_pad, s_pad, np.int32)
+    pos = np.zeros(t_pad, np.int32)
+    bt = np.zeros((s_pad, pages_pad), np.int32)
+    lens = np.zeros(s_pad, np.int32)
+    starts = np.zeros(s_pad, np.int32)
+    cursor, page = 0, 1
+    for s, (cached, new) in enumerate(seqs):
+        per = -(-(cached + new) // PAGE)
+        ids[cursor:cursor + new] = s
+        pos[cursor:cursor + new] = np.arange(cached, cached + new)
+        bt[s, :per] = page + np.arange(per)
+        lens[s], starts[s] = cached + new, cached
+        cursor, page = cursor + new, page + per
+    z = jnp.zeros
+    return AttentionMetadata(
+        q_seq_ids=jnp.asarray(ids), q_positions=jnp.asarray(pos),
+        slot_mapping=z(t_pad, jnp.int32), block_tables=jnp.asarray(bt),
+        seq_lens=jnp.asarray(lens), logits_indices=z(s_pad, jnp.int32),
+        chunk_starts=jnp.asarray(starts)), page
+
+
+def fresh_case(args, toy: bool) -> dict:
+    """One sublayer's many-row attention, absorbed against
+    materialised, part by part."""
+    h, dn, dr, dv, c_w, wp = (
+        (4, 16, 8, 16, 24, 128) if toy else (64, 128, 64, 128, 512, 640))
+    dtype = jnp.float32 if toy else jnp.bfloat16
+    tokens = [64] if toy else args.tokens
+    calls = 1 if toy else args.calls
+    ctx = 48 if toy else args.ctx
+    s_pad, n_dec = (8, 3) if toy else (64, 40)
+    scale = (dn + dr) ** -0.5
+    entry = mla_attention_cpu if toy else mla_attention
+    key = jax.random.PRNGKey(0)
+    ks = jax.random.split(key, 8)
+
+    def rnd(k, shape, std=1.0):
+        return (jax.random.normal(k, shape, jnp.float32) * std).astype(dtype)
+
+    w_uk, w_uv = rnd(ks[0], (h, dn, c_w), 0.05), rnd(ks[1], (h, c_w, dv), 0.05)
+    out = []
+    for t in tokens:
+        third = (t - n_dec - 8) // 3
+        mixes = {
+            "fresh": [(0, third)] * 3,
+            "fresh+decode": [(ctx, 1)] * n_dec + [(0, third)] * 3,
+            "fresh+decode+prefixed": (
+                [(ctx, 1)] * n_dec + [(0, third)] * 2 + [(ctx, third)]),
+        }
+        pages_pad = 1 << (-(-(ctx + third) // PAGE) - 1).bit_length()
+        q_nope, q_rope = rnd(ks[2], (t, h, dn)), rnd(ks[3], (t, h, dr))
+        c, k_rope = rnd(ks[4], (t, c_w)), rnd(ks[5], (t, dr))
+        for mix, seqs in mixes.items():
+            meta, pages = _step_meta(seqs, t, s_pad, pages_pad)
+            pool = rnd(ks[6], (1, pages + 1, PAGE, wp))
+            ops = (q_nope, q_rope, c, k_rope, w_uk, w_uv, pool, meta)
+            line = {"tokens": t, "mix": mix, "chunk": third}
+            for form, fn in (
+                ("absorbed", functools.partial(entry, max_q=t)),
+                ("materialised", bind_max_q(entry, t)),
+            ):
+                whole = jax.jit(lambda *a, fn=fn: mla_sublayer_attention(
+                    fn, *a, scale=scale))
+                line[f"{form}_ms"] = _timed(whole, *ops, calls=calls) * 1e3
+            out.append(line)
+        # the parts, over the three fresh prompts alone
+        meta, pages = _step_meta(mixes["fresh"], t, s_pad, pages_pad)
+        pool = rnd(ks[6], (1, pages + 1, PAGE, wp))
+        parts = {"tokens": t, "mix": "fresh, by part", "chunk": third}
+        absorb = jax.jit(lambda qn, qr, lat: (
+            mla_absorb_queries(qn, qr, w_uk), mla_unabsorb_outputs(lat, w_uv)))
+        parts["absorbed_products_ms"] = _timed(
+            absorb, q_nope, q_rope, rnd(ks[7], (t, h, c_w)), calls=calls) * 1e3
+        q_abs = mla_absorb_queries(q_nope, q_rope, w_uk)
+        kern = jax.jit(lambda q, kv, m: entry(
+            q, kv, m, scale=scale, value_width=c_w, max_q=t))
+        parts["absorbed_kernel_and_gathers_ms"] = _timed(
+            kern, q_abs, pool, meta, calls=calls) * 1e3
+        up = jax.jit(lambda c: (
+            jnp.dot(c, w_uk.transpose(2, 0, 1).reshape(c_w, h * dn),
+                    preferred_element_type=jnp.float32).astype(dtype),
+            jnp.dot(c, w_uv.transpose(1, 0, 2).reshape(c_w, h * dv),
+                    preferred_element_type=jnp.float32).astype(dtype)))
+        parts["up_projection_ms"] = _timed(up, c, calls=calls) * 1e3
+        k_nope, v = up(c)
+        ids = np.asarray(meta.q_seq_ids)
+        fresh = ids < s_pad
+        first = {s: int(np.argmax(ids == s)) for s in set(ids[fresh])}
+        row_start = np.array(
+            [first[s] if f else r for r, (s, f) in enumerate(zip(ids, fresh))],
+            np.int32)
+        flat = (q_nope.reshape(t, -1), q_rope.reshape(t, -1), k_nope, k_rope,
+                v, jnp.asarray(row_start), jnp.asarray(fresh))
+        pairs = 3 * third * (third + 1) / 2
+        default = (min(TILE_Q, t), min(TILE_K, t),
+                   heads_per_step(h, dn, dr, dv))
+        sweep = [default] if toy else [
+            (tq, tk, g) for tq, tk in ((256, 256), (512, 512), (512, 256),
+                                       (256, 128), (128, 128))
+            for g in (2, 4, 8) if tq <= t]
+        parts["chunk_kernel"] = []
+        for tq, tk, g in sweep:
+            kern = jax.jit(functools.partial(
+                mla_chunk_attention, num_heads=h, scale=scale, tile_q=tq,
+                tile_k=tk, heads=g, interpret=toy))
+            sec = _timed(kern, *flat, calls=calls)
+            parts["chunk_kernel"].append({
+                "tile_q": tq, "tile_k": tk, "heads": g, "ms": sec * 1e3,
+                "default": (tq, tk, g) == default,
+                "tflops_real_pairs": (
+                    pairs * h * (dn + dr + dv) * 2 / sec / 1e12)})
+        out.append(parts)
+    return {"device": str(jax.devices()[0]), "ctx": ctx, "fresh": out}
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--tokens", type=int, nargs="+", default=[64, 256, 2048])
+    ap.add_argument("--tokens", type=int, nargs="+", default=None)
+    ap.add_argument("--fresh", action="store_true")
     ap.add_argument("--ctx", type=int, default=1024)
     ap.add_argument("--calls", type=int, default=20)
     ap.add_argument("--cpu-rehearsal", action="store_true")
@@ -78,6 +223,15 @@ def main() -> int:
     toy = args.cpu_rehearsal
     if toy:
         os.environ["JAX_PLATFORMS"] = "cpu"
+    if args.fresh:
+        args.tokens = args.tokens or [256, 1024, 2048]
+        out = fresh_case(args, toy)
+        print(json.dumps(out, indent=1))
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open("chiprun_out/mla_microbench_fresh.json", "w") as f:
+            json.dump(out, f, indent=1)
+        return 0
+    args.tokens = args.tokens or [64, 256, 2048]
     h, w, wp, vw = (4, 24, 128, 16) if toy else (64, 576, 640, 512)
     dtype = jnp.float32 if toy else jnp.bfloat16
     ctx = 48 if toy else args.ctx

@@ -140,6 +140,7 @@ class LLMEngine:
         self._telemetry_fallbacks_seen: dict[str, int] = {}
         self._telemetry_cache_seen: dict[str, int] = {}
         self._telemetry_moe_picks_seen: dict[str, int] = {}
+        self._telemetry_mla_chunk_seen: dict[str, int] = {}
         self._telemetry_moe_grouped_seen = 0
 
         # Disaggregated prefill/decode hand-off (ISSUE 15): export holds
@@ -626,6 +627,11 @@ class LLMEngine:
                 "moe_picks",
                 self._telemetry_moe_picks_seen,
                 self.metrics.record_moe_picks,
+            ),
+            (
+                "mla_chunk_tokens",
+                self._telemetry_mla_chunk_seen,
+                self.metrics.record_mla_chunk_tokens,
             ),
         ):
             for kind, total in (snap.get(field) or {}).items():

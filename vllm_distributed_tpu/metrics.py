@@ -155,6 +155,7 @@ DOCUMENTED_METRICS = (
     "vllm:xla_persistent_cache_events_total",
     "vllm:moe_grouped_steps_total",
     "vllm:moe_picks_total",
+    "vllm:mla_chunk_tokens_total",
     # ---- fleet sentinel (ISSUE 20) ----
     "vllm:slo_burn_rate",
     "vllm:itl_p99_ms",
@@ -604,6 +605,16 @@ class EngineMetrics:
             ["model_name", "kind"],
             registry=self.registry,
         )
+        self._mla_chunk_tokens = Counter(
+            "vllm:mla_chunk_tokens",
+            "Tokens of multi-token chunks in the chunked steps the "
+            "reply-rank worker dispatched, by the form their latent "
+            "(MLA) attention took: materialised (a fresh chunk: per-head "
+            "keys and values over the step's own rows), absorbed (a "
+            "chunk with a cached prefix: the latent cache's rows)",
+            ["model_name", "form"],
+            registry=self.registry,
+        )
         # ---- fleet sentinel (ISSUE 20) ----
         self._slo_burn = Gauge(
             "vllm:slo_burn_rate",
@@ -920,6 +931,12 @@ class EngineMetrics:
         if self.enabled and n > 0:
             self._moe_picks.labels(
                 model_name=self._model_name, kind=kind
+            ).inc(n)
+
+    def record_mla_chunk_tokens(self, form: str, n: int) -> None:
+        if self.enabled and n > 0:
+            self._mla_chunk_tokens.labels(
+                model_name=self._model_name, form=form
             ).inc(n)
 
     def record_moe_grouped_steps(self, n: int) -> None:

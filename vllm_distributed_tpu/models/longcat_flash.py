@@ -8,7 +8,7 @@ One layer (``n`` are RMS norms with a weight, ``A`` an MLA sublayer,
     h = x + A0(n0(x));  u = n1(h);  m = M(u);  h = h + F0(u)
     h = h + A1(n2(h));  y = h + F1(n3(h)) + m
 
-**MLA, absorbed.**  ``q = W_qb · norm(W_qa · x)``, per head a ``nope``
+**MLA.**  ``q = W_qb · norm(W_qa · x)``, per head a ``nope``
 part and a ``rope`` part, both × (H / q_lora_rank)^½; ``[c, k_rope] =
 W_kva · x``, ``c = norm(c)`` × (H / kv_lora_rank)^½, ``k_rope`` one
 vector for all heads, unscaled; RoPE on ``q_rope`` and ``k_rope`` by
@@ -17,10 +17,15 @@ plane, ``kv_lora_rank + qk_rope_head_dim`` values a token, key and value
 at once), padded with zeros to whole 128-lane tiles: the chip lays a
 576-lane row out over 640 lanes of HBM whatever the program calls it,
 and its DMA engine takes whole tiles only, so the pool states the 640
-it occupies.  Every path here reads the cache, so every path is the
-absorbed form: with ``W_kvb`` split per head into ``W_uk`` and ``W_uv``,
-``q̃ = [q_nope · W_uk, q_rope]``, scores ``q̃ · row`` × qk_head_dim^-½,
-``o = (softmax · c) · W_uv``.  Cache ``2·layer + sublayer`` is sublayer
+it occupies.  A row that reads the cache attends in the absorbed form:
+with ``W_kvb`` split per head into ``W_uk`` and ``W_uv``, ``q̃ = [q_nope
+· W_uk, q_rope]``, scores ``q̃ · row`` × qk_head_dim^-½, ``o = (softmax
+· c) · W_uv``.  A prompt chunk that starts at position 0 reads nothing
+cached and attends per head over the step's own rows (``[k_nope, v] =
+c · W_kvb``, the same two halves).  The model makes one call
+(``ops/attention.mla_sublayer_attention``, head space in and out); which
+rows take which form is decided there, from the step's metadata.  Cache
+``2·layer + sublayer`` is sublayer
 ``sublayer`` of layer ``layer``.
 
 **The router** is float32 throughout: ``s = softmax(W_r · u)`` over
@@ -63,13 +68,11 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from vllm_distributed_tpu.models.common import (
-    SCOPE_ATTN,
     SCOPE_ATTN_OUT,
     SCOPE_ATTN_QKV,
     SCOPE_EMBED,
     SCOPE_KV_WRITE,
     SCOPE_LM_HEAD,
-    SCOPE_MLA_ABSORB,
     SCOPE_MLP,
     SCOPE_MOE_EXPERTS,
     SCOPE_MOE_ROUTER,
@@ -83,6 +86,7 @@ from vllm_distributed_tpu.ops.attention import (
     AttentionMetadata,
     KVCacheLayout,
     mla_attention_reference,
+    mla_sublayer_attention,
     write_kv_pages,
 )
 from vllm_distributed_tpu.utils import next_power_of_2
@@ -321,24 +325,12 @@ class LongcatFlashForCausalLM(SupportsQuantization):
                 (t, 1, self.cache_width - self.latent_width), x.dtype
             )
             row = jnp.concatenate([c[:, None, :], k_rope, pad], axis=-1)
-        with jax.named_scope(SCOPE_MLA_ABSORB):
-            q_lat = jnp.einsum(
-                "thd,hdc->thc", q_nope, a["w_uk"].astype(x.dtype),
-                preferred_element_type=jnp.float32,
-            ).astype(x.dtype)
-            q_abs = jnp.concatenate([q_lat, q_rope], axis=-1)
         with jax.named_scope(SCOPE_KV_WRITE):
             kv = kv_write_fn(kv, row, None, meta.slot_mapping)
-        with jax.named_scope(SCOPE_ATTN):
-            o_lat = attn_fn(
-                q_abs, kv, meta,
-                scale=self.scale, value_width=self.kv_lora_rank,
-            )
-        with jax.named_scope(SCOPE_MLA_ABSORB):
-            o = jnp.einsum(
-                "thc,hcv->thv", o_lat, a["w_uv"].astype(x.dtype),
-                preferred_element_type=jnp.float32,
-            ).astype(x.dtype)
+        o = mla_sublayer_attention(
+            attn_fn, q_nope, q_rope, c, k_rope[:, 0], a["w_uk"], a["w_uv"],
+            kv, meta, scale=self.scale,
+        )
         with jax.named_scope(SCOPE_ATTN_OUT):
             x = x + linear(o.reshape(t, nh * self.v_dim), a["wo"])
         return x, kv

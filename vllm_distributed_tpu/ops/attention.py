@@ -42,6 +42,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from vllm_distributed_tpu.models.common import SCOPE_ATTN, SCOPE_MLA_ABSORB
+
 DEFAULT_MASK_VALUE = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
@@ -381,3 +383,85 @@ def mla_attention_reference(
     )
     out = jnp.einsum("thc,tcv->thv", probs, rows_tok[..., :value_width])
     return out.astype(q.dtype)
+
+
+def mla_absorb_queries(
+    q_nope: jax.Array,  # [T, H, dn]
+    q_rope: jax.Array,  # [T, H, dr]
+    w_uk: jax.Array,  # [H, dn, C]
+) -> jax.Array:
+    """Queries into the latent space: ``[q_nope · W_uk, q_rope]``
+    [T, H, C + dr], what scores a cached row ``[c, k_rope]``."""
+    q_lat = jnp.einsum(
+        "thd,hdc->thc", q_nope, w_uk.astype(q_nope.dtype),
+        preferred_element_type=jnp.float32,
+    ).astype(q_nope.dtype)
+    return jnp.concatenate([q_lat, q_rope], axis=-1)
+
+
+def mla_unabsorb_outputs(
+    o_lat: jax.Array,  # [T, H, C] attended latents
+    w_uv: jax.Array,  # [H, C, dv]
+) -> jax.Array:
+    """Attended latents back out of the latent space: [T, H, dv]."""
+    return jnp.einsum(
+        "thc,hcv->thv", o_lat, w_uv.astype(o_lat.dtype),
+        preferred_element_type=jnp.float32,
+    ).astype(o_lat.dtype)
+
+
+def mla_sublayer_attention(
+    attn_fn,
+    q_nope: jax.Array,  # [T, H, dn]
+    q_rope: jax.Array,  # [T, H, dr], rotated
+    c: jax.Array,  # [T, C] this step's latents, as cached
+    k_rope: jax.Array,  # [T, dr] this step's rope keys, as cached
+    w_uk: jax.Array,  # [H, dn, C]
+    w_uv: jax.Array,  # [H, C, dv]
+    kv_pages,  # the sublayer's cache, this step's rows written
+    metadata: AttentionMetadata,
+    *,
+    scale: float,
+) -> jax.Array:
+    """One MLA sublayer's attention, from head space to head space: the
+    model's one attention call.  Returns what ``W_o`` takes once
+    flattened: [T, H, dv], or [T, H·dv] already.
+
+    An ``attn_fn`` whose step can tell rows that need no cache from rows
+    that do brings ``attn_fn.from_heads`` (``bind_max_q`` below, for
+    ops/pallas/mla_attention.py in a chunked step) and gets the pieces.
+    Every other ``attn_fn`` has ``mla_attention_reference``'s contract
+    (this file's XLA path, the fused scan's staged call, a decode-only
+    step) and gets the absorbed form around it: queries into the latent
+    space, the attended latents back out."""
+    from_heads = getattr(attn_fn, "from_heads", None)
+    if from_heads is not None:
+        # whatever it runs outside a scope of its own (the copies XLA
+        # lays round its conditional) is attention's glue
+        with jax.named_scope(SCOPE_ATTN):
+            return from_heads(
+                q_nope, q_rope, c, k_rope, w_uk, w_uv, kv_pages, metadata,
+                scale=scale,
+            )
+    with jax.named_scope(SCOPE_MLA_ABSORB):
+        q_abs = mla_absorb_queries(q_nope, q_rope, w_uk)
+    with jax.named_scope(SCOPE_ATTN):
+        o_lat = attn_fn(
+            q_abs, kv_pages, metadata, scale=scale, value_width=c.shape[-1]
+        )
+    with jax.named_scope(SCOPE_MLA_ABSORB):
+        return mla_unabsorb_outputs(o_lat, w_uv)
+
+
+def bind_max_q(attn_fn, max_q: int):
+    """``attn_fn`` for a program whose longest chunk is in the bucket
+    ``max_q``, where the kernel's grid depends on it (``needs_max_q``);
+    its head-space entry (``from_heads``: chunked steps only) bound
+    alike."""
+    if not getattr(attn_fn, "needs_max_q", False):
+        return attn_fn
+    bound = partial(attn_fn, max_q=max_q)
+    from_heads = getattr(attn_fn, "from_heads", None)
+    if from_heads is not None and max_q > 1:
+        bound.from_heads = partial(from_heads, max_q=max_q)
+    return bound

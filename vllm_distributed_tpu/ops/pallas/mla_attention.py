@@ -23,6 +23,20 @@ latent lanes and the rope lanes — so that neither contracts over a
 width that is not a lane-tile multiple.  Operands keep the pool's dtype
 on the MXU; scores, softmax state and the output accumulate in
 float32.  The staged side buffer of the fused scan is ``[S, 1, K, WP]``.
+
+**Which rows take this kernel.**  The absorbed form pays where one
+cached row serves every head of ONE query token (2·(W + value_width)
+operations a query–key pair a head: 3.4 × the per-head form's at the
+LongCat widths, and a W-wide query and a value_width-wide output a
+head through HBM).  A chunked step therefore enters through
+``mla_attention_from_heads`` (bound by ``ops/attention.bind_max_q``),
+which sends each sequence by what the step's own metadata says of it:
+a fresh chunk to the materialised form (ops/pallas/
+mla_chunk_attention.py), a one-token row to this kernel's decode
+geometry, a chunk with a cached prefix to this kernel's chunk tiles
+behind one ``lax.cond``.  ``mla_attention`` itself (every row absorbed)
+stays the fused scan's and the decode-only step's call, and the
+building block of the other two.
 """
 
 from __future__ import annotations
@@ -34,7 +48,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from vllm_distributed_tpu.ops.attention import AttentionMetadata
+from vllm_distributed_tpu.models.common import (
+    SCOPE_ATTN,
+    SCOPE_MLA_ABSORB,
+)
+from vllm_distributed_tpu.ops.attention import (
+    AttentionMetadata,
+    mla_absorb_queries,
+    mla_unabsorb_outputs,
+)
+from vllm_distributed_tpu.ops.pallas.mla_chunk_attention import (
+    mla_chunk_attention,
+)
 from vllm_distributed_tpu.ops.pallas.paged_attention import (
     _pow2_floor,
     _splits_singles,
@@ -407,6 +432,42 @@ def mla_attention(
     )
 
 
+def _flat_rows(metadata: AttentionMetadata, t: int):
+    """Where each sequence's queries are in the flat batch: (query
+    tokens a sequence [S], its first flat row [S], each row's sequence
+    [T] (padding rows: the last), each row's place in its chunk [T]).
+    Padding tokens carry q_seq_ids == S and match no sequence."""
+    s, _ = metadata.block_tables.shape
+    tok = jnp.arange(t, dtype=jnp.int32)
+    match = metadata.q_seq_ids[None, :] == jnp.arange(s, dtype=jnp.int32)[
+        :, None
+    ]
+    q_len = match.sum(axis=1, dtype=jnp.int32)
+    first_tok = jnp.min(jnp.where(match, tok[None, :], t), axis=1)
+    seq_idx = jnp.minimum(metadata.q_seq_ids, s - 1)
+    return q_len, first_tok, seq_idx, tok - first_tok[seq_idx]
+
+
+def _tile_rows(tiles, first_tok, mq_blk: int, t: int):
+    """Flat rows [NT, mq_blk] of a tile list's query tokens."""
+    tile_seq, tile_q0, _ = tiles
+    rows = (first_tok[tile_seq] + tile_q0)[:, None] + jnp.arange(
+        mq_blk, dtype=jnp.int32
+    )
+    return jnp.clip(rows, 0, t - 1)
+
+
+def _chunk_tiles(in_chunks, q_len, t: int, s: int, mq_blk: int, max_q: int):
+    """The chunk-tile list of the sequences ``in_chunks`` [S] marks, and
+    the first tile of each sequence [S]."""
+    maxq = max(next_power_of_2(max_q), mq_blk)
+    return tile_list(
+        jnp.where(in_chunks, (q_len + mq_blk - 1) // mq_blk, 0),
+        num_query_tiles(t, s, mq_blk, maxq),
+        mq_blk,
+    )
+
+
 @functools.partial(
     jax.jit, static_argnames=("scale", "value_width", "max_q", "interpret")
 )
@@ -426,24 +487,11 @@ def _attention(
         side_len=side_len,
         interpret=interpret,
     )
-    # where each sequence's queries are in the flat batch (padding
-    # tokens carry q_seq_ids == S and match no sequence)
-    tok = jnp.arange(t, dtype=jnp.int32)
-    match = metadata.q_seq_ids[None, :] == jnp.arange(s, dtype=jnp.int32)[
-        :, None
-    ]
-    q_len = match.sum(axis=1, dtype=jnp.int32)
-    first_tok = jnp.min(jnp.where(match, tok[None, :], t), axis=1)
-    seq_idx = jnp.minimum(metadata.q_seq_ids, s - 1)
-    tok_in_chunk = tok - first_tok[seq_idx]
+    q_len, first_tok, seq_idx, tok_in_chunk = _flat_rows(metadata, t)
     is_token = (metadata.q_seq_ids < s)[:, None, None]
 
     def attend_list(tiles, mq_blk):
-        tile_seq, tile_q0, _ = tiles
-        rows = (first_tok[tile_seq] + tile_q0)[:, None] + jnp.arange(
-            mq_blk, dtype=jnp.int32
-        )
-        return attend(q[jnp.clip(rows, 0, t - 1)], tiles)
+        return attend(q[_tile_rows(tiles, first_tok, mq_blk, t)], tiles)
 
     mq_1 = _tile_tokens(1, h, value_width)
     if max_q == 1:
@@ -454,14 +502,9 @@ def _attention(
 
     assert side_kv is None, "staged decode rows come with max_q = 1"
     mq_blk = _tile_tokens(max_q, h, value_width)
-    maxq = max(next_power_of_2(max_q), mq_blk)
     split = _splits_singles(mq_blk)
     in_chunks = q_len > 1 if split else q_len > 0
-    tiles, first_tile = tile_list(
-        jnp.where(in_chunks, (q_len + mq_blk - 1) // mq_blk, 0),
-        num_query_tiles(t, s, mq_blk, maxq),
-        mq_blk,
-    )
+    tiles, first_tile = _chunk_tiles(in_chunks, q_len, t, s, mq_blk, max_q)
     out_rows = attend_list(tiles, mq_blk).reshape(-1, h, value_width)
     row = first_tile[seq_idx] * mq_blk + tok_in_chunk
     out = out_rows[jnp.clip(row, 0, out_rows.shape[0] - 1)]
@@ -492,8 +535,145 @@ def mla_max_q_class(
     return max_q
 
 
+@functools.partial(jax.jit, static_argnames=("scale", "max_q", "interpret"))
+def mla_attention_from_heads(
+    q_nope: jax.Array,  # [T, H, dn]
+    q_rope: jax.Array,  # [T, H, dr], rotated
+    c: jax.Array,  # [T, C] this step's latents, as cached
+    k_rope: jax.Array,  # [T, dr] this step's rope keys, as cached
+    w_uk: jax.Array,  # [H, dn, C]
+    w_uv: jax.Array,  # [H, C, dv]
+    kv_pages: jax.Array,  # [1, P, page, WP], this step's rows written
+    metadata: AttentionMetadata,
+    *,
+    scale: float,
+    max_q: int,
+    interpret: bool = False,
+) -> jax.Array:
+    """A chunked step's (``max_q > 1``) latent attention from head space
+    to head space: returns [T, H·dv], what ``W_o`` takes (on the chip
+    [T, H, dv] is another tiling: a reshape between the two copies the
+    array).  Each sequence goes by what the step's own inputs say of it:
+
+    - a **fresh chunk** (more than one token, ``chunk_starts == 0``:
+      every key is a row of this step) attends in the materialised form
+      over the flat batch (``vdt_mla_chunk_attention``): per-head keys
+      and values made once from the step's latents, no absorb product
+      and no latent-wide per-head array for its rows;
+    - a **one-token row** (a decode row riding the step) takes the
+      absorbed kernel in its decode geometry, the absorb products made
+      for the gathered rows of such sequences only;
+    - a **chunk with a cached prefix** (``chunk_starts > 0``) takes the
+      absorbed chunk tiles, behind one ``lax.cond`` on whether the step
+      has such a chunk at all, so a step with none pays neither their
+      gathers nor the absorb products over every row.
+
+    The two forms share no logic (one cached row for all heads against
+    per-head keys and values).  Padding rows come out finite, not zero:
+    nobody reads them.  A jitted function, traced once a program, like
+    ``mla_attention``'s body and for the same reason.  ``T`` is a token
+    bucket: whole query tiles (a power of two)."""
+    assert max_q > 1, "the fused scan and decode-only steps stay absorbed"
+    t, h, dn = q_nope.shape
+    dv = w_uv.shape[-1]
+    vw = c.shape[-1]
+    s, _ = metadata.block_tables.shape
+    dtype = q_nope.dtype
+    attend = functools.partial(
+        _attend_tiles, kv_pages=kv_pages, metadata=metadata, scale=scale,
+        value_width=vw, side_kv=None, side_len=None, interpret=interpret,
+    )
+    q_len, first_tok, seq_idx, tok_in_chunk = _flat_rows(metadata, t)
+    is_token = metadata.q_seq_ids < s
+    fresh_seq = (q_len > 1) & (metadata.chunk_starts == 0)
+    prefixed_seq = (q_len > 1) & (metadata.chunk_starts > 0)
+    tok = jnp.arange(t, dtype=jnp.int32)
+
+    # ---- fresh chunks: per-head keys and values, the flat kernel ----
+    fresh = is_token & fresh_seq[seq_idx]
+    row_start = jnp.where(fresh, first_tok[seq_idx], tok)
+    with jax.named_scope(SCOPE_MLA_ABSORB):
+        # [C, H·d] weights (8 MB each at the cell's widths): the
+        # products then come out [T, H·d] row-major, as the kernel
+        # reads them; from ``tc,hdc->thd`` XLA emits the tokens minor
+        # and transposes 34 MB a product (compiled for a described v5e)
+        w_k = w_uk.astype(dtype).transpose(2, 0, 1).reshape(vw, h * dn)
+        w_v = w_uv.astype(dtype).transpose(1, 0, 2).reshape(vw, h * dv)
+        k_nope = jnp.dot(
+            c, w_k, preferred_element_type=jnp.float32
+        ).astype(dtype)
+        v = jnp.dot(c, w_v, preferred_element_type=jnp.float32).astype(dtype)
+    with jax.named_scope(SCOPE_ATTN):
+        out = mla_chunk_attention(
+            q_nope.reshape(t, -1), q_rope.reshape(t, -1), k_nope, k_rope, v,
+            row_start, fresh, num_heads=h, scale=scale, interpret=interpret,
+        )  # [T, H·dv], and it stays so: [T, H, dv] is another tiling
+
+    # ---- one-token rows: the absorbed kernel, decode geometry ----
+    mq_1 = _tile_tokens(1, h, vw)
+    with jax.named_scope(SCOPE_ATTN):
+        tiles, _ = tile_list((q_len == 1).astype(jnp.int32), s, mq_1)
+        rows_1 = _tile_rows(tiles, first_tok, mq_1, t)  # [S, mq_1]
+        in_list = jnp.arange(s, dtype=jnp.int32) < tiles[2][0]
+        qn_1, qr_1 = q_nope[rows_1], q_rope[rows_1]
+    with jax.named_scope(SCOPE_MLA_ABSORB):
+        q_1 = mla_absorb_queries(qn_1.reshape(-1, h, dn), qr_1.reshape(
+            -1, h, qr_1.shape[-1]), w_uk)
+    with jax.named_scope(SCOPE_ATTN):
+        lat_1 = attend(q_1.reshape(s, mq_1, h, -1), tiles)[:, 0]
+    with jax.named_scope(SCOPE_MLA_ABSORB):
+        out_1 = mla_unabsorb_outputs(lat_1, w_uv)  # [S, H, dv]
+    with jax.named_scope(SCOPE_ATTN):
+        # entries past the list's live count repeat its last tile: dropped
+        out = out.at[jnp.where(in_list, rows_1[:, 0], t)].set(
+            out_1.reshape(s, -1), mode="drop"
+        )
+
+    # ---- chunks with a cached prefix: today's absorbed chunk tiles ----
+    mq_blk = _tile_tokens(max_q, h, vw)
+
+    def absorbed_chunks(out):
+        with jax.named_scope(SCOPE_MLA_ABSORB):
+            q_abs = mla_absorb_queries(q_nope, q_rope, w_uk)
+        with jax.named_scope(SCOPE_ATTN):
+            tiles, first_tile = _chunk_tiles(
+                prefixed_seq, q_len, t, s, mq_blk, max_q
+            )
+            lat = attend(
+                q_abs[_tile_rows(tiles, first_tok, mq_blk, t)], tiles
+            ).reshape(-1, h, vw)
+            row = first_tile[seq_idx] * mq_blk + tok_in_chunk
+            lat = lat[jnp.clip(row, 0, lat.shape[0] - 1)]
+        with jax.named_scope(SCOPE_MLA_ABSORB):
+            out_p = mla_unabsorb_outputs(lat, w_uv)
+        with jax.named_scope(SCOPE_ATTN):
+            # rows written in place: the conditional's result then keeps
+            # the flat kernel's row-major layout on both sides (a select
+            # took the layout of this side's product, tokens minor, and
+            # the other side paid two transposes of the whole array)
+            prefixed = is_token & prefixed_seq[seq_idx]
+            return out.at[jnp.where(prefixed, tok, t)].set(
+                out_p.reshape(t, -1), mode="drop"
+            )
+
+    with jax.named_scope(SCOPE_ATTN):
+        return jax.lax.cond(
+            prefixed_seq.any(), absorbed_chunks, lambda out: out, out
+        )
+
+
+def mla_chunk_token_counts(q_lens, chunk_starts) -> tuple[int, int]:
+    """(tokens of fresh chunks, tokens of chunks with a cached prefix)
+    of one chunked step: host-side twin of ``mla_attention_from_heads``'
+    split, for the ``runner.dispatch`` span and the counter."""
+    fresh = sum(n for n, c in zip(q_lens, chunk_starts) if n > 1 and c == 0)
+    prefixed = sum(n for n, c in zip(q_lens, chunk_starts) if n > 1 and c > 0)
+    return fresh, prefixed
+
+
 mla_attention.needs_max_q = True
 mla_attention.max_q_class = mla_max_q_class
+mla_attention.from_heads = mla_attention_from_heads
 
 
 def mla_attention_cpu(*args, **kwargs):
@@ -503,3 +683,6 @@ def mla_attention_cpu(*args, **kwargs):
 
 mla_attention_cpu.needs_max_q = True
 mla_attention_cpu.max_q_class = mla_max_q_class
+mla_attention_cpu.from_heads = functools.partial(
+    mla_attention_from_heads, interpret=True
+)

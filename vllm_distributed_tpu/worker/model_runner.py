@@ -40,6 +40,7 @@ from vllm_distributed_tpu.models.common import SCOPE_KV_WRITE, SCOPE_SAMPLER
 from vllm_distributed_tpu.ops import resolve_kernel_backend
 from vllm_distributed_tpu.ops.attention import (
     AttentionMetadata,
+    bind_max_q,
     paged_attention_reference,
 )
 from vllm_distributed_tpu.ops.sampling import (
@@ -574,6 +575,20 @@ class ModelRunner:
             num_kv_heads=m.num_kv_heads // tp,
             head_dim=m.head_dim,
         )
+
+    def _mla_chunk_tokens(self, q_lens: list[int], chunk_starts) -> dict:
+        """A chunked step's multi-token chunks by the form their latent
+        attention takes: the span's ``chunk_tokens_fresh`` /
+        ``chunk_tokens_prefixed``, and the counter's two forms."""
+        from vllm_distributed_tpu.ops.pallas.mla_attention import (
+            mla_chunk_token_counts,
+        )
+
+        fresh, prefixed = mla_chunk_token_counts(q_lens, chunk_starts)
+        self.telemetry.record_mla_chunk_tokens(
+            {"materialised": fresh, "absorbed": prefixed}
+        )
+        return dict(chunk_tokens_fresh=fresh, chunk_tokens_prefixed=prefixed)
 
     def _pick_kv_flush_fn(self):
         """Per-dispatch flush of the staged decode side buffers.  None
@@ -1488,6 +1503,8 @@ class ModelRunner:
             counts["q_tiles"], counts["q_rows"] = self._query_tiles(
                 num_new, max_q_pad
             )
+        if getattr(self._attn_fn, "from_heads", None) and max_q_pad > 1:
+            counts.update(self._mla_chunk_tokens(num_new, chunk_starts))
         prep.end()
         t_step0 = time.perf_counter()
         sampled, logprobs, self.kv_caches, self._carry = self._run_program(
@@ -1648,9 +1665,7 @@ class ModelRunner:
         token_ids = jnp.where(
             carry_idx >= 0, carry[jnp.maximum(carry_idx, 0)], token_ids
         )
-        attn_fn = self._attn_fn
-        if getattr(attn_fn, "needs_max_q", False):
-            attn_fn = partial(attn_fn, max_q=max_q_pad)
+        attn_fn = bind_max_q(self._attn_fn, max_q_pad)
         stats = self._model_stats()
         logits, kv_caches = self.model.forward(
             params,
@@ -1948,9 +1963,7 @@ class ModelRunner:
             logits_indices=verify_idx.reshape(-1),
             chunk_starts=chunk_starts,
         )
-        attn_fn = self._attn_fn
-        if getattr(attn_fn, "needs_max_q", False):
-            attn_fn = partial(attn_fn, max_q=max_q_pad)
+        attn_fn = bind_max_q(self._attn_fn, max_q_pad)
         logits, kv_caches = self.model.forward(
             params,
             tokens,
@@ -2145,9 +2158,7 @@ class ModelRunner:
         s_pad = tokens.shape[0]
         rows = jnp.arange(s_pad, dtype=jnp.int32)
         page_size = self.page_size
-        attn_fn = self._attn_fn
-        if getattr(attn_fn, "needs_max_q", False):
-            attn_fn = partial(attn_fn, max_q=1)
+        attn_fn = bind_max_q(self._attn_fn, 1)
         staged = self._staged_decode
         if staged:
             # Staged decode writes: micro-step K/V rows go to a dense

@@ -129,6 +129,7 @@ class DeviceTelemetry:
         self.fallbacks: dict[str, int] = {}
         self.moe_grouped_steps = 0
         self.moe_picks: dict[str, int] = {}
+        self.mla_chunk_tokens: dict[str, int] = {}
         _install_cache_listener()
 
     def record_fallback(self, kind: str) -> None:
@@ -144,9 +145,18 @@ class DeviceTelemetry:
     def record_moe_picks(self, counts: dict[str, int]) -> None:
         """Router picks of one fetched step by kind (zero | held |
         absent), as the step program counted them on the device."""
+        self._add(self.moe_picks, counts)
+
+    def record_mla_chunk_tokens(self, counts: dict[str, int]) -> None:
+        """Tokens of one dispatched chunked step's multi-token chunks by
+        the form their latent attention took (materialised: a fresh
+        chunk | absorbed: a chunk with a cached prefix)."""
+        self._add(self.mla_chunk_tokens, counts)
+
+    def _add(self, totals: dict[str, int], counts: dict[str, int]) -> None:
         with self._lock:
-            for kind, n in counts.items():
-                self.moe_picks[kind] = self.moe_picks.get(kind, 0) + n
+            for label, n in counts.items():
+                totals[label] = totals.get(label, 0) + n
 
     def record_compile(self, kind: str, seconds: float, key: str) -> None:
         with self._lock:
@@ -194,5 +204,6 @@ class DeviceTelemetry:
                 "fallbacks": dict(self.fallbacks),
                 "moe_grouped_steps": self.moe_grouped_steps,
                 "moe_picks": dict(self.moe_picks),
+                "mla_chunk_tokens": dict(self.mla_chunk_tokens),
                 "persistent_cache": persistent_cache_counts(),
             }
