@@ -453,3 +453,63 @@ def test_attention_compiles_for_the_v5e_at_mixtral_widths(
     assert " while(" not in text and " conditional(" not in text
     # the staging is packed: at t 2048 the dense grid alone was 537 MB
     assert compiled.memory_analysis().temp_size_in_bytes < 160e6
+
+
+# ---- the unquantized kernel (PR 38) ----------------------------------------
+@pytest.mark.parametrize("sizes,fused", [
+    ([5, 0, 300, 1], True), ([5, 0, 300, 1], False),
+    ([0, 0, 0, 7], True), ([128, 128, 128, 128], True)])
+def test_unquantized_kernel_matches_a_loop_over_the_groups(sizes, fused):
+    """``grouped_matmul`` (interpreted): every group's rows times its
+    own expert's weights, the gated epilogue with a second weight, in
+    ``grouped_int8_matmul``'s layout; empty groups and the skipped tail
+    change nothing."""
+    e, k, n = len(sizes), 128, 256
+    rng = np.random.default_rng(0)
+    gs = jnp.asarray(sizes, jnp.int32)
+    mp = gm.padded_rows(sum(sizes), e, gm.BLOCK_ROWS)
+    starts, _, _ = gm.group_layout(gs, mp, gm.BLOCK_ROWS)
+    xs = jnp.asarray(rng.standard_normal((mp, k)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((e, k, n)) * 0.1, jnp.float32)
+    w_up = jnp.asarray(rng.standard_normal((e, k, n)) * 0.1, jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(gm.grouped_matmul(
+            xs, w, gs, w_up=w_up if fused else None, interpret=True))
+        for g, (lo, size) in enumerate(zip(np.asarray(starts), sizes)):
+            if not size:
+                continue
+            rows = xs[lo: lo + size]
+            want = rows @ w[g]
+            if fused:
+                want = jax.nn.silu(want) * (rows @ w_up[g])
+            assert np.abs(got[lo: lo + size] - np.asarray(want)).max() < 1e-4
+
+
+@pytest.mark.parametrize("rows,in_dim,out_dim,fused", [
+    (8192, 2048, 1792, True),  # t 2048, top-4: the fused up projection
+    (8192, 1792, 2048, False),  # and the down projection
+    (512, 2048, 1792, True),  # t 128: the smallest grouped step
+])
+def test_unquantized_kernel_compiles_for_the_v5e_at_lfm2_widths(
+        rows, in_dim, out_dim, fused, one_chip, no_compile_cache):
+    """32 bfloat16 experts of 1,792: the whole output width is one
+    tile, so ``xs`` and an expert's weights are read once."""
+    e = 32
+    mp = gm.padded_rows(rows, e, gm.BLOCK_ROWS)
+    assert gm.pick_unquantized_block_out(
+        in_dim, out_dim, 2, 2 if fused else 1) == out_dim
+    assert gm.has_unquantized_tiles(2048, 1792)
+
+    def shaped(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def call(xs, w, w_up, gs):
+        return gm.grouped_matmul(xs, w, gs, w_up=w_up if fused else None)
+
+    weight = shaped((e, in_dim, out_dim), jnp.bfloat16)
+    compiled = jax.jit(call).lower(
+        shaped((mp, in_dim), jnp.bfloat16), weight, weight,
+        shaped((e,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "vdt_grouped_matmul" in text
+    assert "vdt_grouped_int8_matmul" not in text

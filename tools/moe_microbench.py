@@ -1,6 +1,11 @@
 """One MoE layer on the chip, dense einsum against the grouped int8
 kernel, by the tokens in the step (PERF.md, PR 27: the sweep that fixed
-``models/mixtral.GROUPED_MIN_ROWS``).
+``models/routed_experts.GROUPED_MIN_ROWS``); with ``--weights bf16``,
+unquantized experts instead: the dense einsum against the sorted
+dispatch through ``jax.lax.ragged_dot`` and through the grouped kernel
+for unquantized experts (``grouped_matmul``), beside one dense SwiGLU FFN
+over as many rows as the step routes (the same operations on one
+matrix: the MXU share a routed form is held against; PERF.md, PR 38).
 
 For each t it times ``MixtralForCausalLM._mlp_dense`` and
 ``_mlp_grouped`` on one layer of int8 experts at the given widths
@@ -12,6 +17,8 @@ Times are host-clock seconds over back-to-back calls that end in
 step's padding.
 
 Usage: python tools/moe_microbench.py [--tokens 64 128 ...] [--live 0.75]
+       python tools/moe_microbench.py --weights bf16 --hidden 2048
+           --intermediate 1792 --experts 32 --top-k 4   (LFM2's widths)
        python tools/moe_microbench.py --cpu-rehearsal   (toy widths,
        interpreted kernel: counts nothing, proves the script runs)
 """
@@ -30,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from vllm_distributed_tpu.models.mixtral import MixtralForCausalLM
+from vllm_distributed_tpu.models.routed_experts import experts_grouped
 from vllm_distributed_tpu.ops.pallas import grouped_matmul as gm
 from vllm_distributed_tpu.ops.quant import QuantizedTensor
 
@@ -45,10 +53,15 @@ def _timed(fn, *args, calls: int) -> float:
     return (time.perf_counter() - t0) / calls
 
 
-def _layer(key, e: int, h: int, im: int, dtype, stamp: str) -> dict:
+def _layer(
+    key, e: int, h: int, im: int, dtype, stamp: str, weights: str = "int8"
+) -> dict:
     keys = jax.random.split(key, 4)
 
     def qt(k, shape):
+        if weights == "bf16":  # unquantized, in the model dtype
+            return (jax.random.normal(k, shape, jnp.float32) * 0.02).astype(
+                dtype)
         q = jax.random.randint(k, shape, -127, 128, jnp.int8)
         scale = jnp.full((shape[0], shape[2]), 0.02 / 127.0, jnp.float32)
         return QuantizedTensor(q, scale, 8, 0, shape, dtype, stamp)
@@ -70,6 +83,7 @@ def main() -> None:
     ap.add_argument("--experts", type=int, default=8)
     ap.add_argument("--top-k", type=int, default=2)
     ap.add_argument("--live", type=float, default=1.0)
+    ap.add_argument("--weights", choices=("int8", "bf16"), default="int8")
     ap.add_argument("--block-rows", type=int, default=gm.BLOCK_ROWS)
     ap.add_argument("--calls", type=int, default=20)
     ap.add_argument("--cpu-rehearsal", action="store_true")
@@ -90,9 +104,13 @@ def main() -> None:
     model.num_experts, model.top_k = args.experts, args.top_k
     model.norm_topk = True
     e, k, hid, im = args.experts, args.top_k, args.hidden, args.intermediate
-    layer = _layer(jax.random.PRNGKey(0), e, hid, im, dtype, stamp)
+    layer = _layer(
+        jax.random.PRNGKey(0), e, hid, im, dtype, stamp, args.weights)
     device = jax.devices()[0]
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
+    if args.weights == "bf16":
+        _unquantized(args, model, layer, dtype, device)
+        return
 
     for t in args.tokens:
         h = jax.random.normal(jax.random.PRNGKey(t), (t, hid), dtype)
@@ -139,6 +157,63 @@ def main() -> None:
         row["grouped_tflops_live_rows"] = flops / row["grouped_ms"] / 1e9
         row["kernels_tflops_live_rows"] = flops / (
             row["up_ms"] + row["down_ms"]) / 1e9
+        row["device"] = f"{device.platform}:{device.device_kind}"
+        print(json.dumps(row), flush=True)
+        with open(args.out, "a") as f:
+            f.write(json.dumps(row) + "\n")
+
+
+def _unquantized(args, model, layer, dtype, device) -> None:
+    """Unquantized experts: the dense einsum, the sorted dispatch
+    through ``ragged_dot``, and one dense SwiGLU FFN of the expert's
+    width over as many rows as the live tokens route."""
+    k, hid, im = args.top_k, args.hidden, args.intermediate
+    ffn = {name: layer[name][0] for name in ("w1", "w3", "w2")}
+
+    def dense(layer, h):
+        return model._mlp_dense(h, layer)
+
+    def ragged(layer, h, live):
+        return model._mlp_ragged(h, layer, live)
+
+    interpret = args.cpu_rehearsal
+
+    def grouped(layer, h, live):
+        topw, topi = model._route(h, layer)
+        return experts_grouped(
+            h, topi, topw, layer["w1"], layer["w3"], layer["w2"], live,
+            interpret)
+
+    def one_ffn(w, x):
+        return (jax.nn.silu(x @ w["w1"]) * (x @ w["w3"])) @ w["w2"]
+
+    for t in args.tokens:
+        h = jax.random.normal(jax.random.PRNGKey(t), (t, hid), dtype)
+        n_live = max(int(round(t * args.live)), 1)
+        live = jnp.arange(t) < n_live
+        gs = model._sorted_dispatch(h, layer, live)[3]
+        rows = jax.random.normal(
+            jax.random.PRNGKey(1), (n_live * k, hid), dtype)
+        row = {
+            "t": t, "weights": "bf16", "live_rows": n_live * k,
+            "busiest_expert_rows": int(gs.max()),
+            "dense_ms": _timed(jax.jit(dense), layer, h, calls=args.calls),
+            "ragged_ms": _timed(
+                jax.jit(ragged), layer, h, live, calls=args.calls),
+            "grouped_ms": _timed(
+                jax.jit(grouped), layer, h, live, calls=args.calls),
+            "ffn_same_rows_ms": _timed(
+                jax.jit(one_ffn), ffn, rows, calls=args.calls),
+        }
+        row = {key: v * 1e3 if key.endswith("_ms") else v
+               for key, v in row.items()}
+        flops = n_live * k * 3 * 2 * hid * im
+        row["ragged_tflops_live_rows"] = flops / row["ragged_ms"] / 1e9
+        row["ffn_tflops"] = flops / row["ffn_same_rows_ms"] / 1e9
+        row["grouped_tflops_live_rows"] = flops / row["grouped_ms"] / 1e9
+        row["ragged_over_ffn"] = row["ragged_ms"] / row["ffn_same_rows_ms"]
+        row["grouped_over_ffn"] = (
+            row["grouped_ms"] / row["ffn_same_rows_ms"])
         row["device"] = f"{device.platform}:{device.device_kind}"
         print(json.dumps(row), flush=True)
         with open(args.out, "a") as f:

@@ -33,11 +33,65 @@ _STR_DTYPE_TO_JAX = {
 }
 
 
+# Model types this engine has a block for and the installed
+# ``transformers`` may not know: their config.json is read as a plain
+# ``PretrainedConfig`` (every key an attribute, nothing defaulted).
+_OWN_MODEL_TYPES = ("lfm2_moe",)
+LAYER_TYPES = ("conv", "full_attention")
+
+
 def _load_hf_config(model: str, trust_remote_code: bool = False):
     """Load a HuggingFace config.json for `model` (local dir or hub id)."""
-    from transformers import AutoConfig
+    from transformers import AutoConfig, PretrainedConfig
+    from transformers.models.auto.configuration_auto import (
+        CONFIG_MAPPING_NAMES,
+    )
 
+    path = os.path.join(model, "config.json")
+    if os.path.isfile(path):
+        with open(path) as f:
+            raw = json.load(f)
+        model_type = raw.get("model_type")
+        if (
+            model_type in _OWN_MODEL_TYPES
+            and model_type not in CONFIG_MAPPING_NAMES
+        ):
+            config = PretrainedConfig.from_dict(raw)
+            config.model_type = model_type  # an instance attribute
+            return config
     return AutoConfig.from_pretrained(model, trust_remote_code=trust_remote_code)
+
+
+def check_layer_types(hf_config: Any, model: str = "") -> None:
+    """A config that mixes kinds of layer (``layer_types``) or leads
+    with dense layers (``num_dense_layers``) says so in keys the engine
+    reads; an entry it does not know, a list of another length than the
+    depth or more dense layers than layers is an error here, at load,
+    not a silent default."""
+    depth = getattr(hf_config, "num_hidden_layers", None)
+    kinds = getattr(hf_config, "layer_types", None)
+    if kinds is not None and getattr(hf_config, "model_type", "") in (
+        _OWN_MODEL_TYPES
+    ):
+        unknown = sorted({str(k) for k in kinds} - set(LAYER_TYPES))
+        if unknown:
+            raise ValueError(
+                f"the config of {model!r} has layer_types entries "
+                f"{unknown}; this engine knows {list(LAYER_TYPES)}"
+            )
+        if depth is not None and len(kinds) != int(depth):
+            raise ValueError(
+                f"the config of {model!r} states {len(kinds)} layer_types "
+                f"for num_hidden_layers {depth}"
+            )
+    dense = getattr(hf_config, "num_dense_layers", None)
+    if dense is not None and depth is not None and not (
+        0 <= int(dense) <= int(depth)
+    ):
+        raise ValueError(
+            f"the config of {model!r} states num_dense_layers {dense} "
+            f"for num_hidden_layers {depth}"
+        )
 
 
 @dataclass
@@ -71,6 +125,7 @@ class ModelConfig:
             self.tokenizer = self.model
         if self.hf_config is None:
             self.hf_config = _load_hf_config(self.model, self.trust_remote_code)
+        check_layer_types(self.hf_config, self.model)
         if self.dtype == "auto":
             torch_dtype = getattr(self.hf_config, "torch_dtype", None)
             name = str(torch_dtype).replace("torch.", "") if torch_dtype else "bfloat16"

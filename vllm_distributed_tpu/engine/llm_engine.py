@@ -141,6 +141,8 @@ class LLMEngine:
         self._telemetry_cache_seen: dict[str, int] = {}
         self._telemetry_moe_picks_seen: dict[str, int] = {}
         self._telemetry_mla_chunk_seen: dict[str, int] = {}
+        self._telemetry_expert_picks_seen: dict[str, int] = {}
+        self._telemetry_state_rows_seen: dict[str, int] = {}
         self._telemetry_moe_grouped_seen = 0
 
         # Disaggregated prefill/decode hand-off (ISSUE 15): export holds
@@ -632,6 +634,16 @@ class LLMEngine:
                 "mla_chunk_tokens",
                 self._telemetry_mla_chunk_seen,
                 self.metrics.record_mla_chunk_tokens,
+            ),
+            (
+                "moe_expert_picks",
+                self._telemetry_expert_picks_seen,
+                self.metrics.record_moe_expert_picks,
+            ),
+            (
+                "seq_state_rows",
+                self._telemetry_state_rows_seen,
+                self.metrics.record_seq_state_rows,
             ),
         ):
             for kind, total in (snap.get(field) or {}).items():

@@ -155,6 +155,8 @@ DOCUMENTED_METRICS = (
     "vllm:xla_persistent_cache_events_total",
     "vllm:moe_grouped_steps_total",
     "vllm:moe_picks_total",
+    "vllm:moe_expert_picks_total",
+    "vllm:seq_state_rows_total",
     "vllm:mla_chunk_tokens_total",
     # ---- fleet sentinel (ISSUE 20) ----
     "vllm:slo_burn_rate",
@@ -605,6 +607,24 @@ class EngineMetrics:
             ["model_name", "kind"],
             registry=self.registry,
         )
+        self._moe_expert_picks = Counter(
+            "vllm:moe_expert_picks",
+            "Router picks on the reply-rank worker of a model that "
+            "holds every expert, over all routed layers: all, and "
+            "busiest (each layer's most-picked expert's picks, summed: "
+            "experts x busiest / all is 1.0 when the routing is even)",
+            ["model_name", "kind"],
+            registry=self.registry,
+        )
+        self._seq_state_rows = Counter(
+            "vllm:seq_state_rows",
+            "Sequences in the steps the reply-rank worker dispatched "
+            "whose per-sequence state (a short convolution's last rows) "
+            "the step reads and rewrites: fresh (the sequence starts at "
+            "position 0 and reads nothing of its row), carried",
+            ["model_name", "kind"],
+            registry=self.registry,
+        )
         self._mla_chunk_tokens = Counter(
             "vllm:mla_chunk_tokens",
             "Tokens of multi-token chunks in the chunked steps the "
@@ -930,6 +950,18 @@ class EngineMetrics:
     def record_moe_picks(self, kind: str, n: int) -> None:
         if self.enabled and n > 0:
             self._moe_picks.labels(
+                model_name=self._model_name, kind=kind
+            ).inc(n)
+
+    def record_moe_expert_picks(self, kind: str, n: int) -> None:
+        if self.enabled and n > 0:
+            self._moe_expert_picks.labels(
+                model_name=self._model_name, kind=kind
+            ).inc(n)
+
+    def record_seq_state_rows(self, kind: str, n: int) -> None:
+        if self.enabled and n > 0:
+            self._seq_state_rows.labels(
                 model_name=self._model_name, kind=kind
             ).inc(n)
 

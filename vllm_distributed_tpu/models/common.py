@@ -32,10 +32,13 @@ SCOPE_SAMPLER = "sampler"
 # latent attention only: queries into the latent space (q_nope · W_uk)
 # and the attended latent back out of it (· W_uv)
 SCOPE_MLA_ABSORB = "mla_absorb"
+# a gated short-convolution operator whole: its norm, both projections,
+# the gates, the taps, the sequence state's read and write
+SCOPE_SHORT_CONV = "short_conv"
 SCOPES = (
     SCOPE_EMBED, SCOPE_ATTN_QKV, SCOPE_KV_WRITE, SCOPE_ATTN,
     SCOPE_ATTN_OUT, SCOPE_MOE_ROUTER, SCOPE_MOE_EXPERTS, SCOPE_MLP,
-    SCOPE_LM_HEAD, SCOPE_SAMPLER, SCOPE_MLA_ABSORB,
+    SCOPE_LM_HEAD, SCOPE_SAMPLER, SCOPE_MLA_ABSORB, SCOPE_SHORT_CONV,
 )
 
 

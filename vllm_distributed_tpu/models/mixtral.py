@@ -49,62 +49,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from vllm_distributed_tpu.models.common import (
-    SCOPE_MOE_EXPERTS,
-    SCOPE_MOE_ROUTER,
-)
+from vllm_distributed_tpu.models.common import SCOPE_MOE_ROUTER
 from vllm_distributed_tpu.models.llama import LlamaForCausalLM
-
-# Routed rows (tokens × top_k) above which int8 experts on one chip run
-# the grouped kernel instead of the dense einsum.  Below it the step is
-# bound by reading the experts' weights, which both do once, and the
-# grouped path pads every expert to a 128-row tile: nothing to win.
-# Fixed from a one-layer sweep on the v5e at Mixtral's widths (PERF.md,
-# PR 27).
-GROUPED_MIN_ROWS = 256
-
-
-def select_moe_impl(requested: str, rows: int, w1, on_mesh: bool) -> str:
-    """"dense" | "ragged" | "grouped" for one expert layer, from
-    VDT_MOE_IMPL (``requested``), the routed rows of the step being
-    traced and the expert tensor itself.
-
-    ``auto``: int8 experts stamped for the Pallas backend, on one chip,
-    in a step of more than GROUPED_MIN_ROWS routed rows (prefill, a
-    mixed step, a spec-verify step) take the grouped int8 kernel: such
-    a step is compute-bound and the dense einsum spends E/k times the
-    model's FLOPs there (step_experts_ms 53.97 -> 16.70, ledger, PR 27).
-    Every other quantized case stays dense: decode is
-    weight-BANDWIDTH-bound, where XLA fuses the dequant into the einsum
-    so the compressed bytes stream once (decode_experts_roofline_pct
-    92.6, ledger, PR 30), and ``ragged_dot`` cannot fuse a producer: it
-    materializes the bf16 expert stack per call (pre-round; its cost is
-    not measured on this tree) — a statement about ragged_dot, not about
-    routing.  Unquantized experts route through ragged_dot on big row
-    counts.  ``ragged`` on a tensor the grouped kernel takes means that
-    kernel; ``dense`` is always the dense einsum."""
-    from vllm_distributed_tpu.ops.pallas import grouped_matmul as gm
-    from vllm_distributed_tpu.ops.quant import QuantizedTensor
-
-    quantized = isinstance(w1, QuantizedTensor)
-    grouped_ok = (
-        quantized
-        and w1.bits == 8
-        and w1.matmul in ("pallas", "pallas_interpret")
-        and not on_mesh
-        and w1.mesh is None
-        and (
-            w1.matmul == "pallas_interpret"
-            or gm.has_tiles(w1.shape[-2], w1.shape[-1])
-        )
-    )
-    if requested == "auto":
-        if grouped_ok and rows > GROUPED_MIN_ROWS:
-            return "grouped"
-        return "dense" if (quantized or rows <= 256) else "ragged"
-    if requested == "ragged" and grouped_ok:
-        return "grouped"
-    return requested
+from vllm_distributed_tpu.models.routed_experts import (  # noqa: F401
+    GROUPED_MIN_ROWS,
+    routed_experts,
+    select_moe_impl,
+    sorted_dispatch,
+)
 
 
 class MixtralForCausalLM(LlamaForCausalLM):
@@ -356,223 +308,30 @@ class MixtralForCausalLM(LlamaForCausalLM):
     def _mlp(
         self, h: jax.Array, layer: dict, live: jax.Array | None = None
     ) -> jax.Array:
-        impl = self.moe_impl(layer, h.shape[0])
-        if impl == "dense":
-            return self._mlp_dense(h, layer)
-        if impl == "grouped":
-            return self._mlp_grouped(h, layer, live)
-        return self._mlp_ragged(h, layer)
+        return self._experts(self.moe_impl(layer, h.shape[0]), h, layer, live)
 
-    def _mlp_dense(self, h: jax.Array, layer: dict) -> jax.Array:
-        """GShard-style dense dispatch — every expert runs on every
-        token, a [T, E] combine matrix (zeros outside the top-k) weights
-        the results.  Exact and GSPMD-friendly; the correctness oracle
-        for the routed paths and the fallback for shapes they reject."""
-        from vllm_distributed_tpu.ops.quant import maybe_dequantize
-
-        t = h.shape[0]
+    def _experts(self, impl: str, h, layer: dict, live=None) -> jax.Array:
+        """This model's router, then the shared routed layer
+        (models/routed_experts.py) in the form ``impl``."""
         with jax.named_scope(SCOPE_MOE_ROUTER):
             topw, topi = self._route(h, layer)
-            combine = (
-                jnp.zeros((t, self.num_experts), jnp.float32)
-                .at[jnp.arange(t)[:, None], topi]
-                .set(topw)
-                .astype(h.dtype)
-            )
-        with jax.named_scope(SCOPE_MOE_EXPERTS):
-            w1 = maybe_dequantize(layer["w1"], h.dtype)
-            w3 = maybe_dequantize(layer["w3"], h.dtype)
-            w2 = maybe_dequantize(layer["w2"], h.dtype)
-            h1 = jnp.einsum("th,ehi->tei", h, w1)
-            h3 = jnp.einsum("th,ehi->tei", h, w3)
-            inner = jax.nn.silu(h1) * h3
-            return jnp.einsum("tei,eih,te->th", inner, w2, combine)
+        return routed_experts(
+            impl, h, topi, topw, layer["w1"], layer["w3"], layer["w2"],
+            live=live, mesh=getattr(self, "_mesh", None),
+            expert_parallel=getattr(self, "expert_parallel", False),
+        )
 
-    def _sorted_dispatch(
-        self, h: jax.Array, layer: dict, live: jax.Array | None = None
-    ):
-        """The routed paths' shared first half: route, flatten the T×k
-        assignments and sort them by expert.  Returns (topw [T, k],
-        flat expert ids [T*k], order [T*k]: sorted row -> flat
-        assignment, group sizes [E]).  Rows of tokens outside ``live``
-        (a step's padding) take the id E: they sort last and join no
-        group."""
-        e, k = self.num_experts, self.top_k
+    # The three forms by name (tests, tools/moe_microbench.py).
+    def _mlp_dense(self, h: jax.Array, layer: dict) -> jax.Array:
+        return self._experts("dense", h, layer)
+
+    def _mlp_ragged(self, h: jax.Array, layer: dict, live=None) -> jax.Array:
+        return self._experts("ragged", h, layer, live)
+
+    def _mlp_grouped(self, h: jax.Array, layer: dict, live=None) -> jax.Array:
+        return self._experts("grouped", h, layer, live)
+
+    def _sorted_dispatch(self, h: jax.Array, layer: dict, live=None):
+        """(topw, flat expert ids, order, group sizes) of a step."""
         topw, topi = self._route(h, layer)
-        flat_e = topi.reshape(-1).astype(jnp.int32)  # [T*k]
-        if live is not None:
-            flat_e = jnp.where(jnp.repeat(live, k), flat_e, e)
-        order = jnp.argsort(flat_e)
-        gs = (flat_e[:, None] == jnp.arange(e, dtype=jnp.int32)).sum(
-            axis=0, dtype=jnp.int32
-        )
-        return topw, flat_e, order, gs
-
-    def _mlp_ragged(self, h: jax.Array, layer: dict) -> jax.Array:
-        """Sorted ragged dispatch (SURVEY §2.5's TPU plan; VERDICT r3
-        #4): flatten the T×k assignments, sort rows by expert, run ONE
-        grouped matmul per projection (jax.lax.ragged_dot), and
-        scatter-add the weighted results back — ~k/E of the dense
-        path's expert FLOPs, which is what makes a 160-expert/8-active
-        flagship servable.
-
-        Sharding: under EP each device holds E/tp whole experts; the
-        activations are replicated over "tp", so instead of an
-        all-to-all each shard runs the grouped matmul over the FULL
-        sorted row range with its local expert stack — rows outside its
-        experts' range fold into the edge groups (so group offsets stay
-        aligned without padding/copying weights) and are masked from
-        the psum-combined output.  Without EP, experts split over their
-        intermediate dim like the dense MLP (partial products psum)."""
-        from vllm_distributed_tpu.ops.quant import maybe_dequantize
-
-        t = h.shape[0]
-        k = self.top_k
-        with jax.named_scope(SCOPE_MOE_ROUTER):
-            topw, _, order, gs = self._sorted_dispatch(h, layer)
-            tok = order // k
-            xs = h[tok]  # [T*k, H] sorted by expert
-            row_w = topw.reshape(-1)[order].astype(h.dtype)  # [T*k]
-
-        with jax.named_scope(SCOPE_MOE_EXPERTS):
-            w1 = maybe_dequantize(layer["w1"], h.dtype)
-            w3 = maybe_dequantize(layer["w3"], h.dtype)
-            w2 = maybe_dequantize(layer["w2"], h.dtype)
-
-            mesh = getattr(self, "_mesh", None)
-            tp = mesh.shape.get("tp", 1) if mesh is not None else 1
-            if tp > 1 and self.expert_parallel:
-                orows = self._ragged_ep(xs, gs, w1, w3, w2, mesh, tp)
-            elif tp > 1:
-                orows = self._ragged_tp(xs, gs, w1, w3, w2, mesh)
-            else:
-                h1 = jax.lax.ragged_dot(xs, w1, gs)
-                h3 = jax.lax.ragged_dot(xs, w3, gs)
-                inner = jax.nn.silu(h1) * h3
-                orows = jax.lax.ragged_dot(inner, w2, gs)
-
-        # f32 accumulation for the k-way combine: the dense oracle's
-        # einsum promotes the combine matrix to f32, so a bf16
-        # scatter-add here would drift from it (ADVICE r4 #1).
-        y = jnp.zeros((t, h.shape[1]), jnp.float32)
-        contrib = orows.astype(jnp.float32) * row_w.astype(jnp.float32)[
-            :, None
-        ]
-        return y.at[tok].add(contrib).astype(h.dtype)
-
-    def _mlp_grouped(
-        self, h: jax.Array, layer: dict, live: jax.Array | None = None
-    ) -> jax.Array:
-        """The sorted dispatch over int8 experts on one chip: the rows
-        go through ops/pallas/grouped_matmul, which streams the int8
-        tiles themselves (no bf16 copy of the stack, which is what
-        ``ragged_dot`` would need).  Each expert's group is padded to
-        whole row tiles, so the gather writes that layout directly and
-        the combine gathers back out of it (a token's k rows, weighted
-        and summed in f32 like the other paths) instead of
-        scatter-adding.  Tokens outside ``live`` join no group and
-        come back as zeros."""
-        from vllm_distributed_tpu.ops.pallas import grouped_matmul as gm
-
-        t = h.shape[0]
-        e, k = self.num_experts, self.top_k
-        m = t * k
-        w1, w3, w2 = layer["w1"], layer["w3"], layer["w2"]
-        interpret = w1.matmul == "pallas_interpret"
-        mp = gm.padded_rows(m, e, gm.BLOCK_ROWS)
-        with jax.named_scope(SCOPE_MOE_ROUTER):
-            topw, flat_e, order, gs = self._sorted_dispatch(h, layer, live)
-            starts, tile_expert, _ = gm.group_layout(gs, mp, gm.BLOCK_ROWS)
-            # sorted row r of expert e sits at padded row r + shift[e]
-            shift = starts - (jnp.cumsum(gs) - gs)
-            row_e = jnp.repeat(tile_expert, gm.BLOCK_ROWS)
-            src = jnp.arange(mp, dtype=jnp.int32) - shift[row_e]
-            xs = h[order[jnp.clip(src, 0, m - 1)] // k]  # [MP, H]
-            # where each assignment's row went: its rank among the
-            # sorted rows, moved into its expert's padded range
-            rank = jnp.argsort(order).astype(jnp.int32)
-            pos = rank + shift[jnp.minimum(flat_e, e - 1)]
-            pos = jnp.minimum(pos, mp - 1).reshape(t, k)
-
-        with jax.named_scope(SCOPE_MOE_EXPERTS):
-            inner = gm.grouped_int8_matmul(
-                xs, w1.q, w1.scale, gs, q_up=w3.q, scale_up=w3.scale,
-                interpret=interpret,
-            )
-            orows = gm.grouped_int8_matmul(
-                inner, w2.q, w2.scale, gs, interpret=interpret
-            )
-
-        w = topw.astype(h.dtype).astype(jnp.float32)[..., None]
-        y = (orows[pos].astype(jnp.float32) * w).sum(axis=1)
-        if live is not None:
-            y = jnp.where(live[:, None], y, 0.0)
-        return y.astype(h.dtype)
-
-    def _ragged_ep(self, xs, gs, w1, w3, w2, mesh, tp):
-        """EP shard_map: each device's local experts own a contiguous
-        range of the sorted rows; out-of-range rows are folded into the
-        first/last local group (keeping offsets aligned without weight
-        copies), computed as garbage, masked, and psum-combined."""
-        from jax.sharding import PartitionSpec as P
-
-        e = self.num_experts
-        e_local = e // tp
-        m = xs.shape[0]
-
-        def body(xs_, gs_, w1_, w3_, w2_):
-            idx = jax.lax.axis_index("tp")
-            cum = jnp.cumsum(gs_)
-            lo_e = idx * e_local
-            start = jnp.where(lo_e > 0, cum[jnp.maximum(lo_e - 1, 0)], 0)
-            end = cum[lo_e + e_local - 1]
-            gs_local = jax.lax.dynamic_slice(gs_, (lo_e,), (e_local,))
-            # Fold the out-of-range rows into the edge groups.
-            gs_fold = gs_local.at[0].add(start)
-            gs_fold = gs_fold.at[e_local - 1].add(m - end)
-            h1 = jax.lax.ragged_dot(xs_, w1_, gs_fold)
-            h3 = jax.lax.ragged_dot(xs_, w3_, gs_fold)
-            inner = jax.nn.silu(h1) * h3
-            orows = jax.lax.ragged_dot(inner, w2_, gs_fold)
-            rows = jnp.arange(m, dtype=jnp.int32)
-            in_range = (rows >= start) & (rows < end)
-            orows = jnp.where(in_range[:, None], orows, 0)
-            return jax.lax.psum(orows, "tp")
-
-        f = jax.shard_map(
-            body,
-            mesh=mesh,
-            in_specs=(
-                P(), P(),
-                P("tp", None, None), P("tp", None, None),
-                P("tp", None, None),
-            ),
-            out_specs=P(),
-            check_vma=False,
-        )
-        return f(xs, gs, w1, w3, w2)
-
-    def _ragged_tp(self, xs, gs, w1, w3, w2, mesh):
-        """Non-EP tp: experts split over the intermediate dim (like the
-        dense MLP); w2's partial products psum inside the region."""
-        from jax.sharding import PartitionSpec as P
-
-        def body(xs_, gs_, w1_, w3_, w2_):
-            h1 = jax.lax.ragged_dot(xs_, w1_, gs_)
-            h3 = jax.lax.ragged_dot(xs_, w3_, gs_)
-            inner = jax.nn.silu(h1) * h3
-            part = jax.lax.ragged_dot(inner, w2_, gs_)
-            return jax.lax.psum(part, "tp")
-
-        f = jax.shard_map(
-            body,
-            mesh=mesh,
-            in_specs=(
-                P(), P(),
-                P(None, None, "tp"), P(None, None, "tp"),
-                P(None, "tp", None),
-            ),
-            out_specs=P(),
-            check_vma=False,
-        )
-        return f(xs, gs, w1, w3, w2)
+        return (topw, *sorted_dispatch(topi, self.num_experts, live))

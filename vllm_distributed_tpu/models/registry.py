@@ -17,6 +17,7 @@ def register_model(cls) -> Any:
 def _populate() -> None:
     if _REGISTRY:
         return
+    from vllm_distributed_tpu.models.lfm2_moe import Lfm2MoeForCausalLM
     from vllm_distributed_tpu.models.llama import LlamaForCausalLM
     from vllm_distributed_tpu.models.longcat_flash import (
         LongcatFlashForCausalLM,
@@ -28,6 +29,7 @@ def _populate() -> None:
     register_model(OPTForCausalLM)
     register_model(MixtralForCausalLM)
     register_model(LongcatFlashForCausalLM)
+    register_model(Lfm2MoeForCausalLM)
 
 
 def get_model_class(architecture: str):

@@ -129,6 +129,49 @@ class KVCacheLayout:
         return (self.planes, num_pages, page_size, self.scale_width)
 
 
+@dataclass(frozen=True)
+class SeqStateLayout:
+    """What a model states of the state it keeps a *sequence*, beside
+    its paged caches: ``num_states`` arrays ``[R, rows, width]`` (one a
+    layer that has such state), ``R`` the runner's sequence bucket, a
+    request's row the one its last sampled token has in the device
+    carry (``CachedReqState.slot``).  The state is whatever length the
+    sequence has, so it is no part of ``KVCacheLayout.token_bytes``: its
+    bytes come off the pool once, at ``R`` rows.  Nothing clears a row
+    when it changes hands: a model reads of it only what lies at a
+    position >= 0 of the sequence that holds it now."""
+
+    num_states: int
+    rows: int
+    width: int
+
+    def shape(self, num_seqs: int) -> tuple:
+        return (num_seqs, self.rows, self.width)
+
+    def seq_bytes(self, itemsize: int) -> int:
+        """Bytes one sequence holds over all of the model's states."""
+        return self.num_states * self.rows * self.width * itemsize
+
+
+@jax.tree_util.register_dataclass
+@dataclass(frozen=True)
+class SeqState:
+    """One step's view of the sequence state: the arrays, and which row
+    of them each of the step's sequences holds.
+
+      states:    ``num_states`` arrays [R, rows, width]
+      slots:     [S] int32 — row of each sequence of the step; a padding
+                 or masked sequence points at R (past the end: what a
+                 model writes for it is dropped)
+      last_rows: [S] int32 — flat index of each sequence's last token of
+                 the step (what is left behind is the state after it)
+    """
+
+    states: list
+    slots: jax.Array
+    last_rows: jax.Array
+
+
 def quantize_kv_heads(
     k: jax.Array,  # [..., Hkv * D] flat rows (model dtype)
     num_kv_heads: int,

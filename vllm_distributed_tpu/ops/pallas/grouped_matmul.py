@@ -1,5 +1,8 @@
-"""Pallas grouped (routed) int8 expert matmul: rows sorted by expert,
-each multiplied by its own expert's int8 weights.
+"""Pallas grouped (routed) expert matmuls: rows sorted by expert, each
+multiplied by its own expert's weights — int8 weights streamed and cast
+in the kernel (``grouped_int8_matmul``, below), or weights in the
+activations' own dtype fed to the MXU as they are
+(``grouped_matmul``, at the end: the same layout, no cast and no scale).
 
 A many-row MoE step (prefill, a mixed step) is compute-bound, and the
 dense dispatch computes every expert for every token: E/k times the
@@ -265,3 +268,137 @@ def grouped_int8_matmul(
             for _, s in weights
         ],
     )
+
+
+# ---- unquantized experts ---------------------------------------------------
+def unquantized_vmem_bytes(
+    block_rows: int, in_dim: int, block_out: int, itemsize: int, n_w: int
+) -> int:
+    """VMEM model of one grid step of ``grouped_matmul``: per weight the
+    double-buffered tile and the f32 accumulator; the double-buffered
+    row tile and output tile."""
+    per_w = 2 * in_dim * block_out * itemsize + block_rows * block_out * 4
+    x = 2 * block_rows * in_dim * itemsize
+    out = 2 * block_rows * block_out * itemsize
+    return n_w * per_w + x + out
+
+
+def pick_unquantized_block_out(
+    in_dim: int, out_dim: int, itemsize: int, n_w: int,
+    block_rows: int = BLOCK_ROWS,
+) -> int | None:
+    """The widest output tile of whole 128-lane columns that divides
+    ``out_dim`` and fits the budget — the whole width where it fits, so
+    that ``xs`` is read once and an expert's weights once; ``None``
+    where the compiled kernel has no tile for these dims."""
+    if in_dim % 128 or out_dim % 128:
+        return None
+    for parts in range(1, out_dim // 128 + 1):
+        blk = out_dim // parts
+        if out_dim % parts or blk % 128:
+            continue
+        if unquantized_vmem_bytes(
+            block_rows, in_dim, blk, itemsize, n_w
+        ) <= _VMEM_TILE_BUDGET:
+            return blk
+    return None
+
+
+@functools.cache
+def has_unquantized_tiles(
+    hidden: int, intermediate: int, itemsize: int = 2
+) -> bool:
+    """``has_tiles`` for ``grouped_matmul``."""
+    return (
+        pick_unquantized_block_out(hidden, intermediate, itemsize, 2)
+        is not None
+        and pick_unquantized_block_out(intermediate, hidden, itemsize, 1)
+        is not None
+    )
+
+
+def _unquantized_kernel(te_ref, used_ref, x_ref, *refs, n_w: int):
+    # x [tm, K]; per weight w [K, tn] in x's dtype -> o [tm, tn].
+    del te_ref  # the block indices read it
+    ws, o_ref = refs[:n_w], refs[n_w]
+
+    @pl.when(pl.program_id(1) < used_ref[0])
+    def _dot():
+        x = x_ref[...]
+        acc = [
+            jnp.dot(x, w_ref[...], preferred_element_type=jnp.float32)
+            for w_ref in ws
+        ]
+        out = acc[0] if n_w == 1 else jax.nn.silu(acc[0]) * acc[1]
+        o_ref[...] = out.astype(o_ref.dtype)
+
+
+def grouped_matmul(
+    xs: jax.Array,  # [MP, K] rows in group_layout's padded layout
+    w: jax.Array,  # [E, K, N] in xs' dtype
+    group_sizes: jax.Array,  # [E] int32 rows per expert
+    *,
+    w_up: jax.Array | None = None,  # second weight: silu(x@w) * (x@w_up)
+    block_rows: int = BLOCK_ROWS,
+    block_out: int | None = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """``xs[rows of expert e] @ w[e]`` for rows sorted by expert in
+    ``grouped_int8_matmul``'s layout, the weights in the rows' own dtype
+    (bfloat16 on the chip): the grid, the row-tile → expert map and the
+    skipped tail are the same; a weight tile goes from HBM to the MXU
+    as it is, and consecutive row tiles of one expert keep its block
+    index, so it is fetched once an expert and N tile."""
+    mp, in_dim = xs.shape
+    e, in_w, out_dim = w.shape
+    assert in_w == in_dim and w.dtype == xs.dtype, (xs.dtype, w.dtype)
+    assert mp % block_rows == 0, (mp, block_rows)
+    n_w = 1 if w_up is None else 2
+    itemsize = jnp.dtype(xs.dtype).itemsize
+    if block_out is None:
+        block_out = pick_unquantized_block_out(
+            in_dim, out_dim, itemsize, n_w, block_rows
+        )
+        if block_out is None and interpret:
+            block_out = out_dim
+    if block_out is None or out_dim % block_out:
+        raise ValueError(
+            f"grouped_matmul has no tile for in={in_dim}, out={out_dim} "
+            f"(block {block_out})"
+        )
+    model = unquantized_vmem_bytes(
+        block_rows, in_dim, block_out, itemsize, n_w
+    )
+    _, tile_expert, used = group_layout(group_sizes, mp, block_rows)
+
+    def row_tile(i, used_ref):
+        return jnp.minimum(i, jnp.maximum(used_ref[0] - 1, 0))
+
+    w_spec = pl.BlockSpec(
+        (None, in_dim, block_out), lambda j, i, te, used: (te[i], 0, j)
+    )
+    return pl.pallas_call(
+        functools.partial(_unquantized_kernel, n_w=n_w),
+        name="vdt_grouped_matmul",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(out_dim // block_out, mp // block_rows),
+            in_specs=[
+                pl.BlockSpec(
+                    (block_rows, in_dim),
+                    lambda j, i, te, used: (row_tile(i, used), 0),
+                ),
+                *[w_spec] * n_w,
+            ],
+            out_specs=pl.BlockSpec(
+                (block_rows, block_out),
+                lambda j, i, te, used: (row_tile(i, used), j),
+            ),
+        ),
+        out_shape=jax.ShapeDtypeStruct((mp, out_dim), xs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=model + _VMEM_HEADROOM,
+        ),
+        interpret=interpret,
+    )(tile_expert, used, xs, *([w] if w_up is None else [w, w_up]))

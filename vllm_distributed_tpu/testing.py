@@ -170,6 +170,67 @@ def write_longcat_config(dirname: str | None = None, **sizes) -> str:
     return dirname
 
 
+def lfm2_config(
+    *,
+    vocab_size: int = 128,
+    hidden: int = 64,
+    intermediate: int = 96,
+    moe_intermediate: int = 32,
+    layer_types: tuple = ("conv", "conv", "full_attention", "conv"),
+    dense_layers: int = 1,
+    heads: int = 4,
+    kv_heads: int = 2,
+    experts: int = 8,
+    top_k: int = 3,
+    max_pos: int = 2048,
+    dtype: str = "float32",
+) -> dict:
+    """An LFM2-MoE-architecture config (gated short convolutions with a
+    few attention layers between them, leading dense layers, a sigmoid
+    router with a choice-only bias), keys as the published file spells
+    them (no ``head_dim``)."""
+    return {
+        "architectures": ["Lfm2MoeForCausalLM"],
+        "model_type": "lfm2_moe",
+        "hidden_size": hidden,
+        "intermediate_size": intermediate,
+        "moe_intermediate_size": moe_intermediate,
+        "num_hidden_layers": len(layer_types),
+        "layer_types": list(layer_types),
+        "num_dense_layers": dense_layers,
+        "num_attention_heads": heads,
+        "num_key_value_heads": kv_heads,
+        "num_experts": experts,
+        "num_experts_per_tok": top_k,
+        "norm_topk_prob": True,
+        "use_expert_bias": True,
+        "routed_scaling_factor": 1.0,
+        "conv_L_cache": 3,
+        "conv_bias": False,
+        "vocab_size": vocab_size,
+        "max_position_embeddings": max_pos,
+        "norm_eps": 1e-5,
+        "rope_theta": 1000000.0,
+        "initializer_range": 0.02,
+        "torch_dtype": dtype,
+        "tie_word_embeddings": True,
+        "hidden_act": "silu",
+        "bos_token_id": 1,
+        "eos_token_id": 2,
+    }
+
+
+def write_lfm2_config(dirname: str | None = None, **sizes) -> str:
+    """Write ``lfm2_config(**sizes)`` as a config.json; returns the
+    directory."""
+    if dirname is None:
+        dirname = tempfile.mkdtemp(prefix="vdt_tiny_lfm2_")
+    os.makedirs(dirname, exist_ok=True)
+    with open(os.path.join(dirname, "config.json"), "w") as f:
+        json.dump(lfm2_config(**sizes), f)
+    return dirname
+
+
 def full_attention_reference(q, k, v, scale, causal=True):
     """Dense (non-paged) attention oracle [T, H, D] with GQA repeat —
     the reference for ring attention (tests + multichip dryrun)."""

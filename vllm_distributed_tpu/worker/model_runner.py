@@ -40,6 +40,7 @@ from vllm_distributed_tpu.models.common import SCOPE_KV_WRITE, SCOPE_SAMPLER
 from vllm_distributed_tpu.ops import resolve_kernel_backend
 from vllm_distributed_tpu.ops.attention import (
     AttentionMetadata,
+    SeqState,
     bind_max_q,
     paged_attention_reference,
 )
@@ -173,7 +174,13 @@ class ModelRunner:
         self.global_seed = config.model_config.seed
         self.model = None
         self.params = None
-        self.kv_caches: list | None = None
+        # What every step program takes donated and returns rewritten:
+        # the paged pools (a list, one a cache), and for a model that
+        # keeps state a sequence beside its pages (a short convolution's
+        # last rows) the pair (pools, states): one array a layer that
+        # has such state, [seq bucket, rows, width], a request's row its
+        # carry row (``CachedReqState.slot``).
+        self.kv_caches: list | tuple | None = None
         self.requests: dict[str, CachedReqState] = {}
         self.attn_backend = resolve_kernel_backend(attn_backend)
         self._attn_fn = None
@@ -230,6 +237,7 @@ class ModelRunner:
         if hasattr(self.model, "fuse_quantized_projections"):
             self.params = self.model.fuse_quantized_projections(self.params)
         self._refuse_unsupported_latent()
+        self._refuse_unsupported_seq_state()
         self._attn_fn = self._pick_attn_fn()
         # Two writers: prefill/mixed steps keep the functional XLA
         # scatter (batched, GSPMD-partitionable — the aliased Pallas
@@ -402,7 +410,10 @@ class ModelRunner:
 
         moe_impl = getattr(self.model, "moe_impl", None)
         if moe_impl is not None:
-            counts["moe"] = moe_impl(self.params["layers"][0], rows)
+            # a model with leading dense layers says where its routed
+            # layers start
+            routed = getattr(self.model, "first_routed_layer", 0)
+            counts["moe"] = moe_impl(self.params["layers"][routed], rows)
             if counts["moe"] == "grouped":
                 self.telemetry.record_moe_grouped_step()
         tracer = get_tracer()
@@ -427,9 +438,9 @@ class ModelRunner:
             t0 = time.perf_counter()
             out = run()
             self._compiled_keys.add(key)
-            self.telemetry.record_compile(
-                kind, time.perf_counter() - t0, key
-            )
+            seconds = time.perf_counter() - t0
+            self.telemetry.record_compile(kind, seconds, key)
+            logger.info("first call of %s: %.2f s", name, seconds)
             return out
 
     def _record_step_bw(
@@ -496,6 +507,55 @@ class ModelRunner:
         """A one-plane layout is a latent (MLA) cache, with kernels and
         limits of its own."""
         return self._kv_layout.planes == 1
+
+    @property
+    def _state_layout(self):
+        """What the model states of per-sequence state
+        (``SeqStateLayout``), None for a model that keeps none."""
+        layout = getattr(self.model, "seq_state_layout", None)
+        return layout() if layout is not None else None
+
+    def _seq_state_bytes(self) -> int:
+        layout = self._state_layout
+        if layout is None:
+            return 0
+        return self._seq_bucket() * layout.seq_bytes(
+            jnp.dtype(self.model.dtype).itemsize
+        )
+
+    def _refuse_unsupported_seq_state(self) -> None:
+        """What state that is not pages of tokens cannot do yet fails
+        here, at load, with a message that names it."""
+        if self._state_layout is None:
+            return
+        cc = self.config.cache_config
+        why = (
+            "the model keeps per-sequence state beside its paged cache "
+            "(worker/model_runner.py)"
+        )
+        if cc.enable_prefix_caching:
+            raise ValueError(
+                "--enable-prefix-caching is not supported: a prefix hit "
+                "would resume a sequence whose state at that position "
+                f"was never kept; {why}"
+            )
+        if self.config.scheduler_config.spec_ngram_k > 0:
+            raise ValueError(
+                "speculative decoding (--speculative-ngram-k) is not "
+                "supported: a rejected draft would have to roll the "
+                f"state back; {why}"
+            )
+        if getattr(cc, "kv_spill_host_pages", 0):
+            raise ValueError(
+                "--kv-spill-host-pages is not supported: the spill tier "
+                f"moves pages, not sequence state; {why}"
+            )
+        if self.config.kv_transfer_config is not None:
+            raise ValueError(
+                "--kv-transfer-config is not supported: KV page transfer "
+                "(engine/kv_transfer.py) moves pages, not sequence "
+                f"state; {why}"
+            )
 
     def _refuse_unsupported_latent(self) -> None:
         """What a latent (one-plane) pool cannot do fails here, at load,
@@ -677,7 +737,9 @@ class ModelRunner:
             shards = self.mesh.shape["tp"]
         # Stats are per device: the side-buffer reserve shards with tp.
         reserve = (
-            self._pipeline_reserve_bytes() // shards + _STEP_WORKSPACE_BYTES
+            self._pipeline_reserve_bytes() // shards
+            + _STEP_WORKSPACE_BYTES
+            + self._seq_state_bytes()
         )
         free = min(
             int(s["bytes_limit"] * cc.hbm_utilization)
@@ -704,7 +766,16 @@ class ModelRunner:
         )
         return int(num_pages)
 
-    def alloc_kv_pool(self, num_pages: int) -> list:
+    def alloc_kv_pool(self, num_pages: int) -> list | tuple:
+        """What ``kv_caches`` holds at ``num_pages`` pages: the paged
+        pools, and behind them the sequence states of a model that has
+        them, at the sequence bucket."""
+        pools = self._alloc_pages(num_pages)
+        if self._state_layout is None:
+            return pools
+        return (pools, self.alloc_seq_states(self._seq_bucket()))
+
+    def _alloc_pages(self, num_pages: int) -> list:
         """Allocate a paged KV pool: as many [planes, P, page, width]
         arrays as the model states caches (``kv_cache_layout``; for K
         and V of every head one a layer, see ops/attention.py — K/V
@@ -740,10 +811,62 @@ class ModelRunner:
 
         return [alloc() for _ in range(layout.num_caches)]
 
+    def alloc_seq_states(self, num_seqs: int) -> list | None:
+        """The model's per-sequence state at ``num_seqs`` rows (zeros;
+        nothing ever has to clear a row again), None for a model that
+        keeps none."""
+        layout = self._state_layout
+        if layout is None:
+            return None
+        return [
+            jnp.zeros(layout.shape(num_seqs), self.model.dtype)
+            for _ in range(layout.num_states)
+        ]
+
     def init_kv_cache(self, num_pages: int) -> None:
         self.num_pages = num_pages
         self.kv_caches = self.alloc_kv_pool(num_pages)
+        layout = self._state_layout
+        if layout is not None:
+            logger.info(
+                "sequence state: %d states × %d sequences × %d rows × %d "
+                "(%.2f MiB, off the KV pool)",
+                layout.num_states,
+                self._seq_bucket(),
+                layout.rows,
+                layout.width,
+                self._seq_state_bytes() / 2**20,
+            )
         _log_device_memory("after KV pool")
+
+    def _state_slots(self, states: list, s_pad: int) -> list[np.ndarray]:
+        """For a model with sequence state, one more packed array: the
+        state row of each of the step's sequences (its carry row), the
+        row count for padding (past the end: a write there is dropped).
+        Nothing for any other model, whose programs stay as they were."""
+        if self._state_layout is None:
+            return []
+        slots = np.full(s_pad, self._seq_bucket(), np.int32)
+        slots[: len(states)] = [st.slot for st in states]
+        return [slots]
+
+    def _forward(self, params, tokens, caches, meta, state_slots, **kw):
+        """``model.forward`` for both kinds of model: (logits, caches as
+        ``kv_caches`` holds them).  ``state_slots`` [S]: each
+        sequence's state row; a sequence's last token of the step is
+        the one its logits are read at."""
+        if self._state_layout is None:
+            return self.model.forward(params, tokens, caches, meta, **kw)
+        kv, states = caches
+        logits, kv, states = self.model.forward(
+            params, tokens, kv, meta,
+            seq_state=SeqState(
+                states=states, slots=state_slots,
+                last_rows=meta.logits_indices,
+            ),
+            **kw,
+        )
+        return logits, (kv, states)
 
     def warmup_decode(self) -> int:
         """Pre-compile the fused-decode program so serving never
@@ -989,7 +1112,15 @@ class ModelRunner:
             write_kv_pages,
         )
 
-        return self.model.forward(
+        extra = {}
+        if self._state_layout is not None:
+            # One sequence from position 0: it reads no state, and what
+            # it leaves in this scratch row is thrown away.
+            zero = jnp.zeros(1, jnp.int32)
+            extra["seq_state"] = SeqState(
+                states=self.alloc_seq_states(1), slots=zero, last_rows=zero
+            )
+        logits, kv, *_, hidden = self.model.forward(
             params,
             tokens,
             kv,
@@ -1001,7 +1132,9 @@ class ModelRunner:
             ),
             kv_write_fn=write_kv_pages,
             return_hidden=True,
+            **extra,
         )
+        return logits, kv, hidden
 
     def _aux_forward(self, token_ids: list[int]):
         """One-off teacher-forced forward over a scratch KV pool with
@@ -1018,7 +1151,7 @@ class ModelRunner:
         # whose HBM the serving pool was sized to fill.
         cached = getattr(self, "_aux_pool", None)
         if cached is None or cached[0] < pages:
-            self._aux_pool = (pages, self.alloc_kv_pool(pages))
+            self._aux_pool = (pages, self._alloc_pages(pages))
         kv = self._aux_pool[1]
         # Previous contents are dead history for this single-sequence
         # teacher-forced pass (slots are overwritten; reads are bounded
@@ -1184,7 +1317,7 @@ class ModelRunner:
         """
         import hashlib
 
-        self._refuse_latent_transfer()
+        self._refuse_page_transfer()
         tree = jax.tree_util
         leaves, _ = tree.tree_flatten(self.kv_caches)
         num_layers = len(leaves)
@@ -1216,7 +1349,7 @@ class ModelRunner:
         the transfer, so no step can be reading (or writing) them."""
         import hashlib
 
-        self._refuse_latent_transfer()
+        self._refuse_page_transfer()
         tree = jax.tree_util
         leaves, treedef = tree.tree_flatten(self.kv_caches)
         n = len(page_ids)
@@ -1250,11 +1383,18 @@ class ModelRunner:
         self.kv_caches = tree.tree_unflatten(treedef, leaves)
         return {"ok": True}
 
-    def _refuse_latent_transfer(self) -> None:
+    def _refuse_page_transfer(self) -> None:
         if self._latent:
             raise ValueError(
                 "KV page export/import is not supported for a latent "
                 "(MLA) cache (worker/model_runner.py)"
+            )
+        if self._state_layout is not None:
+            raise ValueError(
+                "KV page export/import is not supported for a model "
+                "that keeps per-sequence state beside its pages: the "
+                "pages alone do not resume a sequence "
+                "(worker/model_runner.py)"
             )
 
     def host_kv_stats(self) -> dict:
@@ -1379,6 +1519,10 @@ class ModelRunner:
         # at least dp wide stay divisible for the dp input sharding.
         t_pad = max(next_power_of_2(t_real), _MIN_TOKEN_BUCKET, self._dp)
         s_pad = max(next_power_of_2(s_real), _MIN_SEQ_BUCKET, self._dp)
+        if getattr(self.model, "pin_step_seq_bucket", False):
+            # One single-step program a (token, longest-chunk) bucket,
+            # whatever the step's sequences: the model asks for it.
+            s_pad = self._seq_bucket()
         max_pages = max(
             max((len(st.page_ids) for st in states), default=1), 1
         )
@@ -1462,6 +1606,7 @@ class ModelRunner:
                     smeta_np.presence_penalty, smeta_np.frequency_penalty,
                     smeta_np.keys, smeta_np.prompt_tokens,
                     smeta_np.output_tokens, carry_idx, slot_rows,
+                    *self._state_slots(states, s_pad),
                 ]
             )
             if self.mesh is not None:
@@ -1472,6 +1617,7 @@ class ModelRunner:
             method = "_jit_step_packed"
             args = (self.params, self.kv_caches, packed, carry)
         else:
+            assert self._state_layout is None, "sequence state under dp>1"
             meta = AttentionMetadata(
                 q_seq_ids=jnp.asarray(seq_ids),
                 q_positions=jnp.asarray(positions),
@@ -1505,6 +1651,8 @@ class ModelRunner:
             )
         if getattr(self._attn_fn, "from_heads", None) and max_q_pad > 1:
             counts.update(self._mla_chunk_tokens(num_new, chunk_starts))
+        if self._state_layout is not None:
+            counts.update(self._count_state_rows(starts))
         prep.end()
         t_step0 = time.perf_counter()
         sampled, logprobs, self.kv_caches, self._carry = self._run_program(
@@ -1659,6 +1807,7 @@ class ModelRunner:
         do_penalties: bool,
         do_top_k_p: bool,
         return_logprobs: bool,
+        state_slots=None,
     ):
         # A decode row whose last token the host has not fetched reads
         # it from the carry, by row.
@@ -1667,11 +1816,12 @@ class ModelRunner:
         )
         attn_fn = bind_max_q(self._attn_fn, max_q_pad)
         stats = self._model_stats()
-        logits, kv_caches = self.model.forward(
+        logits, kv_caches = self._forward(
             params,
             token_ids,
             kv_caches,
             meta,
+            state_slots,
             attn_fn=attn_fn,
             kv_write_fn=self._kv_write_fn,
             **stats,
@@ -1702,13 +1852,27 @@ class ModelRunner:
 
     def _record_moe_picks(self, span, host_tail) -> None:
         """The pick counts a step program appended to its tokens: onto
-        the worker's counters and the step's fetch span."""
+        the worker's counter the model names (``moe_picks`` unless it
+        says otherwise) and the step's fetch span."""
         counts = {
             k: int(n) for k, n in zip(self.model.moe_pick_kinds, host_tail)
         }
-        self.telemetry.record_moe_picks(counts)
+        self.telemetry.record_moe_picks(
+            counts, getattr(self.model, "moe_pick_counter", "moe_picks")
+        )
         for kind, n in counts.items():
             span.set_attribute(f"moe_picks_{kind}", n)
+
+    def _count_state_rows(self, starts: list[int]) -> dict:
+        """A step's sequences by whether their state is read: the
+        dispatch span's ``state_rows`` (all of them: every one's row is
+        read and rewritten) and ``state_fresh`` (those at position 0,
+        which read nothing of the row), and the counter's two kinds."""
+        fresh = sum(1 for lo in starts if lo == 0)
+        self.telemetry.record_seq_state_rows(
+            {"fresh": fresh, "carried": len(starts) - fresh}
+        )
+        return dict(state_rows=len(starts), state_fresh=fresh)
 
     @partial(
         jax.jit,
@@ -1772,6 +1936,7 @@ class ModelRunner:
             tokens, seq_ids, positions, slots, block_tables, seq_lens,
             logits_idx, chunk_starts, temp, top_k, top_p, min_p, rep,
             pres, freq, keys, prompt_toks, out_toks, carry_idx, slot_rows,
+            *state_slots,
         ) = unpack_device_arrays(packed, spec)
         meta = AttentionMetadata(
             q_seq_ids=seq_ids,
@@ -1798,6 +1963,7 @@ class ModelRunner:
             params, kv_caches, tokens, meta, smeta,
             carry, carry_idx, slot_rows,
             max_q_pad, do_penalties, do_top_k_p, return_logprobs,
+            *state_slots,
         )
 
     # ---- speculative verify pass (SchedulerOutput.draft_token_ids) ----
@@ -2062,6 +2228,7 @@ class ModelRunner:
                 smeta_np.presence_penalty, smeta_np.frequency_penalty,
                 smeta_np.keys, smeta_np.prompt_tokens,
                 smeta_np.output_tokens,
+                *self._state_slots(states, s_pad),
             ]
         )
         if self.mesh is not None:
@@ -2073,6 +2240,12 @@ class ModelRunner:
             do_top_k_p=flags["do_top_k_p"],
         )
         kv_tokens_scanned = int(base_lens.sum())
+        counts = dict(
+            seqs=s_real, tokens=int(n_active.sum()),
+            kv_tokens=kv_tokens_scanned,
+        )
+        if self._state_layout is not None:
+            counts.update(self._count_state_rows(starts))
         prep.end()
         t_step0 = time.perf_counter()
         toks, self._carry, self.kv_caches = self._run_program(
@@ -2085,9 +2258,7 @@ class ModelRunner:
             (self.params, self.kv_caches, packed, carry),
             so,
             rows=s_pad,
-            seqs=s_real,
-            tokens=int(n_active.sum()),
-            kv_tokens=kv_tokens_scanned,
+            **counts,
         )
         # Each sequence's LAST VALID token stays on device, in its carry
         # row, as the input of the next dispatch that holds the request
@@ -2103,7 +2274,7 @@ class ModelRunner:
             ) as fetch:
                 host_toks = np.asarray(jax.device_get(toks))  # [K, s_pad]
                 if host_toks.shape[0] > k_steps:
-                    self._record_moe_picks(fetch, host_toks[k_steps, :3])
+                    self._record_moe_picks(fetch, host_toks[k_steps])
             self._num_fetched += 1
             # K micro-steps = K weights+KV HBM passes.  Wall time spans
             # dispatch→resolve (includes any pipeline overlap — an
@@ -2151,7 +2322,14 @@ class ModelRunner:
             tokens, base_lens, valid, n_active, carry_idx, slot_rows,
             block_tables, out_lens, temp, top_k,
             top_p, min_p, rep, pres, freq, keys, prompt_toks, out_toks,
+            *state_slots,
         ) = unpack_device_arrays(packed, spec)
+        # A model with sequence state: its arrays ride behind the pools
+        # (``kv_caches``) and through the loop's carry beside them.
+        seq_states = None
+        if state_slots:
+            kv_caches, seq_states = kv_caches
+            (state_slots,) = state_slots
         tokens = jnp.where(
             carry_idx >= 0, carry[jnp.maximum(carry_idx, 0)], tokens
         )
@@ -2200,8 +2378,8 @@ class ModelRunner:
                 )
 
         def body(carry, i):
-            kv, sides, tok, out_buf = carry
-            stats = self._model_stats()
+            kv, sides, states, tok, out_buf = carry
+            extra = self._model_stats()
             pos = base_lens + i
             # Micro-step i runs only sequences with i < n_active: under-K
             # tails drop their queries (id == s_pad, the kernels' drop
@@ -2255,32 +2433,42 @@ class ModelRunner:
             # bandwidth win.  With it, the int8 bytes stream per
             # micro-step and the dequant fuses into the matmuls.
             params_i = jax.lax.optimization_barrier(params)
+            if states is not None:
+                # A row this micro-step masks keeps its state: its slot
+                # points past the end, where a write is dropped.
+                extra["seq_state"] = SeqState(
+                    states=states,
+                    slots=jnp.where(live, state_slots, states[0].shape[0]),
+                    last_rows=rows,
+                )
             if staged:
                 entries = [
                     make_entry(kv_l, side_l, i)
                     for kv_l, side_l in zip(kv, sides)
                 ]
-                logits, new_entries = self.model.forward(
+                logits, new_entries, *new_states = self.model.forward(
                     params_i,
                     tok,
                     entries,
                     meta,
                     attn_fn=staged_attn,
                     kv_write_fn=staged_write,
-                    **stats,
+                    **extra,
                 )
                 kv = [e[0] for e in new_entries]
                 sides = [e[1] for e in new_entries]
             else:
-                logits, kv = self.model.forward(
+                logits, kv, *new_states = self.model.forward(
                     params_i,
                     tok,
                     kv,
                     meta,
                     attn_fn=attn_fn,
                     kv_write_fn=self._kv_write_fn,
-                    **stats,
+                    **extra,
                 )
+            if new_states:
+                (states,) = new_states
             with jax.named_scope(SCOPE_SAMPLER):
                 new_tok, _ = sample(
                     logits,
@@ -2295,10 +2483,10 @@ class ModelRunner:
                     rows,
                     jnp.where(live, out_lens + i, out_buf.shape[1]),
                 ].set(new_tok, mode="drop")
-            if stats:
-                return (kv, sides, new_tok, out_buf), (
-                    new_tok, stats["stats"]["moe_picks"])
-            return (kv, sides, new_tok, out_buf), new_tok
+            if "stats" in extra:
+                return (kv, sides, states, new_tok, out_buf), (
+                    new_tok, extra["stats"]["moe_picks"])
+            return (kv, sides, states, new_tok, out_buf), new_tok
 
         if staged:
             # Model dtype even for int8 pools: rows quantize ONCE per
@@ -2317,9 +2505,9 @@ class ModelRunner:
             sides0 = [side0(kv_l) for kv_l in kv_caches]
         else:
             sides0 = [jnp.zeros((), jnp.int32) for _ in kv_caches]
-        (kv_caches, sides_out, _, _), toks = jax.lax.scan(
+        (kv_caches, sides_out, seq_states, _, _), toks = jax.lax.scan(
             body,
-            (kv_caches, sides0, tokens, out_toks),
+            (kv_caches, sides0, seq_states, tokens, out_toks),
             jnp.arange(k_steps, dtype=jnp.int32),
         )
         if staged:
@@ -2341,8 +2529,11 @@ class ModelRunner:
         if picks is not None:
             # The router's pick counts over the K micro-steps ride as
             # one more row behind the tokens: one array, one transfer.
-            row = jnp.zeros((1, s_pad), toks.dtype).at[0, :3].set(
-                picks.sum(axis=0).astype(toks.dtype)
-            )
+            picks = picks.sum(axis=0).astype(toks.dtype)
+            row = jnp.zeros((1, s_pad), toks.dtype).at[
+                0, : picks.shape[0]
+            ].set(picks)
             toks = jnp.concatenate([toks, row], axis=0)
+        if seq_states is not None:
+            kv_caches = (kv_caches, seq_states)
         return toks, carry, kv_caches

@@ -21,12 +21,19 @@ runner writes:
   cache directory it could not create), by kind — semantically safe,
   but a run that claims a warm start must show zero;
 - **grouped MoE steps**: step programs dispatched whose int8 experts
-  were traced with the grouped kernel (models/mixtral.select_moe_impl),
+  were traced with the grouped kernel (models/routed_experts.select_moe_impl),
   so how often the routed path engages is a counter, not an inference;
 - **router picks**: of a model that routes over more experts than
   this chip holds, the picks that went to identity (zero-compute)
   experts, to experts held here and to experts held elsewhere, counted
   on the device and fetched with the step's tokens;
+- **expert picks**: of a model that holds every expert, all picks and
+  each routed layer's busiest expert's picks (what a grouped kernel
+  pads the other experts' groups to), counted the same way;
+- **sequence-state rows**: of a model that keeps state a sequence
+  beside its pages, the sequences each dispatched step computed, by
+  whether they started at position 0 (``fresh``: nothing of the row is
+  read) or later (``carried``);
 - **persistent cache**: JAX's own compilation-cache events for this
   process (lookups, hits, and entries written after a miss), so a warm
   restart is judged by what the cache reports, not by a time threshold.
@@ -129,6 +136,8 @@ class DeviceTelemetry:
         self.fallbacks: dict[str, int] = {}
         self.moe_grouped_steps = 0
         self.moe_picks: dict[str, int] = {}
+        self.moe_expert_picks: dict[str, int] = {}
+        self.seq_state_rows: dict[str, int] = {}
         self.mla_chunk_tokens: dict[str, int] = {}
         _install_cache_listener()
 
@@ -142,10 +151,19 @@ class DeviceTelemetry:
         with self._lock:
             self.moe_grouped_steps += 1
 
-    def record_moe_picks(self, counts: dict[str, int]) -> None:
-        """Router picks of one fetched step by kind (zero | held |
-        absent), as the step program counted them on the device."""
-        self._add(self.moe_picks, counts)
+    def record_moe_picks(
+        self, counts: dict[str, int], counter: str = "moe_picks"
+    ) -> None:
+        """Router picks of one fetched step by kind, as the step program
+        counted them on the device: ``moe_picks`` (zero | held | absent,
+        which add up to all picks) or ``moe_expert_picks`` (all |
+        busiest, which do not)."""
+        self._add(getattr(self, counter), counts)
+
+    def record_seq_state_rows(self, counts: dict[str, int]) -> None:
+        """Sequences of one dispatched step whose state rows it reads
+        and rewrites, by kind (fresh | carried)."""
+        self._add(self.seq_state_rows, counts)
 
     def record_mla_chunk_tokens(self, counts: dict[str, int]) -> None:
         """Tokens of one dispatched chunked step's multi-token chunks by
@@ -204,6 +222,8 @@ class DeviceTelemetry:
                 "fallbacks": dict(self.fallbacks),
                 "moe_grouped_steps": self.moe_grouped_steps,
                 "moe_picks": dict(self.moe_picks),
+                "moe_expert_picks": dict(self.moe_expert_picks),
+                "seq_state_rows": dict(self.seq_state_rows),
                 "mla_chunk_tokens": dict(self.mla_chunk_tokens),
                 "persistent_cache": persistent_cache_counts(),
             }
