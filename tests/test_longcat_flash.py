@@ -159,6 +159,57 @@ def test_one_request_goes_materialised_then_absorbed_then_the_scan(tmp_path):
     assert counter == {"materialised": 64.0, "absorbed": 86.0}  # two runs
 
 
+def test_a_scans_span_and_counter_say_what_the_kernel_fetches(tmp_path):
+    """Interpreted kernels: each fused scan's ``runner.dispatch`` span
+    carries ``kv_rows_fetched`` beside ``kv_tokens`` (whole sub-blocks
+    of each sequence's pool-resident rows: the host-side twin of the
+    kernel's list), single steps carry neither, and
+    ``vllm:mla_kv_rows_total`` adds up to the spans."""
+    from vllm_distributed_tpu.ops.pallas import mla_attention as mla
+    from vllm_distributed_tpu.tracing import get_tracer
+
+    d = testing.write_longcat_config(str(tmp_path), layers=1)
+    cfg = testing.longcat_config(layers=1)
+    rng = np.random.default_rng(6)
+    prompts = [list(map(int, rng.integers(3, 128, n))) for n in (20, 7)]
+    tracer = get_tracer()
+    tracer.reset()
+    with mock.patch.dict(os.environ, {"VDT_USE_PALLAS": "pallas_interpret"}):
+        engine = _engine(d, enable_tracing=True)
+        try:
+            _generate(engine, prompts, max_tokens=9)
+            spans = [s["attributes"] for tr in tracer.snapshot()
+                     for s in tr["spans"] if s["name"] == "runner.dispatch"]
+            engine.refresh_device_telemetry()
+            text = engine.metrics.render().decode()
+        finally:
+            engine.shutdown()
+            tracer.configure(False)
+            tracer.reset()
+    scans = [a for a in spans if "kv_rows_fetched" in a]
+    assert scans and all("_decode_" in a["program"] for a in scans)
+    assert all("kv_rows_fetched" in a for a in spans
+               if "_decode_" in a["program"])
+    for a in scans:
+        pages = int(a["program"].rsplit("_p", 1)[1])
+        # the two sequences decode side by side: the scan's kv_tokens
+        # split as their prompts differ, 13 tokens
+        lens = [(a["kv_tokens"] + 13) // 2, (a["kv_tokens"] - 13) // 2]
+        assert a["kv_rows_fetched"] == mla.mla_kv_rows_fetched(
+            lens, num_heads=cfg["num_attention_heads"],
+            value_width=cfg["kv_lora_rank"], lanes=128, itemsize=4,
+            page_size=16, max_pages=pages)
+        assert a["kv_rows_fetched"] >= a["kv_tokens"] > 0
+    counter = {}
+    for line in text.splitlines():
+        if line.startswith("vllm:mla_kv_rows_total{"):
+            kind = line.split('kind="')[1].split('"')[0]
+            counter[kind] = float(line.rsplit(" ", 1)[1])
+    assert counter == {
+        "live": float(sum(a["kv_tokens"] for a in scans)),
+        "fetched": float(sum(a["kv_rows_fetched"] for a in scans))}
+
+
 def test_picks_reach_the_metrics_and_the_fetch_spans(tmp_path):
     """``vllm:moe_picks_total`` by kind on ``/metrics``' text and the
     same counts as attributes of the steps' ``runner.fetch`` spans: the

@@ -54,6 +54,9 @@ def _batch(rng, seqs, h, w, wp, t_pad, s_pad, pages_pad, num_pages=64):
     dense, cursor, page = [], 0, 1
     for s, (cached, new) in enumerate(seqs):
         total = cached + new
+        if not total:  # an empty slot between live sequences
+            dense.append((np.zeros((0, w), np.float32), slice(cursor, cursor)))
+            continue
         n_pages = -(-total // PAGE)
         bt[s, :n_pages] = np.arange(page, page + n_pages)
         rows = rng.standard_normal((total, w)).astype(np.float32)
@@ -242,6 +245,142 @@ def test_tile_counts_are_the_wrappers_lists():
     assert (tiles, rows) == (40 + 7 + 2, 40 + 9 * 16)
     assert mla.mla_query_tile_counts(
         [1] * 64, max_q=1, num_heads=64, value_width=512) == (64, 64)
+
+
+# ---- the in-kernel walk over a tile's live sub-blocks ---------------------
+# 24 live lanes in a 256-lane float32 row: 1 KiB a token, so the buffer
+# slot's budget gives the 512-token sub-block of the LongCat widths
+_WALK = dict(h=4, w=24, wp=256, vw=16)
+_WALK_PAGES = 128  # the pages bucket: 2,048 tokens
+_EDGES = [0, 1, 15, 16, 17, 255, 256, 257, 511, 512, 513, 767, 768, 769,
+          1023, 1024, 1025,
+          _WALK_PAGES * PAGE - 1]
+
+
+def _walk_batch(rng, seqs, t_pad):
+    return _batch(
+        rng, seqs, _WALK["h"], _WALK["w"], _WALK["wp"], t_pad, 8,
+        _WALK_PAGES, num_pages=2 + sum(-(-(c + n) // PAGE) for c, n in seqs))
+
+
+def _assert_walk_matches(got, ref, live):
+    assert np.isfinite(got).all()
+    assert np.abs(got[live] - ref[live]).max() < 3e-5
+
+
+def test_the_walk_tests_sub_block_is_the_cells():
+    """The edges below are edges of the loop only if a sub-block is 512
+    tokens here as at the LongCat widths (bf16 rows of 640 lanes)."""
+    assert mla._kv_blocking(
+        _WALK["wp"], 4, _WALK["h"] * 2, PAGE, _WALK_PAGES) == (32, 512)
+    assert mla._kv_blocking(640, 2, 64, PAGE, 256) == (32, 512)
+
+
+@pytest.mark.parametrize("side", [False, True], ids=["step", "scan"])
+@pytest.mark.parametrize("cached", _EDGES)
+def test_decode_walk_matches_the_reference_at_every_edge(cached, side):
+    """The decode geometry over sequences whose cached lengths sit on
+    the edges of the in-kernel loop (no sub-block, one row, a page more
+    or less, half a sub-block, a whole one, one and a half and two of
+    them more or less, the longest the pages bucket holds), with empty slots between the live ones: behind
+    an empty slot the lookahead misses and the tile fetches its own
+    first sub-block; between live neighbours it hits.  ``scan``: the
+    pool holds ``cached`` rows and the staged side buffer (K = 8) five
+    more; ``step``: a decode-only single step, the row's own token in
+    the pool behind them."""
+    rng = np.random.default_rng(cached)
+    cfg = _WALK
+    # slots: the edge, empty, short, empty, empty, the edge, two
+    # neighbours that prefetch each other
+    lens = [cached, 0, 33, 0, 0, cached, 300, 17]
+    if not side:
+        seqs = [(n, 1) if (n or i in (0, 5)) else (0, 0)
+                for i, n in enumerate(lens)]
+        q, pool, meta, _ = _walk_batch(rng, seqs, 8)
+        live = np.asarray(meta.q_seq_ids) < 8
+        kw = dict(scale=0.3, value_width=cfg["vw"])
+        got = mla.mla_attention_cpu(q, pool, meta, max_q=1, **kw)
+        ref = mla_attention_reference(q, pool, meta, **kw)
+        _assert_walk_matches(np.asarray(got), np.asarray(ref), live)
+        assert live.sum() == 5 and not np.asarray(got)[~live].any()
+        return
+    _, pool, meta, _ = _walk_batch(rng, [(n, 0) for n in lens], 8)
+    n_side = 5
+    side_kv = np.zeros((8, 1, 8, cfg["wp"]), np.float32)
+    side_kv[:, 0, :n_side, :cfg["w"]] = rng.standard_normal(
+        (8, n_side, cfg["w"]))
+    q = rng.standard_normal((8, cfg["h"], cfg["w"])).astype(np.float32)
+    pool_lens = np.asarray(lens, np.int32)
+    # a sequence with nothing in the pool is no row of a scan: masked
+    ids = np.where(pool_lens > 0, np.arange(8), 8).astype(np.int32)
+    meta = AttentionMetadata(
+        q_seq_ids=jnp.asarray(ids),
+        q_positions=jnp.asarray(pool_lens + n_side - 1),
+        slot_mapping=meta.slot_mapping, block_tables=meta.block_tables,
+        seq_lens=jnp.asarray(pool_lens), logits_indices=meta.logits_indices,
+        chunk_starts=jnp.asarray(pool_lens + n_side - 1))
+    kw = dict(scale=0.3, value_width=cfg["vw"], side_kv=jnp.asarray(side_kv),
+              side_len=jnp.asarray([n_side], jnp.int32))
+    got = np.asarray(mla.mla_attention_cpu(
+        jnp.asarray(q), pool, meta, max_q=1, **kw))
+    ref = np.asarray(mla_attention_reference(jnp.asarray(q), pool, meta, **kw))
+    _assert_walk_matches(got, ref, ids < 8)
+    assert not got[ids == 8].any()
+
+
+@pytest.mark.parametrize(
+    "prefix", [16, 255, 256, 257, 511, 513, 767, 769, 1023, 1025])
+def test_chunk_tiles_walk_a_cached_prefix_to_their_causal_bound(
+        prefix, monkeypatch):
+    """The chunk-tile geometry (32 query tokens a tile here): a chunk
+    behind a cached prefix on an edge of the loop walks the prefix and
+    its own rows up to each tile's last token, beside a fresh chunk, a
+    second prefixed chunk and one-token rows (the decode geometry's own
+    call), an empty slot between them."""
+    rng = np.random.default_rng(prefix)
+    seqs = [(prefix, 70), (0, 0), (0, 30), (300, 1), (511, 9), (0, 1)]
+    q, pool, meta, _ = _walk_batch(rng, seqs, 128)
+    # a state budget of 32 query tokens a tile: the 70-token chunk is
+    # three tiles, each with a causal bound of its own
+    monkeypatch.setattr(mla, "_STATE_BYTES", 32 * _WALK["h"] * (
+        _WALK["vw"] + 2 * mla._LANES) * 4)
+    assert mla._tile_tokens(128, _WALK["h"], _WALK["vw"]) == 32
+    kw = dict(scale=0.3, value_width=_WALK["vw"])
+    got = np.asarray(mla.mla_attention_cpu(q, pool, meta, max_q=128, **kw))
+    ref = np.asarray(mla_attention_reference(q, pool, meta, **kw))
+    n = sum(new for _, new in seqs)
+    _assert_walk_matches(got, ref, np.arange(128) < n)
+    assert not got[n:].any()
+
+
+def test_rows_fetched_are_the_wrappers_list():
+    """``mla_kv_rows_fetched`` (the decode ``runner.dispatch`` span's
+    ``kv_rows_fetched``) against the lists the wrapper hands the kernel
+    and the kernel's own rule over them: whole 512-token sub-blocks of
+    each sequence's live rows at the LongCat widths, the last one half
+    where the rows end in its first half, nothing for an empty slot."""
+    kw = dict(num_heads=64, value_width=512, lanes=640, itemsize=2,
+              page_size=PAGE, max_pages=256)
+    lens = [0, 1, 256, 257, 511, 512, 513, 680, 768, 769, 1024, 1025, 3840,
+            4096]
+    rows = [0, 256, 256, 512, 512, 512, 768, 768, 768, 1024, 1024, 1280, 3840,
+            4096]
+    assert [mla.mla_kv_rows_fetched([n], **kw) for n in lens] == rows
+    assert mla.mla_kv_rows_fetched(lens, **kw) == sum(rows)
+    s = len(lens)
+    nb = np.asarray(mla.tile_kv_blocks(
+        jnp.arange(s, dtype=jnp.int32), jnp.zeros(s, jnp.int32),
+        jnp.full((1,), s, jnp.int32), jnp.asarray(lens, jnp.int32),
+        jnp.asarray(lens, jnp.int32), 1, 512))
+    # the kernel's ``is_short`` over (tile_nb, tile_rows): the last
+    # sub-block stops at half its pages
+    short = (nb > 0) & (np.asarray(lens) - (nb - 1) * 512 <= 256)
+    assert int((nb * 512 - short * 256).sum()) == sum(rows)
+    # a short pages bucket caps the sub-block at what it holds
+    assert mla.mla_kv_rows_fetched([1, 100], **{**kw, "max_pages": 8}) == (
+        64 + 128)
+    assert mla.mla_kv_rows_fetched([1, 600], **{**kw, "max_pages": 64}) == (
+        256 + 768)
 
 
 # ---- the materialised form: fresh chunks over the flat batch ---------------

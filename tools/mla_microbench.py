@@ -17,8 +17,24 @@ tile gathers alone.  Materialised: the whole sublayer, the K/V
 up-projection alone, ``vdt_mla_chunk_attention`` alone over a sweep of
 (query tile, key block, heads a step), and what is left (layout).
 
+``--ctx-mix`` instead times the fused scan's call alone at the
+benchmark cell's ragged lengths (PERF.md, PR 39): the 64 cached lengths
+are the (prompt, output) pairs of ``benchmark/traffic/chat-closed-64.json``
+with a seeded part of each output behind its prompt, in the cell's own
+program shape (64 sequences, a 256-page table, the staged side buffer).
+Eight calls a jitted function, as a micro-step's eight sublayers make
+them; it prints the time a call on the host's clock (``call_us``: the
+kernel and the gathers round it), the kernel's own device time from a
+traced pass (``kernel_us``: what ``decode_mla_ms`` counts), the rows its
+DMAs fetch, the live rows and their GB/s, and the largest difference
+from ``mla_attention_reference`` on the device (``max_abs_err``).
+``--kv-buf-kib`` sweeps the kernel's buffer-slot budget (the sub-block's
+size) in the same process.
+
 Usage: python tools/mla_microbench.py [--tokens 64 256 2048] [--ctx 1024]
        python tools/mla_microbench.py --fresh [--tokens 256 1024 2048]
+       python tools/mla_microbench.py --ctx-mix [--seed 0 1 2]
+           [--kv-buf-kib 160 320 640 1280]
        python tools/mla_microbench.py --cpu-rehearsal [--fresh]  (toy
        widths, interpreted kernel: counts nothing, proves the script runs)
 """
@@ -43,9 +59,11 @@ from vllm_distributed_tpu.ops.attention import (
     AttentionMetadata,
     bind_max_q,
     mla_absorb_queries,
+    mla_attention_reference,
     mla_sublayer_attention,
     mla_unabsorb_outputs,
 )
+from vllm_distributed_tpu.ops.pallas import mla_attention as mla
 from vllm_distributed_tpu.ops.pallas.mla_attention import (
     mla_attention,
     mla_attention_cpu,
@@ -212,17 +230,128 @@ def fresh_case(args, toy: bool) -> dict:
     return {"device": str(jax.devices()[0]), "ctx": ctx, "fresh": out}
 
 
+def cell_ctx_lens(seed: int, seqs: int) -> list[int]:
+    """``seqs`` cached lengths as the cell's traffic leaves them in a
+    scan: each (prompt, output) pair of the mix's pool with a seeded
+    part of its output behind the prompt."""
+    from benchmark.loadgen import length_pool
+
+    mix = json.load(open(os.path.join(
+        os.path.dirname(__file__), "..", "benchmark", "traffic",
+        "chat-closed-64.json")))
+    pool = length_pool(mix)
+    rng = np.random.default_rng(seed)
+    picks = [pool[i % len(pool)] for i in rng.permutation(max(
+        seqs, len(pool)))[:seqs]]
+    return [p + int(rng.integers(0, max(o, 1))) for p, o in picks]
+
+
+def _kernel_us(fn, *args) -> float | None:
+    """Median device time of one ``vdt_mla_attention`` call in a traced
+    pass: the call without the gathers around it, as the benchmark's
+    ``decode_mla_ms`` counts it."""
+    import statistics
+    import tempfile
+
+    from benchmark.trace_reduce import find_xplane, load_events
+
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            for _ in range(5):
+                out = fn(*args)
+            jax.block_until_ready(out)
+        events = load_events(find_xplane(d))
+    durs = [dur for plane in events["devices"].values()
+            for name, _, dur in plane.get("XLA Ops", [])
+            if name.startswith("vdt_mla_attention")]
+    return statistics.median(durs) / 1e3 if durs else None
+
+
+def ctx_mix_case(args, toy: bool) -> dict:
+    """The fused scan's call at the cell's ragged lengths."""
+    h, w, wp, vw = (4, 24, 128, 16) if toy else (64, 576, 640, 512)
+    dtype = jnp.float32 if toy else jnp.bfloat16
+    s_dec, pages_pad, layers = (8, 16, 2) if toy else (64, 256, 8)
+    calls = 1 if toy else args.calls
+    itemsize = jnp.dtype(dtype).itemsize
+    key = jax.random.PRNGKey(0)
+    out = {"device": str(jax.devices()[0]), "seqs": s_dec,
+           "pages_pad": pages_pad, "calls_a_jit": layers, "ctx_mix": []}
+    budgets = args.kv_buf_kib or [mla._KV_BUF_BYTES // 1024]
+    for seed in args.seed:
+        lens = cell_ctx_lens(seed, s_dec)
+        if toy:
+            lens = [n % (pages_pad * PAGE - 8) for n in lens]
+        meta, pages = _step_meta(
+            [(n, 1) for n in lens], s_dec, s_dec, pages_pad)
+        # the scan's call: ``seq_lens`` is the pool-resident length
+        meta = dataclasses.replace(meta, seq_lens=meta.seq_lens - 1)
+        pool = jax.random.normal(
+            key, (1, pages + 1, PAGE, wp), jnp.float32).astype(dtype)
+        q = jax.random.normal(
+            key, (layers, s_dec, h, w), jnp.float32).astype(dtype)
+        side = jnp.zeros((s_dec, 1, 8, wp), dtype)
+        side_len = jnp.asarray([4], jnp.int32)
+        for kib in budgets:
+            mla._KV_BUF_BYTES = kib * 1024
+            jax.clear_caches()
+            blk = mla._kv_blocking(wp, itemsize, h, PAGE, pages_pad)[-1]
+            fetched = mla.mla_kv_rows_fetched(
+                lens, num_heads=h, value_width=vw, lanes=wp,
+                itemsize=itemsize, page_size=PAGE, max_pages=pages_pad)
+
+            def sublayers(q, kv, meta, side, side_len):
+                return [mla_attention(
+                    q[i], kv, meta, scale=0.07, value_width=vw, max_q=1,
+                    side_kv=side, side_len=side_len, interpret=toy)
+                    for i in range(layers)]
+
+            fn = jax.jit(sublayers)
+            sec = _timed(fn, q, pool, meta, side, side_len,
+                         calls=calls) / layers
+            kernel_us = None if toy else _kernel_us(
+                fn, q, pool, meta, side, side_len)
+            ref = mla_attention_reference(
+                q[0], pool, meta, scale=0.07, value_width=vw, side_kv=side,
+                side_len=side_len)
+            err = float(jnp.max(jnp.abs(
+                fn(q, pool, meta, side, side_len)[0].astype(jnp.float32)
+                - ref.astype(jnp.float32))))
+            live = sum(lens)
+            out["ctx_mix"].append({
+                "seed": seed, "kv_buf_kib": kib, "sub_block": blk,
+                "call_us": sec * 1e6, "kernel_us": kernel_us,
+                "max_abs_err": err, "rows_live": live,
+                "rows_fetched": fetched, "fill": live / fetched,
+                "longest": max(lens), "past_1024": sum(
+                    n > 1024 for n in lens),
+                "live_gb_per_s": live * w * itemsize / sec / 1e9})
+    return out
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--tokens", type=int, nargs="+", default=None)
     ap.add_argument("--fresh", action="store_true")
     ap.add_argument("--ctx", type=int, default=1024)
     ap.add_argument("--calls", type=int, default=20)
+    ap.add_argument("--ctx-mix", action="store_true")
+    ap.add_argument("--attention-only", action="store_true",
+                    help="skip the routed layer")
+    ap.add_argument("--seed", type=int, nargs="+", default=[0])
+    ap.add_argument("--kv-buf-kib", type=int, nargs="+", default=None)
     ap.add_argument("--cpu-rehearsal", action="store_true")
     args = ap.parse_args()
     toy = args.cpu_rehearsal
     if toy:
         os.environ["JAX_PLATFORMS"] = "cpu"
+    if args.ctx_mix:
+        out = ctx_mix_case(args, toy)
+        print(json.dumps(out, indent=1))
+        os.makedirs("chiprun_out", exist_ok=True)
+        with open("chiprun_out/mla_microbench_ctx_mix.json", "w") as f:
+            json.dump(out, f, indent=1)
+        return 0
     if args.fresh:
         args.tokens = args.tokens or [256, 1024, 2048]
         out = fresh_case(args, toy)
@@ -277,6 +406,10 @@ def main() -> int:
             "call": "step", "tokens": t, "chunk": new, "ctx": ctx,
             "ms": sec * 1e3,
             "tflops": pairs * h * (w + vw) * 2 / sec / 1e12})
+
+    if args.attention_only:
+        print(json.dumps(out, indent=1))
+        return 0
 
     # the routed layer
     from vllm_distributed_tpu import testing

@@ -141,6 +141,7 @@ class LLMEngine:
         self._telemetry_cache_seen: dict[str, int] = {}
         self._telemetry_moe_picks_seen: dict[str, int] = {}
         self._telemetry_mla_chunk_seen: dict[str, int] = {}
+        self._telemetry_mla_kv_rows_seen: dict[str, int] = {}
         self._telemetry_expert_picks_seen: dict[str, int] = {}
         self._telemetry_state_rows_seen: dict[str, int] = {}
         self._telemetry_moe_grouped_seen = 0
@@ -634,6 +635,11 @@ class LLMEngine:
                 "mla_chunk_tokens",
                 self._telemetry_mla_chunk_seen,
                 self.metrics.record_mla_chunk_tokens,
+            ),
+            (
+                "mla_kv_rows",
+                self._telemetry_mla_kv_rows_seen,
+                self.metrics.record_mla_kv_rows,
             ),
             (
                 "moe_expert_picks",

@@ -158,6 +158,7 @@ DOCUMENTED_METRICS = (
     "vllm:moe_expert_picks_total",
     "vllm:seq_state_rows_total",
     "vllm:mla_chunk_tokens_total",
+    "vllm:mla_kv_rows_total",
     # ---- fleet sentinel (ISSUE 20) ----
     "vllm:slo_burn_rate",
     "vllm:itl_p99_ms",
@@ -635,6 +636,16 @@ class EngineMetrics:
             ["model_name", "form"],
             registry=self.registry,
         )
+        self._mla_kv_rows = Counter(
+            "vllm:mla_kv_rows",
+            "Cached rows of the fused decode scans the reply-rank worker "
+            "dispatched, one latent (MLA) attention call's worth a scan: "
+            "live (the sequences' pool-resident tokens), fetched (the "
+            "rows the kernel's DMAs move for them: whole sub-blocks); "
+            "live / fetched is the fill",
+            ["model_name", "kind"],
+            registry=self.registry,
+        )
         # ---- fleet sentinel (ISSUE 20) ----
         self._slo_burn = Gauge(
             "vllm:slo_burn_rate",
@@ -969,6 +980,12 @@ class EngineMetrics:
         if self.enabled and n > 0:
             self._mla_chunk_tokens.labels(
                 model_name=self._model_name, form=form
+            ).inc(n)
+
+    def record_mla_kv_rows(self, kind: str, n: int) -> None:
+        if self.enabled and n > 0:
+            self._mla_kv_rows.labels(
+                model_name=self._model_name, kind=kind
             ).inc(n)
 
     def record_moe_grouped_steps(self, n: int) -> None:

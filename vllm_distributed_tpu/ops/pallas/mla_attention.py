@@ -8,14 +8,32 @@ The queries arrive absorbed (``[q_nope · W_uk, q_rope]``, ``W`` lanes a
 head), so every head scores the same row: a page is fetched ONCE for all heads, and a
 query tile's softmax chain has ``heads × tokens`` rows over it.
 
-The grid, the ragged list of query tiles it walks, the globally
-rotating triple buffer and the one-step prefetch are
-ops/pallas/paged_attention.py's (its docstring has the reasons; the
-list code is imported from there, not copied): a tile is ``mq_blk``
-consecutive query tokens of one sequence; ``max_q == 1`` (the fused
-decode scan, a decode-only step) is one tile a sequence; in chunked
-steps single-token sequences leave the chunk tiles for a second call in
-the decode geometry.  No XLA ``while`` is made here either.
+The ragged list of query tiles, the globally rotating triple buffer
+and the one-fetch lookahead are ops/pallas/paged_attention.py's (its
+docstring has the reasons; the list code is imported from there, not
+copied): a tile is ``mq_blk`` consecutive query tokens of one sequence;
+``max_q == 1`` (the fused decode scan, a decode-only step) is one tile a
+sequence; in chunked steps single-token sequences leave the chunk tiles
+for a second call in the decode geometry.  No XLA ``while`` is made
+here either.
+
+**The grid is the tile list alone; the walk over a tile's cached rows
+is a loop inside the kernel** (PR 39).  A grid step is one query tile;
+in it a ``fori_loop`` runs over the tile's live kv sub-blocks, as many
+as ``tile_kv_blocks`` counts for it (a dynamic trip count: 0 for a tile
+past the list or of an empty sequence), so a sequence costs what its
+length costs: DMAs, score columns and loop trips for its live
+sub-blocks and nothing for the rest of the pages bucket.  While the
+walk was a grid axis, a fine block paid a grid step each (~0.35 us,
+worked or skipped) and a coarse one paid dead rows: at the LongCat
+cell's lengths (~650 cached tokens a sequence in a 4,096-token bucket)
+1,024-token blocks skipped three grid steps in four and fetched 1.9 x
+the live rows.  The lookahead is one sub-block (this tile's next, else
+the first of the next tile if that walks any); where it misses, the
+tile fetches its own first sub-block (``_bootstrap_dma``).  A tile's
+last sub-block is fetched and folded in at half width where its rows
+end in the first half.  The staged side buffer's columns ride the first
+sub-block's softmax update.
 
 What differs: one DMA a page instead of two, no head folding (there is
 one key/value row), no int8 form, and the score is two dots — the
@@ -73,13 +91,16 @@ _MASK = -0.7 * float(jnp.finfo(jnp.float32).max)
 _LANES = 128
 _NBUF = 3
 # Per-slot budget of the rotating page buffer, and of the f32 score /
-# probability tile [rows, kv block] a grid step holds.  A latent row is
-# 1,280 B, so a whole 1,024-token sequence is one block of the decode
-# geometry: a grid step costs ~0.35 us whether it works or is skipped,
-# as much as reading 256 rows (with 256-token blocks the fused scan's
-# call spent most of its 0.36 ms on steps, tools/mla_microbench.py, my
-# chip run, PR 34).
-_KV_BUF_BYTES = 1536 * 1024
+# probability tile [rows, kv sub-block] a loop trip holds.  A latent row
+# is 1,280 B: 512 tokens a sub-block.  The walk is a loop in the kernel,
+# so the size no longer buys grid steps; it trades dead rows in each
+# sequence's last sub-block against what a trip costs beside its
+# columns (~0.4 us: the softmax chain, the MXU's fill and drain).  With
+# the last sub-block cut to half where it can be, over the LongCat
+# cell's lengths: 256 tokens fill 0.90 and the fused scan's call takes
+# 172 us, 512 fill 0.85 and 143 us, 1,024 fill 0.73 and 144 us (220 us
+# before PR 39; tools/mla_microbench.py --ctx-mix, my chip run, PR 39).
+_KV_BUF_BYTES = 640 * 1024
 _SCORE_BYTES = 2 * 1024 * 1024
 # Budget of the f32 flash state (acc [rows, value_width] + m + l).
 _STATE_BYTES = 3 * 1024 * 1024
@@ -90,7 +111,8 @@ def _kernel(
     # scalar prefetch (+ side_len_ref when has_side)
     tile_seq_ref,  # [NT] int32: sequence of each query tile
     tile_q0_ref,  # [NT] int32: the tile's first token within its chunk
-    tile_nb_ref,  # [NT] int32: kv blocks the tile walks (0: skipped)
+    tile_nb_ref,  # [NT] int32: kv sub-blocks the tile walks (0: none)
+    tile_rows_ref,  # [NT] int32: cached rows the tile walks
     n_live_ref,  # [1] int32: tiles in use
     block_tables_ref,  # [S, max_pages] int32
     seq_lens_ref,  # [S] int32
@@ -100,7 +122,7 @@ def _kernel(
     # [1, 1, K, WP] when has_side, kv_pages_ref [1, P, page, WP] ANY, the
     # out block [1, ROWS, VW], and scratch: kv_vmem [NBUF, BLK, WP],
     # m/l [ROWS, LANES] f32, acc [ROWS, VW] f32, DMA sems [NBUF], cnt
-    # SMEM [2] = [completed active blocks, prefetch-pending flag].
+    # SMEM [2] = [completed sub-blocks, prefetch-pending flag].
     *rest,
     scale: float,
     page_size: int,
@@ -117,58 +139,67 @@ def _kernel(
     kv_pages_ref = rest.pop(0)
     out_ref, kv_vmem, m_scr, l_scr, acc_scr, sems, cnt = rest
     tile = pl.program_id(0)
-    kvb = pl.program_id(1)
     num_tiles = pl.num_programs(0)
-    num_kvb = pl.num_programs(1)
     blk = pages_per_blk * page_size
     vw = value_width
     in_list = tile < n_live_ref[0]
     s = tile_seq_ref[tile]
     nb = tile_nb_ref[tile]
-    active = kvb < nb
 
-    @pl.when((tile == 0) & (kvb == 0))
+    @pl.when(tile == 0)
     def _boot():
         cnt[0] = 0
         cnt[1] = 0
 
-    def block_dma(seq, block_idx, buf):
-        """One descriptor a page: the row is key and value at once."""
-        copies = []
-        for i in range(pages_per_blk):
-            page = block_tables_ref[seq, block_idx * pages_per_blk + i]
-            copies.append(
+    def is_short(tile_idx, block_idx):
+        """Whether the tile's rows end in the first half of this
+        sub-block, which is then fetched and folded in at half width
+        (``None``: a sub-block of one page has no half)."""
+        if pages_per_blk % 2:
+            return None
+        return tile_rows_ref[tile_idx] - block_idx * blk <= blk // 2
+
+    def start_fetch(seq, block_idx, buf, short):
+        """Sub-block ``block_idx`` of ``seq``'s rows into slot ``buf``:
+        one descriptor a page (the row is key and value at once), all on
+        the slot's semaphore; a ``short`` one stops at half the pages.
+        The page ids are read before the first descriptor goes out: read
+        one by one between the descriptors, each waited for its scalar
+        load (~3% of the call, my chip run, PR 39)."""
+        first = block_idx * pages_per_blk
+
+        def issue(lo, hi):
+            pages = [block_tables_ref[seq, first + i] for i in range(lo, hi)]
+            for i, page in zip(range(lo, hi), pages):
                 pltpu.make_async_copy(
                     kv_pages_ref.at[0, page],
                     kv_vmem.at[buf, pl.ds(i * page_size, page_size)],
                     sems.at[buf],
-                )
-            )
-        return copies
+                ).start()
 
-    @pl.when(in_list & (kvb == 0))
+        if short is None:
+            issue(0, pages_per_blk)
+        else:
+            issue(0, pages_per_blk // 2)
+            pl.when(jnp.logical_not(short))(
+                lambda: issue(pages_per_blk // 2, pages_per_blk)
+            )
+
+    @pl.when(in_list)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _MASK)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(active & (cnt[1] == 0))
+    # Nobody fetched this tile's first sub-block ahead: the call's first
+    # live tile, or one behind a tile that walks nothing.
+    @pl.when((nb > 0) & (cnt[1] == 0))
     def _bootstrap_dma():
-        for cp in block_dma(s, kvb, cnt[0] % _NBUF):
-            cp.start()
+        start_fetch(s, 0, cnt[0] % _NBUF, is_short(tile, 0))
 
-    next_in_tile = kvb + 1 < nb
     next_tile = jnp.minimum(tile + 1, num_tiles - 1)
-    has_next = next_in_tile | (
-        (tile + 1 < num_tiles) & (tile_nb_ref[next_tile] > 0)
-    )
-    next_s = jnp.where(next_in_tile, s, tile_seq_ref[next_tile])
-    next_kvb = jnp.where(next_in_tile, kvb + 1, 0)
-
-    @pl.when(active & has_next)
-    def _prefetch():
-        for cp in block_dma(next_s, next_kvb, (cnt[0] + 1) % _NBUF):
-            cp.start()
+    next_tile_live = (tile + 1 < num_tiles) & (tile_nb_ref[next_tile] > 0)
+    next_tile_seq = tile_seq_ref[next_tile]
 
     def row_positions(ncols):
         """Row r holds query token r // heads of the tile."""
@@ -180,57 +211,116 @@ def _kernel(
         )
         return q_pos, col_ids
 
-    def flash_update(c, kr, mask):
-        """One online-softmax step over rows ``[c | kr]`` ([ncols, VW],
-        [ncols, W - VW]) of the pool's dtype."""
+    def flash_update(groups):
+        """One online-softmax step over the column groups ``(c, kr,
+        mask)``: rows ``[c | kr]`` ([ncols, VW], [ncols, W - VW]) of
+        the pool's dtype.  Groups share the step's maximum and its one
+        rescale of the state: their dots and exponents do not wait for
+        each other."""
         nt = (((1,), (1,)), ((), ()))
-        scores = jax.lax.dot_general(
-            q_ref[0, :, :vw], c, nt, preferred_element_type=jnp.float32
-        ) + jax.lax.dot_general(
-            q_ref[0, :, vw:], kr, nt, preferred_element_type=jnp.float32
-        )  # [ROWS, ncols]
-        scores = jnp.where(mask, scores * scale, _MASK)
+        scores = [
+            jnp.where(
+                mask,
+                (
+                    jax.lax.dot_general(
+                        q_ref[0, :, :vw], c, nt,
+                        preferred_element_type=jnp.float32,
+                    )
+                    + jax.lax.dot_general(
+                        q_ref[0, :, vw:], kr, nt,
+                        preferred_element_type=jnp.float32,
+                    )
+                ) * scale,
+                _MASK,
+            )
+            for c, kr, mask in groups
+        ]  # [ROWS, ncols] each
         m_prev = m_scr[:, 0:1]
-        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(mask, jnp.exp(scores - m_new), 0.0)
-        l_new = l_scr[:, 0:1] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        m_new = functools.reduce(
+            jnp.maximum,
+            [jnp.max(sc, axis=-1, keepdims=True) for sc in scores],
+            m_prev,
         )
-        acc_scr[:] = acc_scr[:] * alpha + pv
+        alpha = jnp.exp(m_prev - m_new)
+        l_new = l_scr[:, 0:1] * alpha
+        acc = acc_scr[:] * alpha
+        for sc, (c, _, mask) in zip(scores, groups):
+            p = jnp.where(mask, jnp.exp(sc - m_new), 0.0)
+            l_new = l_new + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc + jax.lax.dot_general(
+                p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        acc_scr[:] = acc
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(active)
-    def _compute():
+    def side_group():
+        """This dispatch's rows, staged in the side buffer (positions
+        seq_len + j; seq_len is the POOL length when staging)."""
+        n_side = side_len_ref[0]
+        seq_len = seq_lens_ref[s]
+        q_pos, col_ids = row_positions(side_ref.shape[2])
+        mask = (
+            (col_ids < n_side) & (seq_len + col_ids <= q_pos) & (seq_len > 0)
+        )
+        return side_ref[0, 0, :, :vw], side_ref[0, 0, :, vw:width], mask
+
+    def sub_block(i, carry, *, with_side=False):
+        """Sub-block ``i`` of this tile: fetch the one behind it (this
+        tile's next, else the first of the next tile if that walks
+        any), then wait for this one's pages and fold them in."""
         buf = cnt[0] % _NBUF
-        for cp in block_dma(s, kvb, buf):
-            cp.wait()
-        q_pos, col_ids = row_positions(blk)
-        c_pos = kvb * blk + col_ids
-        mask = (c_pos <= q_pos) & (c_pos < seq_lens_ref[s])
-        flash_update(kv_vmem[buf, :, :vw], kv_vmem[buf, :, vw:width], mask)
+        next_in_tile = i + 1 < nb
+        has_next = next_in_tile | next_tile_live
+        next_s = jnp.where(next_in_tile, s, next_tile_seq)
+        next_i = jnp.where(next_in_tile, i + 1, 0)
+        next_short = is_short(jnp.where(next_in_tile, tile, next_tile), next_i)
+
+        @pl.when(has_next)
+        def _prefetch():
+            start_fetch(next_s, next_i, (cnt[0] + 1) % _NBUF, next_short)
+
+        def fold(ncols):
+            # One wait for the slot's pages: their copies signal one
+            # semaphore, and a wait takes off it what its descriptor moves.
+            rows = kv_vmem.at[buf, pl.ds(0, ncols)]
+            pltpu.make_async_copy(rows, rows, sems.at[buf]).wait()
+            q_pos, col_ids = row_positions(ncols)
+            c_pos = i * blk + col_ids
+            mask = (c_pos <= q_pos) & (c_pos < seq_lens_ref[s])
+            groups = [(
+                kv_vmem[buf, :ncols, :vw], kv_vmem[buf, :ncols, vw:width], mask
+            )]
+            if with_side:
+                groups.append(side_group())
+            flash_update(groups)
+
+        short = is_short(tile, i)
+        if short is None:
+            fold(blk)
+        else:
+            pl.when(short)(lambda: fold(blk // 2))
+            pl.when(jnp.logical_not(short))(lambda: fold(blk))
         cnt[0] = cnt[0] + 1
         cnt[1] = has_next.astype(jnp.int32)
+        return carry
 
-    @pl.when(in_list & (kvb == num_kvb - 1))
+    # The walk over the tile's cached rows: as many sub-blocks as its
+    # sequence has live, a dynamic trip count (0 for a tile past the
+    # list or of a sequence with no pool history).  The staged side
+    # buffer's columns ride the first sub-block's update (a sequence
+    # with no pool history has no staged rows either): one softmax
+    # chain a tile less.
+    if has_side:
+        @pl.when(nb > 0)
+        def _first():
+            sub_block(0, 0, with_side=True)
+
+    jax.lax.fori_loop(1 if has_side else 0, nb, sub_block, 0)
+
+    @pl.when(in_list)
     def _finalize():
-        if has_side:
-            # This dispatch's rows live in the side buffer (positions
-            # seq_len + j; seq_len is the POOL length when staging).
-            n_side = side_len_ref[0]
-            seq_len = seq_lens_ref[s]
-            q_pos, col_ids = row_positions(side_ref.shape[2])
-            smask = (
-                (col_ids < n_side)
-                & (seq_len + col_ids <= q_pos)
-                & (seq_len > 0)
-            )
-            flash_update(
-                side_ref[0, 0, :, :vw], side_ref[0, 0, :, vw:width], smask
-            )
         denom = jnp.maximum(l_scr[:, 0:1], 1e-30)
         out_ref[0] = (acc_scr[:] / denom).astype(out_ref.dtype)
 
@@ -252,17 +342,15 @@ def _tile_tokens(max_q: int, num_heads: int, value_width: int) -> int:
 
 def _kv_blocking(width: int, itemsize: int, rows: int, page_size: int,
                  max_pages: int):
-    """(pages a kv block, kv blocks, tokens a block): as many tokens as
-    the buffer slot and the score tile's budget hold."""
+    """(pages a kv sub-block, tokens a sub-block): as many tokens as
+    the buffer slot and the score tile's budget hold, whole pages."""
     lanes = cdiv(width, _LANES) * _LANES
     tokens = min(
         _KV_BUF_BYTES // (lanes * itemsize), _SCORE_BYTES // (rows * 4)
     )
     tokens = min(_pow2_floor(max(tokens, page_size)), max_pages * page_size)
     pages_per_blk = max(tokens // page_size, 1)
-    return pages_per_blk, cdiv(max_pages, pages_per_blk), (
-        pages_per_blk * page_size
-    )
+    return pages_per_blk, pages_per_blk * page_size
 
 
 def _vmem_limit_bytes(
@@ -300,6 +388,29 @@ def mla_query_tile_counts(
     return singles + chunks, singles * mq_a + chunks * mq_b
 
 
+def mla_kv_rows_fetched(
+    kv_lens, *, num_heads: int, value_width: int, lanes: int, itemsize: int,
+    page_size: int, max_pages: int,
+) -> int:
+    """Rows the kernel's DMAs move in one call of the decode geometry
+    over sequences of ``kv_lens`` pool-resident tokens (whole
+    sub-blocks of each sequence's live rows, the last one half a
+    sub-block where the rows end in its first half): host-side twin of
+    the wrapper's lists, for the decode ``runner.dispatch`` span;
+    ``sum(kv_lens)`` over it is the fill."""
+    rows = num_heads * _tile_tokens(1, num_heads, value_width)
+    pages_per_blk, blk = _kv_blocking(
+        lanes, itemsize, rows, page_size, max_pages
+    )
+    half = blk if pages_per_blk % 2 else blk // 2
+
+    def fetched(n):
+        full, rest = divmod(n, blk)
+        return full * blk + (0 if not rest else half if rest <= half else blk)
+
+    return sum(fetched(n) for n in kv_lens)
+
+
 def _attend_tiles(
     q_tiles: jax.Array,  # [NT, mq_blk, H, W]
     tiles,  # (tile_seq [NT], tile_q0 [NT], n_live [1])
@@ -320,7 +431,7 @@ def _attend_tiles(
     rows = h * mq_blk
     vw = value_width
     itemsize = jnp.dtype(kv_pages.dtype).itemsize
-    pages_per_blk, num_kvb, blk = _kv_blocking(
+    pages_per_blk, blk = _kv_blocking(
         wp, itemsize, rows, page_size, max_pages
     )
     block_tables = metadata.block_tables
@@ -335,12 +446,12 @@ def _attend_tiles(
     q_rows = q_tiles.reshape(num_tiles, rows, w).astype(kv_pages.dtype)
 
     def live_tile(tile, refs):
-        return jnp.minimum(tile, jnp.maximum(refs[3][0] - 1, 0))
+        return jnp.minimum(tile, jnp.maximum(refs[4][0] - 1, 0))
 
-    def tile_map(tile, b_, *refs):
+    def tile_map(tile, *refs):
         return (live_tile(tile, refs), 0, 0)
 
-    def seq_map(tile, b_, *refs):
+    def seq_map(tile, *refs):
         return (refs[0][tile], 0, 0, 0)
 
     tile_seq, tile_q0, n_live = tiles
@@ -351,6 +462,12 @@ def _attend_tiles(
             tile_seq, tile_q0, n_live, metadata.seq_lens,
             metadata.chunk_starts, mq_blk, blk,
         ),
+        # the rows those sub-blocks hold for the tile: its causal bound,
+        # cut at the sequence's length (``tile_kv_blocks``' own span)
+        jnp.minimum(
+            metadata.seq_lens[tile_seq],
+            metadata.chunk_starts[tile_seq] + tile_q0 + mq_blk,
+        ).astype(jnp.int32),
         n_live,
         block_tables,
         metadata.seq_lens,
@@ -381,7 +498,7 @@ def _attend_tiles(
         name="vdt_mla_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
-            grid=(num_tiles, num_kvb),
+            grid=(num_tiles,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, rows, vw), tile_map),
             scratch_shapes=[

@@ -650,6 +650,31 @@ class ModelRunner:
         )
         return dict(chunk_tokens_fresh=fresh, chunk_tokens_prefixed=prefixed)
 
+    def _mla_kv_rows(self, kv_lens, pages_pad: int) -> dict:
+        """A fused scan's cached rows as the latent kernel's DMAs fetch
+        them in one sublayer call (whole sub-blocks of each sequence's
+        live rows, the last one cut to half where it can be): the span's ``kv_rows_fetched`` beside its
+        ``kv_tokens``, and the counter's two kinds."""
+        from vllm_distributed_tpu.ops.pallas.mla_attention import (
+            mla_kv_rows_fetched,
+        )
+
+        m = self.model
+        kv_lens = kv_lens.tolist()
+        fetched = mla_kv_rows_fetched(
+            kv_lens,
+            num_heads=m.num_heads,
+            value_width=m.kv_lora_rank,
+            lanes=self._kv_layout.width,
+            itemsize=jnp.dtype(self.kv_cache_dtype()).itemsize,
+            page_size=self.page_size,
+            max_pages=pages_pad,
+        )
+        self.telemetry.record_mla_kv_rows(
+            {"live": sum(kv_lens), "fetched": fetched}
+        )
+        return dict(kv_rows_fetched=fetched)
+
     def _pick_kv_flush_fn(self):
         """Per-dispatch flush of the staged decode side buffers.  None
         under the XLA reference backend, whose decode scan scatters each
@@ -2244,6 +2269,8 @@ class ModelRunner:
             seqs=s_real, tokens=int(n_active.sum()),
             kv_tokens=kv_tokens_scanned,
         )
+        if getattr(self._attn_fn, "from_heads", None):
+            counts.update(self._mla_kv_rows(base_lens[:s_real], pages_pad))
         if self._state_layout is not None:
             counts.update(self._count_state_rows(starts))
         prep.end()

@@ -139,6 +139,7 @@ class DeviceTelemetry:
         self.moe_expert_picks: dict[str, int] = {}
         self.seq_state_rows: dict[str, int] = {}
         self.mla_chunk_tokens: dict[str, int] = {}
+        self.mla_kv_rows: dict[str, int] = {}
         _install_cache_listener()
 
     def record_fallback(self, kind: str) -> None:
@@ -170,6 +171,12 @@ class DeviceTelemetry:
         the form their latent attention took (materialised: a fresh
         chunk | absorbed: a chunk with a cached prefix)."""
         self._add(self.mla_chunk_tokens, counts)
+
+    def record_mla_kv_rows(self, counts: dict[str, int]) -> None:
+        """Cached rows of one dispatched fused scan, a sublayer call's
+        worth: live (the sequences' pool-resident tokens) | fetched
+        (what the latent kernel's DMAs move for them)."""
+        self._add(self.mla_kv_rows, counts)
 
     def _add(self, totals: dict[str, int], counts: dict[str, int]) -> None:
         with self._lock:
@@ -225,5 +232,6 @@ class DeviceTelemetry:
                 "moe_expert_picks": dict(self.moe_expert_picks),
                 "seq_state_rows": dict(self.seq_state_rows),
                 "mla_chunk_tokens": dict(self.mla_chunk_tokens),
+                "mla_kv_rows": dict(self.mla_kv_rows),
                 "persistent_cache": persistent_cache_counts(),
             }
