@@ -125,8 +125,10 @@ def _step_traces(tracer):
     for trace in tracer.snapshot():
         root = [s for s in trace["spans"] if s["parent_id"] is None]
         if root and root[0]["name"] == "engine.step":
+            # the phases: a compile nests under whichever of them met it
             out.append((root[0], [s for s in trace["spans"]
-                                  if s["parent_id"] is not None]))
+                                  if s["parent_id"] is not None
+                                  and s["name"] != "xla.compile"]))
     return out
 
 

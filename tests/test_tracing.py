@@ -236,21 +236,6 @@ def test_chrome_export_is_valid_trace_event_json():
     )
 
 
-def test_otlp_degrades_silently_without_sdk(monkeypatch):
-    """Trace finalization must not raise when the opentelemetry SDK is
-    absent (prometheus_client parallel: missing optional dep = silently
-    off).  The SDK import is blocked explicitly so the test holds even
-    on machines that have it installed."""
-    import sys
-
-    monkeypatch.setitem(sys.modules, "opentelemetry.sdk", None)
-    t = Tracer().configure(True)
-    with t.span("root", trace_root=True):
-        pass
-    assert t.snapshot()  # finalized fine
-    assert t._otlp is False  # resolved to permanently-off
-
-
 def test_open_span_accounting_survives_errors():
     """A raise inside a with-span must close it (no leaked open span) —
     the property the code-hygiene start_span lint protects."""
@@ -695,3 +680,322 @@ def test_request_produces_one_linked_trace(traced_app):
             await client.close()
 
     asyncio.new_event_loop().run_until_complete(run())
+
+
+# ---------------------------------------------------------------------
+# PR 40: the host's time line closed at both ends
+# ---------------------------------------------------------------------
+def _with_client(app_factory, go):
+    async def run():
+        client = TestClient(TestServer(app_factory()))
+        await client.start_server()
+        try:
+            return await go(client)
+        finally:
+            await client.close()
+
+    return asyncio.new_event_loop().run_until_complete(run())
+
+
+async def _stream(client, **body):
+    r = await client.post(
+        "/v1/completions",
+        json=dict(model="tiny", prompt=[1, 5, 9], stream=True, **body),
+    )
+    assert r.status == 200
+    chunks = [ln async for ln in r.content if ln.startswith(b"data: ")]
+    assert chunks[-1].strip() == b"data: [DONE]"
+    return r.headers.get(TRACE_HEADER), chunks
+
+
+def test_first_chunk_time_is_the_sum_of_the_spans_on_its_way(traced_app):
+    """Closure: between the socket and the first byte back no host time
+    is outside a span — parse + intake + queue + prefill + emit is the
+    root's ``first_chunk_ms`` — and between two steps none is outside
+    the step root's ``since_prev_ms`` account."""
+
+    async def go(client):
+        ids = [(await _stream(client, max_tokens=6))[0] for _ in range(3)]
+        payload = await (await client.get("/debug/traces")).json()
+        return ids, payload
+
+    ids, payload = _with_client(traced_app, go)
+    traces = {t["trace_id"]: t for t in payload["traces"]}
+    for trace_id in ids:
+        by_name = _span_index(traces[trace_id])
+        (root,) = by_name["api.request"]
+        way = [by_name[n][0] for n in (
+            "api.parse", "engine.intake", "engine.queue", "engine.prefill",
+            "api.emit")]
+        assert all(s["parent_id"] == root["span_id"] for s in way)
+        total_ms = sum(s["duration"] for s in way) * 1e3
+        assert abs(total_ms - root["attributes"]["first_chunk_ms"]) < 0.01
+        # each stage begins where the one before it ended
+        for a, b in zip(way, way[1:]):
+            # (span starts are wall-clock anchors taken beside the
+            # monotonic stamps, not the stamps: loosely)
+            assert abs(a["start"] + a["duration"] - b["start"]) < 0.02
+        parse, intake, _, prefill, emit = (s["attributes"] for s in way)
+        assert parse == {"prompt_tokens": 3, "stream": True}
+        assert intake["phase"] in ("idle", "slack", "step", "intake")
+        assert prefill["steps"] == 1 and prefill["behind_ms"] == 0.0
+        assert isinstance(prefill["first_step_id"], int)
+        parts = emit["held_ms"] + emit["hop_ms"] + emit["write_ms"]
+        assert abs(parts - way[-1]["duration"] * 1e3) < 1e-6
+        assert len(by_name["api.emit"]) == 1  # the first chunk only
+    steps = sorted(
+        (s for t in payload["traces"] for s in t["spans"]
+         if s["name"] == "engine.step"), key=lambda s: s["start"])
+    assert len(steps) >= 6
+    for prev, step in zip(steps, steps[1:]):
+        a = step["attributes"]
+        assert a["since_prev_ms"] + 1e-6 >= (
+            a["slack_ms"] + a["idle_ms"] + a["intake_ms"])
+        between = step["start"] - (prev["start"] + prev["duration"])
+        assert abs(between * 1e3 - a["since_prev_ms"]) < 20.0  # wall
+    # an idle engine waited for the second and third request
+    assert max(s["attributes"]["idle_ms"] for s in steps) > 0
+    # every step that gave outputs has its flush, a child of its trace
+    flushes = [s for t in payload["traces"] for s in t["spans"]
+               if s["name"] == "frontend.flush"]
+    assert flushes
+    for f in flushes:
+        (root,) = [s for s in traces[f["trace_id"]]["spans"]
+                   if s["parent_id"] is None]
+        assert root["name"] == "engine.step"
+        assert f["parent_id"] == root["span_id"]
+        assert f["attributes"]["step_id"] == root["attributes"]["step_id"]
+        assert f["attributes"]["outputs"] >= 1
+    # the ring says what it lost
+    assert payload["evicted"] == 0
+    assert payload["oldest_start"] == min(
+        s["start"] for s in payload["traces"][0]["spans"]
+        if s["name"] != "gc.collect")
+
+
+def test_a_collection_is_a_span_of_the_process_trace(traced_app):
+    import gc
+
+    async def go(client):
+        await _stream(client, max_tokens=2)
+        gc.collect(2)
+        payload = await (await client.get("/debug/traces")).json()
+        return payload, await (await client.get("/metrics")).text()
+
+    assert get_tracer()._on_gc in gc.callbacks
+    payload, text = _with_client(traced_app, go)
+    process = payload["traces"][0]
+    assert process["root_span_id"] is None
+    found = [s for s in process["spans"] if s["name"] == "gc.collect"
+             and s["attributes"]["generation"] == 2]
+    assert found and all(s["duration"] > 0 for s in found)
+    assert all("collected" in s["attributes"] for s in found)
+    assert found[-1]["attributes"]["thread"] == "MainThread"
+    # on the tracer's clock: wall seconds, like every other span's start
+    newest = max(s["start"] for t in payload["traces"][1:]
+                 for s in t["spans"])
+    assert abs(found[-1]["start"] - newest) < 60
+    line = next(ln for ln in text.splitlines() if ln.startswith(
+        "vllm:gc_collections_total") and 'generation="2"' in ln)
+    assert float(line.split()[-1]) >= 1
+    assert any(ln.startswith("vllm:gc_pause_seconds_total")
+               and 'generation="2"' in ln and float(ln.split()[-1]) > 0
+               for ln in text.splitlines())
+
+
+def test_tracing_off_stamps_counts_and_hooks_nothing(tmp_path, monkeypatch):
+    """Off is off: no stamp in the intake's payload, no countdown object,
+    no collection hook, the no-op span."""
+    import gc
+
+    from vllm_distributed_tpu.engine import async_llm
+
+    tracer = get_tracer().configure(False)
+    made = []
+    monkeypatch.setattr(
+        async_llm, "_Flush", lambda *a: made.append(a) or None)
+    engine = AsyncLLM.from_engine_args(EngineArgs(
+        model=make_tiny_llama(str(tmp_path / "m")),
+        skip_tokenizer_init=True, num_kv_pages=64, max_model_len=128,
+        max_num_seqs=8, num_decode_steps=4))
+    puts = []
+
+    class Spy(type(engine._intake)):
+        def put(self, item):
+            puts.append(item)
+            super().put(item)
+
+    engine._intake = Spy()
+    state = init_app_state(engine, served_model_name="tiny")
+    try:
+        _, chunks = _with_client(
+            lambda: build_app(state),
+            lambda client: _stream(client, max_tokens=6, ignore_eos=True))
+    finally:
+        engine.shutdown()
+    assert len(chunks) >= 2
+    adds = [payload for op, payload in puts if op == "add"]
+    assert adds and all("_put" not in payload for payload in adds)
+    assert made == []
+    assert engine.engine.last_step is None
+    assert engine.engine.between_steps == {
+        "slack": 0.0, "idle": 0.0, "intake": 0.0}
+    assert engine.engine._prefill_trace == {} and engine.engine._done_at == {}
+    assert tracer._on_gc not in gc.callbacks
+    assert tracer.span("api.parse", trace_root=True) is NOOP_SPAN
+    assert tracer.annotation("api.write") is NOOP_SPAN
+    assert tracer.snapshot(process=True) == []
+
+
+def test_closure_holds_behind_a_dispatch_in_flight(tmp_path):
+    """The same sum with the dispatch pipeline on and streams arriving
+    beside one another: whatever a first dispatch queued behind is
+    inside ``engine.prefill`` (its ``behind_ms``), not outside a span."""
+    tracer = get_tracer()
+    tracer.reset()
+    engine = AsyncLLM.from_engine_args(EngineArgs(
+        model=make_tiny_llama(str(tmp_path / "m")),
+        skip_tokenizer_init=True, num_kv_pages=64, max_model_len=128,
+        max_num_seqs=8, num_decode_steps=4, enable_tracing=True))
+    state = init_app_state(engine, served_model_name="tiny")
+
+    async def go(client):
+        await _stream(client, max_tokens=4, ignore_eos=True)  # first calls
+        got = await asyncio.gather(*(
+            _stream(client, max_tokens=16, ignore_eos=True)
+            for _ in range(6)))
+        traces = (await (await client.get("/debug/traces")).json())["traces"]
+        return [trace_id for trace_id, _ in got], traces
+
+    try:
+        ids, traces = _with_client(lambda: build_app(state), go)
+    finally:
+        engine.shutdown()
+        tracer.configure(False)
+        tracer.reset()
+    traces = {t["trace_id"]: t for t in traces}
+    for trace_id in ids:
+        by_name = _span_index(traces[trace_id])
+        (root,) = by_name["api.request"]
+        total_ms = 1e3 * sum(by_name[n][0]["duration"] for n in (
+            "api.parse", "engine.intake", "engine.queue", "engine.prefill",
+            "api.emit"))
+        assert abs(total_ms - root["attributes"]["first_chunk_ms"]) < 0.01
+        prefill = by_name["engine.prefill"][0]
+        a = prefill["attributes"]
+        assert a["steps"] >= 1 and a["behind_ms"] >= 0.0
+        assert a["behind_ms"] <= prefill["duration"] * 1e3 + 1e-6
+    assert engine.engine._prefill_trace == {}
+
+
+def test_behind_ms_is_first_schedule_to_the_front_dispatch_resolving(
+        tmp_path):
+    engine = LLMEngine.from_engine_args(EngineArgs(
+        model=make_tiny_llama(str(tmp_path / "m")),
+        skip_tokenizer_init=True, num_kv_pages=64, max_model_len=128))
+    try:
+        req = type("R", (), {"request_id": "r"})()
+        engine._done_at.update({4: 100.030, 6: 99.0})
+        for track, want in (
+            ([5, 2, 4], dict(steps=2, first_step_id=5, behind_ms=30.0)),
+            # the chip was free, or the one in front had resolved already
+            ([5, 1, None], dict(steps=1, first_step_id=5, behind_ms=0.0)),
+            ([7, 3, 6], dict(steps=3, first_step_id=7, behind_ms=0.0)),
+        ):
+            engine._prefill_trace["r"] = track
+            got = engine._prefill_attrs(req, 100.0)
+            assert got["behind_ms"] == pytest.approx(want.pop("behind_ms"))
+            assert {k: got[k] for k in want} == want
+        assert engine._prefill_attrs(req, 100.0) == {}  # kept once
+    finally:
+        engine.shutdown()
+
+
+def test_compile_listener_counts_a_jit_that_respecialises_under_one_key():
+    """``vllm:xla_compiles_total`` counts the first execution of a runner
+    key; the same key met with a new shape compiles again and only JAX's
+    own backend-compile event sees it."""
+    import jax
+    import jax.numpy as jnp
+
+    from vllm_distributed_tpu.worker import telemetry
+
+    ledger = telemetry.DeviceTelemetry()  # installs the listeners
+    tracer = get_tracer().configure(True)
+    tracer.reset()
+    try:
+        fn = jax.jit(lambda x: (x * 3 + 1).sum())
+        before = sum(telemetry.backend_compile_totals()[0].values())
+        with tracer.span("engine.step", trace_root=True) as step:
+            with tracer.span("runner.dispatch") as dispatch:
+                fn(jnp.ones((3, 5))).block_until_ready()
+                ledger.record_compile("step", 0.1, "step:fn:[]")
+                fn(jnp.ones((7, 5))).block_until_ready()  # same key
+        counts, seconds = telemetry.backend_compile_totals()
+        assert sum(counts.values()) - before >= 2
+        assert sum(ledger.snapshot(probe_memory=False)[
+            "compiles"].values()) == 1
+        snap = ledger.snapshot(probe_memory=False)
+        assert snap["backend_compiles"] == counts
+        assert snap["backend_compile_seconds"] == seconds
+        assert all(v >= 0 for v in seconds.values())
+        (trace,) = [t for t in tracer.snapshot()
+                    if t["trace_id"] == step.trace_id]
+        found = [s for s in trace["spans"] if s["name"] == "xla.compile"]
+        assert len(found) >= 2
+        for s in found:  # under whatever span was current
+            assert s["parent_id"] == dispatch.span_id
+            assert set(s["attributes"]) == {"seconds", "cache_hit", "module"}
+            assert s["attributes"]["seconds"] == s["duration"]
+        # outside any span it is a span of the process
+        fn(jnp.ones((2, 2))).block_until_ready()
+        (process,) = [t for t in tracer.snapshot(process=True)
+                      if t["root_span_id"] is None]
+        assert any(s["name"] == "xla.compile" for s in process["spans"])
+    finally:
+        tracer.configure(False)
+        tracer.reset()
+
+
+def test_engine_folds_backend_compiles_into_the_new_families(tmp_path):
+    engine = LLMEngine.from_engine_args(EngineArgs(
+        model=make_tiny_llama(str(tmp_path / "m")),
+        skip_tokenizer_init=True, num_kv_pages=64, max_model_len=128))
+    try:
+        snap = engine.refresh_device_telemetry()
+        assert set(snap["backend_compiles"]) == {"compiled", "cache"}
+        text = engine.metrics.render().decode()
+        total = sum(snap["backend_compiles"].values())
+        assert total >= 1  # loading the model compiled something
+        counted = sum(
+            float(ln.split()[-1]) for ln in text.splitlines()
+            if ln.startswith("vllm:xla_backend_compiles_total{"))
+        assert counted == total
+        engine.refresh_device_telemetry()  # each counted once
+        text = engine.metrics.render().decode()
+        assert counted <= sum(
+            float(ln.split()[-1]) for ln in text.splitlines()
+            if ln.startswith("vllm:xla_backend_compiles_total{")
+        ) <= counted + 2
+        assert "vllm:xla_backend_compile_seconds_total{" in text
+    finally:
+        engine.shutdown()
+
+
+def test_ring_counts_what_it_drops():
+    t = Tracer().configure(True, ring_size=2)
+    assert t.ring_state() == {"evicted": 0, "oldest_start": None}
+    starts = []
+    for _ in range(5):
+        with t.span("root", trace_root=True) as s:
+            starts.append(s.start)
+    assert t.ring_state() == {"evicted": 3, "oldest_start": starts[3]}
+    t.configure(True, ring_size=1)
+    assert t.ring_state() == {"evicted": 4, "oldest_start": starts[4]}
+    t.record_loose("gc.collect", 1.0, 0.5, generation=2, collected=0)
+    assert t.snapshot() == t.snapshot(process=True)[1:]
+    (loose,) = t.snapshot(process=True)[0]["spans"]
+    assert loose["name"] == "gc.collect" and loose["parent_id"] is None
+    t.reset()
+    assert t.ring_state()["evicted"] == 0
+    assert t.snapshot(process=True) == []

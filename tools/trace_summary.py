@@ -29,10 +29,16 @@ _STAGE_ORDER = [
     "router.request",
     "router.handoff",
     "api.request",
+    # The request's way in (PR 40): parse, the wait for the engine
+    # thread to take it in, then the engine's own stages.
+    "api.parse",
+    "engine.intake",
     "engine.queue",
     "engine.kv_restore",
     "engine.kv_handoff",
     "engine.prefill",
+    # ... and its first chunk's way out.
+    "api.emit",
     "engine.decode",
     # One trace per engine step (PR 26): the root and the host's phases.
     "engine.step",
@@ -45,7 +51,10 @@ _STAGE_ORDER = [
     "runner.dispatch",
     "runner.fetch",
     "engine.process",
-    "engine.wait",
+    "frontend.flush",
+    # Causes that belong to no request (the process trace).
+    "gc.collect",
+    "xla.compile",
     "profile.capture",
 ]
 

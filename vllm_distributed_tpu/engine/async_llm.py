@@ -48,7 +48,7 @@ from vllm_distributed_tpu.engine.supervisor import (
 from vllm_distributed_tpu.logger import init_logger
 from vllm_distributed_tpu.outputs import RequestOutput
 from vllm_distributed_tpu.sampling_params import SamplingParams
-from vllm_distributed_tpu.tracing import NOOP_SPAN, get_tracer
+from vllm_distributed_tpu.tracing import get_tracer
 
 logger = init_logger(__name__)
 
@@ -72,6 +72,41 @@ class EngineRecoveringError(RuntimeError):
         super().__init__(message)
         self.failure = failure
         self.retry_after = retry_after
+
+
+class _Flush:
+    """One engine step's outputs on their way out, made only while
+    tracing is on: the ``frontend.flush`` span of that step's trace, from
+    ``_dispatch_outputs``' entry to the last of the batch consumed
+    (written, for a streamed request), and what ``api.emit`` needs of
+    the hand-over.  Counted down on the event loop alone."""
+
+    __slots__ = ("ctx", "step_id", "outputs", "left", "handed", "t0", "wall")
+
+    def __init__(self, ctx: tuple, step_id, outputs: int) -> None:
+        self.ctx = ctx
+        self.step_id = step_id
+        self.outputs = self.left = outputs
+        # engine thread: the step's outputs handed to the loop
+        self.handed = time.monotonic()
+        self.t0 = self.wall = 0.0
+
+    def enter(self) -> None:
+        self.t0 = time.monotonic()
+        self.wall = time.time()
+
+    def done(self) -> None:
+        self.left -= 1
+        if self.left == 0:
+            get_tracer().record_span(
+                "frontend.flush",
+                self.wall,
+                time.monotonic() - self.t0,
+                parent=self.ctx,
+                step_id=self.step_id,
+                outputs=self.outputs,
+                hop_ms=(self.t0 - self.handed) * 1e3,
+            )
 
 
 class AsyncLLM:
@@ -129,7 +164,20 @@ class AsyncLLM:
 
     # ---- the background loop ----
     def _drain_intake(self) -> None:
-        """Apply queued add/abort/aux commands (engine thread only)."""
+        """Apply queued add/abort/aux commands (engine thread only).
+        With tracing on, the time it takes counts toward the next
+        ``engine.step``'s ``intake_ms``."""
+        if self._intake.empty():
+            return
+        if not get_tracer().enabled:
+            return self._apply_intake()
+        t0 = time.monotonic()
+        try:
+            self._apply_intake()
+        finally:
+            self.engine.between_steps["intake"] += time.monotonic() - t0
+
+    def _apply_intake(self) -> None:
         while True:
             try:
                 op, payload = self._intake.get_nowait()
@@ -148,6 +196,21 @@ class AsyncLLM:
                     # Consumed from the intake: from here on, recovery
                     # must replay this request (the op won't re-run).
                     entry.admitted = True
+                # generate() stamps a traced request's put: the wait in
+                # the intake is its ``engine.intake`` span, ending where
+                # ``engine.queue`` begins.
+                put = payload.pop("_put", None)
+                if put is not None:
+                    # one stamp ends the intake and begins the queue
+                    payload["arrival_time"] = now = time.monotonic()
+                    get_tracer().record_span(
+                        "engine.intake",
+                        time.time() - (now - put[0]),
+                        now - put[0],
+                        parent=payload["trace_ctx"],
+                        request_id=request_id,
+                        phase=put[1],
+                    )
                 try:
                     self.engine.add_request(**payload)
                 except Exception as e:  # noqa: BLE001 — per-request error
@@ -275,20 +338,7 @@ class AsyncLLM:
                 raise RuntimeError(self.engine._dead_message())
             if not self.engine.has_unfinished_requests():
                 self._phase = "idle"
-                # Opened only while /debug/profile captures, and not
-                # kept: the span is there for the profile's host plane,
-                # where it tells waiting for work from the other things
-                # an engine thread outside a step can be doing.
-                tracer = get_tracer()
-                with (
-                    tracer.span(
-                        "engine.wait", trace_root=True, record=False
-                    )
-                    if tracer.profiling
-                    else NOOP_SPAN
-                ):
-                    self._wake.wait(timeout=0.2)
-                self._wake.clear()
+                self._wait("engine.wait", "idle", 0.2)
                 continue
             # The next step queues behind what is on the device, and
             # what arrives after its schedule queues behind both: wait
@@ -299,15 +349,34 @@ class AsyncLLM:
             slack = self.engine.schedule_slack()
             if slack > 0.0:
                 self._phase = "slack"
-                self._wake.wait(timeout=min(slack, 0.2))
-                self._wake.clear()
+                self._wait("engine.slack", "slack", min(slack, 0.2))
                 continue
             self._phase = "step"
             outputs = self.engine.step()
             if outputs and self._loop is not None:
+                last = self.engine.last_step
                 self._loop.call_soon_threadsafe(
-                    self._dispatch_outputs, outputs
+                    self._dispatch_outputs,
+                    outputs,
+                    None if last is None else _Flush(*last, len(outputs)),
                 )
+
+    def _wait(self, name: str, kind: str, timeout: float) -> None:
+        """Wait to be woken, between two steps.  With tracing on the
+        wait counts toward the next ``engine.step`` root's ``slack_ms``
+        or ``idle_ms``; while a profile captures it is an annotation of
+        ``name`` in the host plane besides, which tells waiting from
+        the other things an engine thread outside a step can be doing."""
+        tracer = get_tracer()
+        if not tracer.enabled:
+            self._wake.wait(timeout=timeout)
+            self._wake.clear()
+            return
+        t0 = time.monotonic()
+        with tracer.annotation(name):
+            self._wake.wait(timeout=timeout)
+        self._wake.clear()
+        self.engine.between_steps[kind] += time.monotonic() - t0
 
     def _sweep_intake(self, error: BaseException) -> None:
         """Fail work still sitting in the intake: aux futures (callers
@@ -341,16 +410,25 @@ class AsyncLLM:
             except RuntimeError:
                 return  # event loop already closed; nobody awaits
 
-    def _dispatch_outputs(self, outputs: list[RequestOutput]) -> None:
-        for out in outputs:
-            entry = self._journal.get(out.request_id)
-            if entry is not None:
-                # Journal what the client is about to see — what a
-                # recovery would restore as output state on replay.
-                entry.observe(out)
-            q = self._queues.get(out.request_id)
-            if q is not None:
-                q.put_nowait(out)
+    def _dispatch_outputs(
+        self, outputs: list[RequestOutput], flush: _Flush | None = None
+    ) -> None:
+        if flush is not None:
+            flush.enter()
+        with get_tracer().annotation("api.dispatch"):
+            for out in outputs:
+                entry = self._journal.get(out.request_id)
+                if entry is not None:
+                    # Journal what the client is about to see — what a
+                    # recovery would restore as output state on replay.
+                    entry.observe(out)
+                q = self._queues.get(out.request_id)
+                if q is not None:
+                    if flush is not None:
+                        out.flush = flush
+                    q.put_nowait(out)
+                elif flush is not None:
+                    flush.done()  # nobody is waiting for this one
 
     def _fail_all_queues(self, e: BaseException) -> None:
         for q in self._queues.values():
@@ -463,9 +541,13 @@ class AsyncLLM:
         prompt_token_ids: list[int] | None = None,
         sampling_params: SamplingParams | None = None,
         trace_ctx: tuple | None = None,
+        parsed_at: float | None = None,
     ) -> AsyncIterator[RequestOutput]:
         """Feed a request and yield cumulative RequestOutputs until
         finished.  Cancellation (client disconnect) aborts the request.
+        ``parsed_at`` (a traced caller's monotonic stamp of where its
+        ``api.parse`` ended) is where ``engine.intake`` begins; without
+        it, at the put.
         A request submitted while the engine is RECOVERING waits in the
         intake and is admitted by the rebuilt engine.  A request id
         journaled by a previous process's drain resumes instead: the
@@ -538,20 +620,23 @@ class AsyncLLM:
             if resume_entry is not None:
                 self._intake.put(("resume", resume_entry))
             else:
-                self._intake.put(
-                    (
-                        "add",
-                        dict(
-                            request_id=request_id,
-                            prompt=prompt,
-                            prompt_token_ids=prompt_token_ids,
-                            sampling_params=sampling_params,
-                            trace_ctx=trace_ctx,
-                            _est_tokens=est,
-                            _est_class=slo,
-                        ),
-                    )
+                payload = dict(
+                    request_id=request_id,
+                    prompt=prompt,
+                    prompt_token_ids=prompt_token_ids,
+                    sampling_params=sampling_params,
+                    trace_ctx=trace_ctx,
+                    _est_tokens=est,
+                    _est_class=slo,
                 )
+                if trace_ctx is not None:
+                    # For ``engine.intake``: when it was put, and what
+                    # the engine thread was doing then.
+                    payload["_put"] = (
+                        time.monotonic() if parsed_at is None else parsed_at,
+                        self._phase,
+                    )
+                self._intake.put(("add", payload))
             self._wake.set()
             if self._shutdown:
                 # Raced shutdown(): the engine thread's final sweep may
@@ -562,7 +647,18 @@ class AsyncLLM:
                 item = await q.get()
                 if isinstance(item, BaseException):
                     raise item
-                yield item
+                if item.flush is None:
+                    yield item
+                else:
+                    # Tracing on.  The consumer's work on the output (a
+                    # streaming handler's delta, dump and write) is an
+                    # ``api.write`` annotation while a profile captures,
+                    # so the event loop's busy time lies in the host
+                    # plane beside the engine thread's; back here it is
+                    # through with it: one less of its step's flush.
+                    with get_tracer().annotation("api.write"):
+                        yield item
+                    item.flush.done()
                 if item.finished:
                     return
         finally:

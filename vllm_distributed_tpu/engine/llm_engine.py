@@ -144,6 +144,8 @@ class LLMEngine:
         self._telemetry_mla_kv_rows_seen: dict[str, int] = {}
         self._telemetry_expert_picks_seen: dict[str, int] = {}
         self._telemetry_state_rows_seen: dict[str, int] = {}
+        self._telemetry_backend_compiles_seen: dict[str, int] = {}
+        self._telemetry_backend_seconds_seen: dict[str, float] = {}
         self._telemetry_moe_grouped_seen = 0
 
         # Disaggregated prefill/decode hand-off (ISSUE 15): export holds
@@ -199,6 +201,21 @@ class LLMEngine:
         self._done_lock = threading.Lock()
         self._last_done = 0.0
         self._issue_seconds = 0.005
+        # Kept only while tracing is on.  Of each traced request still
+        # in prefill: [step_id of its first dispatch, dispatches that
+        # carried its prompt's chunks, step_id of the dispatch in front
+        # of the first (None: the chip was free)]; when each of the last
+        # few dispatches resolved (the resolver's own stamp); and what
+        # the next ``engine.step`` root says of the time since the last:
+        # when that one ended, and the seconds the caller's loop then
+        # spent waiting in slack, idle and in its intake.
+        self._prefill_trace: dict[str, list] = {}
+        self._done_at: dict[int, float] = {}
+        self._step_ended: float | None = None
+        self.between_steps = {"slack": 0.0, "idle": 0.0, "intake": 0.0}
+        # (trace context, step_id) of the last ``engine.step`` span, for
+        # the caller's ``frontend.flush``; None with tracing off.
+        self.last_step: tuple | None = None
 
     @classmethod
     def from_engine_args(cls, engine_args: EngineArgs) -> "LLMEngine":
@@ -270,6 +287,8 @@ class LLMEngine:
             prompt=prompt,
             eos_token_id=eos,
             trace_ctx=trace_ctx,
+            # the monotonic instant the caller took it in, where given
+            **({} if arrival_time is None else {"arrival_time": arrival_time}),
         )
         # Client deadline (deadline_ms param) or server default, anchored
         # to the monotonic arrival instant; enforced at schedule time.
@@ -292,6 +311,7 @@ class LLMEngine:
     def abort_request(self, request_id: str) -> None:
         self.scheduler.abort_request(request_id)
         self.detokenizers.pop(request_id, None)
+        self._prefill_trace.pop(request_id, None)
         if self.config.kv_transfer_config is not None:
             self.executor.kv_output_aggregator.forget(request_id)
 
@@ -432,6 +452,12 @@ class LLMEngine:
                 seen.append(now - max(t_issued, self._last_done))
                 self._device_estimate[key] = min(seen)
                 self._last_done = now
+                if self.tracer.enabled:
+                    # For ``engine.prefill``'s ``behind_ms`` of the
+                    # requests whose first dispatch queued behind this.
+                    self._done_at[so.step_id] = now
+                    if len(self._done_at) > 64:
+                        del self._done_at[next(iter(self._done_at))]
 
         fut.add_done_callback(done)
         self._pending.append((so, fut, key, t_issued))
@@ -496,7 +522,27 @@ class LLMEngine:
         if self._failed:
             raise RuntimeError(self._dead_message())
         with self.tracer.span("engine.step", trace_root=True) as span:
-            return self._step(span)
+            if span is NOOP_SPAN:
+                self.last_step = None
+                return self._step(span)
+            # The thread's time between two steps, on this step's root:
+            # no trace of its own for a wait (the ring is per trace).
+            between, ended = self.between_steps, self._step_ended
+            span.attributes.update(
+                since_prev_ms=(
+                    0.0 if ended is None
+                    else (span.start_mono - ended) * 1e3
+                ),
+                slack_ms=between["slack"] * 1e3,
+                idle_ms=between["idle"] * 1e3,
+                intake_ms=between["intake"] * 1e3,
+            )
+            between.update(slack=0.0, idle=0.0, intake=0.0)
+            try:
+                return self._step(span)
+            finally:
+                self.last_step = (span.ctx, span.attributes.get("step_id"))
+                self._step_ended = time.monotonic()
 
     def _step(self, span) -> list[RequestOutput]:
         t_step0 = time.monotonic()
@@ -523,6 +569,7 @@ class LLMEngine:
                 pipeline_break=reason is not None
                 or bool(self._pending and sync_reason),
             )
+            self._note_prefill_dispatch(scheduler_output)
         if self.flight_recorder.enabled:
             self._record_flight(scheduler_output)
         # Deadline sheds and preempt-to-sheds finish OUTSIDE
@@ -549,6 +596,52 @@ class LLMEngine:
         runner_output = self.executor.execute_model(scheduler_output)
         outputs.extend(self._process(scheduler_output, runner_output))
         return outputs
+
+    def _note_prefill_dispatch(self, so) -> None:
+        """Tracing on: which dispatch first carried each traced request,
+        what was in front of it on the device, and how many dispatches
+        carried its prompt — ``engine.prefill``'s ``first_step_id``,
+        ``behind_ms`` and ``steps``."""
+        requests = self.scheduler.requests
+        front = self._pending[-1][0].step_id if self._pending else None
+        for new in so.new_requests:
+            req = requests.get(new.req_id)
+            if req is None or req.trace_ctx is None:
+                continue
+            track = self._prefill_trace.get(new.req_id)
+            if track is None:
+                self._prefill_trace[new.req_id] = [so.step_id, 1, front]
+            else:  # resumed after a preemption: its prompt again
+                track[1] += 1
+        if not self._prefill_trace:
+            return
+        for cached in so.cached_requests:
+            track = self._prefill_trace.get(cached.req_id)
+            if track is None:
+                continue
+            req = requests.get(cached.req_id)
+            if (
+                req is not None
+                and cached.num_computed_tokens < req.prefill_target
+            ):
+                track[1] += 1
+
+    def _prefill_attrs(self, req: Request, first_sched: float) -> dict:
+        """What ``_note_prefill_dispatch`` kept of ``req``, as the
+        attributes of its ``engine.prefill`` span."""
+        track = self._prefill_trace.pop(req.request_id, None)
+        if track is None:
+            return {}
+        first_step_id, steps, front = track
+        resolved = self._done_at.get(front) if front is not None else None
+        return dict(
+            steps=steps,
+            first_step_id=first_step_id,
+            behind_ms=(
+                0.0 if resolved is None
+                else max(resolved - first_sched, 0.0) * 1e3
+            ),
+        )
 
     def _record_flight(self, so) -> None:
         """One flight-recorder record per scheduled step (positional, in
@@ -651,6 +744,16 @@ class LLMEngine:
                 self._telemetry_state_rows_seen,
                 self.metrics.record_seq_state_rows,
             ),
+            (
+                "backend_compiles",
+                self._telemetry_backend_compiles_seen,
+                self.metrics.record_backend_compiles,
+            ),
+            (
+                "backend_compile_seconds",
+                self._telemetry_backend_seconds_seen,
+                self.metrics.record_backend_compile_seconds,
+            ),
         ):
             for kind, total in (snap.get(field) or {}).items():
                 seen = seen_by.get(kind, 0)
@@ -743,7 +846,12 @@ class LLMEngine:
         return scheduler_output
 
     def _record_stage(
-        self, req: Request, name: str, start_mono: float, end_mono: float
+        self,
+        req: Request,
+        name: str,
+        start_mono: float,
+        end_mono: float,
+        **attributes,
     ) -> None:
         """Synthesize one request-stage span from monotonic stamps.  The
         wall-clock start is derived from the arrival anchor + monotonic
@@ -755,6 +863,7 @@ class LLMEngine:
             max(end_mono - start_mono, 0.0),
             parent=req.trace_ctx,
             request_id=req.request_id,
+            **attributes,
         )
 
     def _record_request_spans(
@@ -770,28 +879,30 @@ class LLMEngine:
             if m.first_scheduled_time_mono is not None
             else now_mono
         )
-        if m.first_token_time_mono == now_mono and not finished:
+
+        def queue_and_prefill(end_mono: float) -> None:
             self._record_stage(
                 req, "engine.queue", m.arrival_time_mono, first_sched
             )
-            self._record_stage(req, "engine.prefill", first_sched, now_mono)
+            self._record_stage(
+                req,
+                "engine.prefill",
+                first_sched,
+                end_mono,
+                **self._prefill_attrs(req, first_sched),
+            )
+
+        if m.first_token_time_mono == now_mono and not finished:
+            queue_and_prefill(now_mono)
             return
         if not finished:
             return
         if m.first_token_time_mono is None:
-            self._record_stage(
-                req, "engine.queue", m.arrival_time_mono, first_sched
-            )
-            self._record_stage(req, "engine.prefill", first_sched, now_mono)
+            queue_and_prefill(now_mono)
         else:
             if m.first_token_time_mono == now_mono:
                 # First token and finish in the same step.
-                self._record_stage(
-                    req, "engine.queue", m.arrival_time_mono, first_sched
-                )
-                self._record_stage(
-                    req, "engine.prefill", first_sched, m.first_token_time_mono
-                )
+                queue_and_prefill(m.first_token_time_mono)
             self._record_stage(
                 req, "engine.decode", m.first_token_time_mono, now_mono
             )

@@ -65,7 +65,7 @@ from vllm_distributed_tpu.version import __version__
 logger = init_logger(__name__)
 
 # Response header echoing the request's 128-bit trace id; look it up in
-# /debug/traces (or your OTLP backend) to see where the latency went.
+# /debug/traces to see where the latency went.
 TRACE_HEADER = "X-VDT-Trace-Id"
 
 # Request header carrying the client's deadline in milliseconds from
@@ -206,6 +206,7 @@ async def trace_middleware(request: web.Request, handler):
         path=request.path,
     ) as span:
         request["trace_ctx"] = span.ctx
+        request["trace_span"] = span
         response = await handler(request)
         span.set_attribute("status", response.status)
     if not response.prepared:
@@ -213,6 +214,60 @@ async def trace_middleware(request: web.Request, handler):
         # prepare(); everything else gets it stamped here.
         response.headers[TRACE_HEADER] = span.ctx[0]
     return response
+
+
+# ---- the request's way in and out, as spans (tracing on) ----
+def _record_parse(
+    request: web.Request, root, prompt_tokens: int, stream: bool
+) -> float:
+    """``api.parse``, child of the root ``api.request``: from the root's
+    start (the handler's entry) to the first ``engine.generate()`` call,
+    which this call stands before — the body read, JSON, validation,
+    sampling parameters, tokenizing a text prompt, a streamed response's
+    headers.  Once a request (n > 1 comes here per choice).  Returns
+    where it ended, which ``generate(parsed_at=)`` takes as the start of
+    ``engine.intake``: one stamp, no time between the two spans."""
+    if "trace_parsed" not in request:
+        request["trace_parsed"] = now = time.monotonic()
+        get_tracer().record_span(
+            "api.parse",
+            root.start,
+            now - root.start_mono,
+            parent=root.ctx,
+            prompt_tokens=prompt_tokens,
+            stream=stream,
+        )
+    return request["trace_parsed"]
+
+
+def _record_emit(root, out: RequestOutput, got: float) -> None:
+    """``api.emit``, for a request's first chunk only (a streaming
+    handler calls it once, after the write of the first output that
+    carries a token, which it took from ``generate()`` at ``got``): from where
+    ``engine.prefill`` ended (the first token's stamp, inside
+    ``engine.process``) to ``response.write`` returned (now), in three
+    parts — ``held_ms`` (the rest of the engine's step, until it hands
+    its outputs to the loop), ``hop_ms`` (``call_soon_threadsafe`` and
+    the request's queue, until the handler has it at ``got``) and
+    ``write_ms``.  The root gets ``first_chunk_ms``, the server-side
+    first-token time: parse + intake + queue + prefill + emit."""
+    flush, stamped = out.flush, out.metrics.first_token_time_mono
+    if flush is None or stamped is None:
+        return
+    if "first_chunk_ms" in root.attributes:  # n > 1: the first wins
+        return
+    now = time.monotonic()
+    root.set_attribute("first_chunk_ms", (now - root.start_mono) * 1e3)
+    get_tracer().record_span(
+        "api.emit",
+        root.start + (stamped - root.start_mono),
+        now - stamped,
+        parent=root.ctx,
+        request_id=out.request_id,
+        held_ms=(flush.handed - stamped) * 1e3,
+        hop_ms=(got - flush.handed) * 1e3,
+        write_ms=(now - got) * 1e3,
+    )
 
 
 # ---- helpers ----
@@ -546,6 +601,11 @@ async def chat_completions(request: web.Request) -> web.Response:
     if req.stream:
         return await _stream_chat(request, state, req, request_id, prompt_ids, prompt, params)
 
+    root = request.get("trace_span")
+    if root is not None:
+        _record_parse(
+            request, root, len(prompt_ids) if prompt_ids else 0, False
+        )
     try:
         outs = await asyncio.gather(
             *(
@@ -647,6 +707,11 @@ async def _stream_chat(
             else None
         )
         sent_tool_deltas = False
+        root, parsed_at = request.get("trace_span"), None
+        if root is not None:
+            parsed_at = _record_parse(
+                request, root, len(prompt_ids) if prompt_ids else 0, True
+            )
 
         async def emit(
             delta: ChatDelta, finish_reason=None, meta: dict | None = None
@@ -671,8 +736,11 @@ async def _stream_chat(
             prompt_token_ids=prompt_ids,
             sampling_params=params.clone(),
             trace_ctx=trace_ctx,
+            parsed_at=parsed_at,
         ):
             comp = out.outputs[0]
+            if root is not None and comp.token_ids:
+                got = time.monotonic()  # first tokens, tracing on
             delta_text = comp.text[sent:]
             sent = len(comp.text)
             new_ids = list(comp.token_ids[sent_toks:])
@@ -711,6 +779,9 @@ async def _stream_chat(
                 await emit(
                     delta, finish if comp.finished else None, meta
                 )
+                if root is not None and comp.token_ids:
+                    _record_emit(root, out, got)
+                    root = None  # the first chunk only
             if comp.finished:
                 usage.prompt_tokens += len(out.prompt_token_ids)
                 usage.completion_tokens += len(comp.token_ids)
@@ -816,6 +887,11 @@ async def completions(request: web.Request) -> web.Response:
             request, state, req, request_id, resolved, params
         )
 
+    root = request.get("trace_span")
+    if root is not None:
+        _record_parse(
+            request, root, sum(len(ids) for _, ids in resolved), False
+        )
     gens = []
     for pi, (text, ids) in enumerate(resolved):
         for i in range(req.n):
@@ -917,14 +993,22 @@ async def _stream_completion(
         sent = 0
         sent_toks = 0
         first = True
+        root, parsed_at = request.get("trace_span"), None
+        if root is not None:
+            parsed_at = _record_parse(
+                request, root, sum(len(ids) for _, ids in resolved), True
+            )
         async for out in state.engine.generate(
             f"{request_id}-{choice_idx}",
             prompt=text,
             prompt_token_ids=ids,
             sampling_params=params.clone(),
             trace_ctx=trace_ctx,
+            parsed_at=parsed_at,
         ):
             comp = out.outputs[0]
+            if root is not None and comp.token_ids:
+                got = time.monotonic()  # first tokens, tracing on
             delta = comp.text[sent:]
             sent = len(comp.text)
             new_ids = list(comp.token_ids[sent_toks:])
@@ -965,6 +1049,9 @@ async def _stream_completion(
                         )
                 first = False
                 await send_json(json.dumps(chunk))
+                if root is not None and comp.token_ids:
+                    _record_emit(root, out, got)
+                    root = None  # the first chunk only
 
     try:
         tasks = []
@@ -1191,7 +1278,16 @@ async def debug_traces(request: web.Request) -> web.Response:
         return _error("limit must be a non-negative integer")
     if request.query.get("format") == "chrome":
         return web.json_response(tracer.to_chrome(limit))
-    return web.json_response({"traces": tracer.snapshot(limit)})
+    # What the ring has lost since start rides along (and goes to the
+    # log): a reader of a window that began before ``oldest_start``
+    # sees a shortened one.
+    state = tracer.ring_state()
+    traces = tracer.snapshot(limit, process=True)
+    logger.info(
+        "/debug/traces: %d traces, %d evicted since start, oldest_start %s",
+        len(traces), state["evicted"], state["oldest_start"],
+    )
+    return web.json_response({"traces": traces, **state})
 
 
 async def embeddings(request: web.Request) -> web.Response:

@@ -551,11 +551,6 @@ environment_variables: dict[str, Callable[[], Any]] = {
     "VDT_TRACE_RING_SIZE": lambda: int(
         os.environ.get("VDT_TRACE_RING_SIZE", "256")
     ),
-    # OTLP export of completed traces (tracing.py): on by default when
-    # the opentelemetry SDK is importable; "0"/"false" disables even
-    # with the SDK present.
-    "VDT_TRACE_OTLP": lambda: os.environ.get("VDT_TRACE_OTLP", "1")
-    not in ("0", "false"),
     # --- fleet sentinel (ISSUE 20) ---
     # Unified event timeline (engine/sentinel.py): events kept per
     # component log (engine ring served at /debug/events, router ring

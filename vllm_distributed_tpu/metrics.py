@@ -16,6 +16,8 @@ from __future__ import annotations
 
 import time
 
+from vllm_distributed_tpu.tracing import get_tracer
+
 _TTFT_BUCKETS = (
     0.001, 0.005, 0.01, 0.02, 0.04, 0.06, 0.08, 0.1, 0.25, 0.5,
     0.75, 1.0, 2.5, 5.0, 7.5, 10.0, 20.0, 40.0, 80.0,
@@ -159,6 +161,11 @@ DOCUMENTED_METRICS = (
     "vllm:seq_state_rows_total",
     "vllm:mla_chunk_tokens_total",
     "vllm:mla_kv_rows_total",
+    # ---- causes outside any thread's phases (ISSUE 40) ----
+    "vllm:xla_backend_compiles_total",
+    "vllm:xla_backend_compile_seconds_total",
+    "vllm:gc_collections_total",
+    "vllm:gc_pause_seconds_total",
     # ---- fleet sentinel (ISSUE 20) ----
     "vllm:slo_burn_rate",
     "vllm:itl_p99_ms",
@@ -646,6 +653,40 @@ class EngineMetrics:
             ["model_name", "kind"],
             registry=self.registry,
         )
+        # ---- causes outside any thread's phases (ISSUE 40) ----
+        self._backend_compiles = Counter(
+            "vllm:xla_backend_compiles",
+            "XLA backend compiles in the reply-rank worker's process, as "
+            "JAX's own compile event reports them, by source: compiled "
+            "(XLA compiled the module: a jit re-specialised by a new "
+            "shape counts, which vllm:xla_compiles_total cannot see), "
+            "cache (the module came from the persistent cache)",
+            ["model_name", "source"],
+            registry=self.registry,
+        )
+        self._backend_compile_seconds = Counter(
+            "vllm:xla_backend_compile_seconds",
+            "Seconds inside the XLA backend compiles counted by "
+            "vllm:xla_backend_compiles_total, by source",
+            ["model_name", "source"],
+            registry=self.registry,
+        )
+        self._gc_collections = Counter(
+            "vllm:gc_collections",
+            "Garbage collections of generation 1 and 2 in the server's "
+            "process since tracing was enabled (the hook is installed "
+            "only then), by generation",
+            ["model_name", "generation"],
+            registry=self.registry,
+        )
+        self._gc_pause_seconds = Counter(
+            "vllm:gc_pause_seconds",
+            "Seconds every thread of the server's process stood still "
+            "in the collections counted by vllm:gc_collections_total",
+            ["model_name", "generation"],
+            registry=self.registry,
+        )
+        self._gc_seen: dict[int, tuple] = {}
         # ---- fleet sentinel (ISSUE 20) ----
         self._slo_burn = Gauge(
             "vllm:slo_burn_rate",
@@ -998,6 +1039,18 @@ class EngineMetrics:
                 model_name=self._model_name, event=event
             ).inc(n)
 
+    def record_backend_compiles(self, source: str, n: int) -> None:
+        if self.enabled and n > 0:
+            self._backend_compiles.labels(
+                model_name=self._model_name, source=source
+            ).inc(n)
+
+    def record_backend_compile_seconds(self, source: str, s: float) -> None:
+        if self.enabled and s > 0:
+            self._backend_compile_seconds.labels(
+                model_name=self._model_name, source=source
+            ).inc(s)
+
     def record_xla_compile_seconds(self, seconds: float) -> None:
         """Histogram fed from individual timed events (best-effort: the
         event ring is bounded, the counter above is the exact tally)."""
@@ -1040,6 +1093,17 @@ class EngineMetrics:
         p99 = self.slo.itl_p99_ms()
         if p99 is not None:
             self.itl_p99_ms.set(p99)
+        # The tracer's collection hook keeps plain totals (it may run
+        # inside any lock, this registry's included); the counters
+        # follow them here.
+        for gen, (n, seconds) in list(get_tracer().gc_totals.items()):
+            seen_n, seen_s = self._gc_seen.get(gen, (0, 0.0))
+            labels = dict(model_name=self._model_name, generation=str(gen))
+            self._gc_collections.labels(**labels).inc(max(n - seen_n, 0))
+            self._gc_pause_seconds.labels(**labels).inc(
+                max(seconds - seen_s, 0.0)
+            )
+            self._gc_seen[gen] = (n, seconds)
         for cls, rates in self.burn.snapshot().items():
             for window, value in rates.items():
                 self._slo_burn.labels(

@@ -9,6 +9,7 @@ streamed by the engine.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+from typing import Any
 
 
 @dataclass
@@ -60,6 +61,9 @@ class RequestOutput:
     outputs: list[CompletionOutput]
     finished: bool
     metrics: "RequestMetrics | None" = None
+    # Set by AsyncLLM while tracing is on: the countdown of the engine
+    # step's outputs this one left the engine with (``frontend.flush``).
+    flush: Any = None
 
 
 @dataclass

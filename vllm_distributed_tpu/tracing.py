@@ -14,9 +14,7 @@ Design rules:
 - **No-op fast path.**  With tracing disabled (the default), ``span()``
   returns a module-level singleton and ``record_span``/``event`` return
   immediately — the hot loop allocates nothing.
-- **No hard deps.**  Pure stdlib.  OTLP export engages only when the
-  ``opentelemetry-sdk`` package is installed (degrading silently, like
-  ``prometheus_client`` in metrics.py).
+- **No hard deps.**  Pure stdlib (jax only while a profile captures).
 - **Spans cross the RPC boundary.**  ``distributed/rpc.py`` embeds the
   current trace context in apply frames and ships the worker-side spans
   back inside the reply frame; ``adopt()`` merges them into the local
@@ -34,6 +32,12 @@ Design rules:
   a ``jax.profiler.TraceAnnotation`` of the same name, so the span is
   in the trace's host plane beside the device plane: the one clock on
   which a device gap can be laid against what the host was doing.
+- **Causes that belong to no request.**  While tracing is on, a
+  ``gc.callbacks`` hook records every collection of generation 1 and 2
+  as a ``gc.collect`` span (a stop-the-world pause under the GIL), and
+  the worker's compile listener records every XLA backend compile as an
+  ``xla.compile`` span.  Spans with no trace to go to are kept in one
+  bounded *process trace*, served first in ``/debug/traces``.
 
 Config: ``ObservabilityConfig.enable_tracing`` (CLI ``--enable-tracing``)
 or ``VDT_TRACING=1``; ring size via ``VDT_TRACE_RING_SIZE``.
@@ -42,6 +46,7 @@ or ``VDT_TRACING=1``; ring size via ``VDT_TRACE_RING_SIZE``.
 from __future__ import annotations
 
 import contextvars
+import gc
 import json
 import os
 import threading
@@ -151,6 +156,12 @@ class Span:
     def ctx(self) -> TraceContext:
         return (self.trace_id, self.span_id)
 
+    @property
+    def start_mono(self) -> float:
+        """``start`` on the monotonic clock, for intervals that begin
+        where this span began."""
+        return self._t0
+
     def set_attribute(self, key: str, value: Any) -> None:
         self.attributes[key] = value
 
@@ -247,7 +258,20 @@ class Tracer:
         # host -> (wall-clock offset vs this process, rtt of the sample)
         self._clock_offsets: dict[str, tuple[float, float]] = {}
         self._metrics_sink: Callable[[str, float], None] | None = None
-        self._otlp = None  # lazily resolved exporter, or False
+        # Traces the ring has dropped since start (or reset()).
+        self._evicted = 0
+        # The process trace: spans that belong to no request and no
+        # step (a collection, a compile outside any span).  Bounded
+        # like the ring, and never in it.
+        self._process_trace_id = new_trace_id()
+        self._loose: deque[dict] = deque(maxlen=4096)
+        # The collection under way: (wall start, monotonic start, its
+        # annotation while profiling).  One at a time, under the GIL.
+        self._gc_open: tuple | None = None
+        # generation -> [collections, seconds paused] since the hook
+        # went in; ``/metrics`` reads it at scrape time (the hook itself
+        # touches no lock: a collection can start inside any of them).
+        self.gc_totals: dict[int, list] = {}
 
     # ---- configuration ----
     def configure(
@@ -259,8 +283,17 @@ class Tracer:
         self.enabled = enabled
         if host is not None:
             self.host = host
+        # The collection hook: the process's tracer alone, and only
+        # while tracing is on.
+        hooked = self._on_gc in gc.callbacks
+        if enabled and not hooked and self is _tracer:
+            gc.callbacks.append(self._on_gc)
+        elif hooked and not enabled:
+            gc.callbacks.remove(self._on_gc)
+            self._gc_open = None
         if ring_size is not None and ring_size != self._ring.maxlen:
             with self._lock:
+                self._evicted += max(len(self._ring) - max(1, ring_size), 0)
                 self._ring = deque(self._ring, maxlen=max(1, ring_size))
                 # Shrinking evicts the oldest traces from the deque; the
                 # id index must follow or get_trace() keeps resurrecting
@@ -289,6 +322,8 @@ class Tracer:
             self._ring.clear()
             self._open_spans = 0
             self._clock_offsets.clear()
+            self._loose.clear()
+            self._evicted = 0
 
     # ---- span creation ----
     def span(
@@ -320,6 +355,17 @@ class Tracer:
             self._open_spans += 1
         return span
 
+    def annotation(self, name: str):
+        """A ``TraceAnnotation`` of ``name`` while a profile captures,
+        else the no-op span: for stretches that are counted, not kept
+        as spans (the engine thread's waits, the event loop's writes),
+        yet belong in the profile's host plane."""
+        if not self.profiling:
+            return NOOP_SPAN
+        from jax.profiler import TraceAnnotation
+
+        return TraceAnnotation(name)
+
     # Manual open; must be paired with .end() under try/finally.  The
     # code-hygiene AST check bans calls outside a `with` so spans cannot
     # leak open — prefer span().
@@ -342,18 +388,75 @@ class Tracer:
         self._sink(name, duration)
         if parent is None:
             return
-        self._store(
-            {
-                "name": name,
-                "trace_id": parent[0],
-                "span_id": new_span_id(),
-                "parent_id": parent[1],
-                "host": self.host,
-                "start": start,
-                "duration": duration,
-                "attributes": attributes,
-            }
+        self._store(self._wire(name, start, duration, parent, attributes))
+
+    def record_loose(
+        self, name: str, start: float, duration: float, **attributes: Any
+    ) -> None:
+        """Record a measured interval that belongs to no trace (a
+        collection, a compile outside any span) in the process trace."""
+        if not self.enabled:
+            return
+        self._loose.append(
+            self._wire(
+                name, start, duration, (self._process_trace_id, None),
+                attributes,
+            )
         )
+
+    def _wire(
+        self,
+        name: str,
+        start: float,
+        duration: float | None,
+        parent: TraceContext,
+        attributes: dict,
+    ) -> dict:
+        return {
+            "name": name,
+            "trace_id": parent[0],
+            "span_id": new_span_id(),
+            "parent_id": parent[1],
+            "host": self.host,
+            "start": start,
+            "duration": duration,
+            "attributes": attributes,
+        }
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` hook (installed while tracing is on): a
+        collection of generation 1 or 2 stops every thread for its
+        length, so it is a ``gc.collect`` span in the process trace, an
+        annotation on the collecting thread while a profile captures,
+        and a count and a pause by generation for ``/metrics``.  It takes
+        no lock: the collecting thread may hold any of them."""
+        generation = info["generation"]
+        if generation < 1:
+            return
+        if phase == "start":
+            annotation = self.annotation("gc.collect")
+            annotation.__enter__()
+            self._gc_open = (time.time(), time.monotonic(), annotation)
+            return
+        opened, self._gc_open = self._gc_open, None
+        if opened is None:
+            return
+        start, t0, annotation = opened
+        duration = time.monotonic() - t0
+        annotation.__exit__(None, None, None)
+        self.record_loose(
+            "gc.collect",
+            start,
+            duration,
+            generation=generation,
+            collected=info.get("collected", 0),
+            # whose allocation started it: a finalizer that lets the GIL
+            # go stops that thread alone for the rest
+            thread=threading.current_thread().name,
+        )
+        total = self.gc_totals.setdefault(generation, [0, 0.0])
+        total[0] += 1
+        total[1] += duration
 
     def event(
         self, ctx: TraceContext | None, name: str, **attributes: Any
@@ -368,16 +471,7 @@ class Tracer:
     ) -> dict:
         """Build (without storing) an instant-span dict — used for the
         worker-side reply marker shipped inside the RPC result frame."""
-        return {
-            "name": name,
-            "trace_id": parent[0],
-            "span_id": new_span_id(),
-            "parent_id": parent[1],
-            "host": self.host,
-            "start": time.time(),
-            "duration": None,
-            "attributes": attributes,
-        }
+        return self._wire(name, time.time(), None, parent, attributes)
 
     # ---- cross-host ----
     def set_clock_offset(self, host: str, offset: float, rtt: float) -> None:
@@ -419,15 +513,12 @@ class Tracer:
         if not span._record:
             return
         wire = span.to_wire()
-        is_root = span.parent_id is None
         with self._lock:
             trace = self._trace_for(span.trace_id)
             trace.spans.append(wire)
-            if is_root:
+            if span.parent_id is None:
                 trace.root_span_id = span.span_id
                 self._finalize(trace)
-        if is_root:
-            self._export_otlp(trace)
 
     def _store(self, wire: dict) -> None:
         with self._lock:
@@ -463,6 +554,7 @@ class Tracer:
         if len(self._ring) == self._ring.maxlen:
             evicted = self._ring[0]
             self._finished.pop(evicted.trace_id, None)
+            self._evicted += 1
         self._ring.append(trace)
         self._finished[trace.trace_id] = trace
 
@@ -479,13 +571,40 @@ class Tracer:
     def num_open_spans(self) -> int:
         return self._open_spans
 
-    def snapshot(self, limit: int | None = None) -> list[dict]:
-        """Recent completed traces, oldest first."""
+    def snapshot(
+        self, limit: int | None = None, process: bool = False
+    ) -> list[dict]:
+        """Recent completed traces, oldest first; with ``process``,
+        the process trace before them (where it holds anything)."""
         with self._lock:
             traces = list(self._ring)
         if limit is not None:
             traces = traces[-limit:]
-        return [t.to_dict() for t in traces]
+        out = [t.to_dict() for t in traces]
+        loose = list(self._loose) if process else None
+        if loose:
+            out.insert(
+                0,
+                {
+                    "trace_id": self._process_trace_id,
+                    "root_span_id": None,
+                    "complete": False,
+                    "spans": loose,
+                },
+            )
+        return out
+
+    def ring_state(self) -> dict:
+        """What the ring has lost: traces dropped since start, and the
+        start of the oldest span it still holds (None while empty) — a
+        reader of a window that began before it sees a shortened one."""
+        with self._lock:
+            evicted = self._evicted
+            oldest = list(self._ring[0].spans) if self._ring else []
+        return {
+            "evicted": evicted,
+            "oldest_start": min((s["start"] for s in oldest), default=None),
+        }
 
     def get_trace(self, trace_id: str) -> dict | None:
         with self._lock:
@@ -501,7 +620,7 @@ class Tracer:
         metadata mapping pids to hosts."""
         events: list[dict] = []
         hosts: dict[str, int] = {}
-        for trace in self.snapshot(limit):
+        for trace in self.snapshot(limit, process=True):
             tid = int(trace["trace_id"][:8], 16) & 0x7FFFFFFF
             for span in trace["spans"]:
                 pid = hosts.setdefault(span["host"], len(hosts) + 1)
@@ -538,65 +657,6 @@ class Tracer:
 
     def to_chrome_json(self, limit: int | None = None) -> str:
         return json.dumps(self.to_chrome(limit))
-
-    # ---- optional OTLP export ----
-    def _export_otlp(self, trace: _Trace) -> None:
-        """Best-effort OTLP export of a completed trace.  Engages only
-        when the opentelemetry *SDK* is installed (the bare -api package
-        is not enough); otherwise degrades silently, exactly like
-        metrics.py does without prometheus_client.  VDT_TRACE_OTLP=0
-        disables even with the SDK present."""
-        if self._otlp is None:
-            self._otlp = self._init_otlp()
-        if not self._otlp:
-            return
-        try:
-            self._otlp(trace)
-        except Exception as e:  # noqa: BLE001 — telemetry only
-            logger.debug("OTLP export failed: %s", e)
-
-    def _init_otlp(self):
-        from vllm_distributed_tpu import envs
-
-        if not envs.VDT_TRACE_OTLP:
-            return False
-        try:
-            from opentelemetry.sdk.resources import Resource
-            from opentelemetry.sdk.trace import TracerProvider
-            from opentelemetry.sdk.trace.export import (
-                BatchSpanProcessor,
-            )
-            from opentelemetry.exporter.otlp.proto.http.trace_exporter import (
-                OTLPSpanExporter,
-            )
-        except ImportError:
-            return False
-        provider = TracerProvider(
-            resource=Resource.create(
-                {"service.name": "vllm-distributed-tpu"}
-            )
-        )
-        provider.add_span_processor(BatchSpanProcessor(OTLPSpanExporter()))
-        otel_tracer = provider.get_tracer("vdt")
-
-        def export(trace: _Trace) -> None:
-            # Re-play the finished spans through the SDK; otel assigns
-            # its own ids, so the original ids ride along as attributes.
-            for span in trace.spans:
-                start_ns = int(span["start"] * 1e9)
-                end_ns = start_ns + int((span["duration"] or 0.0) * 1e9)
-                otel_span = otel_tracer.start_span(
-                    span["name"], start_time=start_ns
-                )
-                try:
-                    for k, v in span["attributes"].items():
-                        otel_span.set_attribute(str(k), str(v))
-                    otel_span.set_attribute("vdt.trace_id", span["trace_id"])
-                    otel_span.set_attribute("vdt.host", span["host"])
-                finally:
-                    otel_span.end(end_time=end_ns)
-
-        return export
 
 
 _tracer = Tracer()

@@ -36,7 +36,12 @@ runner writes:
   read) or later (``carried``);
 - **persistent cache**: JAX's own compilation-cache events for this
   process (lookups, hits, and entries written after a miss), so a warm
-  restart is judged by what the cache reports, not by a time threshold.
+  restart is judged by what the cache reports, not by a time threshold;
+- **backend compiles**: JAX's own backend-compile event for this
+  process, counted and timed by whether XLA compiled the module or the
+  persistent cache held it — what ``compiles`` above cannot see: a jit
+  that re-specialises under an unchanged (kind, statics) key.  With
+  tracing on each is an ``xla.compile`` span too.
 
 The driver pulls snapshots over the existing ``collective_rpc`` path
 (``get_device_telemetry``) on ``/metrics`` scrapes; compile events
@@ -47,7 +52,10 @@ each event into its Prometheus instruments exactly once.
 from __future__ import annotations
 
 import threading
+import time
 from collections import deque
+
+from vllm_distributed_tpu import tracing
 
 # Peak HBM bandwidth (bytes/s) by device-kind prefix, for the roofline
 # gauge.  Rough public numbers; an unknown kind reports frac 0.0 (the
@@ -71,9 +79,20 @@ _CACHE_EVENTS = {
     "/jax/compilation_cache/cache_hits": "hits",
     "/jax/compilation_cache/cache_misses": "writes",
 }
-# The events are process-wide, so the tally is too: one listener,
-# installed on first use, shared by every DeviceTelemetry in the process.
+# JAX's duration events (jax/_src/dispatch.py, compiler.py; JAX 0.9):
+# the first is recorded around compile_or_get_cached with the module's
+# ``fun_name``, whether XLA compiled or the persistent cache answered;
+# the second, just before it and on the same thread, only where the
+# cache answered.
+_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+# The events are process-wide, so the tally is too: one listener of each
+# kind, installed on first use, shared by every DeviceTelemetry in the
+# process.
 _cache_counts = dict.fromkeys(_CACHE_EVENTS.values(), 0)
+# source (compiled | cache) -> [compiles, seconds]
+_backend_compiles = {"compiled": [0, 0.0], "cache": [0, 0.0]}
+_retrieved = threading.local()
 _cache_lock = threading.Lock()
 _cache_listener_installed = False
 
@@ -85,6 +104,38 @@ def _on_jax_event(event: str, **_kwargs) -> None:
             _cache_counts[name] += 1
 
 
+def _on_jax_duration(event: str, duration: float, **kwargs) -> None:
+    if event == _CACHE_RETRIEVAL_EVENT:
+        _retrieved.hit = True
+        return
+    if event != _BACKEND_COMPILE_EVENT:
+        return
+    cache_hit = getattr(_retrieved, "hit", False)
+    _retrieved.hit = False
+    with _cache_lock:
+        total = _backend_compiles["cache" if cache_hit else "compiled"]
+        total[0] += 1
+        total[1] += duration
+    tracer = tracing.get_tracer()
+    if not tracer.enabled:
+        return
+    attributes = dict(
+        seconds=duration,
+        cache_hit=cache_hit,
+        module=str(kwargs.get("fun_name", "")),
+    )
+    start = time.time() - duration
+    # Under whatever span is current on the driver; a worker host keeps
+    # no trace of the driver's, so there it is a span of the process.
+    parent = tracing.current_ctx() if tracer.host == "driver" else None
+    if parent is None:
+        tracer.record_loose("xla.compile", start, duration, **attributes)
+    else:
+        tracer.record_span(
+            "xla.compile", start, duration, parent=parent, **attributes
+        )
+
+
 def _install_cache_listener() -> None:
     global _cache_listener_installed
     with _cache_lock:
@@ -94,6 +145,7 @@ def _install_cache_listener() -> None:
     import jax.monitoring
 
     jax.monitoring.register_event_listener(_on_jax_event)
+    jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 def peak_hbm_bandwidth(device_kind: str) -> float:
@@ -115,6 +167,16 @@ def local_memory_stats() -> list[dict]:
 def persistent_cache_counts() -> dict[str, int]:
     with _cache_lock:
         return dict(_cache_counts)
+
+
+def backend_compile_totals() -> tuple[dict[str, int], dict[str, float]]:
+    """(compiles, seconds) of this process's XLA backend compiles, each
+    by source (compiled | cache)."""
+    with _cache_lock:
+        return (
+            {k: v[0] for k, v in _backend_compiles.items()},
+            {k: v[1] for k, v in _backend_compiles.items()},
+        )
 
 
 class DeviceTelemetry:
@@ -216,6 +278,7 @@ class DeviceTelemetry:
 
     def snapshot(self, probe_memory: bool = True) -> dict:
         live, limit = self._memory_stats() if probe_memory else (0, 0)
+        backend_compiles, backend_compile_seconds = backend_compile_totals()
         with self._lock:
             return {
                 "compile_events": [list(e) for e in self.compile_events],
@@ -234,4 +297,6 @@ class DeviceTelemetry:
                 "mla_chunk_tokens": dict(self.mla_chunk_tokens),
                 "mla_kv_rows": dict(self.mla_kv_rows),
                 "persistent_cache": persistent_cache_counts(),
+                "backend_compiles": backend_compiles,
+                "backend_compile_seconds": backend_compile_seconds,
             }
