@@ -345,3 +345,170 @@ def test_chat_streaming_tool_call_deltas(served_app, monkeypatch):
     assert finals and finals[-1]["choices"][0]["finish_reason"] == (
         "tool_calls"
     )
+
+
+# ---------------------------------------------------------------------
+# PR 42: a streamed completion chunk is bytes from a template a stream;
+# parsed, it is the dict the pydantic form gives
+# ---------------------------------------------------------------------
+_CREATED = 1_700_000_000
+_PROMPT = [3, 1, 4]
+
+
+def _scripted_output(request_id, text, ids, finish):
+    from vllm_distributed_tpu.outputs import CompletionOutput, RequestOutput
+
+    return RequestOutput(
+        request_id=request_id,
+        prompt=None,
+        prompt_token_ids=_PROMPT,
+        outputs=[CompletionOutput(
+            index=0, text=text, token_ids=list(ids), finish_reason=finish)],
+        finished=finish is not None,
+    )
+
+
+class _ScriptedEngine:
+    """What ``_stream_completion`` needs of an engine: every choice's
+    stream plays ``script``, a list of (cumulative text, cumulative
+    token ids, finish reason or None)."""
+
+    def __init__(self, script, tokenizer):
+        self.script = script
+        self.tokenizer = tokenizer
+        self.params = []
+
+    def check_admission(self, **kwargs):
+        pass
+
+    async def generate(self, request_id, sampling_params=None, **kwargs):
+        self.params.append(sampling_params)
+        for text, ids, finish in self.script:
+            yield _scripted_output(request_id, text, ids, finish)
+
+
+def _pydantic_chunks(script, index, router_meta, no_tokenizer, prefill_only):
+    """The chunks of one choice as the handler built them before: two
+    pydantic models, a dump, the router's keys put into the dict."""
+    from vllm_distributed_tpu.entrypoints.openai.protocol import (
+        CompletionChoice,
+        CompletionResponse,
+    )
+
+    chunks, sent, sent_toks, first = [], 0, 0, True
+    for text, ids, finish in script:
+        delta, new_ids = text[sent:], list(ids[sent_toks:])
+        sent, sent_toks = len(text), len(ids)
+        if not (delta or finish or (
+                new_ids and (no_tokenizer or router_meta))):
+            continue
+        chunk = CompletionResponse(
+            id="cmpl-0",
+            created=_CREATED,
+            model="tiny",
+            choices=[CompletionChoice(
+                index=index, text=delta, finish_reason=finish)],
+        ).model_dump(exclude_none=True)
+        if router_meta:
+            chunk["choices"][0]["vdt_token_ids"] = new_ids
+            if first:
+                chunk["choices"][0]["vdt_prompt_token_ids"] = list(_PROMPT)
+            if prefill_only:
+                chunk["choices"][0]["vdt_kv_handle"] = f"cmpl-0-{index}"
+        first = False
+        chunks.append(chunk)
+    return chunks
+
+
+_TEXT = [("He", [5], None), ("He", [5], None), ("Hello", [5, 6], None),
+         ("Hello \"w\"\n✓", [5, 6, 7], None),
+         ("Hello \"w\"\n✓!", [5, 6, 7, 8], "stop")]
+_IDS_ONLY = [("", [], None), ("", [5], None), ("", [5, 6, 7], None),
+             ("", [5, 6, 7, 8], "length")]
+_CHUNK_CASES = {
+    # name: (script, tokenizer?, router header, disagg header, body)
+    "text-delta": (_TEXT, True, False, False, {}),
+    "text-delta-router": (_TEXT, True, True, False, {}),
+    "empty-delta-with-ids": (_IDS_ONLY, False, False, False, {}),
+    "empty-delta-with-ids-router": (_IDS_ONLY, False, True, False, {}),
+    "ids-behind-a-tokenizer-router": (_IDS_ONLY, True, True, False, {}),
+    "ids-behind-a-tokenizer": (_IDS_ONLY, True, False, False, {}),
+    "first-chunk-is-the-last": (
+        [("", [], None), ("x", [9], "length")], True, True, False, {}),
+    "prefill-only": (
+        [("", [], None), ("", [9], "length")], False, True, True, {}),
+    "prefill-only-without-the-router": (
+        [("", [9], "length")], False, False, True, {}),
+    "n2": (_TEXT, True, True, False, {"n": 2}),
+    "n2-prefill-header-ignored": (_IDS_ONLY, False, True, True, {"n": 2}),
+    "include-usage": (
+        _IDS_ONLY, False, True, False,
+        {"stream_options": {"include_usage": True}}),
+    "include-usage-n2": (
+        _TEXT, True, False, False,
+        {"n": 2, "stream_options": {"include_usage": True}}),
+    **{f"finish-{reason}": (
+        [("a", [5], None), ("ab", [5, 6], reason)], True, True, False, {})
+       for reason in ("stop", "length", "abort", "timeout", "overloaded")},
+}
+
+
+@pytest.mark.parametrize("case", sorted(_CHUNK_CASES))
+def test_streamed_completion_chunks_parse_to_the_pydantic_form(
+    case, monkeypatch
+):
+    """Key for key.  The one stated difference: ``created`` is the
+    second the stream began, in every chunk of a request (the clock is
+    pinned, so the pydantic form is given the same second)."""
+    import time
+    from types import SimpleNamespace
+
+    from vllm_distributed_tpu.entrypoints.openai import api_server
+    from vllm_distributed_tpu.entrypoints.openai.protocol import (
+        CompletionResponse,
+        UsageInfo,
+    )
+
+    script, tokenizer, router, disagg, body = _CHUNK_CASES[case]
+    monkeypatch.setattr(api_server, "time", SimpleNamespace(
+        time=lambda: _CREATED + 0.9, monotonic=time.monotonic))
+    engine = _ScriptedEngine(script, object() if tokenizer else None)
+    state = api_server.ServerState(
+        engine=engine, model_name="tiny", max_model_len=64)
+    headers = {}
+    if router:
+        headers[api_server.ROUTER_HEADER] = "1"
+    if disagg:
+        headers[api_server.DISAGG_HEADER] = "prefill"
+
+    async def go(client):
+        r = await client.post(
+            "/v1/completions", headers=headers,
+            json={"prompt": _PROMPT, "stream": True, "max_tokens": 8, **body})
+        assert r.status == 200
+        return await r.read()
+
+    raw = _client_call(lambda: build_app(state), go)
+    frames = raw.split(b"\n\n")
+    assert frames.pop() == b"" and frames.pop() == b"data: [DONE]"
+    assert all(f.startswith(b"data: {") and b"\n" not in f for f in frames)
+    chunks = [json.loads(f[len(b"data: "):]) for f in frames]
+    n = body.get("n", 1)
+    prefill_only = engine.params[0].prefill_only
+    assert prefill_only == (disagg and n == 1)
+    if "stream_options" in body:
+        done = sum(len(script[-1][1]) for _ in range(n))
+        assert chunks.pop() == CompletionResponse(
+            id="cmpl-0", created=_CREATED, model="tiny", choices=[],
+            usage=UsageInfo(
+                prompt_tokens=n * len(_PROMPT), completion_tokens=done,
+                total_tokens=n * len(_PROMPT) + done),
+        ).model_dump(exclude_none=True)
+    for index in range(n):
+        got = [c for c in chunks if c["choices"][0]["index"] == index]
+        want = _pydantic_chunks(
+            script, index, router, not tokenizer, prefill_only)
+        assert got == want
+        # ... in the pydantic form's key order too
+        assert [json.dumps(c) for c in got] == [json.dumps(c) for c in want]
+    assert len(chunks) == n * len(want) and want

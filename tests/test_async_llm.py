@@ -10,8 +10,14 @@ import pytest
 
 from tests.utils import make_tiny_llama
 from vllm_distributed_tpu.config import EngineArgs
-from vllm_distributed_tpu.engine.async_llm import AsyncLLM
+from vllm_distributed_tpu.engine.async_llm import AsyncLLM, _Flush
+from vllm_distributed_tpu.outputs import (
+    CompletionOutput,
+    RequestMetrics,
+    RequestOutput,
+)
 from vllm_distributed_tpu.sampling_params import SamplingParams
+from vllm_distributed_tpu.tracing import get_tracer
 
 
 @pytest.fixture()
@@ -163,3 +169,115 @@ def test_loop_waits_out_the_slack_and_takes_arrivals_in(tmp_path):
     assert [r.outputs[0].token_ids for r in got] == [
         r.outputs[0].token_ids for r in want]
     assert all(len(r.outputs[0].token_ids) == 30 for r in got)
+
+
+# ---------------------------------------------------------------------
+# PR 42: a step's first tokens are handed over first
+# ---------------------------------------------------------------------
+def _step_output(request_id, first_token):
+    return RequestOutput(
+        request_id=request_id,
+        prompt=None,
+        prompt_token_ids=[1, 2],
+        outputs=[CompletionOutput(
+            index=0, text="", token_ids=[7], finish_reason="length")],
+        finished=True,
+        metrics=RequestMetrics(),
+        first_token=first_token,
+    )
+
+
+@pytest.mark.parametrize("traced", [True, False], ids=["traced", "untraced"])
+@pytest.mark.parametrize(
+    "first", [(), (5,), (2, 5, 6), (0, 1, 2, 3, 4, 5, 6)],
+    ids=["k0", "k1", "k3", "k7"])
+def test_a_steps_first_tokens_are_handed_over_first(
+    engine, tmp_path, first, traced
+):
+    """Of a step's n outputs the k that hold a first token are consumed
+    first, each group in the engine's order; the engine's own list keeps
+    its order, the journal has observed all n, and with tracing on the
+    step's ``frontend.flush`` says how many went ahead."""
+    n = 7
+    rids = [f"r{i}" for i in range(n)]
+    tracer = get_tracer()
+    tracer.reset()
+    tracer.configure(traced)
+    engine._drain_journal_path = str(tmp_path / "journal")  # journaling on
+
+    class NoAdds(type(engine._intake)):
+        """The real engine never meets these requests: the step's
+        outputs below are handed to ``_dispatch_outputs`` by hand."""
+
+        def put(self, item):
+            if item[0] != "add":
+                super().put(item)
+
+    engine._intake = NoAdds()
+    seen = []
+
+    async def consume(rid):
+        sp = SamplingParams(max_tokens=1)
+        async for out in engine.generate(
+            rid, prompt_token_ids=[1, 2], sampling_params=sp
+        ):
+            seen.append((out.request_id, out.flush))
+
+    async def go():
+        tasks = [asyncio.create_task(consume(rid)) for rid in rids]
+        await asyncio.sleep(0.05)  # each generate() waits on its queue
+        assert list(engine._queues) == rids
+        entries = dict(engine._journal)
+        flush = None
+        if traced:
+            with tracer.span("engine.step", trace_root=True, step_id=3) as s:
+                pass
+            flush = _Flush(s.ctx, 3, n)
+        outputs = [_step_output(rid, i in first) for i, rid in enumerate(rids)]
+        engine._dispatch_outputs(outputs, flush)
+        await asyncio.wait_for(asyncio.gather(*tasks), timeout=10)
+        return entries, [out.request_id for out in outputs], flush
+
+    try:
+        entries, engine_order, flush = (
+            asyncio.new_event_loop().run_until_complete(go()))
+        spans = [s for t in tracer.snapshot() for s in t["spans"]
+                 if s["name"] == "frontend.flush"]
+    finally:
+        tracer.configure(False)
+        tracer.reset()
+    want = [rids[i] for i in first] + [
+        rid for i, rid in enumerate(rids) if i not in first]
+    assert [rid for rid, _ in seen] == want
+    assert engine_order == rids  # the step's own list is left alone
+    assert sorted(entries) == rids
+    assert all(e.emitted_token_ids == [7] and e.finished
+               for e in entries.values())
+    if not traced:
+        assert spans == [] and all(f is None for _, f in seen)
+        return
+    assert all(f is flush for _, f in seen) and flush.left == 0
+    (span,) = spans
+    assert span["attributes"]["outputs"] == n
+    assert span["attributes"]["first_outputs"] == len(first)
+    assert span["attributes"]["step_id"] == 3
+
+
+def test_the_engine_marks_the_output_that_holds_a_first_token(engine):
+    """``first_token`` is set by the engine on the one output of a
+    request that carries its first token (where it stamps
+    ``first_token_time``), not re-derived from lengths downstream."""
+
+    async def go():
+        sp = SamplingParams(temperature=0.0, max_tokens=5, ignore_eos=True)
+        return [
+            (out.first_token, len(out.outputs[0].token_ids))
+            async for out in engine.generate(
+                "a", prompt_token_ids=[1, 2, 3], sampling_params=sp)
+        ]
+
+    outs = asyncio.new_event_loop().run_until_complete(go())
+    with_tokens = [o for o in outs if o[1]]
+    assert with_tokens[0][0] is True
+    assert [first for first, _ in outs].count(True) == 1
+    assert outs[-1][1] == 5

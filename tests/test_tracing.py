@@ -743,6 +743,8 @@ def test_first_chunk_time_is_the_sum_of_the_spans_on_its_way(traced_app):
         parts = emit["held_ms"] + emit["hop_ms"] + emit["write_ms"]
         assert abs(parts - way[-1]["duration"] * 1e3) < 1e-6
         assert len(by_name["api.emit"]) == 1  # the first chunk only
+        # one stream at a time: its first token is its step's one output
+        assert emit["position"] == 0
     steps = sorted(
         (s for t in payload["traces"] for s in t["spans"]
          if s["name"] == "engine.step"), key=lambda s: s["start"])
@@ -766,6 +768,9 @@ def test_first_chunk_time_is_the_sum_of_the_spans_on_its_way(traced_app):
         assert f["parent_id"] == root["span_id"]
         assert f["attributes"]["step_id"] == root["attributes"]["step_id"]
         assert f["attributes"]["outputs"] >= 1
+        assert 0 <= f["attributes"]["first_outputs"] <= 1
+    # each stream's first token went ahead in the flush of its step
+    assert sum(f["attributes"]["first_outputs"] for f in flushes) == 3
     # the ring says what it lost
     assert payload["evicted"] == 0
     assert payload["oldest_start"] == min(
@@ -885,6 +890,8 @@ def test_closure_holds_behind_a_dispatch_in_flight(tmp_path):
         a = prefill["attributes"]
         assert a["steps"] >= 1 and a["behind_ms"] >= 0.0
         assert a["behind_ms"] <= prefill["duration"] * 1e3 + 1e-6
+        # six first tokens at most in a step, and they go first
+        assert 0 <= by_name["api.emit"][0]["attributes"]["position"] <= 5
     assert engine.engine._prefill_trace == {}
 
 

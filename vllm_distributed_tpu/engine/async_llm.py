@@ -81,12 +81,15 @@ class _Flush:
     (written, for a streamed request), and what ``api.emit`` needs of
     the hand-over.  Counted down on the event loop alone."""
 
-    __slots__ = ("ctx", "step_id", "outputs", "left", "handed", "t0", "wall")
+    __slots__ = (
+        "ctx", "step_id", "outputs", "first", "left", "handed", "t0", "wall"
+    )
 
     def __init__(self, ctx: tuple, step_id, outputs: int) -> None:
         self.ctx = ctx
         self.step_id = step_id
         self.outputs = self.left = outputs
+        self.first = 0  # how many of them went ahead: first tokens
         # engine thread: the step's outputs handed to the loop
         self.handed = time.monotonic()
         self.t0 = self.wall = 0.0
@@ -94,6 +97,12 @@ class _Flush:
     def enter(self) -> None:
         self.t0 = time.monotonic()
         self.wall = time.time()
+
+    @property
+    def through(self) -> int:
+        """How many of the step's outputs are through: for the one a
+        handler holds, its 0-based place in the hand-over."""
+        return self.outputs - self.left
 
     def done(self) -> None:
         self.left -= 1
@@ -105,6 +114,7 @@ class _Flush:
                 parent=self.ctx,
                 step_id=self.step_id,
                 outputs=self.outputs,
+                first_outputs=self.first,
                 hop_ms=(self.t0 - self.handed) * 1e3,
             )
 
@@ -413,8 +423,19 @@ class AsyncLLM:
     def _dispatch_outputs(
         self, outputs: list[RequestOutput], flush: _Flush | None = None
     ) -> None:
+        """Hand one step's outputs to their streams (event loop).  The
+        outputs that hold their request's first token go first, the
+        others after them, each group in the engine's order: handlers
+        wake and write in the order of the hand-over, and a first token
+        is what a client waits for, while a running stream's chunk loses
+        nothing by leaving a few places later in the same flush."""
         if flush is not None:
-            flush.enter()
+            flush.enter()  # ``frontend.flush`` starts here: the split is its
+        first = [out for out in outputs if out.first_token]
+        if first:
+            outputs = first + [out for out in outputs if not out.first_token]
+        if flush is not None:
+            flush.first = len(first)
         with get_tracer().annotation("api.dispatch"):
             for out in outputs:
                 entry = self._journal.get(out.request_id)

@@ -1038,7 +1038,10 @@ class LLMEngine:
             if n_prefill:
                 req.metrics.prompt_tokens_counted += n_prefill
                 self.metrics.record_prompt_tokens(n_prefill)
-            if new_tokens and req.metrics.first_token_time is None:
+            first_token = (
+                bool(new_tokens) and req.metrics.first_token_time is None
+            )
+            if first_token:
                 req.metrics.first_token_time = now
                 req.metrics.first_token_time_mono = now_mono
                 # The final prefill chunk samples a token and reports no
@@ -1083,7 +1086,7 @@ class LLMEngine:
                 self._record_request_spans(
                     req, now_mono, req.status.is_finished
                 )
-            outputs.append(self._make_output(req, detok))
+            outputs.append(self._make_output(req, detok, first_token))
 
         for req in finished:
             self.metrics.record_finished(
@@ -1095,7 +1098,10 @@ class LLMEngine:
         return outputs
 
     def _make_output(
-        self, req: Request, detok: IncrementalDetokenizer | None
+        self,
+        req: Request,
+        detok: IncrementalDetokenizer | None,
+        first_token: bool = False,
     ) -> RequestOutput:
         finish_reason = FINISH_REASON.get(req.status)
         completion = CompletionOutput(
@@ -1116,6 +1122,7 @@ class LLMEngine:
             outputs=[completion],
             finished=req.status.is_finished,
             metrics=req.metrics,
+            first_token=first_token,
         )
 
     def embed(self, prompt_token_ids: list[int]) -> list[float]:

@@ -248,9 +248,11 @@ def _record_emit(root, out: RequestOutput, got: float) -> None:
     ``engine.process``) to ``response.write`` returned (now), in three
     parts — ``held_ms`` (the rest of the engine's step, until it hands
     its outputs to the loop), ``hop_ms`` (``call_soon_threadsafe`` and
-    the request's queue, until the handler has it at ``got``) and
-    ``write_ms``.  The root gets ``first_chunk_ms``, the server-side
-    first-token time: parse + intake + queue + prefill + emit."""
+    the request's queue, until the handler has it at ``got``: the
+    loop's entry plus the chunks of the ``position`` outputs of its step
+    that were through before it) and ``write_ms``.  The root gets
+    ``first_chunk_ms``, the server-side first-token time: parse + intake
+    + queue + prefill + emit."""
     flush, stamped = out.flush, out.metrics.first_token_time_mono
     if flush is None or stamped is None:
         return
@@ -267,6 +269,7 @@ def _record_emit(root, out: RequestOutput, got: float) -> None:
         held_ms=(flush.handed - stamped) * 1e3,
         hop_ms=(got - flush.handed) * 1e3,
         write_ms=(now - got) * 1e3,
+        position=flush.through,
     )
 
 
@@ -964,6 +967,32 @@ async def completions(request: web.Request) -> web.Response:
     return web.json_response(resp.model_dump(exclude_none=True))
 
 
+def _completion_chunk_frame(
+    request_id: str, model: str, created: int, index: int
+) -> tuple[str, str]:
+    """A streamed completion chunk's SSE frame either side of what
+    varies from chunk to chunk: everything up to the choice's ``text``
+    value, and everything after the choice's last key.  Made once a
+    stream from the pydantic form itself, so id, object, created, model,
+    index and the zero usage of every chunk are what
+    ``CompletionResponse`` gives, in its key order; a chunk is then
+    ``head + json.dumps(text) + [further keys of the choice] + tail``."""
+    frame = json.dumps(
+        CompletionResponse(
+            id=request_id,
+            created=created,
+            model=model,
+            choices=[CompletionChoice(index=index, text="")],
+        ).model_dump(exclude_none=True)
+    )
+    # A quote inside id or model is escaped, so only the choice's own
+    # key can read ``"text": ""``.
+    head, hole, tail = frame.partition('"text": ""')
+    if not hole:
+        raise RuntimeError(f"no text key to split at in {frame!r}")
+    return f'data: {head}"text": ', f"{tail}\n\n"
+
+
 async def _stream_completion(
     request, state, req, request_id, resolved, params
 ) -> web.StreamResponse:
@@ -976,11 +1005,14 @@ async def _stream_completion(
         headers[TRACE_HEADER] = trace_ctx[0]
     if state.replica_id:
         headers[REPLICA_HEADER] = state.replica_id
+    # The second the stream began: the same in every chunk of a request.
+    created = int(time.time())
     response = web.StreamResponse(headers=headers)
     await response.prepare(request)
+    write = response.write
 
     async def send_json(payload: str) -> None:
-        await response.write(f"data: {payload}\n\n".encode())
+        await write(f"data: {payload}\n\n".encode())
 
     no_tokenizer = state.engine.tokenizer is None
     router_meta = request.headers.get(ROUTER_HEADER) == "1"
@@ -988,11 +1020,22 @@ async def _stream_completion(
         (req.stream_options or {}).get("include_usage", False)
     )
     usage = UsageInfo()
+    dumps = json.dumps
 
     async def stream_one(choice_idx: int, text, ids) -> None:
         sent = 0
         sent_toks = 0
         first = True
+        head, tail = _completion_chunk_frame(
+            request_id, state.model_name, created, choice_idx
+        )
+        # The export handle the router drives /internal/kv/export with
+        # (ISSUE 15).
+        kv_handle = (
+            dumps(f"{request_id}-{choice_idx}")
+            if params.prefill_only
+            else None
+        )
         root, parsed_at = request.get("trace_span"), None
         if root is not None:
             parsed_at = _record_parse(
@@ -1007,49 +1050,40 @@ async def _stream_completion(
             parsed_at=parsed_at,
         ):
             comp = out.outputs[0]
-            if root is not None and comp.token_ids:
+            token_ids = comp.token_ids
+            if root is not None and token_ids:
                 got = time.monotonic()  # first tokens, tracing on
             delta = comp.text[sent:]
             sent = len(comp.text)
-            new_ids = list(comp.token_ids[sent_toks:])
-            sent_toks = len(comp.token_ids)
-            if comp.finished:
+            new_ids = token_ids[sent_toks:]
+            sent_toks = len(token_ids)
+            finished = comp.finished
+            if finished:
                 usage.prompt_tokens += len(out.prompt_token_ids)
-                usage.completion_tokens += len(comp.token_ids)
+                usage.completion_tokens += sent_toks
             # Without a tokenizer (dummy-weight serving/benches) there is
             # no text to delta — stream empty chunks on token arrival so
             # SSE timing still reflects token delivery.
-            if delta or comp.finished or (
+            if delta or finished or (
                 new_ids and (no_tokenizer or router_meta)
             ):
-                chunk = CompletionResponse(
-                    id=request_id,
-                    model=state.model_name,
-                    choices=[
-                        CompletionChoice(
-                            index=choice_idx,
-                            text=delta,
-                            finish_reason=(
-                                comp.finish_reason if comp.finished else None
-                            ),
-                        )
-                    ],
-                ).model_dump(exclude_none=True)
+                # The choice's keys in the pydantic form's order: text,
+                # finish_reason when there is one, then the router's.
+                body = dumps(delta)
+                if finished:
+                    body += f', "finish_reason": {dumps(comp.finish_reason)}'
                 if router_meta:
-                    chunk["choices"][0]["vdt_token_ids"] = new_ids
+                    body += f', "vdt_token_ids": {dumps(new_ids)}'
                     if first:
-                        chunk["choices"][0]["vdt_prompt_token_ids"] = list(
-                            out.prompt_token_ids
+                        body += (
+                            ', "vdt_prompt_token_ids": '
+                            f"{dumps(out.prompt_token_ids)}"
                         )
-                    if params.prefill_only:
-                        # The export handle the router drives
-                        # /internal/kv/export with (ISSUE 15).
-                        chunk["choices"][0]["vdt_kv_handle"] = (
-                            f"{request_id}-{choice_idx}"
-                        )
+                    if kv_handle is not None:
+                        body += f', "vdt_kv_handle": {kv_handle}'
                 first = False
-                await send_json(json.dumps(chunk))
-                if root is not None and comp.token_ids:
+                await write(f"{head}{body}{tail}".encode())
+                if root is not None and token_ids:
                     _record_emit(root, out, got)
                     root = None  # the first chunk only
 
@@ -1067,6 +1101,7 @@ async def _stream_completion(
             )
             final = CompletionResponse(
                 id=request_id,
+                created=created,
                 model=state.model_name,
                 choices=[],
                 usage=usage,

@@ -64,6 +64,10 @@ class RequestOutput:
     # Set by AsyncLLM while tracing is on: the countdown of the engine
     # step's outputs this one left the engine with (``frontend.flush``).
     flush: Any = None
+    # This output holds its request's first token (the engine stamped
+    # ``first_token_time`` while building it): AsyncLLM hands such
+    # outputs to their streams ahead of the rest of the step's.
+    first_token: bool = False
 
 
 @dataclass
