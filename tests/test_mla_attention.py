@@ -399,8 +399,22 @@ def test_chunk_kernel_matches_plain_attention_over_ragged_fresh_chunks(tq, tk):
     decode row, three more), padding tokens at the end of the flat
     batch: each fresh row sees its own sequence's rows up to itself and
     nothing else; every other row comes out finite."""
+    _check_chunk_kernel(tq, tk, dv=16)
+
+
+@pytest.mark.parametrize("tq,tk,dv", [(16, 16, 24), (32, 16, 24), (64, 64, 24),
+                                      (16, 8, 8)])
+def test_chunk_kernel_takes_values_of_another_width_than_the_keys(tq, tk, dv):
+    """``v_head_dim`` 1.5 x ``qk_nope_head_dim`` (192 / 128 in the
+    DeepSeek-V3-architecture cell) and half of it: the value blocks,
+    the accumulator and the output are ``dv`` wide, the scores are
+    not."""
+    _check_chunk_kernel(tq, tk, dv=dv)
+
+
+def _check_chunk_kernel(tq, tk, dv):
     rng = np.random.default_rng(3)
-    h, dn, dr, dv, t = 4, 16, 8, 16, 64
+    h, dn, dr, t = 4, 16, 8, 64
     kinds = [(3, False), (20, True), (1, False), (15, True), (9, True)]
     start, fresh = np.arange(t, dtype=np.int32), np.zeros(t, bool)
     cursor, chunks = 0, []
@@ -543,6 +557,39 @@ def test_a_step_of_all_three_kinds_of_row_matches_the_reference(seqs, taken):
     assert bind_max_q(mla_attention_reference, 64) is mla_attention_reference
 
 
+@pytest.mark.parametrize("dv", [24, 8])
+@pytest.mark.parametrize("seqs", [
+    [(0, 25), (50, 1), (33, 17), (0, 1), (90, 1), (0, 3), (16, 3)],
+    [(0, 30), (12, 1), (0, 20), (3, 1)],
+], ids=["all-three-kinds", "no-cached-prefix"])
+def test_a_step_whose_values_are_not_as_wide_as_its_keys_matches_the_reference(
+        seqs, dv):
+    """The absorbed path and the flat kernel at ``v_head_dim`` 1.5 x
+    (192 / 128 at the DeepSeek-V3-architecture cell's widths) and half
+    ``qk_nope_head_dim``: ``W_uv`` alone says how wide a head comes
+    out; the cached row, the scores and the latent kernel do not."""
+    rng = np.random.default_rng(4)
+    ops, n = _latent_step(rng, seqs, 64, 8, 8, dv=dv)
+    want = np.asarray(mla_sublayer_attention(
+        mla_attention_reference, *ops, scale=0.3))
+    assert want.shape == (64, 4, dv)
+    fn = bind_max_q(mla.mla_attention_cpu, 64)
+    got = np.asarray(mla_sublayer_attention(fn, *ops, scale=0.3))
+    assert got.shape == (64, 4 * dv)
+    assert np.isfinite(got).all()
+    assert np.abs(got.reshape(want.shape)[:n] - want[:n]).max() < 2e-5
+    # a decode-only step (max_q 1) keeps the absorbed form round the
+    # kernel: latent in, latent out, W_uv after
+    one = [(40, 1), (7, 1), (19, 1)]
+    ops, n = _latent_step(np.random.default_rng(5), one, 16, 8, 8, dv=dv)
+    want = np.asarray(mla_sublayer_attention(
+        mla_attention_reference, *ops, scale=0.3))
+    got = np.asarray(mla_sublayer_attention(
+        bind_max_q(mla.mla_attention_cpu, 1), *ops, scale=0.3))
+    assert got.shape == want.shape == (16, 4, dv)
+    assert np.abs(got[:n] - want[:n]).max() < 2e-5
+
+
 # ---- compiled for a described v5e chip, no chip attached -----------------
 @pytest.fixture(scope="module")
 def one_chip():
@@ -612,16 +659,20 @@ def test_attention_compiles_for_the_v5e_at_longcat_widths(
     assert compiled.memory_analysis().temp_size_in_bytes < 500e6
 
 
+@pytest.mark.parametrize("dv", [128, 192], ids=["dv128", "dv192"])
 @pytest.mark.parametrize("t", [2048, 256])
 def test_chunk_attention_compiles_for_the_v5e_at_longcat_widths(
-        t, one_chip, no_compile_cache):
+        t, dv, one_chip, no_compile_cache):
     """The materialised form at the cell's widths (64 heads of 128 + 64
     / 128 over 512-wide latents): Mosaic takes the flat kernel's column
     blocks of eight heads, a chunked step's sublayer is three kernel
     calls outside the conditional (the flat kernel, the one-token rows)
     and in it (the absorbed chunk tiles), and no XLA loop comes with
-    the lists."""
-    h, dn, dr, dv, c_w, wp, s, pages, pool = 64, 128, 64, 128, 512, 640, 64, 256, 8192
+    the lists.  At the DeepSeek-V3-architecture cell's **192**-wide
+    values the same: eight heads are 1,536 value lanes, whole tiles,
+    though one head's 192 are not, and Mosaic takes the head slices at
+    192-lane offsets."""
+    h, dn, dr, c_w, wp, s, pages, pool = 64, 128, 64, 512, 640, 64, 256, 8192
     bf = jnp.bfloat16
 
     def shaped(shape, dtype=jnp.int32):

@@ -611,7 +611,10 @@ class EngineMetrics:
             "Router picks on the reply-rank worker by kind: zero "
             "(identity experts: no compute), held (experts this chip "
             "holds), absent (experts held by other chips: nothing is "
-            "computed for them here)",
+            "computed for them here); a model without identity experts "
+            "counts busiest instead of zero (each routed layer's "
+            "most-picked held expert's picks, summed: not a part of "
+            "the others' sum)",
             ["model_name", "kind"],
             registry=self.registry,
         )

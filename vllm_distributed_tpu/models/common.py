@@ -9,6 +9,8 @@ weights are stored input-major (``[in, out]``) so application is a plain
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -35,10 +37,14 @@ SCOPE_MLA_ABSORB = "mla_absorb"
 # a gated short-convolution operator whole: its norm, both projections,
 # the gates, the taps, the sequence state's read and write
 SCOPE_SHORT_CONV = "short_conv"
+# a routed layer's always-on shared expert (norm excluded): what every
+# chip of an expert-parallel group computes alike
+SCOPE_MOE_SHARED = "moe_shared"
 SCOPES = (
     SCOPE_EMBED, SCOPE_ATTN_QKV, SCOPE_KV_WRITE, SCOPE_ATTN,
     SCOPE_ATTN_OUT, SCOPE_MOE_ROUTER, SCOPE_MOE_EXPERTS, SCOPE_MLP,
     SCOPE_LM_HEAD, SCOPE_SAMPLER, SCOPE_MLA_ABSORB, SCOPE_SHORT_CONV,
+    SCOPE_MOE_SHARED,
 )
 
 
@@ -125,6 +131,51 @@ def rope_frequencies(
         return scaled
     # Unknown scaling types fall back to unscaled (logged by the loader).
     return inv_freq
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention-magnitude correction for a context stretched
+    ``factor`` times: ``0.1 · mscale · ln(factor) + 1`` (1 at no
+    stretch)."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_correction_range(
+    head_dim: int, theta: float, rope_scaling: dict
+) -> tuple[int, int]:
+    """The frequency pairs YaRN blends between, as published: pair
+    ``lo`` is the last that turns ``beta_fast`` times over the original
+    context (kept as it is), pair ``hi`` the first that turns
+    ``beta_slow`` times (fully interpolated); floor and ceiling, clamped
+    to the head's lanes."""
+    orig = float(rope_scaling["original_max_position_embeddings"])
+
+    def pair_of(turns: float) -> float:
+        return (
+            head_dim * math.log(orig / (turns * 2.0 * math.pi))
+        ) / (2.0 * math.log(theta))
+
+    lo = math.floor(pair_of(float(rope_scaling.get("beta_fast", 32))))
+    hi = math.ceil(pair_of(float(rope_scaling.get("beta_slow", 1))))
+    return max(lo, 0), min(hi, head_dim - 1)
+
+
+def yarn_inv_freq(
+    head_dim: int, theta: float, rope_scaling: dict
+) -> jax.Array:
+    """YaRN's inverse frequencies [head_dim // 2]: pair ``i`` keeps
+    ``f_i = theta^(-2i/d)`` below the correction range, takes ``f_i /
+    factor`` above it, and a linear blend of the two inside."""
+    factor = float(rope_scaling["factor"])
+    base = rope_frequencies(head_dim, theta)
+    lo, hi = yarn_correction_range(head_dim, theta, rope_scaling)
+    ramp = (jnp.arange(head_dim // 2, dtype=jnp.float32) - lo) / max(
+        hi - lo, 0.001
+    )
+    keep = 1.0 - jnp.clip(ramp, 0.0, 1.0)
+    return (base / factor) * (1.0 - keep) + base * keep
 
 
 def apply_rope(

@@ -17,6 +17,7 @@ def register_model(cls) -> Any:
 def _populate() -> None:
     if _REGISTRY:
         return
+    from vllm_distributed_tpu.models.deepseek_v3 import DeepseekV3ForCausalLM
     from vllm_distributed_tpu.models.lfm2_moe import Lfm2MoeForCausalLM
     from vllm_distributed_tpu.models.llama import LlamaForCausalLM
     from vllm_distributed_tpu.models.longcat_flash import (
@@ -30,6 +31,7 @@ def _populate() -> None:
     register_model(MixtralForCausalLM)
     register_model(LongcatFlashForCausalLM)
     register_model(Lfm2MoeForCausalLM)
+    register_model(DeepseekV3ForCausalLM)
 
 
 def get_model_class(architecture: str):

@@ -21,6 +21,14 @@ traced and from what the expert tensor says of itself:
 
 Token drop/capacity factors are never used.  Rows outside ``live`` (a
 step's padding) join no expert's group in the sorted forms.
+
+**A pick may belong to no expert held here.**  The chip may hold E of a
+wider router's experts (one chip of an expert-parallel group, outside a
+mesh: models/deepseek_v3.py); its model hands over indices local to
+the held stack with every pick of an expert held elsewhere at ``E`` or
+above (``local_expert_ids``).  Such a pick has no column in the dense
+form's combine matrix, joins no group in the sorted forms and adds
+nothing; its weight is never read.
 """
 
 from __future__ import annotations
@@ -104,8 +112,19 @@ def select_moe_impl(
     return requested
 
 
+def local_expert_ids(topi, held_lo: int, num_held: int):
+    """A wider router's indices as the held stack's: expert ``held_lo +
+    j`` becomes ``j``, every expert held elsewhere ``num_held`` (no
+    expert here)."""
+    local = topi - held_lo
+    return jnp.where(
+        (local >= 0) & (local < num_held), local, num_held
+    ).astype(jnp.int32)
+
+
 def experts_dense(h, topi, topw, w1, w3, w2):
-    """Every expert on every token; h [T, H], topi/topw [T, k]."""
+    """Every expert on every token; h [T, H], topi/topw [T, k].  An
+    index of E or above has no column: the scatter drops it."""
     from vllm_distributed_tpu.ops.quant import maybe_dequantize
 
     t = h.shape[0]
@@ -113,7 +132,7 @@ def experts_dense(h, topi, topw, w1, w3, w2):
         combine = (
             jnp.zeros((t, w1.shape[0]), jnp.float32)
             .at[jnp.arange(t)[:, None], topi]
-            .set(topw)
+            .set(topw, mode="drop")
             .astype(h.dtype)
         )
     with jax.named_scope(SCOPE_MOE_EXPERTS):
@@ -130,8 +149,9 @@ def sorted_dispatch(topi, num_experts: int, live=None):
     """The sorted forms' shared first half: flatten the T×k assignments
     and sort them by expert.  Returns (flat expert ids [T*k], order
     [T*k]: sorted row -> flat assignment, group sizes [E]).  Rows of
-    tokens outside ``live`` (a step's padding) take the id E: they sort
-    last and join no group."""
+    tokens outside ``live`` (a step's padding) take the id E, which
+    picks of experts held elsewhere come with: they sort last and join
+    no group."""
     e, k = num_experts, topi.shape[1]
     flat_e = topi.reshape(-1).astype(jnp.int32)  # [T*k]
     if live is not None:
@@ -187,10 +207,10 @@ def experts_ragged(
     # scatter-add here would drift from it (ADVICE r4 #1).
     y = jnp.zeros((t, h.shape[1]), jnp.float32)
     contrib = orows.astype(jnp.float32) * row_w.astype(jnp.float32)[:, None]
-    if live is not None:
-        # rows past the last group belong to no expert: whatever the
-        # grouped matmul left there is not a product
-        contrib = jnp.where((flat_e[order] < w1.shape[0])[:, None], contrib, 0.0)
+    # rows past the last group (padding; picks of experts held
+    # elsewhere) belong to no expert: whatever the grouped matmul left
+    # there is not a product
+    contrib = jnp.where((flat_e[order] < w1.shape[0])[:, None], contrib, 0.0)
     return y.at[tok].add(contrib).astype(h.dtype)
 
 
@@ -207,7 +227,8 @@ def experts_grouped(
     the gather writes that layout directly and the combine gathers back
     out of it (a token's k rows, weighted and summed in f32 like the
     other forms) instead of scatter-adding.  Tokens outside ``live``
-    join no group and come back as zeros."""
+    join no group and come back as zeros; a pick of an expert held
+    elsewhere joins none and adds nothing."""
     from vllm_distributed_tpu.ops.pallas import grouped_matmul as gm
     from vllm_distributed_tpu.ops.quant import QuantizedTensor
 
@@ -248,10 +269,11 @@ def experts_grouped(
             orows = gm.grouped_matmul(inner, w2, gs, interpret=interpret)
 
     w = topw.astype(h.dtype).astype(jnp.float32)[..., None]
-    y = (orows[pos].astype(jnp.float32) * w).sum(axis=1)
-    if live is not None:
-        y = jnp.where(live[:, None], y, 0.0)
-    return y.astype(h.dtype)
+    # a pick outside every group (a padding token's; an expert held
+    # elsewhere) points at a row that is no product of its own
+    in_group = (flat_e < e).reshape(t, k, 1)
+    y = jnp.where(in_group, orows[pos].astype(jnp.float32) * w, 0.0)
+    return y.sum(axis=1).astype(h.dtype)
 
 
 def routed_experts(

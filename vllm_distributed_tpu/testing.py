@@ -170,6 +170,99 @@ def write_longcat_config(dirname: str | None = None, **sizes) -> str:
     return dirname
 
 
+def deepseek_v3_config(
+    *,
+    vocab_size: int = 131,
+    hidden: int = 64,
+    ffn: int = 96,
+    expert_ffn: int = 32,
+    layers: int = 3,
+    dense: int = 1,
+    heads: int = 4,
+    q_lora: int = 24,
+    kv_lora: int = 16,
+    nope: int = 8,
+    rope: int = 8,
+    v_dim: int = 12,
+    held: int = 8,
+    ep_size: int = 2,
+    ep_rank: int = 0,
+    shared: int = 1,
+    n_group: int = 4,
+    topk_group: int = 2,
+    top_k: int = 3,
+    yarn_factor: float = 4.0,
+    yarn_original: int = 64,
+    max_pos: int = 2048,
+    dtype: str = "float32",
+) -> dict:
+    """A DeepSeek-V3-architecture config (one latent sublayer a layer
+    with YaRN and values wider than keys, ``dense`` leading dense
+    layers, a group-limited sigmoid router over ``held * ep_size``
+    experts of which this chip holds ``held``, ``shared`` shared
+    experts), keys as the published files spell them."""
+    return {
+        "architectures": ["DeepseekV3ForCausalLM"],
+        "model_type": "deepseek_v3",
+        "attention_bias": False,
+        "hidden_size": hidden,
+        "intermediate_size": ffn,
+        "moe_intermediate_size": expert_ffn,
+        "num_hidden_layers": layers,
+        "num_nextn_predict_layers": 0,
+        "first_k_dense_replace": dense,
+        "moe_layer_freq": 1,
+        "num_attention_heads": heads,
+        "num_key_value_heads": heads,
+        "q_lora_rank": q_lora,
+        "kv_lora_rank": kv_lora,
+        "qk_nope_head_dim": nope,
+        "qk_rope_head_dim": rope,
+        "v_head_dim": v_dim,
+        "n_routed_experts": held,
+        "expert_parallel_size": ep_size,
+        "expert_parallel_rank": ep_rank,
+        "n_shared_experts": shared,
+        "n_group": n_group,
+        "topk_group": topk_group,
+        "num_experts_per_tok": top_k,
+        "topk_method": "noaux_tc",
+        "scoring_func": "sigmoid",
+        "norm_topk_prob": True,
+        "routed_scaling_factor": 2.5,
+        "vocab_size": vocab_size,
+        "max_position_embeddings": max_pos,
+        "rms_norm_eps": 1e-6,
+        "rope_theta": 10000.0,
+        "rope_scaling": {
+            "rope_type": "yarn",
+            "factor": yarn_factor,
+            "original_max_position_embeddings": yarn_original,
+            "beta_fast": 32,
+            "beta_slow": 1,
+            "mscale": 1,
+            "mscale_all_dim": 1,
+        },
+        "initializer_range": 0.02,
+        "torch_dtype": dtype,
+        "hidden_act": "silu",
+        "tie_word_embeddings": False,
+        "bos_token_id": 1,
+        "eos_token_id": 2,
+    }
+
+
+def write_deepseek_v3_config(dirname: str | None = None, **sizes) -> str:
+    """Write ``deepseek_v3_config(**sizes)`` as a config.json; returns
+    the directory."""
+    if dirname is None:
+        dirname = tempfile.mkdtemp(prefix="vdt_tiny_deepseek_v3_")
+    os.makedirs(dirname, exist_ok=True)
+    with open(os.path.join(dirname, "config.json"), "w") as f:
+        json.dump(deepseek_v3_config(**sizes), f)
+    return dirname
+
+
 def lfm2_config(
     *,
     vocab_size: int = 128,

@@ -238,6 +238,7 @@ class ModelRunner:
             self.params = self.model.fuse_quantized_projections(self.params)
         self._refuse_unsupported_latent()
         self._refuse_unsupported_seq_state()
+        self._refuse_unsupported_spec_decode()
         self._attn_fn = self._pick_attn_fn()
         # Two writers: prefill/mixed steps keep the functional XLA
         # scatter (batched, GSPMD-partitionable — the aliased Pallas
@@ -555,6 +556,16 @@ class ModelRunner:
                 "--kv-transfer-config is not supported: KV page transfer "
                 "(engine/kv_transfer.py) moves pages, not sequence "
                 f"state; {why}"
+            )
+
+    def _refuse_unsupported_spec_decode(self) -> None:
+        """A model that says why it has no speculative path
+        (``refuses_spec_decode``) fails here, at load, with its reason."""
+        why = getattr(self.model, "refuses_spec_decode", None)
+        if why and self.config.scheduler_config.spec_ngram_k > 0:
+            raise ValueError(
+                "speculative decoding (--speculative-ngram-k) is not "
+                f"supported for {type(self.model).__name__}: {why}"
             )
 
     def _refuse_unsupported_latent(self) -> None:
