@@ -4,7 +4,10 @@ cache and the fused decode scan at a preset whose values are wider
 than its keys, the group-limited router against a hand-written loop,
 YaRN at the published settings against hand numbers, the chip's share
 of the experts adding up, preemption, a prefix-cache hit, what is
-refused at load, and no XLA loop in a single step."""
+refused at load, and no XLA loop in a single step.  ISSUE 45: the
+``branch`` form (an expert behind a conditional) against the same
+reference, which steps of the cell's shape take it, and the held
+experts it skipped as the fourth pick kind."""
 
 import contextlib
 import math
@@ -122,8 +125,11 @@ def test_engine_agrees_with_the_reference_through_the_latent_cache(
     assert regret < TOL
     assert [o.token_ids for o in fused] == [o.token_ids for o in with_lp]
     # every live row made top_k picks in each routed layer: held and
-    # absent add up to them, the busiest expert's are some of the held
-    assert set(picks) == set(ds.PICK_KINDS) and min(picks.values()) > 0
+    # absent add up to them, the busiest expert's are some of the held;
+    # ``skipped`` is no pick (experts whose conditional did not run)
+    assert set(picks) == set(ds.PICK_KINDS)
+    assert min(picks[k] for k in ("held", "absent", "busiest")) > 0
+    assert picks["skipped"] >= 0
     routed = cfg["num_hidden_layers"] - cfg["first_k_dense_replace"]
     assert (picks["held"] + picks["absent"]) % (
         cfg["num_experts_per_tok"] * routed) == 0
@@ -151,10 +157,44 @@ def test_the_many_row_form_serves_the_same_logits(tmp_path):
     assert diff < TOL and regret < TOL, (diff, regret)
 
 
+def test_the_branch_form_serves_the_same_logits(tmp_path):
+    """``VDT_MOE_IMPL=branch``: every step's routed layers run an
+    expert behind a conditional, in single steps (whose logprobs are
+    compared) and in the fused scan (whose tokens the reference must
+    rank first), at the tolerance the dense form is held to."""
+    d = testing.write_deepseek_v3_config(str(tmp_path))
+    cfg = testing.deepseek_v3_config()
+    prompts = _prompts()
+    with mock.patch.dict(os.environ, {"VDT_MOE_IMPL": "branch"}):
+        engine = _engine(d)
+        try:
+            runner = engine.executor.worker.runner
+            layer = runner.params["layers"][runner.model.first_routed_layer]
+            assert runner.model.moe_impl(layer, 32) == "branch"
+            with_lp = _generate(engine, prompts, logprobs=3)
+            fused = _generate(engine, prompts)
+            picks = dict(runner.telemetry.moe_picks)
+        finally:
+            engine.shutdown()
+    diff, regret = _worst(cfg, prompts, with_lp)
+    assert diff is not None and diff < TOL, diff
+    assert regret < TOL
+    _, regret = _worst(cfg, prompts, fused)
+    assert regret < TOL
+    assert [o.token_ids for o in fused] == [o.token_ids for o in with_lp]
+    # 8 held experts a layer, two routed layers: fewer skipped than
+    # there are, and some (a decode step of three rows makes 9 picks
+    # of 16 experts, half of them held here)
+    assert 0 < picks["skipped"] < picks["held"] + picks["absent"]
+
+
 def test_picks_reach_the_metrics_and_the_fetch_spans(tmp_path):
     from vllm_distributed_tpu.tracing import get_tracer
 
-    d = testing.write_deepseek_v3_config(str(tmp_path))
+    # 8 held of 32 routed: the scan's 8 rows are expected to leave
+    # 8 x (29/32)^8 = 3.6 of them unreached, so it takes the branch
+    # form and the fourth kind counts
+    d = testing.write_deepseek_v3_config(str(tmp_path), ep_size=4)
     tracer = get_tracer()
     tracer.reset()
     engine = _engine(d, enable_tracing=True)
@@ -176,6 +216,7 @@ def test_picks_reach_the_metrics_and_the_fetch_spans(tmp_path):
         assert sum(a.get(f"moe_picks_{kind}", 0) for a in fetches) == (
             picks[kind])
     assert not any("moe_picks_zero" in a for a in fetches)
+    assert picks["skipped"] > 0
 
 
 @pytest.mark.parametrize("what,least", [("router", 3), ("weights", 10)])
@@ -275,7 +316,7 @@ def test_the_shares_add_up_to_the_uncut_layer(tmp_path):
         want = np.asarray(ref.feed_forward(u, w, flat))
         shared = np.asarray(ref.ffn(u, w["shared"]))
         total_prog, total_ref = shared.copy(), shared.copy()
-        kinds = np.zeros(3, np.int64)
+        kinds = np.zeros(len(ds.PICK_KINDS), np.int64)
         for rank in range(4):
             sizes = dict(held=4, ep_size=4, ep_rank=rank)
             share = ref.flat(testing.deepseek_v3_config(**sizes))
@@ -299,14 +340,85 @@ def test_padding_rows_are_not_counted_and_add_nothing(tmp_path):
     u = jnp.asarray(np.random.default_rng(3).standard_normal(
         (16, cfg["hidden_size"])), jnp.float32)
     live = jnp.arange(16) < 10
-    for impl in ("dense", "ragged"):
+    for impl in ("dense", "ragged", "branch"):
         with mock.patch.dict(os.environ, {"VDT_MOE_IMPL": impl}):
             m, picks = jax.jit(lambda x: model._moe(x, w, live))(u)
-        held, absent, busiest = map(int, np.asarray(picks))
+        held, absent, busiest, skipped = map(int, np.asarray(picks))
         assert held + absent == 10 * cfg["num_experts_per_tok"]
         assert 0 < busiest <= held
-        if impl == "ragged":
+        if impl != "dense":
             assert not np.asarray(m)[10:].any()
+        if impl != "branch":
+            assert skipped == 0
+
+
+def test_skipped_counts_the_held_experts_no_live_row_picked(tmp_path):
+    """The fourth kind against the router's own picks: under ``branch``
+    the held experts (8 of 32 routed) that no live row's picks name,
+    counted by hand; the padding rows' picks reach nothing; and the
+    count reaches ``stats["moe_picks"]`` summed over the routed
+    layers."""
+    from vllm_distributed_tpu.ops.attention import AttentionMetadata
+
+    sizes = dict(ep_size=4, ep_rank=1)
+    model = _model(tmp_path, **sizes)
+    cfg = testing.deepseek_v3_config(**sizes)
+    held = cfg["n_routed_experts"]
+    w = ref.make_weights(cfg, SEED)["layers"][1]
+    u = jnp.asarray(np.random.default_rng(5).standard_normal(
+        (8, cfg["hidden_size"])), jnp.float32)
+    live = jnp.arange(8) < 6
+    idx = np.asarray(model._route(u, w)[0])[:6] - held  # rank 1's ids
+    by_hand = held - len({int(e) for e in idx.ravel() if 0 <= e < held})
+    assert 0 < by_hand < held
+    with mock.patch.dict(os.environ, {"VDT_MOE_IMPL": "branch"}):
+        m, picks = jax.jit(lambda x: model._moe(x, w, live))(u)
+    with mock.patch.dict(os.environ, {"VDT_MOE_IMPL": "dense"}):
+        want, _ = jax.jit(lambda x: model._moe(x, w, live))(u)
+    assert int(picks[ds.PICK_KINDS.index("skipped")]) == by_hand
+    assert np.abs(np.asarray(m)[:6] - np.asarray(want)[:6]).max() < 1e-5
+    # through forward: both routed layers' counts in stats["moe_picks"]
+    params = model.init_params(jax.random.PRNGKey(0))
+    i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
+    meta = AttentionMetadata(
+        q_seq_ids=jnp.arange(8, dtype=jnp.int32), q_positions=i32(8),
+        slot_mapping=jnp.arange(8, dtype=jnp.int32),
+        block_tables=jnp.arange(8, dtype=jnp.int32)[:, None],
+        seq_lens=jnp.ones(8, jnp.int32),
+        logits_indices=jnp.arange(8, dtype=jnp.int32), chunk_starts=i32(8))
+    kv = [jnp.zeros((1, 8, 16, 128), jnp.float32)
+          for _ in range(model.num_layers)]
+    tokens = jnp.arange(3, 11, dtype=jnp.int32)
+    got = {}
+    for impl in ("branch", "dense"):
+        stats = {}
+        with mock.patch.dict(os.environ, {"VDT_MOE_IMPL": impl}):
+            model.forward(params, tokens, kv, meta, stats=stats)
+        got[impl] = dict(zip(ds.PICK_KINDS, map(int, stats["moe_picks"])))
+    routed = cfg["num_hidden_layers"] - cfg["first_k_dense_replace"]
+    assert 0 < got["branch"]["skipped"] < routed * held
+    assert got["dense"]["skipped"] == 0
+    assert {k: v for k, v in got["branch"].items() if k != "skipped"} == {
+        k: v for k, v in got["dense"].items() if k != "skipped"}
+
+
+def test_the_cells_steps_branch_at_64_rows_and_no_other(tmp_path):
+    """The GigaChat cell's ratios at toy widths (16 held of a 256-wide
+    router in 8 groups, 8 picks a row): the scan's 64 rows and the
+    ``t64`` single step take ``branch`` (2.1 experts expected
+    unreached), 128 to 512 tokens the dense einsum as before (0.27 at
+    128), 1,024 and up the grouped kernel where Pallas runs and
+    ``ragged_dot`` elsewhere, as before."""
+    sizes = dict(held=16, ep_size=16, top_k=8, n_group=8, topk_group=4)
+    layer = {"w1": jax.ShapeDtypeStruct((16, 64, 32), jnp.float32)}
+    want = {64: "branch", 128: "dense", 256: "dense", 512: "dense"}
+    for backend, many in (("pallas_interpret", "grouped"),
+                          ("reference", "ragged")):
+        with mock.patch.dict(os.environ, {"VDT_USE_PALLAS": backend}):
+            model = _model(tmp_path / backend, **sizes)
+        got = {t: model.moe_impl(layer, t)
+               for t in (64, 128, 256, 512, 1024, 2048)}
+        assert got == {**want, 1024: many, 2048: many}, backend
 
 
 # ---- the router -----------------------------------------------------------
@@ -485,7 +597,7 @@ def test_a_single_step_holds_no_xla_while(tmp_path):
         chunk_starts=i32(s))
     kv = [jax.ShapeDtypeStruct((1, 16, 16, 128), jnp.float32)
           for _ in range(model.num_layers)]
-    for impl in ("dense", "ragged"):
+    for impl in ("dense", "ragged", "branch"):
         with mock.patch.dict(os.environ, {"VDT_MOE_IMPL": impl}):
             jaxpr = jax.make_jaxpr(
                 lambda p, tok, kv, m: model.forward(p, tok, kv, m, stats={})

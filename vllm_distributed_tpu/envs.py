@@ -622,7 +622,10 @@ environment_variables: dict[str, Callable[[], Any]] = {
     # bandwidth-bound shapes (decode, or quantized experts whose
     # dequant fuses into the dense dot but not into ragged_dot),
     # ragged (sorted jax.lax.ragged_dot, ~k/E of the dense FLOPs) for
-    # compute-bound prefill rows.  "ragged"/"dense" force one path.
+    # compute-bound prefill rows, "branch" (an expert behind a
+    # conditional) for the few-row steps of a model that says how wide
+    # its router is.  "ragged"/"dense"/"grouped"/"branch" force one
+    # path (models/routed_experts.select_moe_impl).
     "VDT_MOE_IMPL": lambda: os.environ.get("VDT_MOE_IMPL", "auto"),
     # --- external, replicated for weight download ---
     "HF_TOKEN": lambda: os.environ.get("HF_TOKEN", ""),

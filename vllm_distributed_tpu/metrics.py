@@ -614,7 +614,8 @@ class EngineMetrics:
             "computed for them here); a model without identity experts "
             "counts busiest instead of zero (each routed layer's "
             "most-picked held expert's picks, summed: not a part of "
-            "the others' sum)",
+            "the others' sum) and skipped (no pick: held experts whose "
+            "conditional did not run in a program of the branch form)",
             ["model_name", "kind"],
             registry=self.registry,
         )

@@ -87,6 +87,7 @@ from vllm_distributed_tpu.models.common import (
 )
 from vllm_distributed_tpu.models.routed_experts import (
     local_expert_ids,
+    picks_by_expert,
     routed_experts,
     select_moe_impl,
 )
@@ -102,8 +103,11 @@ from vllm_distributed_tpu.ops.attention import (
 # live rows and all routed layers: picks of experts held here, picks of
 # experts held elsewhere (the two add up to all picks), and each
 # layer's busiest held expert's picks summed (the rows a grouped kernel
-# pads the other groups towards).
-PICK_KINDS = ("held", "absent", "busiest")
+# pads the other groups towards).  ``skipped`` is no pick: the held
+# experts no live row reached in a layer of the ``branch`` form, whose
+# conditional did not run and whose matrices were not read (0 in a
+# program of another form).
+PICK_KINDS = ("held", "absent", "busiest", "skipped")
 # The seeded router bias, in float32.  A sigmoid of logits of spread
 # ~1.7 (hidden 7168 at initializer_range 0.02) puts the top picks'
 # scores about 0.01 apart: a bias of that order moves about one pick in
@@ -433,33 +437,41 @@ class DeepseekV3ForCausalLM(SupportsQuantization):
 
     def moe_impl(self, layer: dict, tokens: int) -> str:
         """What the routed layers of a step of ``tokens`` rows are
-        traced with: "dense" | "ragged" | "grouped", by the rows an even
-        router sends the experts held *here* (``tokens · k · held /
-        routed``), not by all the step's picks."""
+        traced with: "dense" | "ragged" | "grouped" | "branch", by the
+        rows an even router sends the experts held *here* (``tokens · k
+        · held / routed``), not by all the step's picks; and, where that
+        says the dense einsum, by whether so few rows are expected to
+        leave a held expert unreached (``branch``)."""
         from vllm_distributed_tpu import envs
 
         rows_here = -(-tokens * self.top_k * self.num_held // self.num_routed)
         return select_moe_impl(
             envs.VDT_MOE_IMPL, rows_here, layer["w1"],
             on_mesh=False, unquantized_backend=self.kernel_backend,
+            tokens=tokens, top_k=self.top_k, router_width=self.num_routed,
         )
 
     def _moe(self, u, layer: dict, live):
         """This chip's part of the routed experts' sum: (R(u) [T, H] in
-        u.dtype, picks [3] int32 by PICK_KINDS over the live rows)."""
+        u.dtype, picks [4] int32 by PICK_KINDS over the live rows)."""
         held = self.num_held
+        impl = self.moe_impl(layer, u.shape[0])
         with jax.named_scope(SCOPE_MOE_ROUTER):
             idx, w = self._route(u, layer)
             local = local_expert_ids(idx, self.held_lo, held)
-            counts = (
-                (local[..., None] == jnp.arange(held, dtype=jnp.int32))
-                & live[:, None, None]
-            ).sum(axis=(0, 1), dtype=jnp.int32)  # [held]
+            _, counts = picks_by_expert(local, held, live)  # [held]
             n_held = counts.sum()
             n_all = live.sum(dtype=jnp.int32) * self.top_k
-            picks = jnp.stack([n_held, n_all - n_held, counts.max()])
+            # the branch form's own condition: an expert with no pick
+            skipped = (
+                (counts == 0).sum(dtype=jnp.int32) if impl == "branch"
+                else jnp.zeros((), jnp.int32)
+            )
+            picks = jnp.stack(
+                [n_held, n_all - n_held, counts.max(), skipped]
+            )
         out = routed_experts(
-            self.moe_impl(layer, u.shape[0]), u, local, w,
+            impl, u, local, w,
             layer["w1"], layer["w3"], layer["w2"], live=live,
             interpret=self.kernel_backend == "pallas_interpret",
         )
@@ -478,7 +490,7 @@ class DeepseekV3ForCausalLM(SupportsQuantization):
     ) -> tuple:
         """Returns (logits [S, V] at meta.logits_indices, updated
         caches); with ``stats`` a dict, also leaves the router's pick
-        counts there (``stats["moe_picks"]``: [3] int32 by PICK_KINDS,
+        counts there (``stats["moe_picks"]``: [4] int32 by PICK_KINDS,
         summed over the routed layers, padding rows not counted)."""
         with jax.named_scope(SCOPE_EMBED):
             x = params["embed"][token_ids].astype(self.dtype)

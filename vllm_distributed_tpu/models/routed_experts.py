@@ -18,6 +18,12 @@ traced and from what the expert tensor says of itself:
   tiles (no bf16 copy of the stack); over unquantized experts of a
   model that asks for it (``unquantized_backend``) the tiles go to the
   MXU as they are.
+- ``branch`` (the fourth form, ISSUE 45): the dense form's arithmetic
+  one expert at a time, each behind a ``jax.lax.cond`` on "some live
+  row picked it", so a weight-bandwidth-bound step reads only the
+  experts its rows reach.  Unquantized experts off a mesh, in a step
+  so small beside the router's width that an even router is expected
+  to leave an expert of the stack unreached (``BRANCH_MIN_UNREACHED``).
 
 Token drop/capacity factors are never used.  Rows outside ``live`` (a
 step's padding) join no expert's group in the sorted forms.
@@ -48,13 +54,27 @@ from vllm_distributed_tpu.models.common import (
 # Fixed from a one-layer sweep on the v5e at Mixtral's widths (PERF.md,
 # PR 27).
 GROUPED_MIN_ROWS = 256
+# Experts of the stack that an even router is expected to leave with no
+# row, ``E * (1 - top_k / router_width) ** tokens``, from which a step
+# that would run the dense einsum runs the ``branch`` form instead.
+# The einsum reads all E experts whatever the picks; a conditional an
+# expert reads the reached ones, three products of one expert at a
+# time: alone on the chip those read at 82% of HBM's peak against the
+# einsum's 88% (break-even at 1.2 of 16 unreached), inside the GigaChat
+# cell's fused scan at the einsum's own rate (PERF.md, PR 45), so there
+# is something to win from about one expert skipped.  At 16 of a
+# 256-wide router's experts and 8 picks a row: 64 rows leave 2.1
+# (branch), 128 rows 0.27 (dense).
+BRANCH_MIN_UNREACHED = 1.0
 
 
 def select_moe_impl(
     requested: str, rows: int, w1, on_mesh: bool,
     unquantized_backend: str | None = None,
+    *, tokens: int | None = None, top_k: int | None = None,
+    router_width: int | None = None,
 ) -> str:
-    """"dense" | "ragged" | "grouped" for one expert layer, from
+    """"dense" | "ragged" | "grouped" | "branch" for one expert layer, from
     VDT_MOE_IMPL (``requested``), the routed rows of the step being
     traced and the expert tensor itself.
 
@@ -76,7 +96,17 @@ def select_moe_impl(
     tiles, because the TPU's ``ragged_dot`` cost 4.9 x a dense FFN of
     the same rows at 32 experts of 1,792 (PERF.md, PR 38).  ``ragged``
     on a tensor the grouped kernel takes means that kernel; ``dense`` is
-    always the dense einsum."""
+    always the dense einsum.
+
+    A model that says what its router does (``tokens``: the step's
+    padded rows, ``top_k``: the picks a row makes, ``router_width``: the
+    experts it picks over, which may be more than the stack holds) gets
+    ``branch`` for unquantized experts off a mesh where ``auto`` would
+    run the dense einsum and an even router is expected to leave
+    BRANCH_MIN_UNREACHED experts of the stack with no row; without the
+    three the answer is what it was.  ``branch`` asked for by name is
+    the dense einsum over quantized experts (its dequant fuses there)
+    and on a mesh."""
     from vllm_distributed_tpu.ops.pallas import grouped_matmul as gm
     from vllm_distributed_tpu.ops.quant import QuantizedTensor
 
@@ -106,9 +136,20 @@ def select_moe_impl(
     if requested == "auto":
         if grouped_ok and rows > GROUPED_MIN_ROWS:
             return "grouped"
-        return "dense" if (quantized or rows <= 256) else "ragged"
+        impl = "dense" if (quantized or rows <= 256) else "ragged"
+        if (
+            impl == "dense"
+            and not (quantized or on_mesh)
+            and None not in (tokens, top_k, router_width)
+            and w1.shape[0] * (1.0 - top_k / router_width) ** tokens
+            >= BRANCH_MIN_UNREACHED
+        ):
+            return "branch"
+        return impl
     if requested == "ragged" and grouped_ok:
         return "grouped"
+    if requested == "branch" and (quantized or on_mesh):
+        return "dense"
     return requested
 
 
@@ -143,6 +184,46 @@ def experts_dense(h, topi, topw, w1, w3, w2):
         h3 = jnp.einsum("th,ehi->tei", h, w3)
         inner = jax.nn.silu(h1) * h3
         return jnp.einsum("tei,eih,te->th", inner, w2, combine)
+
+
+def picks_by_expert(topi, num_experts: int, live=None):
+    """(picked [T, k, E] bool: pick ``j`` of row ``t`` is of expert
+    ``e`` of the stack; counts [E] int32: the picks of each expert).
+    Rows outside ``live`` and indices of E or above (experts held
+    elsewhere) pick no expert of the stack."""
+    picked = topi[..., None] == jnp.arange(num_experts, dtype=topi.dtype)
+    if live is not None:
+        picked = picked & live[:, None, None]
+    return picked, picked.sum(axis=(0, 1), dtype=jnp.int32)
+
+
+def experts_branch(h, topi, topw, w1, w3, w2, live=None):
+    """The dense form one expert at a time, each behind a conditional:
+    expert ``e`` runs over all T rows, weighted by its column of the
+    combine matrix (zeros for the rows that did not pick it), only
+    where some live row picked it; an expert no row reached is not
+    read.  No gather buffer, no capacity, no drop.  Padding rows come
+    back as zeros.  Python's loop over the stack makes E conditionals
+    and no XLA loop (a single step must hold none)."""
+    e = w1.shape[0]
+    with jax.named_scope(SCOPE_MOE_ROUTER):
+        picked, counts = picks_by_expert(topi, e, live)
+        combine = jnp.where(
+            picked, topw.astype(jnp.float32)[..., None], 0.0
+        ).sum(axis=1)  # [T, E]
+        reached = counts > 0
+    with jax.named_scope(SCOPE_MOE_EXPERTS):
+        y = jnp.zeros(h.shape, jnp.float32)
+        for j in range(e):
+            def run(y, j=j):
+                inner = jax.nn.silu(h @ w1[j]) * (h @ w3[j])
+                out = jnp.dot(
+                    inner, w2[j], preferred_element_type=jnp.float32
+                )
+                return y + out * combine[:, j, None]
+
+            y = jax.lax.cond(reached[j], run, lambda y: y, y)
+        return y.astype(h.dtype)
 
 
 def sorted_dispatch(topi, num_experts: int, live=None):
@@ -286,6 +367,8 @@ def routed_experts(
         return experts_dense(h, topi, topw, w1, w3, w2)
     if impl == "grouped":
         return experts_grouped(h, topi, topw, w1, w3, w2, live, interpret)
+    if impl == "branch":
+        return experts_branch(h, topi, topw, w1, w3, w2, live)
     return experts_ragged(
         h, topi, topw, w1, w3, w2, live, mesh, expert_parallel
     )
