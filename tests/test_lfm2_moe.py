@@ -341,8 +341,10 @@ def test_one_paged_cache_and_40960_bytes_of_state_a_row_at_the_cut(tmp_path):
     pool, states = jax.eval_shape(lambda: runner.alloc_kv_pool(32))
     assert len(pool) == 1 and pool[0].shape == (2, 32, 16, 512)
     layout = m.seq_state_layout()
-    assert (layout.num_states, layout.rows, layout.width) == (5, 2, 2048)
-    assert layout.seq_bytes(2) == 40960  # 65,536 at ten layers' 8
+    (kind,) = layout.kinds
+    assert (kind.name, kind.num_layers, kind.shape) == ("conv", 5, (2, 2048))
+    assert layout.num_states == 5
+    assert layout.seq_bytes(m.dtype) == 40960  # 65,536 at ten layers' 8
     assert len(states) == 5 and states[0].shape == (64, 2, 2048)
     assert states[0].dtype == jnp.bfloat16
     assert runner._seq_state_bytes() == 64 * 40960

@@ -28,6 +28,7 @@ MOE_SCOPES = {common.SCOPE_MOE_ROUTER, common.SCOPE_MOE_EXPERTS}
 MLA_SCOPES = {common.SCOPE_MLA_ABSORB}
 CONV_SCOPES = {common.SCOPE_SHORT_CONV}
 SHARED_SCOPES = {common.SCOPE_MOE_SHARED}
+SSM_SCOPES = {common.SCOPE_SSM_MIXER, common.SCOPE_SSM_SCAN}
 
 
 def make_tiny_longcat(path: str) -> str:
@@ -40,6 +41,12 @@ def make_tiny_lfm2(path: str) -> str:
     from vllm_distributed_tpu import testing
 
     return testing.write_lfm2_config(path)
+
+
+def make_tiny_falcon_h1(path: str) -> str:
+    from vllm_distributed_tpu import testing
+
+    return testing.write_falcon_h1_config(path)
 
 
 def make_tiny_deepseek_v3(path: str) -> str:
@@ -127,12 +134,16 @@ def _exported_programs(model_dir: str, cache_dir: str) -> dict[str, set]:
 
 @pytest.mark.parametrize("make,absent", [
     (make_tiny_llama,
-     MOE_SCOPES | MLA_SCOPES | CONV_SCOPES | SHARED_SCOPES),
-    (make_tiny_mixtral, MLA_SCOPES | CONV_SCOPES | SHARED_SCOPES),
-    (make_tiny_longcat, CONV_SCOPES | SHARED_SCOPES),
-    (make_tiny_lfm2, MLA_SCOPES | SHARED_SCOPES),
-    (make_tiny_deepseek_v3, CONV_SCOPES)],
-    ids=["llama", "mixtral", "longcat", "lfm2", "deepseek_v3"])
+     MOE_SCOPES | MLA_SCOPES | CONV_SCOPES | SHARED_SCOPES | SSM_SCOPES),
+    (make_tiny_mixtral,
+     MLA_SCOPES | CONV_SCOPES | SHARED_SCOPES | SSM_SCOPES),
+    (make_tiny_longcat, CONV_SCOPES | SHARED_SCOPES | SSM_SCOPES),
+    (make_tiny_lfm2, MLA_SCOPES | SHARED_SCOPES | SSM_SCOPES),
+    (make_tiny_deepseek_v3, CONV_SCOPES | SSM_SCOPES),
+    (make_tiny_falcon_h1,
+     MOE_SCOPES | MLA_SCOPES | CONV_SCOPES | SHARED_SCOPES)],
+    ids=["llama", "mixtral", "longcat", "lfm2", "deepseek_v3",
+         "falcon_h1"])
 def test_exported_step_programs_hold_every_scope(make, absent, tmp_path):
     programs = _exported_programs(
         make(str(tmp_path / "m")), str(tmp_path / "cache"))

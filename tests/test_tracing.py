@@ -18,6 +18,7 @@ import asyncio
 import json
 import multiprocessing
 import os
+import time
 
 import pytest
 from aiohttp.test_utils import TestClient, TestServer
@@ -716,8 +717,20 @@ def test_first_chunk_time_is_the_sum_of_the_spans_on_its_way(traced_app):
 
     async def go(client):
         ids = [(await _stream(client, max_tokens=6))[0] for _ in range(3)]
-        payload = await (await client.get("/debug/traces")).json()
-        return ids, payload
+        # A step's trace closes on the engine thread, which a busy
+        # machine may run after the stream's last byte: wait for the
+        # steps read below, ten seconds at most.
+        deadline = time.monotonic() + 10.0
+        while True:
+            payload = await (await client.get("/debug/traces")).json()
+            closed = sum(
+                s["name"] == "engine.step"
+                for t in payload["traces"] for s in t["spans"])
+            have = {t["trace_id"] for t in payload["traces"]}
+            if (closed >= 6 and have.issuperset(ids)) or (
+                    time.monotonic() > deadline):
+                return ids, payload
+            await asyncio.sleep(0.05)
 
     ids, payload = _with_client(traced_app, go)
     traces = {t["trace_id"]: t for t in payload["traces"]}
@@ -773,9 +786,11 @@ def test_first_chunk_time_is_the_sum_of_the_spans_on_its_way(traced_app):
     assert sum(f["attributes"]["first_outputs"] for f in flushes) == 3
     # the ring says what it lost
     assert payload["evicted"] == 0
+    # (the ring's oldest trace: where a collection or a compile left a
+    # span, the process trace stands before it and is no part of the ring)
+    ring = [t for t in payload["traces"] if t["root_span_id"] is not None]
     assert payload["oldest_start"] == min(
-        s["start"] for s in payload["traces"][0]["spans"]
-        if s["name"] != "gc.collect")
+        s["start"] for s in ring[0]["spans"] if s["name"] != "gc.collect")
 
 
 def test_a_collection_is_a_span_of_the_process_trace(traced_app):

@@ -144,6 +144,7 @@ class LLMEngine:
         self._telemetry_mla_kv_rows_seen: dict[str, int] = {}
         self._telemetry_expert_picks_seen: dict[str, int] = {}
         self._telemetry_state_rows_seen: dict[str, int] = {}
+        self._telemetry_ssm_chunk_seen: dict[str, int] = {}
         self._telemetry_backend_compiles_seen: dict[str, int] = {}
         self._telemetry_backend_seconds_seen: dict[str, float] = {}
         self._telemetry_moe_grouped_seen = 0
@@ -743,6 +744,11 @@ class LLMEngine:
                 "seq_state_rows",
                 self._telemetry_state_rows_seen,
                 self.metrics.record_seq_state_rows,
+            ),
+            (
+                "ssm_chunk_tokens",
+                self._telemetry_ssm_chunk_seen,
+                self.metrics.record_ssm_chunk_tokens,
             ),
             (
                 "backend_compiles",

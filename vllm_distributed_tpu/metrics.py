@@ -159,6 +159,8 @@ DOCUMENTED_METRICS = (
     "vllm:moe_picks_total",
     "vllm:moe_expert_picks_total",
     "vllm:seq_state_rows_total",
+    "vllm:seq_state_bytes",
+    "vllm:ssm_chunk_tokens_total",
     "vllm:mla_chunk_tokens_total",
     "vllm:mla_kv_rows_total",
     # ---- causes outside any thread's phases (ISSUE 40) ----
@@ -637,6 +639,25 @@ class EngineMetrics:
             ["model_name", "kind"],
             registry=self.registry,
         )
+        self._seq_state_bytes = Gauge(
+            "vllm:seq_state_bytes",
+            "Bytes of per-sequence state the reply-rank worker "
+            "allocated beside its paged caches, at its sequence bucket, "
+            "by kind of state (conv: the rows a short convolution looks "
+            "back on; ssm: a state-space state)",
+            ["model_name", "kind"],
+            registry=self.registry,
+        )
+        self._ssm_chunk_tokens = Counter(
+            "vllm:ssm_chunk_tokens",
+            "Tokens of the single steps the reply-rank worker "
+            "dispatched for a model with a chunked state-space scan: "
+            "live (the steps' own tokens), tiled (the chunk size times "
+            "the chunk tiles the scans walked); live / tiled is the "
+            "tiles' fill",
+            ["model_name", "kind"],
+            registry=self.registry,
+        )
         self._mla_chunk_tokens = Counter(
             "vllm:mla_chunk_tokens",
             "Tokens of multi-token chunks in the chunked steps the "
@@ -1021,6 +1042,12 @@ class EngineMetrics:
                 model_name=self._model_name, kind=kind
             ).inc(n)
 
+    def record_ssm_chunk_tokens(self, kind: str, n: int) -> None:
+        if self.enabled and n > 0:
+            self._ssm_chunk_tokens.labels(
+                model_name=self._model_name, kind=kind
+            ).inc(n)
+
     def record_mla_chunk_tokens(self, form: str, n: int) -> None:
         if self.enabled and n > 0:
             self._mla_chunk_tokens.labels(
@@ -1068,6 +1095,10 @@ class EngineMetrics:
             return
         self.hbm_live_bytes.set(snap.get("hbm_live_bytes", 0) or 0)
         self.step_roofline_frac.set(snap.get("roofline_frac", 0.0) or 0.0)
+        for kind, n in (snap.get("seq_state_bytes") or {}).items():
+            self._seq_state_bytes.labels(
+                model_name=self._model_name, kind=str(kind)
+            ).set(n)
 
     def slo_snapshot(self, include_timelines: bool = True) -> dict | None:
         """Replica /slo view (None while metrics are disabled)."""

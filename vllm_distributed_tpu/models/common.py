@@ -40,11 +40,17 @@ SCOPE_SHORT_CONV = "short_conv"
 # a routed layer's always-on shared expert (norm excluded): what every
 # chip of an expert-parallel group computes alike
 SCOPE_MOE_SHARED = "moe_shared"
+# a Mamba-2 mixer whole (the layer's input norm, in_proj, the
+# convolution and its state, the gate, the grouped norm, out_proj) and,
+# inside it, the state-space recurrence alone (the chunked scan or the
+# one-token update, with the state's read and write)
+SCOPE_SSM_MIXER = "ssm_mixer"
+SCOPE_SSM_SCAN = "ssm_scan"
 SCOPES = (
     SCOPE_EMBED, SCOPE_ATTN_QKV, SCOPE_KV_WRITE, SCOPE_ATTN,
     SCOPE_ATTN_OUT, SCOPE_MOE_ROUTER, SCOPE_MOE_EXPERTS, SCOPE_MLP,
     SCOPE_LM_HEAD, SCOPE_SAMPLER, SCOPE_MLA_ABSORB, SCOPE_SHORT_CONV,
-    SCOPE_MOE_SHARED,
+    SCOPE_MOE_SHARED, SCOPE_SSM_MIXER, SCOPE_SSM_SCAN,
 )
 
 

@@ -186,10 +186,9 @@ class Lfm2MoeForCausalLM(SupportsQuantization):
     def seq_state_layout(self) -> SeqStateLayout:
         """One state a convolution layer: the last ``conv_L_cache - 1``
         gated rows of a sequence."""
-        return SeqStateLayout(
-            num_states=len(self.conv_layers),
-            rows=self.conv_taps - 1,
-            width=self.hidden_size,
+        return SeqStateLayout.one_kind(
+            "conv", len(self.conv_layers), self.conv_taps - 1,
+            self.hidden_size,
         )
 
     def kv_cache_spec(self) -> P:

@@ -18,6 +18,7 @@ def _populate() -> None:
     if _REGISTRY:
         return
     from vllm_distributed_tpu.models.deepseek_v3 import DeepseekV3ForCausalLM
+    from vllm_distributed_tpu.models.falcon_h1 import FalconH1ForCausalLM
     from vllm_distributed_tpu.models.lfm2_moe import Lfm2MoeForCausalLM
     from vllm_distributed_tpu.models.llama import LlamaForCausalLM
     from vllm_distributed_tpu.models.longcat_flash import (
@@ -32,6 +33,7 @@ def _populate() -> None:
     register_model(LongcatFlashForCausalLM)
     register_model(Lfm2MoeForCausalLM)
     register_model(DeepseekV3ForCausalLM)
+    register_model(FalconH1ForCausalLM)
 
 
 def get_model_class(architecture: str):

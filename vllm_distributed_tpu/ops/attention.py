@@ -36,7 +36,8 @@ oracle (tested against each other, SURVEY.md §4.2) and the CPU fallback.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+import math
+from dataclasses import dataclass, field
 from functools import partial
 
 import jax
@@ -130,27 +131,82 @@ class KVCacheLayout:
 
 
 @dataclass(frozen=True)
+class StateKind:
+    """One kind of state a sequence keeps: ``num_layers`` arrays ``[R,
+    *shape]`` (one a layer that has such state), ``shape`` the state of
+    one sequence in one layer (``(rows, width)`` for the rows a short
+    convolution looks back on, ``(heads, head_dim, d_state)`` for a
+    state-space state), ``dtype`` its stored type (None: the model's)."""
+
+    name: str
+    shape: tuple
+    num_layers: int
+    dtype: str | None = None
+
+    def seq_bytes(self, model_dtype) -> int:
+        """Bytes one sequence holds of this kind over all its layers."""
+        itemsize = jnp.dtype(self.dtype or model_dtype).itemsize
+        return self.num_layers * math.prod(self.shape) * itemsize
+
+
+@dataclass(frozen=True)
 class SeqStateLayout:
     """What a model states of the state it keeps a *sequence*, beside
-    its paged caches: ``num_states`` arrays ``[R, rows, width]`` (one a
-    layer that has such state), ``R`` the runner's sequence bucket, a
-    request's row the one its last sampled token has in the device
-    carry (``CachedReqState.slot``).  The state is whatever length the
-    sequence has, so it is no part of ``KVCacheLayout.token_bytes``: its
-    bytes come off the pool once, at ``R`` rows.  Nothing clears a row
-    when it changes hands: a model reads of it only what lies at a
-    position >= 0 of the sequence that holds it now."""
+    its paged caches: a list of state kinds (``StateKind``), each with
+    a shape, a dtype and a count of layers of its own.  The runner
+    allocates every kind's arrays at ``R`` rows, its sequence bucket; a
+    request's row is the one its last sampled token has in the device
+    carry (``CachedReqState.slot``), the same in every array.  The
+    state is whatever length the sequence has, so it is no part of
+    ``KVCacheLayout.token_bytes``: its bytes come off the pool once, at
+    ``R`` rows.  Nothing clears a row when it changes hands: a model
+    reads of it only what lies at a position >= 0 of the sequence that
+    holds it now.
 
-    num_states: int
-    rows: int
-    width: int
+    Which models state which: ``Lfm2MoeForCausalLM`` one kind, ``conv``
+    (the last two gated rows of every convolution layer, model dtype);
+    ``FalconH1ForCausalLM`` two, ``conv`` (the last three rows that
+    enter every layer's four-tap convolution) and ``ssm`` (every
+    layer's state-space state, heads × head size × state size)."""
 
-    def shape(self, num_seqs: int) -> tuple:
-        return (num_seqs, self.rows, self.width)
+    kinds: tuple
 
-    def seq_bytes(self, itemsize: int) -> int:
+    @classmethod
+    def one_kind(
+        cls, name: str, num_layers: int, rows: int, width: int
+    ) -> "SeqStateLayout":
+        """One kind of ``rows`` rows of ``width`` in the model's dtype."""
+        return cls(kinds=(StateKind(name, (rows, width), num_layers),))
+
+    @property
+    def num_states(self) -> int:
+        return sum(k.num_layers for k in self.kinds)
+
+    def arrays(self, num_seqs: int, model_dtype) -> list[tuple]:
+        """(shape, dtype) of every array at ``num_seqs`` rows, in the
+        order ``SeqState.states`` holds them: kind by kind, each kind's
+        layers in layer order."""
+        return [
+            ((num_seqs, *k.shape), jnp.dtype(k.dtype or model_dtype))
+            for k in self.kinds
+            for _ in range(k.num_layers)
+        ]
+
+    def by_kind(self, states: list) -> dict:
+        """``SeqState.states`` split into each kind's per-layer list."""
+        out, lo = {}, 0
+        for k in self.kinds:
+            out[k.name] = states[lo : lo + k.num_layers]
+            lo += k.num_layers
+        return out
+
+    def bytes_by_kind(self, model_dtype) -> dict:
+        """Bytes one sequence holds of each kind."""
+        return {k.name: k.seq_bytes(model_dtype) for k in self.kinds}
+
+    def seq_bytes(self, model_dtype) -> int:
         """Bytes one sequence holds over all of the model's states."""
-        return self.num_states * self.rows * self.width * itemsize
+        return sum(self.bytes_by_kind(model_dtype).values())
 
 
 @jax.tree_util.register_dataclass
@@ -159,17 +215,25 @@ class SeqState:
     """One step's view of the sequence state: the arrays, and which row
     of them each of the step's sequences holds.
 
-      states:    ``num_states`` arrays [R, rows, width]
+      states:    the layout's arrays [R, *kind.shape], kind by kind
+                 (``SeqStateLayout.arrays``; ``by_kind`` splits them)
       slots:     [S] int32 — row of each sequence of the step; a padding
                  or masked sequence points at R (past the end: what a
                  model writes for it is dropped)
       last_rows: [S] int32 — flat index of each sequence's last token of
                  the step (what is left behind is the state after it)
+      max_q:     static — the step's longest-chunk bucket; 1 says every
+                 sequence of the step has one token (the fused scan's
+                 micro-steps, a decode-only single step), 0 that nothing
+                 is known.  A model whose state costs more to walk in
+                 chunks than to update by a token reads it; no array
+                 depends on it.
     """
 
     states: list
     slots: jax.Array
     last_rows: jax.Array
+    max_q: int = field(default=0, metadata=dict(static=True))
 
 
 def quantize_kv_heads(

@@ -324,6 +324,91 @@ def write_lfm2_config(dirname: str | None = None, **sizes) -> str:
     return dirname
 
 
+def falcon_h1_config(
+    *,
+    vocab_size: int = 128,
+    hidden: int = 64,
+    intermediate: int = 96,
+    layers: int = 2,
+    heads: int = 5,
+    kv_heads: int = 1,
+    head_dim: int = 16,
+    ssm_heads: int = 4,
+    ssm_head_dim: int = 8,
+    ssm_groups: int = 2,
+    ssm_state: int = 16,
+    chunk: int = 8,
+    max_pos: int = 2048,
+    dtype: str = "float32",
+    **more,
+) -> dict:
+    """A Falcon-H1-architecture config (a Mamba-2 mixer beside
+    grouped-query attention in every layer; a query group of 5, no
+    power of two, as published), keys and multipliers as the published
+    34B file spells them."""
+    return {
+        "architectures": ["FalconH1ForCausalLM"],
+        "model_type": "falcon_h1",
+        "attention_bias": False,
+        "attention_in_multiplier": 1,
+        "attention_out_multiplier": 0.0375,
+        "attn_layer_indices": None,
+        "embedding_multiplier": 5.656854249492381,
+        "head_dim": head_dim,
+        "hidden_act": "silu",
+        "hidden_size": hidden,
+        "intermediate_size": intermediate,
+        "key_multiplier": 0.011048543456039804,
+        "lm_head_multiplier": 0.0078125,
+        "mamba_chunk_size": chunk,
+        "mamba_conv_bias": True,
+        "mamba_d_conv": 4,
+        "mamba_d_head": ssm_head_dim,
+        "mamba_d_ssm": ssm_heads * ssm_head_dim,
+        "mamba_d_state": ssm_state,
+        "mamba_expand": 2,
+        "mamba_n_groups": ssm_groups,
+        "mamba_n_heads": ssm_heads,
+        "mamba_norm_before_gate": False,
+        "mamba_proj_bias": False,
+        "mamba_rms_norm": True,
+        "mamba_use_mlp": True,
+        "max_position_embeddings": max_pos,
+        "mlp_bias": False,
+        "mlp_expansion_factor": 8,
+        "mlp_multipliers": [0.1767766952966369, 0.011160714285714284],
+        "num_attention_heads": heads,
+        "num_hidden_layers": layers,
+        "num_key_value_heads": kv_heads,
+        "num_logits_to_keep": 1,
+        "projectors_bias": False,
+        "rms_norm_eps": 1e-5,
+        "rope_scaling": None,
+        "rope_theta": 100000000000,
+        "ssm_in_multiplier": 0.25,
+        "ssm_multipliers": [0.3535533905932738, 0.25, 0.1767766952966369,
+                            0.5, 0.3535533905932738],
+        "ssm_out_multiplier": 0.08838834764831845,
+        "tie_word_embeddings": False,
+        "vocab_size": vocab_size,
+        "torch_dtype": dtype,
+        "bos_token_id": 1,
+        "eos_token_id": 2,
+        **more,
+    }
+
+
+def write_falcon_h1_config(dirname: str | None = None, **sizes) -> str:
+    """Write ``falcon_h1_config(**sizes)`` as a config.json; returns
+    the directory."""
+    if dirname is None:
+        dirname = tempfile.mkdtemp(prefix="vdt_tiny_falcon_h1_")
+    os.makedirs(dirname, exist_ok=True)
+    with open(os.path.join(dirname, "config.json"), "w") as f:
+        json.dump(falcon_h1_config(**sizes), f)
+    return dirname
+
+
 def full_attention_reference(q, k, v, scale, causal=True):
     """Dense (non-paged) attention oracle [T, H, D] with GQA repeat —
     the reference for ring attention (tests + multichip dryrun)."""

@@ -520,9 +520,7 @@ class ModelRunner:
         layout = self._state_layout
         if layout is None:
             return 0
-        return self._seq_bucket() * layout.seq_bytes(
-            jnp.dtype(self.model.dtype).itemsize
-        )
+        return self._seq_bucket() * layout.seq_bytes(self.model.dtype)
 
     def _refuse_unsupported_seq_state(self) -> None:
         """What state that is not pages of tokens cannot do yet fails
@@ -855,8 +853,8 @@ class ModelRunner:
         if layout is None:
             return None
         return [
-            jnp.zeros(layout.shape(num_seqs), self.model.dtype)
-            for _ in range(layout.num_states)
+            jnp.zeros(shape, dtype)
+            for shape, dtype in layout.arrays(num_seqs, self.model.dtype)
         ]
 
     def init_kv_cache(self, num_pages: int) -> None:
@@ -864,13 +862,23 @@ class ModelRunner:
         self.kv_caches = self.alloc_kv_pool(num_pages)
         layout = self._state_layout
         if layout is not None:
+            rows = self._seq_bucket()
+            by_kind = {
+                name: rows * n
+                for name, n in layout.bytes_by_kind(self.model.dtype).items()
+            }
+            self.telemetry.seq_state_bytes = by_kind
             logger.info(
-                "sequence state: %d states × %d sequences × %d rows × %d "
-                "(%.2f MiB, off the KV pool)",
-                layout.num_states,
-                self._seq_bucket(),
-                layout.rows,
-                layout.width,
+                "sequence state: %s at %d sequences (%.2f MiB, off the "
+                "KV pool)",
+                "; ".join(
+                    f"{k.num_layers} × {k.name} "
+                    f"{'×'.join(map(str, k.shape))} "
+                    f"{jnp.dtype(k.dtype or self.model.dtype).name} "
+                    f"({by_kind[k.name] / 2**20:.2f} MiB)"
+                    for k in layout.kinds
+                ),
+                rows,
                 self._seq_state_bytes() / 2**20,
             )
         _log_device_memory("after KV pool")
@@ -886,11 +894,14 @@ class ModelRunner:
         slots[: len(states)] = [st.slot for st in states]
         return [slots]
 
-    def _forward(self, params, tokens, caches, meta, state_slots, **kw):
+    def _forward(
+        self, params, tokens, caches, meta, state_slots, max_q=0, **kw
+    ):
         """``model.forward`` for both kinds of model: (logits, caches as
         ``kv_caches`` holds them).  ``state_slots`` [S]: each
         sequence's state row; a sequence's last token of the step is
-        the one its logits are read at."""
+        the one its logits are read at; ``max_q`` the step's
+        longest-chunk bucket."""
         if self._state_layout is None:
             return self.model.forward(params, tokens, caches, meta, **kw)
         kv, states = caches
@@ -898,7 +909,7 @@ class ModelRunner:
             params, tokens, kv, meta,
             seq_state=SeqState(
                 states=states, slots=state_slots,
-                last_rows=meta.logits_indices,
+                last_rows=meta.logits_indices, max_q=max_q,
             ),
             **kw,
         )
@@ -1620,7 +1631,17 @@ class ModelRunner:
                 max_q_pad, t_pad, num_heads=self.model.num_heads,
                 value_width=self.model.kv_lora_rank,
             )
+        step_max_q_class = getattr(self.model, "step_max_q_class", None)
+        if step_max_q_class is not None:
+            # ... and so are those the model says it compiles alike
+            max_q_pad = step_max_q_class(max_q_pad, t_pad)
         smeta_np, flags = self._build_sampling_metadata(states, s_pad)
+        # Whether a request of the step reads logprobs; a model may ask
+        # for one single-step program either way (the program computes
+        # them, the host fetches them only when wanted).
+        want_lp = flags["return_logprobs"]
+        if getattr(self.model, "step_logprobs_always", False):
+            flags["return_logprobs"] = True
         # The PRNG stream position is the index of the token a row
         # samples, by the dispatched count: the mirrored token list may
         # lag behind it.
@@ -1689,6 +1710,8 @@ class ModelRunner:
             counts.update(self._mla_chunk_tokens(num_new, chunk_starts))
         if self._state_layout is not None:
             counts.update(self._count_state_rows(starts))
+        if getattr(self.model, "ssm_chunk_size", 0) and max_q_pad > 1:
+            counts.update(self._ssm_chunk_tokens(t_real, t_pad))
         prep.end()
         t_step0 = time.perf_counter()
         sampled, logprobs, self.kv_caches, self._carry = self._run_program(
@@ -1711,7 +1734,7 @@ class ModelRunner:
             with get_tracer().span(
                 "runner.fetch", parent=step_ctx, step_id=so.step_id
             ) as fetch:
-                if logprobs is not None:
+                if logprobs is not None and want_lp:
                     host_toks, host_lps = jax.device_get((sampled, logprobs))
                     host_lps = np.asarray(host_lps)
                 else:
@@ -1858,6 +1881,7 @@ class ModelRunner:
             kv_caches,
             meta,
             state_slots,
+            max_q=max_q_pad,
             attn_fn=attn_fn,
             kv_write_fn=self._kv_write_fn,
             **stats,
@@ -1898,6 +1922,20 @@ class ModelRunner:
         )
         for kind, n in counts.items():
             span.set_attribute(f"moe_picks_{kind}", n)
+
+    def _ssm_chunk_tokens(self, t_real: int, t_pad: int) -> dict:
+        """A single step's tokens as its chunked state-space scans walk
+        them: the dispatch span's ``ssm_tokens_live`` (the step's own)
+        and ``ssm_tokens_tiled`` (the tile length times the tiles a
+        scan walks: every tile of the token bucket, padding or not),
+        and the counter's two kinds.  A step in which every sequence
+        has one token runs the one-token update and counts nothing."""
+        tile = min(self.model.ssm_chunk_size, t_pad)
+        tiled = tile * cdiv(t_pad, tile)
+        self.telemetry.record_ssm_chunk_tokens(
+            {"live": t_real, "tiled": tiled}
+        )
+        return dict(ssm_tokens_live=t_real, ssm_tokens_tiled=tiled)
 
     def _count_state_rows(self, starts: list[int]) -> dict:
         """A step's sequences by whether their state is read: the
@@ -2478,6 +2516,7 @@ class ModelRunner:
                     states=states,
                     slots=jnp.where(live, state_slots, states[0].shape[0]),
                     last_rows=rows,
+                    max_q=1,
                 )
             if staged:
                 entries = [

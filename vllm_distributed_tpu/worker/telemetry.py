@@ -200,6 +200,10 @@ class DeviceTelemetry:
         self.moe_picks: dict[str, int] = {}
         self.moe_expert_picks: dict[str, int] = {}
         self.seq_state_rows: dict[str, int] = {}
+        # bytes of sequence state allocated, by kind (set once, when
+        # the runner allocates its KV pool)
+        self.seq_state_bytes: dict[str, int] = {}
+        self.ssm_chunk_tokens: dict[str, int] = {}
         self.mla_chunk_tokens: dict[str, int] = {}
         self.mla_kv_rows: dict[str, int] = {}
         _install_cache_listener()
@@ -227,6 +231,12 @@ class DeviceTelemetry:
         """Sequences of one dispatched step whose state rows it reads
         and rewrites, by kind (fresh | carried)."""
         self._add(self.seq_state_rows, counts)
+
+    def record_ssm_chunk_tokens(self, counts: dict[str, int]) -> None:
+        """Tokens of one dispatched single step of a model with a
+        chunked state-space scan: live (the step's own tokens) | tiled
+        (chunk size × the chunk tiles its scans walked)."""
+        self._add(self.ssm_chunk_tokens, counts)
 
     def record_mla_chunk_tokens(self, counts: dict[str, int]) -> None:
         """Tokens of one dispatched chunked step's multi-token chunks by
@@ -294,6 +304,8 @@ class DeviceTelemetry:
                 "moe_picks": dict(self.moe_picks),
                 "moe_expert_picks": dict(self.moe_expert_picks),
                 "seq_state_rows": dict(self.seq_state_rows),
+                "seq_state_bytes": dict(self.seq_state_bytes),
+                "ssm_chunk_tokens": dict(self.ssm_chunk_tokens),
                 "mla_chunk_tokens": dict(self.mla_chunk_tokens),
                 "mla_kv_rows": dict(self.mla_kv_rows),
                 "persistent_cache": persistent_cache_counts(),
