@@ -652,9 +652,9 @@ class EngineMetrics:
             "vllm:ssm_chunk_tokens",
             "Tokens of the single steps the reply-rank worker "
             "dispatched for a model with a chunked state-space scan: "
-            "live (the steps' own tokens), tiled (the chunk size times "
-            "the chunk tiles the scans walked); live / tiled is the "
-            "tiles' fill",
+            "live (the steps' own tokens), tiled (the token slots the "
+            "scans' matrix products ran over: the bucket's tiles in the plain "
+            "form, each sequence's windows in the kernel); live / tiled: fill",
             ["model_name", "kind"],
             registry=self.registry,
         )

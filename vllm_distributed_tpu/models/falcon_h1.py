@@ -49,6 +49,10 @@ both from the sequence's row; a sequence at position 0 starts from
 zeros without any row being cleared.  The recurrence runs in
 ``ops/ssm.py``: chunked over a single step's flat ragged batch, one
 multiply-add a row where every sequence has one token (the fused scan).
+Under the Pallas backend the chunked form runs as one kernel a layer
+(``ops/pallas/ssd_chunk_scan.py``, ``vdt_ssd_chunk_scan``) where the
+shapes are the ones it is written for: the plain form stays the oracle
+and the path of every other backend and shape.
 
 Seeded weights only (``--load-format dummy``).  The published
 multipliers are made for trained weights; ``init_params`` draws each
@@ -63,6 +67,7 @@ the host spill tier, KV transfer (worker/model_runner.py).
 
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Any, Callable
 
@@ -96,11 +101,13 @@ from vllm_distributed_tpu.ops.attention import (
     paged_attention_reference,
     write_kv_pages,
 )
+from vllm_distributed_tpu.ops.pallas import ssd_chunk_scan as ssd_kernel
 from vllm_distributed_tpu.ops.ssm import (
     ragged_causal_conv,
     ssd_chunk_scan,
     ssm_update,
 )
+from vllm_distributed_tpu.utils import cdiv
 
 # The seeded recipe (benchmark/reference/falcon_h1.py states the same
 # numbers).  A matrix [in, out] is ``normal × gain · in^-½ / m``, ``m``
@@ -114,6 +121,13 @@ DT_MIN, DT_MAX = 0.001, 0.1  # dt_bias: inverse softplus of log-uniform
 A_MIN, A_MAX = 1.0, 16.0  # A_log = log of uniform
 CONV_BIAS_STD = 0.02
 KEYS_PER_LAYER = 17
+
+
+@contextlib.contextmanager
+def _scan_scope():
+    """``ssm_scan`` inside ``ssm_mixer``, from outside both."""
+    with jax.named_scope(SCOPE_SSM_MIXER), jax.named_scope(SCOPE_SSM_SCAN):
+        yield
 
 
 def inv_softplus(x):
@@ -206,6 +220,13 @@ class FalconH1ForCausalLM(SupportsQuantization):
             getattr(hf, "ssm_state_dtype", None) or self.dtype
         )
         self.scale = self.head_dim**-0.5
+        # The backend a chunked single step's recurrence may use: the
+        # kernel where Pallas runs and the shapes are the ones it is
+        # written for (``_scan_in_kernel``), the plain form elsewhere.
+        from vllm_distributed_tpu import envs
+        from vllm_distributed_tpu.ops import resolve_kernel_backend
+
+        self.kernel_backend = resolve_kernel_backend(envs.VDT_USE_PALLAS)
         self._init_quant(model_config)
 
     # ---- what the runner reads of the caches and the state ----
@@ -243,8 +264,8 @@ class FalconH1ForCausalLM(SupportsQuantization):
     def step_max_q_class(self, max_q: int, tokens: int) -> int:
         """The longest-chunk bucket a single step of ``tokens`` rows is
         compiled for: 1 where every sequence has one token (the
-        one-token update), else the token bucket itself.  The scan
-        walks the token bucket's tiles whatever the chunks' lengths,
+        one-token update), else the token bucket itself.  The scan's
+        shapes are the token bucket's whatever the chunks' lengths,
         and the attention kernel's query tile is full at 128 tokens for
         these heads (beyond it ``max_q`` only bounds a tile list that
         the rows bound as well; below it a short chunk fills part of a
@@ -351,11 +372,30 @@ class FalconH1ForCausalLM(SupportsQuantization):
         return params
 
     # ---- forward ----
-    def _mixer(self, u, layer: dict, conv_state, ssm_state, seq: SeqState,
-               meta):
-        """The Mamba-2 mixer over the flat batch: (out [T, hidden]
-        before ``ssm_out_multiplier``, the layer's two states with this
-        step's sequences' rows rewritten)."""
+    def _scan_in_kernel(self, tokens: int) -> bool:
+        """Whether a chunked single step of ``tokens`` rows runs its
+        recurrence in ``vdt_ssd_chunk_scan``: by backend and by shape,
+        nothing else."""
+        return self.kernel_backend != "reference" and ssd_kernel.accepts(
+            self.ssm_head_dim, self.ssm_state, tokens, self.ssm_chunk_size
+        )
+
+    def ssm_tokens_walked(self, chunk_lens, tokens: int) -> int:
+        """Token slots the recurrence's matrix products run over in a
+        chunked single step of ``tokens`` rows whose sequences have
+        ``chunk_lens`` tokens, in batch order: every tile of the bucket
+        in the plain form, each sequence's own windows in the kernel
+        (the runner's ``ssm_tokens_tiled``)."""
+        if self._scan_in_kernel(tokens):
+            return ssd_kernel.walked_tokens(chunk_lens, self.ssm_chunk_size)
+        tile = min(self.ssm_chunk_size, tokens)
+        return tile * cdiv(tokens, tile)
+
+    def _mixer_in(self, u, layer: dict, conv_state, seq: SeqState, meta):
+        """The mixer up to the recurrence: (the gate z [T, d_ssm]
+        float32, the recurrence's inputs (x, Δ, A, B, C, each token's
+        state row), the layer's convolution state with this step's
+        sequences' rows rewritten)."""
         t = u.shape[0]
         f32 = jnp.float32
         hs, hp, g, n = (
@@ -401,22 +441,43 @@ class FalconH1ForCausalLM(SupportsQuantization):
         b = xbc_c[:, self.d_ssm : self.d_ssm + g * n].reshape(t, g, n)
         c = xbc_c[:, self.d_ssm + g * n :].reshape(t, g, n)
         a = -jnp.exp(layer["a_log"])
-        with jax.named_scope(SCOPE_SSM_SCAN):
+        return z, (x, dt, a, b, c, tok_rows), conv_state
+
+    def _recurrence(self, inputs, layer: dict, ssm_state, seq: SeqState,
+                    meta):
+        """(y [T, H, P] float32, the layer's state-space state with this
+        step's sequences' rows rewritten).  It names its own scopes:
+        ``ssm_scan`` inside ``ssm_mixer``, but for the kernel's call,
+        which lies under neither (a trace files a kernel's time under
+        its name and under the scope around it: under both it would be
+        counted twice)."""
+        x, dt, a, b, c, tok_rows = inputs
+        chunked = dict(
+            seq_ids=meta.q_seq_ids, positions=meta.q_positions,
+            chunk_starts=meta.chunk_starts, slots=seq.slots,
+            last_rows=seq.last_rows, chunk=self.ssm_chunk_size,
+        )
+        if seq.max_q > 1 and self._scan_in_kernel(x.shape[0]):
+            return ssd_kernel.ssd_chunk_scan(
+                x, dt, a, b, c, layer["d_skip"], ssm_state, **chunked,
+                scope=_scan_scope,
+                interpret=self.kernel_backend == "pallas_interpret",
+            )
+        with _scan_scope():
             if seq.max_q == 1:
-                y, ssm_state = ssm_update(
+                return ssm_update(
                     x, dt, a, b, c, layer["d_skip"], ssm_state,
-                    tok_rows, pos == 0,
+                    tok_rows, meta.q_positions == 0,
                 )
-            else:
-                y, ssm_state = ssd_chunk_scan(
-                    x, dt, a, b, c, layer["d_skip"], ssm_state,
-                    seq_ids=sid, positions=pos,
-                    chunk_starts=meta.chunk_starts, slots=seq.slots,
-                    last_rows=seq.last_rows, chunk=self.ssm_chunk_size,
-                )
-        y = self._gated_norm(y.reshape(t, self.d_ssm), z, layer)
-        return linear(y.astype(u.dtype), layer["out_proj"]), (
-            conv_state, ssm_state)
+            return ssd_chunk_scan(
+                x, dt, a, b, c, layer["d_skip"], ssm_state, **chunked
+            )
+
+    def _mixer_out(self, y, z, layer: dict, dtype):
+        """From the recurrence's output to the mixer's [T, hidden]
+        (before ``ssm_out_multiplier``)."""
+        y = self._gated_norm(y.reshape(y.shape[0], self.d_ssm), z, layer)
+        return linear(y.astype(dtype), layer["out_proj"])
 
     def _gated_norm(self, y, z, layer: dict):
         """The gate first, then the RMS norm over each group's lanes
@@ -477,10 +538,14 @@ class FalconH1ForCausalLM(SupportsQuantization):
         for i, layer in enumerate(params["layers"]):
             with jax.named_scope(SCOPE_SSM_MIXER):
                 u = rms_norm(x, layer["in_ln"], self.rms_eps)
-                mixed, (conv_i, ssm_i) = self._mixer(
-                    u, layer, states["conv"][i], states["ssm"][i],
-                    seq_state, meta,
+                z, scan_in, conv_i = self._mixer_in(
+                    u, layer, states["conv"][i], seq_state, meta
                 )
+            y, ssm_i = self._recurrence(
+                scan_in, layer, states["ssm"][i], seq_state, meta
+            )
+            with jax.named_scope(SCOPE_SSM_MIXER):
+                mixed = self._mixer_out(y, z, layer, u.dtype)
             new_conv.append(conv_i)
             new_ssm.append(ssm_i)
             attn, kv = self._attention(

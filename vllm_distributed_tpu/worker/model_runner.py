@@ -1711,7 +1711,7 @@ class ModelRunner:
         if self._state_layout is not None:
             counts.update(self._count_state_rows(starts))
         if getattr(self.model, "ssm_chunk_size", 0) and max_q_pad > 1:
-            counts.update(self._ssm_chunk_tokens(t_real, t_pad))
+            counts.update(self._ssm_chunk_tokens(num_new, t_pad))
         prep.end()
         t_step0 = time.perf_counter()
         sampled, logprobs, self.kv_caches, self._carry = self._run_program(
@@ -1923,15 +1923,15 @@ class ModelRunner:
         for kind, n in counts.items():
             span.set_attribute(f"moe_picks_{kind}", n)
 
-    def _ssm_chunk_tokens(self, t_real: int, t_pad: int) -> dict:
+    def _ssm_chunk_tokens(self, num_new: list[int], t_pad: int) -> dict:
         """A single step's tokens as its chunked state-space scans walk
         them: the dispatch span's ``ssm_tokens_live`` (the step's own)
-        and ``ssm_tokens_tiled`` (the tile length times the tiles a
-        scan walks: every tile of the token bucket, padding or not),
+        and ``ssm_tokens_tiled`` (the token slots the scans' matrix
+        products run over: ``model.ssm_tokens_walked`` of the chunks),
         and the counter's two kinds.  A step in which every sequence
         has one token runs the one-token update and counts nothing."""
-        tile = min(self.model.ssm_chunk_size, t_pad)
-        tiled = tile * cdiv(t_pad, tile)
+        t_real = sum(num_new)
+        tiled = self.model.ssm_tokens_walked(num_new, t_pad)
         self.telemetry.record_ssm_chunk_tokens(
             {"live": t_real, "tiled": tiled}
         )
