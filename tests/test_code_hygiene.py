@@ -145,3 +145,60 @@ def test_every_file_the_readme_and_envs_comments_name_exists():
     assert not dangling, "files cited that are not in the tree:\n" + "\n".join(
         dangling
     )
+
+
+def _identifiers(tree: ast.AST) -> set[str]:
+    names = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Name):
+            names.add(node.id)
+        elif isinstance(node, ast.Attribute):
+            names.add(node.attr)
+        elif isinstance(node, ast.arg):
+            names.add(node.arg)
+        elif isinstance(node, ast.keyword) and node.arg:
+            names.add(node.arg)
+        elif isinstance(node, (ast.FunctionDef, ast.ClassDef)):
+            names.add(node.name)
+    return names
+
+
+def test_the_runner_and_the_engine_know_no_worker_counter_by_name():
+    """ISSUE 52: a worker counter is a row of ``metrics.WORKER_COUNTERS``
+    and nothing else in the two files every architecture used to edit:
+    no key of the table is an identifier, or part of one (a variable,
+    an attribute, a method, a parameter: ``record_moe_picks``,
+    ``_telemetry_moe_picks_seen``), in ``worker/model_runner.py`` or
+    ``engine/llm_engine.py``; the runner's sizing of the state arrays
+    it allocates is its own.  And what a step counts is said beside
+    the kernels: the runner imports the attention functions from
+    ``ops/pallas/{mla,paged}_attention`` and nothing else."""
+    from vllm_distributed_tpu.metrics import WORKER_COUNTERS
+
+    package = _ROOT / "vllm_distributed_tpu"
+    runner = ast.parse((package / "worker" / "model_runner.py").read_text())
+    engine = ast.parse((package / "engine" / "llm_engine.py").read_text())
+    for where, tree in (("model_runner.py", runner), ("llm_engine.py", engine)):
+        known = {
+            name for name in _identifiers(tree) - {"_seq_state_bytes"}
+            if any(key in name for key in WORKER_COUNTERS)
+        }
+        assert not known, f"{where} names worker counters: {sorted(known)}"
+    allowed = {
+        "mla_attention": {"mla_attention", "mla_attention_cpu"},
+        "paged_attention": {"paged_attention", "paged_attention_cpu"},
+    }
+    imported = {}
+    for node in ast.walk(runner):
+        if isinstance(node, ast.ImportFrom) and node.module:
+            head, _, module = node.module.rpartition(".")
+            if head.endswith("ops.pallas") and module in allowed:
+                imported.setdefault(module, set()).update(
+                    a.name for a in node.names)
+        elif isinstance(node, ast.Import):
+            assert not any(
+                a.name.endswith(tuple(f"ops.pallas.{m}" for m in allowed))
+                for a in node.names), "import the attention functions by name"
+    assert imported and all(
+        names <= allowed[module] for module, names in imported.items()
+    ), imported

@@ -115,7 +115,8 @@ def test_engine_agrees_with_the_reference_through_the_latent_cache(
         try:
             with_lp = _generate(engine, prompts, logprobs=3)
             fused = _generate(engine, prompts)
-            picks = dict(engine.executor.worker.runner.telemetry.moe_picks)
+            picks = dict(engine.executor.worker.runner.telemetry.counters[
+            "moe_picks"])
         finally:
             engine.shutdown()
     diff, regret = _worst(cfg, prompts, with_lp)
@@ -173,7 +174,7 @@ def test_the_branch_form_serves_the_same_logits(tmp_path):
             assert runner.model.moe_impl(layer, 32) == "branch"
             with_lp = _generate(engine, prompts, logprobs=3)
             fused = _generate(engine, prompts)
-            picks = dict(runner.telemetry.moe_picks)
+            picks = dict(runner.telemetry.counters["moe_picks"])
         finally:
             engine.shutdown()
     diff, regret = _worst(cfg, prompts, with_lp)
@@ -202,7 +203,8 @@ def test_picks_reach_the_metrics_and_the_fetch_spans(tmp_path):
         _generate(engine, _prompts()[:2], max_tokens=6)
         engine.refresh_device_telemetry()
         text = engine.metrics.render().decode()
-        picks = dict(engine.executor.worker.runner.telemetry.moe_picks)
+        picks = dict(engine.executor.worker.runner.telemetry.counters[
+            "moe_picks"])
         fetches = [s["attributes"] for tr in tracer.snapshot()
                    for s in tr["spans"] if s["name"] == "runner.fetch"]
     finally:

@@ -129,9 +129,9 @@ def test_engine_agrees_with_the_reference_at_every_split(backend, tmp_path):
             fused = _generate(engine, prompts, tag="f")
             scans = [s for s in _steps(tracer) if s[1] > 1]
             tel = engine.executor.worker.runner.telemetry
-            rows = dict(tel.seq_state_rows)
-            tiles = dict(tel.ssm_chunk_tokens)
-            state_bytes = dict(tel.seq_state_bytes)
+            rows = dict(tel.counters["seq_state_rows"])
+            tiles = dict(tel.counters["ssm_chunk_tokens"])
+            state_bytes = dict(tel.counters["seq_state_bytes"])
         finally:
             engine.shutdown()
             tracer.configure(False)

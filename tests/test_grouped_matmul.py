@@ -249,9 +249,9 @@ def _serve(model_dir, env, prompts, max_tokens=9):
             return {
                 "tokens": [done[f"r{i}"] for i in range(len(prompts))],
                 "dispatches": dispatches,
-                "grouped_steps":
+                "grouped_steps": sum(
                     engine.executor.worker.runner.telemetry
-                    .moe_grouped_steps,
+                    .counters["moe_grouped_steps"].values()),
                 "counter": float(line.rsplit(" ", 1)[1]),
             }
         finally:
@@ -347,7 +347,8 @@ def test_a_dense_model_has_no_moe_on_its_dispatch_span(tmp_path):
         assert spans and all("moe" not in s["attributes"] for s in spans)
         # nor query tiles: the reference backend has no kernel
         assert all("q_tiles" not in s["attributes"] for s in spans)
-        assert engine.executor.worker.runner.telemetry.moe_grouped_steps == 0
+        assert not engine.executor.worker.runner.telemetry.counters[
+            "moe_grouped_steps"]
     finally:
         engine.shutdown()
         tracer.configure(False)

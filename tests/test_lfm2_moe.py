@@ -122,8 +122,8 @@ def test_engine_agrees_with_the_reference_at_every_split(backend, tmp_path):
             fused = _generate(engine, prompts, tag="f")
             scans = [c for c in _chunks(tracer) if c[2] > 1]
             tel = engine.executor.worker.runner.telemetry
-            picks = dict(tel.moe_expert_picks)
-            rows = dict(tel.seq_state_rows)
+            picks = dict(tel.counters["moe_expert_picks"])
+            rows = dict(tel.counters["seq_state_rows"])
         finally:
             engine.shutdown()
             tracer.configure(False)

@@ -97,7 +97,8 @@ def test_engine_agrees_with_the_reference_through_the_latent_cache(
         try:
             with_lp = _generate(engine, prompts, logprobs=3)
             fused = _generate(engine, prompts)
-            picks = dict(engine.executor.worker.runner.telemetry.moe_picks)
+            picks = dict(engine.executor.worker.runner.telemetry.counters[
+                "moe_picks"])
         finally:
             engine.shutdown()
     diff, regret = _worst(cfg, prompts, with_lp)

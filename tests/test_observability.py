@@ -175,6 +175,31 @@ def test_mock_recompile_observed_in_metrics(model_dir):
 # ---------------------------------------------------------------------
 # HTTP surfaces: /debug/flightrecorder, /metrics pull, /debug/profile
 # ---------------------------------------------------------------------
+def test_a_worker_counter_is_a_row_of_the_table_or_an_error():
+    """One store behind one ``record``: a row of
+    ``metrics.WORKER_COUNTERS`` adds up by label (a gauge row is set),
+    every row is a key of the snapshot, and a name that is no row is
+    refused where it is recorded, not served as a new series."""
+    from vllm_distributed_tpu.metrics import WORKER_COUNTERS, EngineMetrics
+    from vllm_distributed_tpu.worker.telemetry import DeviceTelemetry
+
+    ledger = DeviceTelemetry()
+    ledger.record("moe_picks", {"held": 3, "absent": 1})
+    ledger.record("moe_picks", {"held": 2})
+    ledger.record("seq_state_bytes", {"conv": 10})
+    ledger.record("seq_state_bytes", {"conv": 7})
+    ledger.record_fallback("aot_load")
+    snap = ledger.snapshot(probe_memory=False)
+    assert set(WORKER_COUNTERS) <= set(snap)
+    assert snap["moe_picks"] == {"held": 5, "absent": 1}
+    assert snap["seq_state_bytes"] == {"conv": 7}
+    assert snap["fallbacks"] == {"aot_load": 1}
+    with pytest.raises(KeyError):
+        ledger.record("moe_pickz", {"held": 1})
+    with pytest.raises(KeyError):
+        EngineMetrics("m").record_worker_counter("moe_pickz", "held", 1)
+
+
 def test_http_observability_surfaces(model_dir, tmp_path, monkeypatch):
     monkeypatch.setenv("VDT_FLIGHT_RECORDER_DIR", str(tmp_path / "fr"))
     engine = AsyncLLM.from_engine_args(_engine_args(model_dir))

@@ -408,7 +408,7 @@ def test_engine_through_the_kernel_agrees_with_the_reference(tmp_path):
             runner = engine.executor.worker.runner
             assert runner.model._scan_in_kernel(128)
             outs = _generate(engine, prompts, logprobs=3)
-            tiles = dict(runner.telemetry.ssm_chunk_tokens)
+            tiles = dict(runner.telemetry.counters["ssm_chunk_tokens"])
         finally:
             engine.shutdown()
     diff, regret = _worst(cfg, prompts, outs)

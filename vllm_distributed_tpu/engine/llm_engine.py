@@ -23,6 +23,7 @@ from vllm_distributed_tpu.engine.scheduler import Scheduler
 from vllm_distributed_tpu.engine.spec_decode import spec_eligible
 from vllm_distributed_tpu.executor.abstract import Executor
 from vllm_distributed_tpu.logger import init_logger
+from vllm_distributed_tpu.metrics import WORKER_COUNTERS
 from vllm_distributed_tpu.outputs import CompletionOutput, RequestOutput
 from vllm_distributed_tpu.sampling_params import SamplingParams
 from vllm_distributed_tpu.tokenizer import (
@@ -136,18 +137,9 @@ class LLMEngine:
         # counted (exact even when the bounded event ring overflows
         # between scrapes — the recompile-storm case).
         self._telemetry_seq = 0
-        self._telemetry_compiles_seen: dict[str, int] = {}
-        self._telemetry_fallbacks_seen: dict[str, int] = {}
-        self._telemetry_cache_seen: dict[str, int] = {}
-        self._telemetry_moe_picks_seen: dict[str, int] = {}
-        self._telemetry_mla_chunk_seen: dict[str, int] = {}
-        self._telemetry_mla_kv_rows_seen: dict[str, int] = {}
-        self._telemetry_expert_picks_seen: dict[str, int] = {}
-        self._telemetry_state_rows_seen: dict[str, int] = {}
-        self._telemetry_ssm_chunk_seen: dict[str, int] = {}
-        self._telemetry_backend_compiles_seen: dict[str, int] = {}
-        self._telemetry_backend_seconds_seen: dict[str, float] = {}
-        self._telemetry_moe_grouped_seen = 0
+        # worker counter (a row of metrics.WORKER_COUNTERS) -> label ->
+        # the total already counted
+        self._telemetry_seen: dict[str, dict[str, float]] = {}
 
         # Disaggregated prefill/decode hand-off (ISSUE 15): export holds
         # + inbound transfers.  Always constructed (cheap, idle costs
@@ -704,74 +696,13 @@ class LLMEngine:
                 self.metrics.record_xla_compile_seconds(float(seconds))
         # Cumulative worker totals -> counter increments, each counted
         # exactly once.
-        for field, seen_by, record in (
-            (
-                "compiles",
-                self._telemetry_compiles_seen,
-                self.metrics.record_xla_compiles,
-            ),
-            (
-                "fallbacks",
-                self._telemetry_fallbacks_seen,
-                self.metrics.record_worker_fallbacks,
-            ),
-            (
-                "persistent_cache",
-                self._telemetry_cache_seen,
-                self.metrics.record_persistent_cache_events,
-            ),
-            (
-                "moe_picks",
-                self._telemetry_moe_picks_seen,
-                self.metrics.record_moe_picks,
-            ),
-            (
-                "mla_chunk_tokens",
-                self._telemetry_mla_chunk_seen,
-                self.metrics.record_mla_chunk_tokens,
-            ),
-            (
-                "mla_kv_rows",
-                self._telemetry_mla_kv_rows_seen,
-                self.metrics.record_mla_kv_rows,
-            ),
-            (
-                "moe_expert_picks",
-                self._telemetry_expert_picks_seen,
-                self.metrics.record_moe_expert_picks,
-            ),
-            (
-                "seq_state_rows",
-                self._telemetry_state_rows_seen,
-                self.metrics.record_seq_state_rows,
-            ),
-            (
-                "ssm_chunk_tokens",
-                self._telemetry_ssm_chunk_seen,
-                self.metrics.record_ssm_chunk_tokens,
-            ),
-            (
-                "backend_compiles",
-                self._telemetry_backend_compiles_seen,
-                self.metrics.record_backend_compiles,
-            ),
-            (
-                "backend_compile_seconds",
-                self._telemetry_backend_seconds_seen,
-                self.metrics.record_backend_compile_seconds,
-            ),
-        ):
-            for kind, total in (snap.get(field) or {}).items():
-                seen = seen_by.get(kind, 0)
-                if total > seen:
-                    record(str(kind), total - seen)
-                    seen_by[kind] = total
-        grouped = int(snap.get("moe_grouped_steps") or 0)
-        if grouped > self._telemetry_moe_grouped_seen:
-            self.metrics.record_moe_grouped_steps(
-                grouped - self._telemetry_moe_grouped_seen
-            )
-            self._telemetry_moe_grouped_seen = grouped
+        for key, row in WORKER_COUNTERS.items():
+            seen = self._telemetry_seen.setdefault(key, {})
+            for label, total in (snap.get(key) or {}).items():
+                new = total if row.gauge else total - seen.get(label, 0)
+                if new > 0:
+                    self.metrics.record_worker_counter(key, str(label), new)
+                    seen[label] = total
         self.metrics.record_device_snapshot(snap)
         return snap
 

@@ -15,6 +15,7 @@ process defaults.
 from __future__ import annotations
 
 import time
+from dataclasses import dataclass
 
 from vllm_distributed_tpu.tracing import get_tracer
 
@@ -94,6 +95,132 @@ SPAN_METRIC_MAP = {
     "executor.gather": "step_gather_time",
 }
 
+@dataclass(frozen=True)
+class WorkerCounter:
+    """One row of ``WORKER_COUNTERS``: how a total the worker keeps by
+    label reaches ``/metrics``."""
+
+    metric: str  # the family's name, without a counter's ``_total``
+    label: str | None  # the label its keys are values of; None: one series
+    help: str
+    gauge: bool = False  # the worker's value is set, not added up
+
+    @property
+    def family(self) -> str:
+        return self.metric if self.gauge else self.metric + "_total"
+
+
+# The worker's labelled counters: key in ``DeviceTelemetry``'s store and
+# snapshot -> family on /metrics.  The only list of them:
+# ``DeviceTelemetry.record`` refuses a key that is no row, the engine
+# folds a snapshot row by row (``LLMEngine.refresh_device_telemetry``),
+# ``EngineMetrics`` builds one instrument a row, and a row's help text
+# is the one description of what it counts.  All are the reply-rank
+# worker's (one representative host).
+WORKER_COUNTERS: dict[str, WorkerCounter] = {
+    "compiles": WorkerCounter(
+        "vllm:xla_compiles", "kind",
+        "jit compiles observed on the reply-rank worker (the first "
+        "execution of each distinct (kind, static shape) key, counted "
+        "from cumulative totals so a storm that overflows the event "
+        "ring between scrapes still counts exactly), by the triggering "
+        "bucket-shape kind (prefill | decode | spec); a climbing "
+        "counter in steady state is a recompile storm",
+    ),
+    "fallbacks": WorkerCounter(
+        "vllm:worker_fallbacks", "kind",
+        "Times the reply-rank worker carried on without something "
+        "it was asked to use (aot_load | aot_export | "
+        "compile_cache_setup); a warm start must show none",
+    ),
+    "persistent_cache": WorkerCounter(
+        "vllm:xla_persistent_cache_events", "event",
+        "JAX persistent-compilation-cache events in the reply-rank "
+        "worker's process: lookups, hits, and writes (a miss whose "
+        "compile was long enough to be stored)",
+    ),
+    "moe_grouped_steps": WorkerCounter(
+        "vllm:moe_grouped_steps", None,
+        "Step programs dispatched on the reply-rank worker whose "
+        "int8 experts ran the grouped (routed) kernel: many-row "
+        "steps of an MoE model on one chip; a fused decode scan "
+        "never counts",
+    ),
+    "moe_picks": WorkerCounter(
+        "vllm:moe_picks", "kind",
+        "Router picks on the reply-rank worker by kind, counted on the "
+        "device and fetched with the step's tokens: zero "
+        "(identity experts: no compute), held (experts this chip "
+        "holds), absent (experts held by other chips: nothing is "
+        "computed for them here); a model without identity experts "
+        "counts busiest instead of zero (each routed layer's "
+        "most-picked held expert's picks, summed: not a part of "
+        "the others' sum) and skipped (no pick: held experts whose "
+        "conditional did not run in a program of the branch form)",
+    ),
+    "moe_expert_picks": WorkerCounter(
+        "vllm:moe_expert_picks", "kind",
+        "Router picks on the reply-rank worker of a model that "
+        "holds every expert, over all routed layers: all, and "
+        "busiest (each layer's most-picked expert's picks, summed: "
+        "experts x busiest / all is 1.0 when the routing is even)",
+    ),
+    "seq_state_rows": WorkerCounter(
+        "vllm:seq_state_rows", "kind",
+        "Sequences in the steps the reply-rank worker dispatched "
+        "whose per-sequence state (a short convolution's last rows) "
+        "the step reads and rewrites: fresh (the sequence starts at "
+        "position 0 and reads nothing of its row), carried",
+    ),
+    "seq_state_bytes": WorkerCounter(
+        "vllm:seq_state_bytes", "kind",
+        "Bytes of per-sequence state the reply-rank worker "
+        "allocated beside its paged caches, at its sequence bucket, "
+        "by kind of state (conv: the rows a short convolution looks "
+        "back on; ssm: a state-space state)",
+        gauge=True,
+    ),
+    "ssm_chunk_tokens": WorkerCounter(
+        "vllm:ssm_chunk_tokens", "kind",
+        "Tokens of the single steps the reply-rank worker "
+        "dispatched for a model with a chunked state-space scan: "
+        "live (the steps' own tokens), tiled (the token slots the "
+        "scans' matrix products ran over: the bucket's tiles in the plain "
+        "form, each sequence's windows in the kernel); live / tiled: fill",
+    ),
+    "mla_chunk_tokens": WorkerCounter(
+        "vllm:mla_chunk_tokens", "form",
+        "Tokens of multi-token chunks in the chunked steps the "
+        "reply-rank worker dispatched, by the form their latent "
+        "(MLA) attention took: materialised (a fresh chunk: per-head "
+        "keys and values over the step's own rows), absorbed (a "
+        "chunk with a cached prefix: the latent cache's rows)",
+    ),
+    "mla_kv_rows": WorkerCounter(
+        "vllm:mla_kv_rows", "kind",
+        "Cached rows of the fused decode scans the reply-rank worker "
+        "dispatched, one latent (MLA) attention call's worth a scan: "
+        "live (the sequences' pool-resident tokens), fetched (the "
+        "rows the kernel's DMAs move for them: whole sub-blocks); "
+        "live / fetched is the fill",
+    ),
+    # ---- causes outside any thread's phases (ISSUE 40) ----
+    "backend_compiles": WorkerCounter(
+        "vllm:xla_backend_compiles", "source",
+        "XLA backend compiles in the reply-rank worker's process, as "
+        "JAX's own compile event reports them, by source: compiled "
+        "(XLA compiled the module: a jit re-specialised by a new "
+        "shape counts, which vllm:xla_compiles_total cannot see), "
+        "cache (the module came from the persistent cache)",
+    ),
+    "backend_compile_seconds": WorkerCounter(
+        "vllm:xla_backend_compile_seconds", "source",
+        "Seconds inside the XLA backend compiles counted by "
+        "vllm:xla_backend_compiles_total, by source",
+    ),
+}
+
+
 # Every vllm:* metric family this engine documents (README
 # "Observability"), as rendered in `# TYPE` lines (counters carry the
 # `_total` suffix there).  tests/test_metrics.py asserts render()
@@ -149,23 +276,11 @@ DOCUMENTED_METRICS = (
     "vllm:slo_ttft_ms",
     "vllm:slo_itl_ms",
     # ---- XLA/device telemetry (ISSUE 12) ----
-    "vllm:xla_compiles_total",
     "vllm:xla_compile_seconds",
     "vllm:hbm_live_bytes",
     "vllm:step_roofline_frac",
-    "vllm:worker_fallbacks_total",
-    "vllm:xla_persistent_cache_events_total",
-    "vllm:moe_grouped_steps_total",
-    "vllm:moe_picks_total",
-    "vllm:moe_expert_picks_total",
-    "vllm:seq_state_rows_total",
-    "vllm:seq_state_bytes",
-    "vllm:ssm_chunk_tokens_total",
-    "vllm:mla_chunk_tokens_total",
-    "vllm:mla_kv_rows_total",
+    *(row.family for row in WORKER_COUNTERS.values()),
     # ---- causes outside any thread's phases (ISSUE 40) ----
-    "vllm:xla_backend_compiles_total",
-    "vllm:xla_backend_compile_seconds_total",
     "vllm:gc_collections_total",
     "vllm:gc_pause_seconds_total",
     # ---- fleet sentinel (ISSUE 20) ----
@@ -561,14 +676,18 @@ class EngineMetrics:
         # ---- XLA/device telemetry (ISSUE 12), fed by the driver's
         # pull of worker DeviceTelemetry snapshots (one representative
         # host: the executor's reply rank).
-        self._xla_compiles = Counter(
-            "vllm:xla_compiles",
-            "jit compiles observed on the reply-rank worker, by the "
-            "triggering bucket-shape kind (prefill | decode | spec); a "
-            "climbing counter in steady state is a recompile storm",
-            ["model_name", "kind"],
-            registry=self.registry,
-        )
+        self._worker_counters = {
+            key: (Gauge if row.gauge else Counter)(
+                row.metric,
+                row.help,
+                ["model_name", *([row.label] if row.label else [])],
+                registry=self.registry,
+            )
+            for key, row in WORKER_COUNTERS.items()
+        }
+        for key, row in WORKER_COUNTERS.items():
+            if row.label is None:  # its one series reads 0 from the start
+                self._worker_counters[key].labels(**label)
         self.xla_compile_seconds = histogram(
             "vllm:xla_compile_seconds",
             "Wall time of each observed jit compile "
@@ -585,117 +704,7 @@ class EngineMetrics:
             "Last step's estimated bytes-touched/second over the "
             "device's peak HBM bandwidth (0 when unknown)",
         )
-        self._worker_fallbacks = Counter(
-            "vllm:worker_fallbacks",
-            "Times the reply-rank worker carried on without something "
-            "it was asked to use (aot_load | aot_export | "
-            "compile_cache_setup); a warm start must show none",
-            ["model_name", "kind"],
-            registry=self.registry,
-        )
-        self._persistent_cache_events = Counter(
-            "vllm:xla_persistent_cache_events",
-            "JAX persistent-compilation-cache events in the reply-rank "
-            "worker's process: lookups, hits, and writes (a miss whose "
-            "compile was long enough to be stored)",
-            ["model_name", "event"],
-            registry=self.registry,
-        )
-        self.moe_grouped_steps = counter(
-            "vllm:moe_grouped_steps",
-            "Step programs dispatched on the reply-rank worker whose "
-            "int8 experts ran the grouped (routed) kernel: many-row "
-            "steps of an MoE model on one chip; a fused decode scan "
-            "never counts",
-        )
-        self._moe_picks = Counter(
-            "vllm:moe_picks",
-            "Router picks on the reply-rank worker by kind: zero "
-            "(identity experts: no compute), held (experts this chip "
-            "holds), absent (experts held by other chips: nothing is "
-            "computed for them here); a model without identity experts "
-            "counts busiest instead of zero (each routed layer's "
-            "most-picked held expert's picks, summed: not a part of "
-            "the others' sum) and skipped (no pick: held experts whose "
-            "conditional did not run in a program of the branch form)",
-            ["model_name", "kind"],
-            registry=self.registry,
-        )
-        self._moe_expert_picks = Counter(
-            "vllm:moe_expert_picks",
-            "Router picks on the reply-rank worker of a model that "
-            "holds every expert, over all routed layers: all, and "
-            "busiest (each layer's most-picked expert's picks, summed: "
-            "experts x busiest / all is 1.0 when the routing is even)",
-            ["model_name", "kind"],
-            registry=self.registry,
-        )
-        self._seq_state_rows = Counter(
-            "vllm:seq_state_rows",
-            "Sequences in the steps the reply-rank worker dispatched "
-            "whose per-sequence state (a short convolution's last rows) "
-            "the step reads and rewrites: fresh (the sequence starts at "
-            "position 0 and reads nothing of its row), carried",
-            ["model_name", "kind"],
-            registry=self.registry,
-        )
-        self._seq_state_bytes = Gauge(
-            "vllm:seq_state_bytes",
-            "Bytes of per-sequence state the reply-rank worker "
-            "allocated beside its paged caches, at its sequence bucket, "
-            "by kind of state (conv: the rows a short convolution looks "
-            "back on; ssm: a state-space state)",
-            ["model_name", "kind"],
-            registry=self.registry,
-        )
-        self._ssm_chunk_tokens = Counter(
-            "vllm:ssm_chunk_tokens",
-            "Tokens of the single steps the reply-rank worker "
-            "dispatched for a model with a chunked state-space scan: "
-            "live (the steps' own tokens), tiled (the token slots the "
-            "scans' matrix products ran over: the bucket's tiles in the plain "
-            "form, each sequence's windows in the kernel); live / tiled: fill",
-            ["model_name", "kind"],
-            registry=self.registry,
-        )
-        self._mla_chunk_tokens = Counter(
-            "vllm:mla_chunk_tokens",
-            "Tokens of multi-token chunks in the chunked steps the "
-            "reply-rank worker dispatched, by the form their latent "
-            "(MLA) attention took: materialised (a fresh chunk: per-head "
-            "keys and values over the step's own rows), absorbed (a "
-            "chunk with a cached prefix: the latent cache's rows)",
-            ["model_name", "form"],
-            registry=self.registry,
-        )
-        self._mla_kv_rows = Counter(
-            "vllm:mla_kv_rows",
-            "Cached rows of the fused decode scans the reply-rank worker "
-            "dispatched, one latent (MLA) attention call's worth a scan: "
-            "live (the sequences' pool-resident tokens), fetched (the "
-            "rows the kernel's DMAs move for them: whole sub-blocks); "
-            "live / fetched is the fill",
-            ["model_name", "kind"],
-            registry=self.registry,
-        )
         # ---- causes outside any thread's phases (ISSUE 40) ----
-        self._backend_compiles = Counter(
-            "vllm:xla_backend_compiles",
-            "XLA backend compiles in the reply-rank worker's process, as "
-            "JAX's own compile event reports them, by source: compiled "
-            "(XLA compiled the module: a jit re-specialised by a new "
-            "shape counts, which vllm:xla_compiles_total cannot see), "
-            "cache (the module came from the persistent cache)",
-            ["model_name", "source"],
-            registry=self.registry,
-        )
-        self._backend_compile_seconds = Counter(
-            "vllm:xla_backend_compile_seconds",
-            "Seconds inside the XLA backend compiles counted by "
-            "vllm:xla_backend_compiles_total, by source",
-            ["model_name", "source"],
-            registry=self.registry,
-        )
         self._gc_collections = Counter(
             "vllm:gc_collections",
             "Garbage collections of generation 1 and 2 in the server's "
@@ -1009,78 +1018,20 @@ class EngineMetrics:
 
     # ---- XLA/device telemetry hooks (ISSUE 12), fed by
     # LLMEngine.refresh_device_telemetry from worker snapshots ----
-    def record_xla_compiles(self, kind: str, n: int) -> None:
-        """Counter fed from CUMULATIVE per-kind worker totals (delta
-        computed by the engine), so a recompile storm that overflows
-        the bounded event ring between scrapes still counts exactly."""
-        if self.enabled and n > 0:
-            self._xla_compiles.labels(
-                model_name=self._model_name, kind=kind
-            ).inc(n)
-
-    def record_worker_fallbacks(self, kind: str, n: int) -> None:
-        if self.enabled and n > 0:
-            self._worker_fallbacks.labels(
-                model_name=self._model_name, kind=kind
-            ).inc(n)
-
-    def record_moe_picks(self, kind: str, n: int) -> None:
-        if self.enabled and n > 0:
-            self._moe_picks.labels(
-                model_name=self._model_name, kind=kind
-            ).inc(n)
-
-    def record_moe_expert_picks(self, kind: str, n: int) -> None:
-        if self.enabled and n > 0:
-            self._moe_expert_picks.labels(
-                model_name=self._model_name, kind=kind
-            ).inc(n)
-
-    def record_seq_state_rows(self, kind: str, n: int) -> None:
-        if self.enabled and n > 0:
-            self._seq_state_rows.labels(
-                model_name=self._model_name, kind=kind
-            ).inc(n)
-
-    def record_ssm_chunk_tokens(self, kind: str, n: int) -> None:
-        if self.enabled and n > 0:
-            self._ssm_chunk_tokens.labels(
-                model_name=self._model_name, kind=kind
-            ).inc(n)
-
-    def record_mla_chunk_tokens(self, form: str, n: int) -> None:
-        if self.enabled and n > 0:
-            self._mla_chunk_tokens.labels(
-                model_name=self._model_name, form=form
-            ).inc(n)
-
-    def record_mla_kv_rows(self, kind: str, n: int) -> None:
-        if self.enabled and n > 0:
-            self._mla_kv_rows.labels(
-                model_name=self._model_name, kind=kind
-            ).inc(n)
-
-    def record_moe_grouped_steps(self, n: int) -> None:
-        if self.enabled and n > 0:
-            self.moe_grouped_steps.inc(n)
-
-    def record_persistent_cache_events(self, event: str, n: int) -> None:
-        if self.enabled and n > 0:
-            self._persistent_cache_events.labels(
-                model_name=self._model_name, event=event
-            ).inc(n)
-
-    def record_backend_compiles(self, source: str, n: int) -> None:
-        if self.enabled and n > 0:
-            self._backend_compiles.labels(
-                model_name=self._model_name, source=source
-            ).inc(n)
-
-    def record_backend_compile_seconds(self, source: str, s: float) -> None:
-        if self.enabled and s > 0:
-            self._backend_compile_seconds.labels(
-                model_name=self._model_name, source=source
-            ).inc(s)
+    def record_worker_counter(self, key: str, label: str, n: float) -> None:
+        """``n`` more of the worker counter ``key`` (a row of
+        ``WORKER_COUNTERS``) under ``label``; a gauge row is set to it."""
+        if not self.enabled or n <= 0:
+            return
+        row = WORKER_COUNTERS[key]
+        labels = {"model_name": self._model_name}
+        if row.label:
+            labels[row.label] = label
+        series = self._worker_counters[key].labels(**labels)
+        if row.gauge:
+            series.set(n)
+        else:
+            series.inc(n)
 
     def record_xla_compile_seconds(self, seconds: float) -> None:
         """Histogram fed from individual timed events (best-effort: the
@@ -1095,10 +1046,6 @@ class EngineMetrics:
             return
         self.hbm_live_bytes.set(snap.get("hbm_live_bytes", 0) or 0)
         self.step_roofline_frac.set(snap.get("roofline_frac", 0.0) or 0.0)
-        for kind, n in (snap.get("seq_state_bytes") or {}).items():
-            self._seq_state_bytes.labels(
-                model_name=self._model_name, kind=str(kind)
-            ).set(n)
 
     def slo_snapshot(self, include_timelines: bool = True) -> dict | None:
         """Replica /slo view (None while metrics are disabled)."""

@@ -74,6 +74,26 @@ class SupportsQuantization:
         return bool(names) and names[-1] in self.QUANT_PARAMS
 
 
+class CountsRouterPicks:
+    """A model whose step programs count their router's picks on the
+    device (``forward``'s ``stats``); the runner fetches the counts
+    behind the step's tokens and hands them back (``fetched_counts``).
+    ``moe_pick_kinds`` names them in order; ``moe_pick_counter`` is the
+    worker counter they add to (a row of ``metrics.WORKER_COUNTERS``)."""
+
+    moe_pick_kinds: tuple[str, ...] = ()
+    moe_pick_counter = "moe_picks"
+
+    def fetched_counts(self, tail) -> tuple[dict, dict]:
+        """(the ``runner.fetch`` span's attributes, the worker counters'
+        increments) of one step's fetched pick counts."""
+        counts = {k: int(n) for k, n in zip(self.moe_pick_kinds, tail)}
+        return (
+            {f"moe_picks_{kind}": n for kind, n in counts.items()},
+            {self.moe_pick_counter: counts},
+        )
+
+
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
     dtype = x.dtype
     x = x.astype(jnp.float32)

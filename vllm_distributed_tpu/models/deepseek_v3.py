@@ -69,6 +69,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from vllm_distributed_tpu.models.common import (
+    CountsRouterPicks,
     SCOPE_ATTN_OUT,
     SCOPE_ATTN_QKV,
     SCOPE_EMBED,
@@ -86,6 +87,7 @@ from vllm_distributed_tpu.models.common import (
     yarn_mscale,
 )
 from vllm_distributed_tpu.models.routed_experts import (
+    RoutedLayerCounts,
     local_expert_ids,
     picks_by_expert,
     routed_experts,
@@ -117,7 +119,9 @@ ROUTER_BIAS_STD = 0.01
 _LANES = 128
 
 
-class DeepseekV3ForCausalLM(SupportsQuantization):
+class DeepseekV3ForCausalLM(
+    RoutedLayerCounts, CountsRouterPicks, SupportsQuantization
+):
     architectures = ("DeepseekV3ForCausalLM",)
     # The large matmuls.  The router (float32 by the published code),
     # the per-head up-projections W_uk / W_uv (einsum operands of the
@@ -235,7 +239,7 @@ class DeepseekV3ForCausalLM(SupportsQuantization):
         if getattr(hf, "tie_word_embeddings", False):
             raise NotImplementedError("deepseek_v3: tied embeddings")
         self.dtype = jnp.dtype(model_config.dtype)
-        # the runner names a program's routed form from this layer
+        # ``step_counts`` names a program's routed form from this layer
         self.first_routed_layer = min(self.num_dense, self.num_layers - 1)
         # The backend the unquantized experts' many-row form may use:
         # the grouped kernel where Pallas runs, ragged_dot elsewhere.

@@ -385,11 +385,28 @@ class FalconH1ForCausalLM(SupportsQuantization):
         chunked single step of ``tokens`` rows whose sequences have
         ``chunk_lens`` tokens, in batch order: every tile of the bucket
         in the plain form, each sequence's own windows in the kernel
-        (the runner's ``ssm_tokens_tiled``)."""
+        (``step_counts``' ``ssm_tokens_tiled``)."""
         if self._scan_in_kernel(tokens):
             return ssd_kernel.walked_tokens(chunk_lens, self.ssm_chunk_size)
         tile = min(self.ssm_chunk_size, tokens)
         return tile * cdiv(tokens, tile)
+
+    def step_counts(self, shape, params) -> tuple[dict, dict]:
+        """What a step the runner hands over (``ops.attention.StepShape``)
+        says of the chunked scans: a chunked single step's own tokens
+        (the ``runner.dispatch`` span's ``ssm_tokens_live``) and the
+        token slots its scans' matrix products run over
+        (``ssm_tokens_tiled``), which are the worker counter's live |
+        tiled.  A step in which every sequence has one token runs the
+        one-token update and says nothing; so does a fused scan."""
+        if shape.kind != "prefill" or shape.max_q_pad == 1:
+            return {}, {}
+        live = sum(shape.num_new)
+        tiled = self.ssm_tokens_walked(shape.num_new, shape.rows_pad)
+        return (
+            {"ssm_tokens_live": live, "ssm_tokens_tiled": tiled},
+            {"ssm_chunk_tokens": {"live": live, "tiled": tiled}},
+        )
 
     def _mixer_in(self, u, layer: dict, conv_state, seq: SeqState, meta):
         """The mixer up to the recurrence: (the gate z [T, d_ssm]

@@ -56,6 +56,7 @@ from jax.sharding import PartitionSpec as P
 
 from vllm_distributed_tpu.config import LAYER_TYPES
 from vllm_distributed_tpu.models.common import (
+    CountsRouterPicks,
     SCOPE_ATTN,
     SCOPE_ATTN_OUT,
     SCOPE_ATTN_QKV,
@@ -72,6 +73,7 @@ from vllm_distributed_tpu.models.common import (
     rope_frequencies,
 )
 from vllm_distributed_tpu.models.routed_experts import (
+    RoutedLayerCounts,
     routed_experts,
     select_moe_impl,
 )
@@ -97,7 +99,9 @@ EXPERT_BIAS_STD = 0.1
 CONV_TAP_STD = 3.0**-0.5
 
 
-class Lfm2MoeForCausalLM(SupportsQuantization):
+class Lfm2MoeForCausalLM(
+    RoutedLayerCounts, CountsRouterPicks, SupportsQuantization
+):
     architectures = ("Lfm2MoeForCausalLM",)
     # The large matmuls.  The router (float32), its bias, the taps, the
     # embedding (gathered, and tied to the head) and the norms stay in
@@ -166,7 +170,7 @@ class Lfm2MoeForCausalLM(SupportsQuantization):
         self.conv_layers = tuple(
             i for i, kind in enumerate(self.layer_types) if kind == "conv"
         )
-        # the runner names a program's routed form from this layer
+        # ``step_counts`` names a program's routed form from this layer
         self.first_routed_layer = min(self.num_dense, self.num_layers - 1)
         # The backend the unquantized experts' many-row form may use:
         # the grouped kernel where Pallas runs, ragged_dot elsewhere.

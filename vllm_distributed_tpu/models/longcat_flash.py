@@ -68,6 +68,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from vllm_distributed_tpu.models.common import (
+    CountsRouterPicks,
     SCOPE_ATTN_OUT,
     SCOPE_ATTN_QKV,
     SCOPE_EMBED,
@@ -109,7 +110,7 @@ def _capacity(tokens: int, top_k: int, router_width: int) -> int:
     return min(max(_MIN_CAPACITY, next_power_of_2(4 * mean)), tokens)
 
 
-class LongcatFlashForCausalLM(SupportsQuantization):
+class LongcatFlashForCausalLM(CountsRouterPicks, SupportsQuantization):
     architectures = ("LongcatFlashForCausalLM",)
     # The large matmuls.  The router (float32 by the published code),
     # the per-head up-projections W_uk / W_uv (einsum operands of the

@@ -53,13 +53,14 @@ from vllm_distributed_tpu.models.common import SCOPE_MOE_ROUTER
 from vllm_distributed_tpu.models.llama import LlamaForCausalLM
 from vllm_distributed_tpu.models.routed_experts import (  # noqa: F401
     GROUPED_MIN_ROWS,
+    RoutedLayerCounts,
     routed_experts,
     select_moe_impl,
     sorted_dispatch,
 )
 
 
-class MixtralForCausalLM(LlamaForCausalLM):
+class MixtralForCausalLM(RoutedLayerCounts, LlamaForCausalLM):
     architectures = (
         "MixtralForCausalLM",
         "Qwen3MoeForCausalLM",
@@ -297,7 +298,7 @@ class MixtralForCausalLM(LlamaForCausalLM):
         """What ``_mlp`` traces for a step of ``tokens`` rows over this
         layer's experts: "dense" | "ragged" | "grouped".  A function of
         the trace-time shapes and of what the tensor says of itself, so
-        the runner can say which one a dispatched program holds."""
+        ``step_counts`` can say which one a dispatched program holds."""
         return select_moe_impl(
             self._moe_impl(),
             tokens * self.top_k,

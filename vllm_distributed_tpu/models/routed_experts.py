@@ -153,6 +153,25 @@ def select_moe_impl(
     return requested
 
 
+class RoutedLayerCounts:
+    """What a model with ``moe_impl`` says of a step it is handed
+    (``ops.attention.StepShape``): the form its routed layers were
+    traced with, from the first of them (``first_routed_layer``: a model
+    with leading dense layers says where they start) and the rows a
+    layer sees."""
+
+    first_routed_layer = 0
+
+    def step_counts(self, shape, params) -> tuple[dict, dict]:
+        """(the ``runner.dispatch`` span's ``moe``, one grouped step
+        more on the worker's counter where it says so)."""
+        impl = self.moe_impl(
+            params["layers"][self.first_routed_layer], shape.rows_pad
+        )
+        grouped = {"moe_grouped_steps": {"": 1}} if impl == "grouped" else {}
+        return {"moe": impl}, grouped
+
+
 def local_expert_ids(topi, held_lo: int, num_held: int):
     """A wider router's indices as the held stack's: expert ``held_lo +
     j`` becomes ``j``, every expert held elsewhere ``num_held`` (no

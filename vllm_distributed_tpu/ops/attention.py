@@ -37,8 +37,9 @@ oracle (tested against each other, SURVEY.md §4.2) and the CPU fallback.
 from __future__ import annotations
 
 import math
+from collections.abc import Sequence
 from dataclasses import dataclass, field
-from functools import partial
+from functools import partial, wraps
 
 import jax
 import jax.numpy as jnp
@@ -558,6 +559,48 @@ def mla_sublayer_attention(
         )
     with jax.named_scope(SCOPE_MLA_ABSORB):
         return mla_unabsorb_outputs(o_lat, w_uv)
+
+
+@dataclass(frozen=True)
+class StepShape:
+    """What the runner knows of one step it dispatches.  It hands this
+    to the attention function it picked and to the model
+    (``step_counts``); each answers with ``(attributes, increments)``:
+    what the step's ``runner.dispatch`` span says of it, and what those
+    add to the worker's counters (counter -> label -> n, a counter a
+    row of ``metrics.WORKER_COUNTERS``).  The runner merges the first
+    and passes the second on unread; who has nothing to say has no
+    ``step_counts``."""
+
+    # the step program's kind, as ``vllm:xla_compiles_total`` counts it:
+    # "prefill" (a single step: chunks and one-token rows alike) |
+    # "decode" (a fused scan) | "spec" (a verify step)
+    kind: str
+    num_new: Sequence[int]  # each row's new tokens
+    starts: Sequence[int]  # each row's start: its tokens already cached
+    # the padded token rows a layer sees: a single step's token bucket,
+    # a scan's sequence bucket
+    rows_pad: int
+    seqs_pad: int
+    max_q_pad: int  # the longest-chunk bucket; 1 in a scan
+    pages_pad: int
+    page_size: int
+    cache_itemsize: int  # bytes an element of the pool
+    head_shards: int  # the mesh's tp
+
+
+def bind_step_counts(attn_fn, model):
+    """``attn_fn`` as picked for ``model``: where the function's module
+    counts what a step of it walks (``step_counts_for``, the host-side
+    twins of the kernel's lists), the function with ``step_counts``
+    bound to the model's widths beside its other attributes; any other
+    function as it is."""
+    counts_for = getattr(attn_fn, "step_counts_for", None)
+    if counts_for is None:
+        return attn_fn
+    bound = wraps(attn_fn)(partial(attn_fn))
+    bound.step_counts = counts_for(model)
+    return bound
 
 
 def bind_max_q(attn_fn, max_q: int):

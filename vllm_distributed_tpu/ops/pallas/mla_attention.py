@@ -788,8 +788,49 @@ def mla_chunk_token_counts(q_lens, chunk_starts) -> tuple[int, int]:
     return fresh, prefixed
 
 
+def step_counts_for(model):
+    """What a step of the latent kernels counts (``StepShape`` ->
+    attributes, increments), bound to ``model``'s widths.  A single
+    step: ``q_tiles`` / ``q_rows``, and where it is chunked its
+    multi-token chunks by the form their attention takes
+    (``chunk_tokens_fresh`` / ``chunk_tokens_prefixed``, the counter's
+    materialised | absorbed).  A fused scan: ``kv_rows_fetched``, what
+    the kernel's DMAs move in one sublayer call for the sequences'
+    cached rows, beside the span's ``kv_tokens`` (the counter's live |
+    fetched)."""
+    widths = dict(num_heads=model.num_heads, value_width=model.kv_lora_rank)
+    lanes = model.kv_cache_layout().width
+
+    def step_counts(shape):
+        if shape.kind == "decode":
+            fetched = mla_kv_rows_fetched(
+                shape.starts, **widths, lanes=lanes,
+                itemsize=shape.cache_itemsize, page_size=shape.page_size,
+                max_pages=shape.pages_pad,
+            )
+            return {"kv_rows_fetched": fetched}, {
+                "mla_kv_rows": {"live": sum(shape.starts), "fetched": fetched}
+            }
+        if shape.kind != "prefill":
+            return {}, {}
+        tiles, rows = mla_query_tile_counts(
+            shape.num_new, max_q=shape.max_q_pad, **widths
+        )
+        attrs = {"q_tiles": tiles, "q_rows": rows}
+        if shape.max_q_pad == 1:
+            return attrs, {}
+        fresh, prefixed = mla_chunk_token_counts(shape.num_new, shape.starts)
+        attrs.update(chunk_tokens_fresh=fresh, chunk_tokens_prefixed=prefixed)
+        return attrs, {
+            "mla_chunk_tokens": {"materialised": fresh, "absorbed": prefixed}
+        }
+
+    return step_counts
+
+
 mla_attention.needs_max_q = True
 mla_attention.max_q_class = mla_max_q_class
+mla_attention.step_counts_for = step_counts_for
 mla_attention.from_heads = mla_attention_from_heads
 
 
@@ -800,6 +841,7 @@ def mla_attention_cpu(*args, **kwargs):
 
 mla_attention_cpu.needs_max_q = True
 mla_attention_cpu.max_q_class = mla_max_q_class
+mla_attention_cpu.step_counts_for = step_counts_for
 mla_attention_cpu.from_heads = functools.partial(
     mla_attention_from_heads, interpret=True
 )

@@ -525,6 +525,30 @@ def query_tile_counts(
     return singles + chunks, singles * mq_a + chunks * mq_b
 
 
+def step_counts_for(model):
+    """What a step of ``paged_attention`` counts (``StepShape`` ->
+    attributes, increments), bound to ``model``'s widths: a single
+    step's ``q_tiles`` / ``q_rows`` on one head shard, beside the
+    span's ``tokens``; nothing of a scan or a verify step."""
+    heads, kv_heads, head_dim = (
+        model.num_heads, model.num_kv_heads, model.head_dim
+    )
+
+    def step_counts(shape):
+        if shape.kind != "prefill":
+            return {}, {}
+        tiles, rows = query_tile_counts(
+            shape.num_new,
+            max_q=shape.max_q_pad,
+            num_q_heads=heads // shape.head_shards,
+            num_kv_heads=kv_heads // shape.head_shards,
+            head_dim=head_dim,
+        )
+        return {"q_tiles": tiles, "q_rows": rows}, {}
+
+    return step_counts
+
+
 def _kv_blocking(kv_pages, hkv: int, has_quant: bool, max_pages: int):
     """(pages per kv block, kv blocks, tokens per block): blocks sized
     to the per-slot VMEM budget."""
@@ -844,6 +868,7 @@ def paged_attention(
 
 
 paged_attention.needs_max_q = True
+paged_attention.step_counts_for = step_counts_for
 
 
 def paged_attention_cpu(*args, **kwargs):
@@ -852,3 +877,4 @@ def paged_attention_cpu(*args, **kwargs):
 
 
 paged_attention_cpu.needs_max_q = True
+paged_attention_cpu.step_counts_for = step_counts_for

@@ -99,6 +99,7 @@ def shard_attention(attn_fn, mesh: Mesh):
         return f(*operands)
 
     wrapped.needs_max_q = getattr(attn_fn, "needs_max_q", False)
+    wrapped.step_counts = getattr(attn_fn, "step_counts", None)
     return wrapped
 
 

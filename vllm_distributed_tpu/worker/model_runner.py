@@ -41,7 +41,9 @@ from vllm_distributed_tpu.ops import resolve_kernel_backend
 from vllm_distributed_tpu.ops.attention import (
     AttentionMetadata,
     SeqState,
+    StepShape,
     bind_max_q,
+    bind_step_counts,
     paged_attention_reference,
 )
 from vllm_distributed_tpu.ops.sampling import (
@@ -372,17 +374,15 @@ class ModelRunner:
         statics: dict,
         args: tuple,
         so: SchedulerOutput,
-        rows: int,
         **counts: int,
     ):
         """Run one step program, ``method`` of this class with
         ``statics`` baked in, under its stable ``name``: through the AOT
         cache where that is armed, else through the method's own jit
         (which XLA then names after the method: the name stays the
-        span's).  The call is a ``runner.dispatch`` span; ``rows`` is
-        the padded token rows the program's layers see, from which an
-        MoE model says what its experts were traced with (the span's
-        ``moe``).
+        span's).  The call is a ``runner.dispatch`` span, which carries
+        ``counts``: the step's sizes and what its attention function and
+        model say of it (``_step_counts``).
 
         The FIRST execution of each distinct (kind, statics) key is
         timed and recorded as an XLA compile (trace+lower+compile
@@ -409,14 +409,6 @@ class ModelRunner:
                 )
             return getattr(self, method)(*args, **statics)
 
-        moe_impl = getattr(self.model, "moe_impl", None)
-        if moe_impl is not None:
-            # a model with leading dense layers says where its routed
-            # layers start
-            routed = getattr(self.model, "first_routed_layer", 0)
-            counts["moe"] = moe_impl(self.params["layers"][routed], rows)
-            if counts["moe"] == "grouped":
-                self.telemetry.record_moe_grouped_step()
         tracer = get_tracer()
         # Each counter has one writer at a time (this thread; the thread
         # that fetches), so their difference needs no lock.
@@ -592,6 +584,9 @@ class ModelRunner:
             )
 
     def _pick_attn_fn(self):
+        """The backend's attention function for the model's cache, with
+        what a step of it counts bound to the model's widths
+        (``step_counts``) where its module says that."""
         if self._latent:
             from vllm_distributed_tpu.ops.attention import (
                 mla_attention_reference,
@@ -600,89 +595,61 @@ class ModelRunner:
             if self.attn_backend == "reference":
                 return mla_attention_reference
             from vllm_distributed_tpu.ops.pallas.mla_attention import (
-                mla_attention,
-                mla_attention_cpu,
+                mla_attention as compiled,
+                mla_attention_cpu as interpreted,
             )
-
-            if self.attn_backend == "pallas_interpret":
-                return mla_attention_cpu
-            return mla_attention
-        if self.attn_backend == "reference":
-            return paged_attention_reference
-        from vllm_distributed_tpu.ops.pallas.paged_attention import (
-            paged_attention,
-            paged_attention_cpu,
-        )
-
-        if self.attn_backend == "pallas_interpret":
-            return paged_attention_cpu
-        return paged_attention
-
-    def _query_tiles(self, q_lens: list[int], max_q_pad: int):
-        """(query tiles the attention kernel walks in this step, the
-        query rows those tiles compute) on one head shard: the span's
-        ``q_tiles`` / ``q_rows``, beside its ``tokens``."""
-        from vllm_distributed_tpu.ops.pallas.paged_attention import (
-            query_tile_counts,
-        )
-
-        m = self.model
-        if self._latent:
-            from vllm_distributed_tpu.ops.pallas.mla_attention import (
-                mla_query_tile_counts,
+        else:
+            if self.attn_backend == "reference":
+                return paged_attention_reference
+            from vllm_distributed_tpu.ops.pallas.paged_attention import (
+                paged_attention as compiled,
+                paged_attention_cpu as interpreted,
             )
-
-            return mla_query_tile_counts(
-                q_lens, max_q=max_q_pad, num_heads=m.num_heads,
-                value_width=m.kv_lora_rank,
-            )
-        tp = self.mesh.shape.get("tp", 1) if self.mesh is not None else 1
-        return query_tile_counts(
-            q_lens,
-            max_q=max_q_pad,
-            num_q_heads=m.num_heads // tp,
-            num_kv_heads=m.num_kv_heads // tp,
-            head_dim=m.head_dim,
+        return bind_step_counts(
+            interpreted if self.attn_backend == "pallas_interpret"
+            else compiled,
+            self.model,
         )
 
-    def _mla_chunk_tokens(self, q_lens: list[int], chunk_starts) -> dict:
-        """A chunked step's multi-token chunks by the form their latent
-        attention takes: the span's ``chunk_tokens_fresh`` /
-        ``chunk_tokens_prefixed``, and the counter's two forms."""
-        from vllm_distributed_tpu.ops.pallas.mla_attention import (
-            mla_chunk_token_counts,
-        )
-
-        fresh, prefixed = mla_chunk_token_counts(q_lens, chunk_starts)
-        self.telemetry.record_mla_chunk_tokens(
-            {"materialised": fresh, "absorbed": prefixed}
-        )
-        return dict(chunk_tokens_fresh=fresh, chunk_tokens_prefixed=prefixed)
-
-    def _mla_kv_rows(self, kv_lens, pages_pad: int) -> dict:
-        """A fused scan's cached rows as the latent kernel's DMAs fetch
-        them in one sublayer call (whole sub-blocks of each sequence's
-        live rows, the last one cut to half where it can be): the span's ``kv_rows_fetched`` beside its
-        ``kv_tokens``, and the counter's two kinds."""
-        from vllm_distributed_tpu.ops.pallas.mla_attention import (
-            mla_kv_rows_fetched,
-        )
-
-        m = self.model
-        kv_lens = kv_lens.tolist()
-        fetched = mla_kv_rows_fetched(
-            kv_lens,
-            num_heads=m.num_heads,
-            value_width=m.kv_lora_rank,
-            lanes=self._kv_layout.width,
-            itemsize=jnp.dtype(self.kv_cache_dtype()).itemsize,
+    def _step_counts(
+        self, kind: str, num_new, starts, *, rows_pad: int, seqs_pad: int,
+        max_q_pad: int, pages_pad: int,
+    ) -> dict:
+        """What a step about to be dispatched counts: its
+        ``runner.dispatch`` attributes beside its sizes.  The runner
+        says what it knows of the step (``StepShape``); the attention
+        function it picked and the model answer with attributes and
+        with increments of the worker's counters, which are passed on
+        unread.  The runner's own part is the sequence state it lays
+        out: ``state_rows`` (every row's is read and rewritten) and
+        ``state_fresh`` (those that start at position 0 and read
+        nothing of theirs), the counter's fresh | carried."""
+        shape = StepShape(
+            kind=kind, num_new=num_new, starts=starts, rows_pad=rows_pad,
+            seqs_pad=seqs_pad, max_q_pad=max_q_pad, pages_pad=pages_pad,
             page_size=self.page_size,
-            max_pages=pages_pad,
+            cache_itemsize=jnp.dtype(self.kv_cache_dtype()).itemsize,
+            head_shards=(
+                self.mesh.shape.get("tp", 1) if self.mesh is not None else 1
+            ),
         )
-        self.telemetry.record_mla_kv_rows(
-            {"live": sum(kv_lens), "fetched": fetched}
-        )
-        return dict(kv_rows_fetched=fetched)
+        attrs, increments = {}, {}
+        attn_counts = getattr(self._attn_fn, "step_counts", None)
+        if attn_counts is not None:
+            attrs, increments = attn_counts(shape)
+        model_counts = getattr(self.model, "step_counts", None)
+        if model_counts is not None:
+            more, added = model_counts(shape, self.params)
+            attrs, increments = {**attrs, **more}, {**increments, **added}
+        if self._state_layout is not None:
+            fresh = sum(1 for lo in starts if lo == 0)
+            attrs.update(state_rows=len(starts), state_fresh=fresh)
+            increments["seq_state_rows"] = {
+                "fresh": fresh, "carried": len(starts) - fresh
+            }
+        for counter, by_label in increments.items():
+            self.telemetry.record(counter, by_label)
+        return attrs
 
     def _pick_kv_flush_fn(self):
         """Per-dispatch flush of the staged decode side buffers.  None
@@ -867,7 +834,7 @@ class ModelRunner:
                 name: rows * n
                 for name, n in layout.bytes_by_kind(self.model.dtype).items()
             }
-            self.telemetry.seq_state_bytes = by_kind
+            self.telemetry.record("seq_state_bytes", by_kind)
             logger.info(
                 "sequence state: %s at %d sequences (%.2f MiB, off the "
                 "KV pool)",
@@ -1702,16 +1669,10 @@ class ModelRunner:
 
         kv_tokens = int(seq_lens.sum())
         counts = dict(seqs=s_real, tokens=t_real, kv_tokens=kv_tokens)
-        if getattr(self._attn_fn, "needs_max_q", False):
-            counts["q_tiles"], counts["q_rows"] = self._query_tiles(
-                num_new, max_q_pad
-            )
-        if getattr(self._attn_fn, "from_heads", None) and max_q_pad > 1:
-            counts.update(self._mla_chunk_tokens(num_new, chunk_starts))
-        if self._state_layout is not None:
-            counts.update(self._count_state_rows(starts))
-        if getattr(self.model, "ssm_chunk_size", 0) and max_q_pad > 1:
-            counts.update(self._ssm_chunk_tokens(num_new, t_pad))
+        counts.update(self._step_counts(
+            "prefill", num_new, starts, rows_pad=t_pad, seqs_pad=s_pad,
+            max_q_pad=max_q_pad, pages_pad=pages_pad,
+        ))
         prep.end()
         t_step0 = time.perf_counter()
         sampled, logprobs, self.kv_caches, self._carry = self._run_program(
@@ -1723,7 +1684,6 @@ class ModelRunner:
             statics,
             args,
             so,
-            rows=t_pad,
             **counts,
         )
         # As in the fused scan: resolve() may run on the executor's
@@ -1741,7 +1701,7 @@ class ModelRunner:
                     host_toks, host_lps = jax.device_get(sampled), None
                 host_toks = np.asarray(host_toks)
                 if host_toks.shape[0] > s_pad:
-                    self._record_moe_picks(fetch, host_toks[s_pad:])
+                    self._record_fetched(fetch, host_toks[s_pad:])
             self._num_fetched += 1
             self._record_step_bw(time.perf_counter() - t_step0, kv_tokens)
 
@@ -1910,43 +1870,15 @@ class ModelRunner:
             return {"stats": {}}
         return {}
 
-    def _record_moe_picks(self, span, host_tail) -> None:
-        """The pick counts a step program appended to its tokens: onto
-        the worker's counter the model names (``moe_picks`` unless it
-        says otherwise) and the step's fetch span."""
-        counts = {
-            k: int(n) for k, n in zip(self.model.moe_pick_kinds, host_tail)
-        }
-        self.telemetry.record_moe_picks(
-            counts, getattr(self.model, "moe_pick_counter", "moe_picks")
-        )
-        for kind, n in counts.items():
-            span.set_attribute(f"moe_picks_{kind}", n)
-
-    def _ssm_chunk_tokens(self, num_new: list[int], t_pad: int) -> dict:
-        """A single step's tokens as its chunked state-space scans walk
-        them: the dispatch span's ``ssm_tokens_live`` (the step's own)
-        and ``ssm_tokens_tiled`` (the token slots the scans' matrix
-        products run over: ``model.ssm_tokens_walked`` of the chunks),
-        and the counter's two kinds.  A step in which every sequence
-        has one token runs the one-token update and counts nothing."""
-        t_real = sum(num_new)
-        tiled = self.model.ssm_tokens_walked(num_new, t_pad)
-        self.telemetry.record_ssm_chunk_tokens(
-            {"live": t_real, "tiled": tiled}
-        )
-        return dict(ssm_tokens_live=t_real, ssm_tokens_tiled=tiled)
-
-    def _count_state_rows(self, starts: list[int]) -> dict:
-        """A step's sequences by whether their state is read: the
-        dispatch span's ``state_rows`` (all of them: every one's row is
-        read and rewritten) and ``state_fresh`` (those at position 0,
-        which read nothing of the row), and the counter's two kinds."""
-        fresh = sum(1 for lo in starts if lo == 0)
-        self.telemetry.record_seq_state_rows(
-            {"fresh": fresh, "carried": len(starts) - fresh}
-        )
-        return dict(state_rows=len(starts), state_fresh=fresh)
+    def _record_fetched(self, span, host_tail) -> None:
+        """The counts a step program appended to its tokens, as the
+        model that counted them reads them: onto the step's fetch span
+        and, unread, onto the worker's counters."""
+        attrs, increments = self.model.fetched_counts(host_tail)
+        for counter, by_label in increments.items():
+            self.telemetry.record(counter, by_label)
+        for name, n in attrs.items():
+            span.set_attribute(name, n)
 
     @partial(
         jax.jit,
@@ -2149,7 +2081,11 @@ class ModelRunner:
             statics,
             (self.params, self.kv_caches, packed),
             so,
-            rows=t_pad,
+            **self._step_counts(
+                "spec", num_new, chunk_starts[:s_real].tolist(),
+                rows_pad=t_pad, seqs_pad=s_pad, max_q_pad=kp1,
+                pages_pad=pages_pad,
+            ),
             seqs=s_real,
             tokens=t_real,
             kv_tokens=kv_tokens,
@@ -2318,10 +2254,10 @@ class ModelRunner:
             seqs=s_real, tokens=int(n_active.sum()),
             kv_tokens=kv_tokens_scanned,
         )
-        if getattr(self._attn_fn, "from_heads", None):
-            counts.update(self._mla_kv_rows(base_lens[:s_real], pages_pad))
-        if self._state_layout is not None:
-            counts.update(self._count_state_rows(starts))
+        counts.update(self._step_counts(
+            "decode", [num_new[r] for r in order], starts, rows_pad=s_pad,
+            seqs_pad=s_pad, max_q_pad=1, pages_pad=pages_pad,
+        ))
         prep.end()
         t_step0 = time.perf_counter()
         toks, self._carry, self.kv_caches = self._run_program(
@@ -2333,7 +2269,6 @@ class ModelRunner:
             statics,
             (self.params, self.kv_caches, packed, carry),
             so,
-            rows=s_pad,
             **counts,
         )
         # Each sequence's LAST VALID token stays on device, in its carry
@@ -2350,7 +2285,7 @@ class ModelRunner:
             ) as fetch:
                 host_toks = np.asarray(jax.device_get(toks))  # [K, s_pad]
                 if host_toks.shape[0] > k_steps:
-                    self._record_moe_picks(fetch, host_toks[k_steps])
+                    self._record_fetched(fetch, host_toks[k_steps])
             self._num_fetched += 1
             # K micro-steps = K weights+KV HBM passes.  Wall time spans
             # dispatch→resolve (includes any pipeline overlap — an

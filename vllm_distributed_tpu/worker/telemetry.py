@@ -6,42 +6,26 @@ compile, and nothing surfaced it in steady state — only offline benches
 noticed.  ``DeviceTelemetry`` is the worker-local ledger the model
 runner writes:
 
-- **compiles**: every first execution of a distinct (kind, static
-  shape) jit key is counted and timed, tagged with the triggering
-  bucket kind (``prefill``/``decode``/``spec``) — a recompile storm
-  shows up as a climbing ``vllm:xla_compiles_total`` instead of
-  mystery latency spikes;
+- **compile events**: every first execution of a distinct (kind,
+  static shape) jit key, timed, tagged with the triggering bucket kind
+  (``prefill``/``decode``/``spec``): a recompile storm shows up as a
+  climbing ``vllm:xla_compiles_total`` instead of mystery latency spikes;
 - **HBM**: live/limit bytes from the runtime's ``memory_stats`` so
   memory creep is a gauge, not an OOM post-mortem;
 - **step roofline**: estimated bytes-touched / step-time over the
   device's peak HBM bandwidth (host wall time, not a device
   reading);
-- **fallbacks**: every time the worker carried on without something it
-  was asked to use (an unusable or unexportable AOT artifact, a compile
-  cache directory it could not create), by kind — semantically safe,
-  but a run that claims a warm start must show zero;
-- **grouped MoE steps**: step programs dispatched whose int8 experts
-  were traced with the grouped kernel (models/routed_experts.select_moe_impl),
-  so how often the routed path engages is a counter, not an inference;
-- **router picks**: of a model that routes over more experts than
-  this chip holds, the picks that went to identity (zero-compute)
-  experts, to experts held here and to experts held elsewhere, counted
-  on the device and fetched with the step's tokens;
-- **expert picks**: of a model that holds every expert, all picks and
-  each routed layer's busiest expert's picks (what a grouped kernel
-  pads the other experts' groups to), counted the same way;
-- **sequence-state rows**: of a model that keeps state a sequence
-  beside its pages, the sequences each dispatched step computed, by
-  whether they started at position 0 (``fresh``: nothing of the row is
-  read) or later (``carried``);
-- **persistent cache**: JAX's own compilation-cache events for this
-  process (lookups, hits, and entries written after a miss), so a warm
-  restart is judged by what the cache reports, not by a time threshold;
-- **backend compiles**: JAX's own backend-compile event for this
-  process, counted and timed by whether XLA compiled the module or the
-  persistent cache held it — what ``compiles`` above cannot see: a jit
-  that re-specialises under an unchanged (kind, statics) key.  With
-  tracing on each is an ``xla.compile`` span too.
+- **counters**: one store of totals by label behind one ``record``,
+  a key a row of ``metrics.WORKER_COUNTERS`` (which says what each
+  counts, and under what name it reaches ``/metrics``).  What a step
+  counts is said by its model and its attention function
+  (``step_counts``); the runner passes their increments on unread.
+  Three rows are JAX's own events for this process (the persistent
+  cache's lookups, hits and writes; backend compiles and their seconds
+  by whether XLA compiled the module or the cache held it: what the
+  compile events above cannot see, a jit that re-specialises under an
+  unchanged key).  With tracing on each backend compile is an
+  ``xla.compile`` span too.
 
 The driver pulls snapshots over the existing ``collective_rpc`` path
 (``get_device_telemetry``) on ``/metrics`` scrapes; compile events
@@ -56,6 +40,7 @@ import time
 from collections import deque
 
 from vllm_distributed_tpu import tracing
+from vllm_distributed_tpu.metrics import WORKER_COUNTERS
 
 # Peak HBM bandwidth (bytes/s) by device-kind prefix, for the roofline
 # gauge.  Rough public numbers; an unknown kind reports frac 0.0 (the
@@ -190,77 +175,36 @@ class DeviceTelemetry:
         # (seq, kind, seconds, shape_key) — bounded; the cumulative
         # totals below survive ring eviction.
         self.compile_events: deque[tuple] = deque(maxlen=max_events)
-        self.compiles: dict[str, int] = {}
         self.compile_seconds_total = 0.0
         self.last_step_seconds = 0.0
         self.last_step_bytes = 0
         self.roofline_frac = 0.0
-        self.fallbacks: dict[str, int] = {}
-        self.moe_grouped_steps = 0
-        self.moe_picks: dict[str, int] = {}
-        self.moe_expert_picks: dict[str, int] = {}
-        self.seq_state_rows: dict[str, int] = {}
-        # bytes of sequence state allocated, by kind (set once, when
-        # the runner allocates its KV pool)
-        self.seq_state_bytes: dict[str, int] = {}
-        self.ssm_chunk_tokens: dict[str, int] = {}
-        self.mla_chunk_tokens: dict[str, int] = {}
-        self.mla_kv_rows: dict[str, int] = {}
+        # totals by label, a key a row of WORKER_COUNTERS (the three
+        # rows of the process's own events are read at snapshot time)
+        self.counters: dict[str, dict[str, int]] = {
+            key: {} for key in WORKER_COUNTERS
+        }
         _install_cache_listener()
 
-    def record_fallback(self, kind: str) -> None:
+    def record(self, counter: str, counts: dict[str, int]) -> None:
+        """``counts`` by label onto ``counter``, a row of
+        ``WORKER_COUNTERS`` (anything else is a KeyError: no series is
+        made by a typo); a gauge row is set to them."""
+        gauge = WORKER_COUNTERS[counter].gauge
         with self._lock:
-            self.fallbacks[kind] = self.fallbacks.get(kind, 0) + 1
-
-    def record_moe_grouped_step(self) -> None:
-        """One dispatched step program whose int8 experts run the
-        grouped (routed) kernel; a fused decode scan never is one."""
-        with self._lock:
-            self.moe_grouped_steps += 1
-
-    def record_moe_picks(
-        self, counts: dict[str, int], counter: str = "moe_picks"
-    ) -> None:
-        """Router picks of one fetched step by kind, as the step program
-        counted them on the device: ``moe_picks`` (zero | held | absent,
-        which add up to all picks) or ``moe_expert_picks`` (all |
-        busiest, which do not)."""
-        self._add(getattr(self, counter), counts)
-
-    def record_seq_state_rows(self, counts: dict[str, int]) -> None:
-        """Sequences of one dispatched step whose state rows it reads
-        and rewrites, by kind (fresh | carried)."""
-        self._add(self.seq_state_rows, counts)
-
-    def record_ssm_chunk_tokens(self, counts: dict[str, int]) -> None:
-        """Tokens of one dispatched single step of a model with a
-        chunked state-space scan: live (the step's own tokens) | tiled
-        (chunk size × the chunk tiles its scans walked)."""
-        self._add(self.ssm_chunk_tokens, counts)
-
-    def record_mla_chunk_tokens(self, counts: dict[str, int]) -> None:
-        """Tokens of one dispatched chunked step's multi-token chunks by
-        the form their latent attention took (materialised: a fresh
-        chunk | absorbed: a chunk with a cached prefix)."""
-        self._add(self.mla_chunk_tokens, counts)
-
-    def record_mla_kv_rows(self, counts: dict[str, int]) -> None:
-        """Cached rows of one dispatched fused scan, a sublayer call's
-        worth: live (the sequences' pool-resident tokens) | fetched
-        (what the latent kernel's DMAs move for them)."""
-        self._add(self.mla_kv_rows, counts)
-
-    def _add(self, totals: dict[str, int], counts: dict[str, int]) -> None:
-        with self._lock:
+            totals = self.counters[counter]
             for label, n in counts.items():
-                totals[label] = totals.get(label, 0) + n
+                totals[label] = n if gauge else totals.get(label, 0) + n
+
+    def record_fallback(self, kind: str) -> None:
+        self.record("fallbacks", {kind: 1})
 
     def record_compile(self, kind: str, seconds: float, key: str) -> None:
         with self._lock:
             self._seq += 1
             self.compile_events.append((self._seq, kind, seconds, key))
-            self.compiles[kind] = self.compiles.get(kind, 0) + 1
             self.compile_seconds_total += seconds
+        self.record("compiles", {kind: 1})
 
     def record_step(
         self, seconds: float, est_bytes: int, peak_bw: float
@@ -291,23 +235,14 @@ class DeviceTelemetry:
         backend_compiles, backend_compile_seconds = backend_compile_totals()
         with self._lock:
             return {
+                **{k: dict(v) for k, v in self.counters.items()},
                 "compile_events": [list(e) for e in self.compile_events],
-                "compiles": dict(self.compiles),
                 "compile_seconds_total": self.compile_seconds_total,
                 "hbm_live_bytes": live,
                 "hbm_limit_bytes": limit,
                 "last_step_seconds": self.last_step_seconds,
                 "last_step_bytes": self.last_step_bytes,
                 "roofline_frac": self.roofline_frac,
-                "fallbacks": dict(self.fallbacks),
-                "moe_grouped_steps": self.moe_grouped_steps,
-                "moe_picks": dict(self.moe_picks),
-                "moe_expert_picks": dict(self.moe_expert_picks),
-                "seq_state_rows": dict(self.seq_state_rows),
-                "seq_state_bytes": dict(self.seq_state_bytes),
-                "ssm_chunk_tokens": dict(self.ssm_chunk_tokens),
-                "mla_chunk_tokens": dict(self.mla_chunk_tokens),
-                "mla_kv_rows": dict(self.mla_kv_rows),
                 "persistent_cache": persistent_cache_counts(),
                 "backend_compiles": backend_compiles,
                 "backend_compile_seconds": backend_compile_seconds,
